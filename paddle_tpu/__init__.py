@@ -10,26 +10,6 @@ from __future__ import annotations
 
 __version__ = "0.1.0"
 
-# ---- jax compat: expose jax.shard_map on builds that only ship the
-# experimental module (the API this codebase targets promotes it to a
-# top-level name with check_rep renamed check_vma).  Installed before
-# any submodule import so every `from jax import shard_map` /
-# `jax.shard_map(...)` site sees one surface.
-import jax as _jax
-
-if not hasattr(_jax, "shard_map"):
-    import functools as _functools
-
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    @_functools.wraps(_exp_shard_map)
-    def _shard_map_compat(f, *args, check_vma=None, **kwargs):
-        if check_vma is not None:
-            kwargs.setdefault("check_rep", check_vma)
-        return _exp_shard_map(f, *args, **kwargs)
-
-    _jax.shard_map = _shard_map_compat
-
 from . import core
 from .core import (  # noqa: F401
     CPUPlace,

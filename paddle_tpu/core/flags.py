@@ -77,7 +77,6 @@ define_flag("benchmark", False, "synchronize and time each op")
 define_flag("eager_op_jit", False, "jit-cache eager per-op execution")
 define_flag("use_bf16_matmul", True, "prefer bf16 inputs on MXU matmuls")
 define_flag("seed", 0, "global random seed (0 = nondeterministic)")
-define_flag("tpu_interpret_pallas", False, "run pallas kernels in interpret mode")
 define_flag("log_level", 0, "framework VLOG-style verbosity")
 
 # --- allocator knobs (reference: FLAGS_fraction_of_gpu_memory_to_use +

@@ -77,8 +77,7 @@ class Tensor:
         d = self.data.devices() if hasattr(self.data, "devices") else None
         if d:
             dev = next(iter(d))
-            kind = "tpu" if dev.platform not in ("cpu", "gpu", "cuda") else dev.platform
-            return Place(kind, dev.id)
+            return Place(dev.platform, dev.id)
         return _current_place()
 
     @property

@@ -21,7 +21,7 @@ def vma_of(*refs):
     """Sorted union of the refs' varying axes."""
     union = set()
     for r in refs:
-        union |= set(getattr(jax.typeof(r), "vma", ()) or ())
+        union |= jax.typeof(r).vma
     return tuple(sorted(union))
 
 

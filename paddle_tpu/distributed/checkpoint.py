@@ -515,6 +515,6 @@ def load_engine_state(path, engine):
     like = {"params": params_t, "opt": canon_t}
     tree, manifest = load_sharded(path, like_tree=like)
     slots = engine.opt_from_canonical()(tree["opt"])
-    opt_state = {"step": jnp.asarray(manifest["step"] or 0, jnp.int32),
+    opt_state = {"step": engine.step_counter(manifest["step"] or 0),
                  "slots": slots}
     return tree["params"], opt_state

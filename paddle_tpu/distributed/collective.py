@@ -256,8 +256,7 @@ def scatter(tensor, tensor_list=None, src=0, group=None, sync_op=True):
         stacked = jnp.stack([_unwrap(t) for t in x])
         out = stacked[idx]
     else:
-        n = jax.lax.axis_size(axis) if hasattr(jax.lax, "axis_size") else None
-        out = jnp.split(x, n)[idx]
+        out = jnp.split(x, jax.lax.axis_size(axis))[idx]
     if isinstance(tensor, Tensor):
         tensor.data = out
         return tensor
@@ -332,7 +331,7 @@ def barrier(group=None):
     axis = _axis(group)
     if axis is None:
         # eager: drain device queue (closest analog of a stream sync barrier)
-        jax.effects_barrier() if hasattr(jax, "effects_barrier") else None
+        jax.effects_barrier()
         return
     jax.lax.psum(jnp.zeros((), jnp.float32), axis)
 

@@ -453,7 +453,15 @@ class HybridEngine:
         mapped = shard_map(init_local, mesh=self.mesh, in_specs=(specs,),
                            out_specs=slots_specs, check_vma=True)
         state = jax.jit(mapped)(params)
-        return {"step": jnp.zeros((), jnp.int32), "slots": state}
+        return {"step": self.step_counter(0), "slots": state}
+
+    def step_counter(self, value):
+        """The optimizer's step count, placed as the jitted step returns
+        it (replicated over the mesh).  A plain host scalar here is a
+        different jit-cache key from the step's own output, so the SECOND
+        train step would retrace and recompile the whole program."""
+        return jax.device_put(jnp.asarray(value, jnp.int32),
+                              NamedSharding(self.mesh, P()))
 
     # ------------------------------------------------ opt-state canonical
     # The optimizer's [pp?, mp/ep?, zr, chunk] flat-chunk layout is

@@ -7,8 +7,10 @@ env contract) + launch/job/container.py (per-rank ``workerlog.N`` files).
 TPU-native mapping: the reference forks one process per GPU and wires
 NCCL ids through a TCPStore; here each process is one jax *host* whose
 rendezvous is the jax coordination service (`jax.distributed.initialize`).
-On real multi-host TPU pods one process per host is the norm; for tests
-the same contract runs N CPU processes with gloo collectives.
+On TPU hosts one process per host is the rule — it drives all of the host's
+chips through the mesh, and ``--backend tpu`` refuses ``--nproc_per_node``
+above 1 (a chip belongs to one process); for tests the same contract runs N
+CPU processes with gloo collectives.
 
 Env contract written per rank (reference names, collective.py:89-91):
   PADDLE_TRAINER_ID        global rank
@@ -16,7 +18,7 @@ Env contract written per rank (reference names, collective.py:89-91):
   PADDLE_LOCAL_RANK        rank within this node
   PADDLE_MASTER            coordinator host:port
   PADDLE_TRAINER_ENDPOINTS comma list of worker endpoints
-  PADDLE_DIST_BACKEND      'tpu' (default) or 'gloo' (CPU testing)
+  PADDLE_DIST_BACKEND      'tpu' or 'gloo' (CPU testing); unset = jax's default
 """
 from .main import launch, main
 

@@ -24,7 +24,8 @@ def _parse(argv):
         description="Launch a distributed training script, one process per "
                     "host/worker (reference: paddle.distributed.launch)")
     p.add_argument("--nproc_per_node", type=int, default=1,
-                   help="worker processes to fork on this node")
+                   help="worker processes to fork on this node (on a TPU "
+                        "host: 1 — one process drives all its chips)")
     p.add_argument("--nnodes", type=int, default=1)
     p.add_argument("--node_rank", type=int, default=0)
     p.add_argument("--master", default=None,
@@ -51,6 +52,17 @@ def launch(argv=None):
     Controller.watch() policy (controllers/controller.py:67)."""
     args = _parse(argv if argv is not None else sys.argv[1:])
     nproc = args.nproc_per_node
+    if args.backend == "tpu" and nproc > 1:
+        # a chip belongs to one process: every child would open every
+        # chip of the host, and all but the first fail there ("Unable to
+        # initialize backend 'tpu': ABORTED ... libtpu multi-process
+        # lockfile", seen on a v5e host) or hang
+        raise SystemExit(
+            "--backend tpu runs ONE process per host, which drives all of "
+            f"that host's chips through the mesh; --nproc_per_node {nproc} "
+            "would start processes that each try to take every chip. Use "
+            "--nproc_per_node 1 (and --nnodes N for N hosts), or "
+            "--backend gloo for N CPU processes.")
     world = nproc * args.nnodes
     if args.nnodes > 1 and not args.master:
         raise SystemExit(

@@ -34,11 +34,7 @@ __all__ = ["ColumnParallelLinear", "RowParallelLinear",
 def _mp_info(mp_axis):
     """(size, index) of the mp axis inside a shard_map, else (1, 0)."""
     try:
-        idx = jax.lax.axis_index(mp_axis)
-        size = jax.lax.axis_size(mp_axis) if hasattr(jax.lax, "axis_size") else None
-        if size is None:
-            size = jax.lax.psum(jnp.ones((), jnp.int32), mp_axis)
-        return size, idx
+        return jax.lax.axis_size(mp_axis), jax.lax.axis_index(mp_axis)
     except (NameError, KeyError, ValueError):
         return 1, 0
 

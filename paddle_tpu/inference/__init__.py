@@ -50,7 +50,7 @@ class Config:
         # jit.save artifact prefix (…pdmodel/.pdiparams.npz live beside it)
         self.prog_file = prog_file
         self.params_file = params_file
-        self.device = "tpu"
+        self.device = None        # None = jax's default backend
         self.precision = PrecisionType.Float32
         self.model_layer = None
         self.quant_scales = None
@@ -59,14 +59,11 @@ class Config:
 
     # ---- device selection (Config::EnableUseGpu analog) ----
     def enable_tpu(self):
+        """Require a TPU: create_predictor raises when jax's default
+        backend is anything else, instead of serving on the CPU.  (The
+        CPU is selected outside the program, with JAX_PLATFORMS=cpu.)"""
         self.device = "tpu"
         return self
-
-    def disable_gpu(self):
-        self.device = "cpu"
-        return self
-
-    enable_use_cpu = disable_gpu
 
     # ---- precision ----
     def set_precision(self, precision):
@@ -330,6 +327,11 @@ class GenerationPredictor:
 def create_predictor(config: Config):
     """paddle_infer.create_predictor parity; generation-enabled configs
     build the serving-engine predictor instead."""
+    if config.device is not None and jax.default_backend() != config.device:
+        raise RuntimeError(
+            f"Config asks for device {config.device!r} but jax's default "
+            f"backend is {jax.default_backend()!r} "
+            f"(devices: {jax.devices()})")
     if config.generation is not None:
         return GenerationPredictor(config)
     return Predictor(config)

@@ -8,4 +8,4 @@ flash attention (+ring variant for sequence parallelism) and the
 paged-attention decode kernel behind the serving engine's KV cache.
 """
 from .flash_attention import flash_attention, flash_attention_available  # noqa: F401
-from .paged_attention import paged_attention, paged_attention_available  # noqa: F401
+from .paged_attention import paged_attention, ragged_paged_attention  # noqa: F401

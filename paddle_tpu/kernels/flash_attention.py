@@ -17,35 +17,20 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from ..core.flags import flag
-
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _PALLAS_OK = True
-except Exception:  # pragma: no cover
-    _PALLAS_OK = False
+from . import dispatch
 
 __all__ = ["flash_attention", "flash_attention_available"]
 
 _NEG_INF = -1e30
 
 
-def _on_tpu():
-    try:
-        return jax.devices()[0].platform not in ("cpu", "gpu", "cuda")
-    except Exception:
-        return False
-
-
-def _interpret():
-    return (not _on_tpu()) or flag("tpu_interpret_pallas")
-
-
 def flash_attention_available(q, k, v, mask, causal=False):
-    if not _PALLAS_OK or mask is not None:
+    """Whether the kernel covers these operands (shape and mask only —
+    the platform never makes it unavailable)."""
+    if mask is not None:
         return False
     if q.ndim != 4 or q.shape != k.shape or q.shape != v.shape:
         return False
@@ -114,17 +99,11 @@ def _sds(shape, dtype, *like):
     is elementwise in the device dimension, so outputs vary over every mesh
     axis any input does (pallas does not validate this itself — an
     under-declared vma would silently drop AD's psums downstream)."""
-    typeof = getattr(jax, "typeof", None)
-    if typeof is None:            # older jax: no vma tracking, plain struct
-        return jax.ShapeDtypeStruct(shape, dtype)
-    vmas = [getattr(typeof(x), "vma", None) for x in like]
-    if all(v is None for v in vmas):
-        return jax.ShapeDtypeStruct(shape, dtype)
-    vma = frozenset().union(*[v for v in vmas if v is not None])
+    vma = frozenset().union(*(jax.typeof(x).vma for x in like))
     return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
-def _flash_fwd(q, k, v, scale, causal, block_q, block_kv):
+def _flash_fwd(q, k, v, scale, causal, block_q, block_kv, interpret):
     B, H, S, D = q.shape
     bh = B * H
     qf = q.reshape(bh, S, D)
@@ -157,7 +136,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_kv):
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=interpret,
     )(qf, kf, vf)
     return out.reshape(B, H, S, D), lse[..., 0].reshape(B, H, S)
 
@@ -254,20 +233,24 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
 
-def _flash_bwd(scale, causal, block_q, block_kv, res, g):
-    q, k, v, out, lse = res
-    B, H, S, D = q.shape
+def _flash_bwd(q, k, v, do, lse, delta, scale, causal, block_q, block_kv,
+               interpret, out_dtype):
+    """The two backward sweeps for q/do [B, H, Sq, D] against k/v
+    [B, H, Skv, D] with per-row ``lse``/``delta`` [B, H, Sq] → (dq, dk,
+    dv) in ``out_dtype``.  ``lse``/``delta`` are operands so ring
+    attention can pass the ring-global values: p = exp(s - lse) is then
+    the global softmax weight of this (Q-shard, KV-block) pair."""
+    B, H, Sq, D = q.shape
+    Skv = k.shape[2]
     bh = B * H
-    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    qf, dof = q.reshape(bh, Sq, D), do.reshape(bh, Sq, D)
+    kf, vf = k.reshape(bh, Skv, D), v.reshape(bh, Skv, D)
+    lsef = lse.reshape(bh, Sq, 1)
+    deltaf = delta.reshape(bh, Sq, 1)
+    num_q = Sq // block_q
+    num_kv = Skv // block_kv
 
-    qf, kf, vf = (t.reshape(bh, S, D) for t in (q, k, v))
-    dof = g.reshape(bh, S, D)
-    lsef = lse.reshape(bh, S, 1)
-    deltaf = delta.reshape(bh, S, 1)
-    num_q = S // block_q
-    num_kv = S // block_kv
-
-    dkdv = pl.pallas_call(
+    dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkdv_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_kv=block_kv, num_q=num_q),
         grid=(bh, num_kv, num_q),
@@ -284,16 +267,15 @@ def _flash_bwd(scale, causal, block_q, block_kv, res, g):
             pl.BlockSpec((1, block_kv, D), lambda b, j, i: (b, j, 0)),
         ],
         out_shape=[
-            _sds((bh, S, D), q.dtype, qf, kf, vf),
-            _sds((bh, S, D), q.dtype, qf, kf, vf),
+            _sds((bh, Skv, D), out_dtype, qf, kf, vf, dof),
+            _sds((bh, Skv, D), out_dtype, qf, kf, vf, dof),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_kv, D), jnp.float32),
             pltpu.VMEM((block_kv, D), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=interpret,
     )(qf, kf, vf, dof, lsef, deltaf)
-    dk, dv = dkdv
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
@@ -308,44 +290,66 @@ def _flash_bwd(scale, causal, block_q, block_kv, res, g):
             pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
         ],
         out_specs=pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-        out_shape=_sds((bh, S, D), q.dtype, qf, kf, vf),
+        out_shape=_sds((bh, Sq, D), out_dtype, qf, kf, vf, dof),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        interpret=_interpret(),
+        interpret=interpret,
     )(qf, kf, vf, dof, lsef, deltaf)
 
-    return (dq.reshape(B, H, S, D), dk.reshape(B, H, S, D),
-            dv.reshape(B, H, S, D))
+    return (dq.reshape(B, H, Sq, D), dk.reshape(B, H, Skv, D),
+            dv.reshape(B, H, Skv, D))
 
 
 # -------------------------------------------------------------- public API
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, scale, causal, block_q, block_kv):
-    out, _ = _flash_fwd(q, k, v, scale, causal, block_q, block_kv)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash(q, k, v, scale, causal, block_q, block_kv, interpret):
+    out, _ = _flash_fwd(q, k, v, scale, causal, block_q, block_kv, interpret)
     return out
 
 
-def _flash_fwd_rule(q, k, v, scale, causal, block_q, block_kv):
-    out, lse = _flash_fwd(q, k, v, scale, causal, block_q, block_kv)
+def _flash_fwd_rule(q, k, v, scale, causal, block_q, block_kv, interpret):
+    out, lse = _flash_fwd(q, k, v, scale, causal, block_q, block_kv,
+                          interpret)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd_rule(scale, causal, block_q, block_kv, res, g):
-    return _flash_bwd(scale, causal, block_q, block_kv, res, g)
+def _flash_bwd_rule(scale, causal, block_q, block_kv, interpret, res, g):
+    q, k, v, out, lse = res
+    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    return _flash_bwd(q, k, v, g, lse, delta, scale, causal, block_q,
+                      block_kv, interpret, q.dtype)
 
 
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
+def _fit_blocks(S, block_q, block_kv):
+    """Largest 128-multiple divisors of S (itself a 128-multiple) under
+    the requested block sizes and the 1024 cap."""
+    def fit(b):
+        b = min(b, S, 1024)
+        b -= b % 128       # align to the TPU tile (terminates the search)
+        while b > 128 and S % b:
+            b -= 128
+        return max(b, 128)
+
+    return fit(block_q), fit(block_kv)
+
+
 def flash_attention(q, k, v, causal=False, scale=None,
-                    block_q=512, block_kv=1024):
+                    block_q=512, block_kv=1024, path=None):
     """q/k/v: [B, H, S, D] → [B, H, S, D].
 
-    Default blocks (512, 1024) measured fastest on v5e at S=2048-16384
-    (1.4x over XLA's fused attention at 2k, ~60x at 8k where the naive
-    path spills the [S,S] scores to HBM).
+    ``path`` is ``dispatch.MOSAIC`` or ``dispatch.INTERPRET``; ``None``
+    takes Mosaic on a TPU and the interpreter elsewhere.  The default
+    blocks (512, 1024) were picked on a v5e before PR 1; their speed
+    against XLA's attention is not measured on the current code.
     """
+    # no REFERENCE here: callers pick ops.attention._naive_attention
+    path = dispatch.resolve_path(
+        path, off_tpu=dispatch.INTERPRET,
+        allowed=(dispatch.MOSAIC, dispatch.INTERPRET))
     S = q.shape[2]
     if S % 128 != 0:
         # TPU tiling needs S in 128-multiples.  Causal: zero-pad the tail
@@ -361,18 +365,11 @@ def flash_attention(q, k, v, causal=False, scale=None,
         zpad = [(0, 0), (0, 0), (0, pad), (0, 0)]
         out = flash_attention(jnp.pad(q, zpad), jnp.pad(k, zpad),
                               jnp.pad(v, zpad), causal=causal, scale=scale,
-                              block_q=block_q, block_kv=block_kv)
+                              block_q=block_q, block_kv=block_kv, path=path)
         return out[:, :, :S]
 
-    def fit(b):
-        b = min(b, S, 1024)
-        b -= b % 128       # align to the TPU tile (terminates the search)
-        while b > 128 and S % b:  # largest 128-multiple divisor under cap
-            b -= 128
-        return max(b, 128)
-
-    block_q = fit(block_q)
-    block_kv = fit(block_kv)
+    block_q, block_kv = _fit_blocks(S, block_q, block_kv)
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    return _flash(q, k, v, scale, causal, block_q, block_kv)
+    return _flash(q, k, v, scale, causal, block_q, block_kv,
+                  path == dispatch.INTERPRET)

@@ -18,7 +18,9 @@ context_lens[b] - 1``.  Query token ``t`` attends causally to every kv
 position ``<= context_lens[b] - query_lens[b] + t``.  ``query_lens[b] ==
 0`` marks an idle row (output zeros).
 
-Two implementations with one contract:
+Two implementations with one contract (``path=`` picks one; the default
+comes from ``kernels.dispatch``: the kernel on a TPU, the reference
+elsewhere):
 
 - ``_ragged_attention_ref`` — pure-jnp gather + fp32 softmax.  Serves CPU
   tests and is the numerics oracle.
@@ -40,8 +42,8 @@ Returns [B, Q, H, hd] in q.dtype; padded query slots and idle rows
 return zeros.
 
 ``paged_attention`` (the original decode-only entry: one query token per
-row, ``seq_lens`` masking) is kept as the Q == 1 degenerate case of the
-same kernel.
+row, ``seq_lens`` masking) is the Q == 1 degenerate case of the same
+entry.
 """
 from __future__ import annotations
 
@@ -50,32 +52,14 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from ..core.flags import flag
+from . import dispatch
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _PALLAS_OK = True
-except Exception:  # pragma: no cover
-    _PALLAS_OK = False
-
-__all__ = ["paged_attention", "ragged_paged_attention",
-           "paged_attention_available"]
+__all__ = ["paged_attention", "ragged_paged_attention"]
 
 _NEG_INF = -1e30
-
-
-def _on_tpu():
-    try:
-        return jax.devices()[0].platform not in ("cpu", "gpu", "cuda")
-    except Exception:
-        return False
-
-
-def paged_attention_available():
-    return _PALLAS_OK
 
 
 # ---------------------------------------------------------------- reference
@@ -208,56 +192,34 @@ def _ragged_attention_kernel(q, k_pages, v_pages, page_tables, query_lens,
 
 
 def ragged_paged_attention(q, k_pages, v_pages, page_tables, query_lens,
-                           context_lens, scale=None):
+                           context_lens, scale=None, path=None):
     """Fused prefill+decode attention over a paged KV cache (see module
-    docstring for layouts).  Routes to the Pallas kernel on TPU; the jnp
-    gather path elsewhere (identical contract, fp32 softmax in both)."""
+    docstring for layouts).  ``path`` is one of ``dispatch.MOSAIC`` /
+    ``INTERPRET`` / ``REFERENCE``; ``None`` takes the Mosaic kernel on a
+    TPU and the jnp gather reference elsewhere (identical contract, fp32
+    softmax in both)."""
+    path = dispatch.resolve_path(path, off_tpu=dispatch.REFERENCE)
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     page_tables = page_tables.astype(jnp.int32)
     query_lens = query_lens.astype(jnp.int32)
     context_lens = context_lens.astype(jnp.int32)
-    if _PALLAS_OK and (_on_tpu() or flag("tpu_interpret_pallas")):
-        return _ragged_attention_kernel(q, k_pages, v_pages, page_tables,
-                                        query_lens, context_lens, scale,
-                                        interpret=not _on_tpu())
-    return _ragged_attention_ref(q, k_pages, v_pages, page_tables,
-                                 query_lens, context_lens, scale)
+    if path == dispatch.REFERENCE:
+        return _ragged_attention_ref(q, k_pages, v_pages, page_tables,
+                                     query_lens, context_lens, scale)
+    return _ragged_attention_kernel(q, k_pages, v_pages, page_tables,
+                                    query_lens, context_lens, scale,
+                                    interpret=(path == dispatch.INTERPRET))
 
 
-# ------------------------------------------- decode (Q == 1) degenerate
-
-
-def _paged_attention_ref(q, k_pages, v_pages, page_tables, seq_lens, scale):
-    """Decode oracle: one query per row — the q_len == 1 row of the
-    ragged reference (seq_len 0 marks an inactive slot)."""
-    seq_lens = seq_lens.astype(jnp.int32)
-    qlens = (seq_lens > 0).astype(jnp.int32)
-    return _ragged_attention_ref(q[:, None], k_pages, v_pages, page_tables,
-                                 qlens, seq_lens, scale)[:, 0]
-
-
-def _paged_attention_kernel(q, k_pages, v_pages, page_tables, seq_lens,
-                            scale, interpret):
-    seq_lens = seq_lens.astype(jnp.int32)
-    qlens = (seq_lens > 0).astype(jnp.int32)
-    return _ragged_attention_kernel(q[:, None], k_pages, v_pages,
-                                    page_tables, qlens, seq_lens, scale,
-                                    interpret)[:, 0]
-
-
-def paged_attention(q, k_pages, v_pages, page_tables, seq_lens, scale=None):
+def paged_attention(q, k_pages, v_pages, page_tables, seq_lens, scale=None,
+                    path=None):
     """Single-token decode attention over a paged KV cache: q [B, H, hd],
     one query token per sequence attending over its first ``seq_lens``
-    kv tokens — the query_len == 1 degenerate row of the ragged kernel,
-    kept as a stable API for decode-only callers and tests."""
-    if scale is None:
-        scale = 1.0 / math.sqrt(q.shape[-1])
-    page_tables = page_tables.astype(jnp.int32)
+    kv tokens (0 marks an inactive slot) — the query_len == 1 degenerate
+    row of ``ragged_paged_attention``, kept as a stable API for
+    decode-only callers and tests."""
     seq_lens = seq_lens.astype(jnp.int32)
-    if _PALLAS_OK and (_on_tpu() or flag("tpu_interpret_pallas")):
-        return _paged_attention_kernel(q, k_pages, v_pages, page_tables,
-                                       seq_lens, scale,
-                                       interpret=not _on_tpu())
-    return _paged_attention_ref(q, k_pages, v_pages, page_tables, seq_lens,
-                                scale)
+    return ragged_paged_attention(
+        q[:, None], k_pages, v_pages, page_tables,
+        (seq_lens > 0).astype(jnp.int32), seq_lens, scale, path)[:, 0]

@@ -28,16 +28,9 @@ import math
 import jax
 import jax.numpy as jnp
 
-from .flash_attention import (_bwd_dkdv_kernel, _bwd_dq_kernel, _flash_fwd,
-                              _interpret, _sds)
-
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _PALLAS_OK = True
-except Exception:  # pragma: no cover
-    _PALLAS_OK = False
+from ..core.vma import lifter as _vma_lift  # branch outputs must share vma
+from . import dispatch
+from .flash_attention import _fit_blocks, _flash_bwd, _flash_fwd
 
 __all__ = ["ring_attention"]
 
@@ -49,8 +42,7 @@ def _causal_mask(S):
 
 
 def _pair_fwd_ref(q, k, v, scale, causal):
-    """jnp reference of one pair's flash forward (used in interpret mode —
-    pallas's HLO interpreter cannot run under shard_map(check_vma) yet)."""
+    """jnp reference of one pair's flash forward → (out, lse)."""
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     if causal:
@@ -79,101 +71,32 @@ def _pair_bwd_ref(q, k, v, do, lse, delta, scale, causal):
     return dq, dk, dv
 
 
-def _pair_fwd(q, k, v, scale, causal, block_q, block_kv):
+def _pair_fwd(q, k, v, scale, causal, block_q, block_kv, mosaic):
     """One (Q-shard, KV-block) flash forward → (out, lse)."""
-    if _interpret():
-        return _pair_fwd_ref(q, k, v, scale, causal)
-    return _flash_fwd(q, k, v, scale, causal, block_q, block_kv)
+    if mosaic:
+        return _flash_fwd(q, k, v, scale, causal, block_q, block_kv, False)
+    return _pair_fwd_ref(q, k, v, scale, causal)
 
 
-def _pair_bwd(q, k, v, do, lse, delta, scale, causal, block_q, block_kv):
-    """Per-pair backward with the GLOBAL lse/delta: returns (dq, dk, dv).
-    Reuses the flash kernels, whose p = exp(s - lse) is exactly the
+def _pair_bwd(q, k, v, do, lse, delta, scale, causal, block_q, block_kv,
+              mosaic):
+    """Per-pair backward with the GLOBAL lse/delta: returns (dq, dk, dv)
+    in fp32.  The flash kernels' p = exp(s - lse) is exactly the
     ring-global softmax weight when lse is the final merged value."""
-    if _interpret():
-        return _pair_bwd_ref(q, k, v, do, lse, delta, scale, causal)
-    B, H, Sq, D = q.shape
-    Skv = k.shape[2]
-    bh = B * H
-    qf, dof = q.reshape(bh, Sq, D), do.reshape(bh, Sq, D)
-    kf, vf = k.reshape(bh, Skv, D), v.reshape(bh, Skv, D)
-    lsef = lse.reshape(bh, Sq, 1)
-    deltaf = delta.reshape(bh, Sq, 1)
-    num_q = Sq // block_q
-    num_kv = Skv // block_kv
-
-    dkdv = pl.pallas_call(
-        functools.partial(_bwd_dkdv_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_kv=block_kv, num_q=num_q),
-        grid=(bh, num_kv, num_q),
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, block_kv, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_kv, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_q, D), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, j, i: (b, i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_kv, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_kv, D), lambda b, j, i: (b, j, 0)),
-        ],
-        out_shape=[
-            _sds((bh, Skv, D), jnp.float32, qf, kf, vf, dof),
-            _sds((bh, Skv, D), jnp.float32, qf, kf, vf, dof),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_kv, D), jnp.float32),
-            pltpu.VMEM((block_kv, D), jnp.float32),
-        ],
-        interpret=_interpret(),
-    )(qf, kf, vf, dof, lsef, deltaf)
-    dk, dv = dkdv
-
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_kv=block_kv, num_kv=num_kv),
-        grid=(bh, num_q, num_kv),
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_kv, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_kv, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-        out_shape=_sds((bh, Sq, D), jnp.float32, qf, kf, vf, dof),
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        interpret=_interpret(),
-    )(qf, kf, vf, dof, lsef, deltaf)
-
-    shape = (B, H, Sq, D)
-    return (dq.reshape(shape), dk.reshape(B, H, Skv, D),
-            dv.reshape(B, H, Skv, D))
+    if mosaic:
+        return _flash_bwd(q, k, v, do, lse, delta, scale, causal, block_q,
+                          block_kv, False, jnp.float32)
+    return _pair_bwd_ref(q, k, v, do, lse, delta, scale, causal)
 
 
-def _fit_blocks(S, block_q, block_kv):
-    def fit(b):
-        b = min(b, S, 1024)
-        b -= b % 128            # align to the TPU tile first
-        while b > 128 and S % b:
-            b -= 128
-        return max(b, 128)      # S % 128 == 0 guaranteed by the caller
-
-    return fit(block_q), fit(block_kv)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _ring(q, k, v, axis_name, scale, block_q, block_kv):
-    out, _ = _ring_fwd_impl(q, k, v, axis_name, scale, block_q, block_kv)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _ring(q, k, v, axis_name, scale, block_q, block_kv, mosaic):
+    out, _ = _ring_fwd_impl(q, k, v, axis_name, scale, block_q, block_kv,
+                            mosaic)
     return out
 
 
-from ..core.vma import lifter as _vma_lift  # branch outputs must share vma
-
-
-def _ring_fwd_impl(q, k, v, axis_name, scale, block_q, block_kv):
+def _ring_fwd_impl(q, k, v, axis_name, scale, block_q, block_kv, mosaic):
     sep = jax.lax.psum(1, axis_name)
     my = jax.lax.axis_index(axis_name)
     fwd_perm = [(i, (i + 1) % sep) for i in range(sep)]
@@ -186,12 +109,14 @@ def _ring_fwd_impl(q, k, v, axis_name, scale, block_q, block_kv):
 
         def full_pair(args):
             kk, vv = args
-            o, l = _pair_fwd(q, kk, vv, scale, False, block_q, block_kv)
+            o, l = _pair_fwd(q, kk, vv, scale, False, block_q, block_kv,
+                             mosaic)
             return lift(o.astype(jnp.float32)), lift(l)
 
         def causal_pair(args):
             kk, vv = args
-            o, l = _pair_fwd(q, kk, vv, scale, True, block_q, block_kv)
+            o, l = _pair_fwd(q, kk, vv, scale, True, block_q, block_kv,
+                             mosaic)
             return lift(o.astype(jnp.float32)), lift(l)
 
         def skip_pair(args):
@@ -219,12 +144,13 @@ def _ring_fwd_impl(q, k, v, axis_name, scale, block_q, block_kv):
     return out, lse
 
 
-def _ring_fwd_rule(q, k, v, axis_name, scale, block_q, block_kv):
-    out, lse = _ring_fwd_impl(q, k, v, axis_name, scale, block_q, block_kv)
+def _ring_fwd_rule(q, k, v, axis_name, scale, block_q, block_kv, mosaic):
+    out, lse = _ring_fwd_impl(q, k, v, axis_name, scale, block_q, block_kv,
+                              mosaic)
     return out, (q, k, v, out, lse)
 
 
-def _ring_bwd_rule(axis_name, scale, block_q, block_kv, res, g):
+def _ring_bwd_rule(axis_name, scale, block_q, block_kv, mosaic, res, g):
     q, k, v, out, lse = res
     sep = jax.lax.psum(1, axis_name)
     my = jax.lax.axis_index(axis_name)
@@ -240,13 +166,13 @@ def _ring_bwd_rule(axis_name, scale, block_q, block_kv, res, g):
         def full_pair(args):
             kk, vv = args
             r_ = _pair_bwd(q, kk, vv, do, lse, delta, scale, False,
-                           block_q, block_kv)
+                           block_q, block_kv, mosaic)
             return tuple(lift(t) for t in r_)
 
         def causal_pair(args):
             kk, vv = args
             r_ = _pair_bwd(q, kk, vv, do, lse, delta, scale, True,
-                           block_q, block_kv)
+                           block_q, block_kv, mosaic)
             return tuple(lift(t) for t in r_)
 
         def skip_pair(args):
@@ -281,7 +207,7 @@ _ring.defvjp(_ring_fwd_rule, _ring_bwd_rule)
 
 
 def ring_attention(q, k, v, axis_name, causal=True, scale=None,
-                   block_q=512, block_kv=1024):
+                   block_q=512, block_kv=1024, path=None):
     """Sequence-parallel causal attention over mesh axis ``axis_name``.
 
     q/k/v: [B, H, S_local, hd] — the LOCAL sequence shard (global S =
@@ -289,7 +215,14 @@ def ring_attention(q, k, v, axis_name, causal=True, scale=None,
     inside shard_map with ``axis_name`` mapped.  S_local must be a
     multiple of 128 (TPU tile).  Only causal=True is supported (the
     non-causal case is just flash over an all_gather'd sequence).
+    ``path`` is ``dispatch.MOSAIC`` (the flash kernels per pair; the
+    default on a TPU) or ``dispatch.REFERENCE`` (a jnp pair, elsewhere).
+    There is no interpreted path: the Pallas HLO interpreter's
+    dynamic_slice fails shard_map's check_vma on sep-varying operands.
     """
+    path = dispatch.resolve_path(
+        path, off_tpu=dispatch.REFERENCE,
+        allowed=(dispatch.MOSAIC, dispatch.REFERENCE))
     if not causal:
         raise NotImplementedError(
             "ring_attention is causal-only; for non-causal, all_gather the "
@@ -300,4 +233,4 @@ def ring_attention(q, k, v, axis_name, causal=True, scale=None,
     bq, bkv = _fit_blocks(S, block_q, block_kv)
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    return _ring(q, k, v, axis_name, scale, bq, bkv)
+    return _ring(q, k, v, axis_name, scale, bq, bkv, path == dispatch.MOSAIC)

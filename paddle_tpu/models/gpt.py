@@ -207,17 +207,13 @@ def gpt_block(cfg: GPTConfig, bp, x, dropout_key=None, return_kv=False):
     k = k_tm.transpose(0, 2, 1, 3)
     v = v_tm.transpose(0, 2, 1, 3)
 
-    attn_out = None
-    if cfg.use_flash:
-        try:
-            from ..kernels.flash_attention import (flash_attention,
-                                                   flash_attention_available)
+    from ..kernels.flash_attention import (flash_attention,
+                                           flash_attention_available)
 
-            if flash_attention_available(q, k, v, None, causal=True):
-                attn_out = flash_attention(q, k, v, causal=True)
-        except ImportError:
-            pass
-    if attn_out is None:
+    if cfg.use_flash and flash_attention_available(q, k, v, None,
+                                                   causal=True):
+        attn_out = flash_attention(q, k, v, causal=True)
+    else:
         from ..ops.attention import _naive_attention
 
         attn_out = _naive_attention(q, k, v, causal=True, training=False)
@@ -344,7 +340,8 @@ def _paged_write(pages, page_idx, slot_idx, vals):
 
 def gpt_ragged_step(cfg: GPTConfig, params, tokens, row_of_token,
                     slot_of_token, query_lens, context_lens, k_pages,
-                    v_pages, page_tables, *, max_q=None):
+                    v_pages, page_tables, *, max_q=None, attn_path=None,
+                    mesh=None):
     """Unified ragged step over the paged KV cache — the serving
     engine's single jitted program for both prompt chunks and decode.
 
@@ -357,7 +354,13 @@ def gpt_ragged_step(cfg: GPTConfig, params, tokens, row_of_token,
     the row's total tokens *including* this chunk, so token t of row b
     sits at absolute position ``context_lens[b] - query_lens[b] + t``.
     ``max_q`` (static) bounds any single row's chunk — the padded query
-    width handed to the attention kernel.
+    width handed to the attention kernel.  ``attn_path`` (static) is
+    handed to ``ragged_paged_attention`` as its ``path``: ``None`` lets
+    ``kernels.dispatch`` pick from the platform.  ``mesh`` (static) is
+    the serving mesh when params and pages are sharded over its "mp"
+    axis: attention then runs under a shard_map over the head axis —
+    heads are independent, and GSPMD cannot partition a Mosaic kernel
+    by itself ("wrap the call in a shard_map").
 
     Compute is flat [T, D] (a decode row costs one token, not a padded
     chunk); only the attention kernel sees a per-row padded [B, max_q]
@@ -392,6 +395,17 @@ def gpt_ragged_step(cfg: GPTConfig, params, tokens, row_of_token,
 
     from ..kernels.paged_attention import ragged_paged_attention
 
+    attend = functools.partial(ragged_paged_attention, path=attn_path)
+    if mesh is not None:
+        # heads that do not divide stay whole on every shard, as
+        # mesh.resolve_spec leaves the page pool
+        heads = jax.sharding.PartitionSpec(
+            None, None, "mp" if H % mesh.shape["mp"] == 0 else None, None)
+        rep = jax.sharding.PartitionSpec()
+        attend = jax.shard_map(
+            attend, mesh=mesh, in_specs=(heads, heads, heads, rep, rep, rep),
+            out_specs=heads, check_vma=False)
+
     def body(x, xs):
         bp, kp, vp = xs
         h = _layer_norm(x, bp["ln1_g"], bp["ln1_b"])
@@ -405,8 +419,7 @@ def gpt_ragged_step(cfg: GPTConfig, params, tokens, row_of_token,
         # zeros/junk that never reaches pages or logits)
         q_pad = jnp.zeros((B, Q, H, hd), q.dtype) \
             .at[scat_row, scat_slot].set(q, mode="drop")
-        attn = ragged_paged_attention(q_pad, kp, vp, page_tables,
-                                      query_lens, context_lens)
+        attn = attend(q_pad, kp, vp, page_tables, query_lens, context_lens)
         attn = attn[row_c, scat_slot].reshape(T, D).astype(x.dtype)
         x = x + jnp.einsum("td,de->te", attn, bp["proj_w"]) + bp["proj_b"]
 

@@ -48,31 +48,27 @@ PEAK_FLOPS = {
     "TPU v5 lite": 197.0e12, "TPU v5e": 197.0e12, "TPU v5p": 459.0e12,
     "TPU v5": 459.0e12, "TPU v4": 275.0e12, "TPU v3": 123.0e12,
     "TPU v2": 45.0e12,
-    "cpu": 1.0e12,
 }
 
 #: the breakdown's phase vocabulary, in display order
 PHASES = ("compute", "data_wait", "compile", "checkpoint", "eval")
 
 
-def device_peak_flops(device=None, table=None, default=None):
+def device_peak_flops(device=None, table=None):
     """``(peak_flops, device_kind)`` for ``device`` (default: the first
     local jax device).
 
     Resolution order: the ``PADDLE_TPU_PEAK_FLOPS`` environment variable
     (an absolute FLOPs value — the escape hatch for unlisted hardware),
     then the longest :data:`PEAK_FLOPS` substring match on the device
-    kind, then ``default`` (``None`` = unknown; callers should skip MFU
-    rather than report one against a made-up peak)."""
-    env = os.environ.get("PADDLE_TPU_PEAK_FLOPS")
-    kind = "unknown"
-    try:
-        import jax
+    kind.  A kind the table does not list — the CPU included — has no
+    peak: ``(None, kind)``, and callers report no MFU rather than one
+    against a made-up denominator."""
+    import jax
 
-        d = device if device is not None else jax.local_devices()[0]
-        kind = getattr(d, "device_kind", None) or d.platform
-    except Exception:
-        pass    # silent-ok: best-effort device probe; table fallback
+    d = device if device is not None else jax.local_devices()[0]
+    kind = d.device_kind
+    env = os.environ.get("PADDLE_TPU_PEAK_FLOPS")
     if env:
         return float(env), kind
     best = None
@@ -80,9 +76,7 @@ def device_peak_flops(device=None, table=None, default=None):
         if k.lower() in kind.lower() and \
                 (best is None or len(k) > best[0]):
             best = (len(k), v)
-    if best is not None:
-        return best[1], kind
-    return default, kind
+    return (best[1] if best is not None else None), kind
 
 
 def mfu(flops_per_step, step_time_s, peak_flops):

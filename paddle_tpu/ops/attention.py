@@ -57,12 +57,10 @@ def scaled_dot_product_attention(q, k, v, attn_mask=None, dropout_p=0.0,
     reference's fused_attention fast path).
     """
     if use_flash and (dropout_p == 0.0 or not training):
-        try:
-            from ..kernels.flash_attention import flash_attention_available, flash_attention
+        from ..kernels.flash_attention import (flash_attention,
+                                               flash_attention_available)
 
-            if flash_attention_available(q, k, v, attn_mask, causal=is_causal):
-                return flash_attention(q, k, v, causal=is_causal, scale=scale)
-        except ImportError:
-            pass
+        if flash_attention_available(q, k, v, attn_mask, causal=is_causal):
+            return flash_attention(q, k, v, causal=is_causal, scale=scale)
     return _naive_attention(q, k, v, mask=attn_mask, dropout_p=dropout_p,
                             causal=is_causal, scale=scale, training=training)

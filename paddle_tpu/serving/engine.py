@@ -278,7 +278,7 @@ class Engine:
                   ctxs, tables):
             return gpt_ragged_step(cfg_, params, tokens, rows, slots,
                                    qlens, ctxs, k_pages, v_pages, tables,
-                                   max_q=max_q)
+                                   max_q=max_q, mesh=mesh)
 
         # GSPMD serving (prepare(mesh=...) analogue): params follow the
         # mesh.py GPT rule table and the KV page pool [L, P, ps, H, hd]

@@ -119,3 +119,13 @@ class TestLauncherRestart:
         proc, _ = self._launch(tmp_path, max_restarts=0,
                                code=ELASTIC_EXIT_CODE)
         assert proc.returncode == ELASTIC_EXIT_CODE
+
+    def test_tpu_backend_refuses_processes_per_chip(self, tmp_path):
+        """A chip belongs to one process: --backend tpu with more than
+        one process per node must say so, not start children that hang."""
+        from paddle_tpu.distributed.launch import launch
+
+        with pytest.raises(SystemExit, match="ONE process per host"):
+            launch(["--backend", "tpu", "--nproc_per_node", "2",
+                    "--log_dir", str(tmp_path / "logs"), "unused.py"])
+        assert not (tmp_path / "logs").exists()      # nothing was started

@@ -1,12 +1,19 @@
 """Flash-attention kernel conformance: forward + backward vs naive XLA path
-(interpret mode on the CPU fixture; same code compiles for TPU)."""
+(the kernel body under the Pallas interpreter, chosen explicitly; that
+the same code compiles for the TPU is tests/test_tpu_aot_compile.py)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from paddle_tpu.kernels.flash_attention import flash_attention
+from paddle_tpu import kernels
+from paddle_tpu.kernels import dispatch
 from paddle_tpu.ops.attention import _naive_attention
+
+flash_attention = functools.partial(kernels.flash_attention,
+                                    path=dispatch.INTERPRET)
 
 
 def _rand_qkv(B=1, H=2, S=256, D=64, seed=0):

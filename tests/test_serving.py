@@ -10,12 +10,10 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from paddle_tpu.kernels.paged_attention import (_paged_attention_kernel,
-                                                _paged_attention_ref,
-                                                _ragged_attention_kernel,
+from paddle_tpu.kernels import dispatch
+from paddle_tpu.kernels.paged_attention import (_ragged_attention_kernel,
                                                 _ragged_attention_ref,
-                                                paged_attention,
-                                                paged_attention_available)
+                                                paged_attention)
 from paddle_tpu.models.gpt import GPT_CONFIGS, gpt_forward, gpt_init
 from paddle_tpu.serving import (Engine, PagedKVCache, RequestState,
                                 SamplingParams)
@@ -143,8 +141,8 @@ class TestPagedAttention:
         """The paged gather+mask must equal dense softmax attention over
         each sequence's first seq_len tokens."""
         q, kp, vp, tables, lens = self._case()
-        out = _paged_attention_ref(q, kp, vp, tables, lens,
-                                   1.0 / np.sqrt(q.shape[-1]))
+        out = paged_attention(q, kp, vp, tables, lens,
+                              path=dispatch.REFERENCE)
         ps = kp.shape[1]
         for b in range(q.shape[0]):
             n = int(lens[b])
@@ -162,14 +160,12 @@ class TestPagedAttention:
             np.testing.assert_allclose(np.asarray(out[b]), np.asarray(ref),
                                        rtol=1e-5, atol=1e-5)
 
-    @pytest.mark.skipif(not paged_attention_available(),
-                        reason="pallas unavailable")
     def test_kernel_matches_ref_interpret(self):
         q, kp, vp, tables, lens = self._case()
-        scale = 1.0 / np.sqrt(q.shape[-1])
-        ref = _paged_attention_ref(q, kp, vp, tables, lens, scale)
-        ker = _paged_attention_kernel(q, kp, vp, tables, lens, scale,
-                                      interpret=True)
+        ref = paged_attention(q, kp, vp, tables, lens,
+                              path=dispatch.REFERENCE)
+        ker = paged_attention(q, kp, vp, tables, lens,
+                              path=dispatch.INTERPRET)
         np.testing.assert_allclose(np.asarray(ker), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
 
@@ -224,8 +220,6 @@ class TestRaggedAttention:
                                            np.asarray(ref),
                                            rtol=1e-5, atol=1e-5)
 
-    @pytest.mark.skipif(not paged_attention_available(),
-                        reason="pallas unavailable")
     def test_kernel_matches_ref_mixed_rows(self):
         """Interpret-mode kernel == ref for a batch mixing a mid-prefill
         chunk, a prompt-completing chunk, a decode row, and an idle row,
@@ -241,15 +235,13 @@ class TestRaggedAttention:
             np.testing.assert_allclose(np.asarray(ker), np.asarray(ref),
                                        rtol=2e-5, atol=2e-5)
 
-    @pytest.mark.skipif(not paged_attention_available(),
-                        reason="pallas unavailable")
     def test_decode_entry_is_qlen1_degenerate_row(self):
         """The legacy decode entry must equal a Q=1 ragged call."""
         q, kp, vp, tables, _, _ = self._case([1, 1, 1], [9, 4, 0], Q=1)
         lens = jnp.asarray([9, 4, 0], jnp.int32)
         scale = 1.0 / np.sqrt(q.shape[-1])
-        dec = _paged_attention_kernel(q[:, 0], kp, vp, tables, lens, scale,
-                                      interpret=True)
+        dec = paged_attention(q[:, 0], kp, vp, tables, lens, scale,
+                              path=dispatch.INTERPRET)
         rag = _ragged_attention_kernel(q, kp, vp, tables,
                                        (lens > 0).astype(jnp.int32), lens,
                                        scale, interpret=True)[:, 0]

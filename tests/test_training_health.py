@@ -59,8 +59,14 @@ class ManualClock:
 
 class TestGoodput:
     def test_peak_flops_table_and_env(self, monkeypatch):
+        # the CPU is not in the table: no peak, so no MFU against it
         flops, kind = device_peak_flops()
-        assert kind == "cpu" and flops == 1.0e12
+        assert kind == "cpu" and flops is None
+
+        class V5e:
+            device_kind = "TPU v5 lite"
+
+        assert device_peak_flops(V5e()) == (197.0e12, "TPU v5 lite")
         monkeypatch.setenv("PADDLE_TPU_PEAK_FLOPS", "5e13")
         flops, _ = device_peak_flops()
         assert flops == 5e13
