@@ -46,6 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..profiler.profiler import RecordEvent
 from .topology import build_mesh
 
 __all__ = ["HybridEngine", "EngineConfig"]
@@ -680,6 +681,10 @@ class HybridEngine:
         the shared loss-head building block for model adapters.
         x: [b, s_local, D]; wte local: [V/mp, D]; labels: [b, s_local]
         with -100 = ignore.  Returns (sum_loss, count)."""
+        with jax.named_scope("ce_head"):
+            return self._tied_vocab_ce(x, wte, labels)
+
+    def _tied_vocab_ce(self, x, wte, labels):
         mp = self.mp
         from .mp_layers import parallel_cross_entropy
 
@@ -1117,8 +1122,9 @@ class HybridEngine:
             return chunks
 
         if accum == 1:
-            loss, grads = grad_fn(params, tokens, labels, key)
-            g_chunks = to_chunks(grads, dtype=None)
+            with jax.named_scope("forward_backward"):
+                loss, grads = grad_fn(params, tokens, labels, key)
+                g_chunks = to_chunks(grads, dtype=None)
         else:
             # gradient merge (reference: gradient_merge_optimizer): scan
             # accum chunks of the local batch.  The carry holds only each
@@ -1133,8 +1139,9 @@ class HybridEngine:
                 loss_sum, gsum = carry
                 k = (jax.random.fold_in(key, xs[2])
                      if key is not None else None)
-                l, g = grad_fn(params, xs[0], xs[1], k)
-                gc = to_chunks(g)
+                with jax.named_scope("forward_backward"):
+                    l, g = grad_fn(params, xs[0], xs[1], k)
+                    gc = to_chunks(g)
                 return (loss_sum + l,
                         tuple(a + c for a, c in zip(gsum, gc))), None
 
@@ -1153,7 +1160,19 @@ class HybridEngine:
             loss = loss_sum / accum
             g_chunks = [g / accum for g in g_chunks]
 
-        step = opt_state["step"] + 1
+        with jax.named_scope("optimizer"):
+            new_params, new_opt = self._apply_grads(
+                treedef, paths, flat_p, flat_slots, z3_leaf, zr_idx,
+                g_chunks, opt_state["step"] + 1, lr)
+        return new_params, new_opt, loss
+
+    def _apply_grads(self, treedef, paths, flat_p, flat_slots, z3_leaf,
+                     zr_idx, g_chunks, step, lr):
+        """The optimizer's half of ``_step_local``: global-norm clip,
+        Adam on each rank's chunks (windowed), weight decay, and the
+        params rebuilt from the updated chunks.  Everything is flat, in
+        ``treedef``'s leaf order.  Returns ``(params, opt_state)``."""
+        ec, zr = self.ec, self.zr
 
         # --- global-norm clip over the sharded chunks ---
         # per-leaf vma-aware reduce: an mp-sharded leaf's chunks must be
@@ -1291,7 +1310,7 @@ class HybridEngine:
 
         new_params = jax.tree_util.tree_unflatten(treedef, new_flat_p)
         new_slots = jax.tree_util.tree_unflatten(treedef, new_flat_slots)
-        return new_params, {"step": step, "slots": new_slots}, loss
+        return new_params, {"step": step, "slots": new_slots}
 
     # ------------------------------------------------------------ build/jit
     def build_step(self):
@@ -1324,7 +1343,9 @@ class HybridEngine:
         fn = self.build_step()
         lr = jnp.asarray(lr if lr is not None else self.ec.lr, jnp.float32)
         seed = jnp.asarray(dropout_seed, jnp.uint32)
-        return fn(params, opt_state, tokens, labels, lr, seed)
+        # the host's part of a step: the dispatch (enqueue), not the wait
+        with RecordEvent("hybrid_engine::step"):
+            return fn(params, opt_state, tokens, labels, lr, seed)
 
     # ----------------------------------------------------------- eval/debug
     def loss_fn_reference(self, params_host, tokens, labels):

@@ -105,19 +105,32 @@ class ModelAdapter:
         if key is not None and cfg.dropout > 0.0:
             k_attn, k_ffn = jax.random.split(key)
 
-        h = _layer_norm(x, bp["ln1_g"], bp["ln1_b"])
-        qkv = jnp.einsum("bsd,de->bse", h, bp["qkv_w"]) + bp["qkv_b"]
-        # global qkv column order is head-major [H, 3, hd] so an mp shard
-        # is a whole group of heads (models/gpt.py uses the same layout)
-        qkv = qkv.reshape(B, s_local, H_local, 3, hd)
-        q = qkv[:, :, :, 0].transpose(0, 2, 1, 3)
-        k = qkv[:, :, :, 1].transpose(0, 2, 1, 3)
-        v = qkv[:, :, :, 2].transpose(0, 2, 1, 3)
-        attn = engine._attention(q, k, v, causal=self.causal)
-        attn = attn.transpose(0, 2, 1, 3).reshape(B, s_local, H_local * hd)
-        proj = jnp.einsum("bse,ed->bsd", attn, bp["proj_w"])
-        proj = _psum_varying(proj, ("mp",))
-        x = x + _dropout(proj + bp["proj_b"], cfg.dropout, k_attn)
+        with jax.named_scope("attn"):
+            h = _layer_norm(x, bp["ln1_g"], bp["ln1_b"])
+            qkv = jnp.einsum("bsd,de->bse", h, bp["qkv_w"]) + bp["qkv_b"]
+            # global qkv column order is head-major [H, 3, hd] so an mp
+            # shard is a whole group of heads (models/gpt.py uses the
+            # same layout)
+            qkv = qkv.reshape(B, s_local, H_local, 3, hd)
+            q = qkv[:, :, :, 0].transpose(0, 2, 1, 3)
+            k = qkv[:, :, :, 1].transpose(0, 2, 1, 3)
+            v = qkv[:, :, :, 2].transpose(0, 2, 1, 3)
+            attn = engine._attention(q, k, v, causal=self.causal)
+            attn = attn.transpose(0, 2, 1, 3).reshape(B, s_local,
+                                                      H_local * hd)
+            proj = jnp.einsum("bse,ed->bsd", attn, bp["proj_w"])
+            proj = _psum_varying(proj, ("mp",))
+            x = x + _dropout(proj + bp["proj_b"], cfg.dropout, k_attn)
+
+        with jax.named_scope("mlp"):
+            return self._tp_mlp(engine, bp, x, k_ffn)
+
+    def _tp_mlp(self, engine, bp, x, k_ffn):
+        """The block's second half: pre-LN FFN (dense or MoE) and its
+        residual.  Returns (x, aux_loss)."""
+        cfg = self.cfg
+        from ..models.gpt import _dropout, _layer_norm
+        from .engine import _psum_varying
 
         h = _layer_norm(x, bp["ln2_g"], bp["ln2_b"])
         if getattr(cfg, "moe_experts", 0):

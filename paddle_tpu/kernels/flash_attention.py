@@ -137,6 +137,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_kv, interpret):
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(qf, kf, vf)
     return out.reshape(B, H, S, D), lse[..., 0].reshape(B, H, S)
 
@@ -275,6 +276,7 @@ def _flash_bwd(q, k, v, do, lse, delta, scale, causal, block_q, block_kv,
             pltpu.VMEM((block_kv, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_bwd_dkdv",
     )(qf, kf, vf, dof, lsef, deltaf)
 
     dq = pl.pallas_call(
@@ -293,6 +295,7 @@ def _flash_bwd(q, k, v, do, lse, delta, scale, causal, block_q, block_kv,
         out_shape=_sds((bh, Sq, D), out_dtype, qf, kf, vf, dof),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qf, kf, vf, dof, lsef, deltaf)
 
     return (dq.reshape(B, H, Sq, D), dk.reshape(B, H, Skv, D),

@@ -185,6 +185,7 @@ def _ragged_attention_kernel(q, k_pages, v_pages, page_tables, query_lens,
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Q, H, hd), q.dtype),
         interpret=interpret,
+        name="ragged_paged_attention",
     )(page_tables, query_lens, context_lens, q, k_pages, v_pages)
 
 

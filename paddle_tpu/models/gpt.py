@@ -197,30 +197,42 @@ def gpt_block(cfg: GPTConfig, bp, x, dropout_key=None, return_kv=False):
     if dropout_key is not None and cfg.dropout > 0.0:
         k_attn, k_ffn = jax.random.split(dropout_key)
 
-    h = _layer_norm(x, bp["ln1_g"], bp["ln1_b"])
-    qkv = jnp.einsum("bsd,de->bse", h, bp["qkv_w"]) + bp["qkv_b"]
-    # qkv columns are head-major [H, 3, hd] so a TP shard of the columns is
-    # a whole group of heads (keeps engine.py mp splits layout-compatible)
-    qkv = qkv.reshape(B, S, H, 3, hd)
-    k_tm, v_tm = qkv[:, :, :, 1], qkv[:, :, :, 2]    # token-major [B,S,H,hd]
-    q = qkv[:, :, :, 0].transpose(0, 2, 1, 3)
-    k = k_tm.transpose(0, 2, 1, 3)
-    v = v_tm.transpose(0, 2, 1, 3)
+    with jax.named_scope("attn"):
+        h = _layer_norm(x, bp["ln1_g"], bp["ln1_b"])
+        qkv = jnp.einsum("bsd,de->bse", h, bp["qkv_w"]) + bp["qkv_b"]
+        # qkv columns are head-major [H, 3, hd] so a TP shard of the
+        # columns is a whole group of heads (keeps engine.py mp splits
+        # layout-compatible)
+        qkv = qkv.reshape(B, S, H, 3, hd)
+        k_tm, v_tm = qkv[:, :, :, 1], qkv[:, :, :, 2]  # token-major [B,S,H,hd]
+        q = qkv[:, :, :, 0].transpose(0, 2, 1, 3)
+        k = k_tm.transpose(0, 2, 1, 3)
+        v = v_tm.transpose(0, 2, 1, 3)
 
-    from ..kernels.flash_attention import (flash_attention,
-                                           flash_attention_available)
+        from ..kernels.flash_attention import (flash_attention,
+                                               flash_attention_available)
 
-    if cfg.use_flash and flash_attention_available(q, k, v, None,
-                                                   causal=True):
-        attn_out = flash_attention(q, k, v, causal=True)
-    else:
-        from ..ops.attention import _naive_attention
+        if cfg.use_flash and flash_attention_available(q, k, v, None,
+                                                       causal=True):
+            attn_out = flash_attention(q, k, v, causal=True)
+        else:
+            from ..ops.attention import _naive_attention
 
-        attn_out = _naive_attention(q, k, v, causal=True, training=False)
-    attn_out = attn_out.transpose(0, 2, 1, 3).reshape(B, S, D)
-    proj = jnp.einsum("bsd,de->bse", attn_out, bp["proj_w"]) + bp["proj_b"]
-    x = x + _dropout(proj, cfg.dropout, k_attn)
+            attn_out = _naive_attention(q, k, v, causal=True,
+                                        training=False)
+        attn_out = attn_out.transpose(0, 2, 1, 3).reshape(B, S, D)
+        proj = jnp.einsum("bsd,de->bse", attn_out, bp["proj_w"]) \
+            + bp["proj_b"]
+        x = x + _dropout(proj, cfg.dropout, k_attn)
 
+    with jax.named_scope("mlp"):
+        out, aux = _block_mlp(cfg, bp, x, k_ffn)
+    return (out, aux, k_tm, v_tm) if return_kv else (out, aux)
+
+
+def _block_mlp(cfg: GPTConfig, bp, x, k_ffn):
+    """The block's second half: pre-LN FFN (dense or MoE) and its
+    residual.  Returns (x, aux)."""
     h = _layer_norm(x, bp["ln2_g"], bp["ln2_b"])
     if cfg.moe_experts:
         from ..distributed.moe import moe_layer
@@ -230,14 +242,11 @@ def gpt_block(cfg: GPTConfig, bp, x, dropout_key=None, return_kv=False):
              "down_w": bp["down_w"], "down_b": bp["down_b"]},
             h, top_k=cfg.moe_top_k,
             capacity_factor=cfg.moe_capacity_factor)
-        out = x + _dropout(y, cfg.dropout, k_ffn)
-        return (out, aux, k_tm, v_tm) if return_kv else (out, aux)
+        return x + _dropout(y, cfg.dropout, k_ffn), aux
     h = jnp.einsum("bsd,df->bsf", h, bp["up_w"]) + bp["up_b"]
     h = jax.nn.gelu(h, approximate=True)
     h = jnp.einsum("bsf,fd->bsd", h, bp["down_w"]) + bp["down_b"]
-    out = x + _dropout(h, cfg.dropout, k_ffn)
-    aux = jnp.zeros((), jnp.float32)
-    return (out, aux, k_tm, v_tm) if return_kv else (out, aux)
+    return x + _dropout(h, cfg.dropout, k_ffn), jnp.zeros((), jnp.float32)
 
 
 def gpt_forward(cfg: GPTConfig, params, tokens, *, blocks=None,
@@ -298,12 +307,13 @@ def gpt_loss(cfg: GPTConfig, params, tokens, labels=None, dropout_key=None):
         labels = jnp.pad(tokens[:, 1:], ((0, 0), (0, 1)), constant_values=-100)
     logits, aux = gpt_forward(cfg, params, tokens, return_aux=True,
                               dropout_key=dropout_key)
-    logits = logits.astype(jnp.float32)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    safe = jnp.maximum(labels, 0)
-    picked = jnp.take_along_axis(logp, safe[..., None], axis=-1)[..., 0]
-    mask = (labels != -100).astype(jnp.float32)
-    ce = -(picked * mask).sum() / jnp.maximum(mask.sum(), 1.0)
+    with jax.named_scope("ce_head"):
+        logits = logits.astype(jnp.float32)
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        safe = jnp.maximum(labels, 0)
+        picked = jnp.take_along_axis(logp, safe[..., None], axis=-1)[..., 0]
+        mask = (labels != -100).astype(jnp.float32)
+        ce = -(picked * mask).sum() / jnp.maximum(mask.sum(), 1.0)
     if cfg.moe_experts:
         # per-layer mean aux (sum over layers / L keeps the weight's scale
         # independent of depth, matching the engine's normalization)
@@ -408,47 +418,53 @@ def gpt_ragged_step(cfg: GPTConfig, params, tokens, row_of_token,
 
     def body(x, xs):
         bp, kp, vp = xs
-        h = _layer_norm(x, bp["ln1_g"], bp["ln1_b"])
-        qkv = jnp.einsum("td,de->te", h, bp["qkv_w"]) + bp["qkv_b"]
-        qkv = qkv.reshape(T, H, 3, hd)
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]   # [T, H, hd]
-        kp = _paged_write(kp, safe_page, slot_in_page, k)
-        vp = _paged_write(vp, safe_page, slot_in_page, v)
-        # the kernel wants per-row padded queries; scatter the packed
-        # tokens out, gather the outputs back flat (padding slots read
-        # zeros/junk that never reaches pages or logits)
-        q_pad = jnp.zeros((B, Q, H, hd), q.dtype) \
-            .at[scat_row, scat_slot].set(q, mode="drop")
-        attn = attend(q_pad, kp, vp, page_tables, query_lens, context_lens)
-        attn = attn[row_c, scat_slot].reshape(T, D).astype(x.dtype)
-        x = x + jnp.einsum("td,de->te", attn, bp["proj_w"]) + bp["proj_b"]
+        with jax.named_scope("attn"):
+            h = _layer_norm(x, bp["ln1_g"], bp["ln1_b"])
+            qkv = jnp.einsum("td,de->te", h, bp["qkv_w"]) + bp["qkv_b"]
+            qkv = qkv.reshape(T, H, 3, hd)
+            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]  # [T, H, hd]
+            with jax.named_scope("kv_write"):
+                kp = _paged_write(kp, safe_page, slot_in_page, k)
+                vp = _paged_write(vp, safe_page, slot_in_page, v)
+            # the kernel wants per-row padded queries; scatter the packed
+            # tokens out, gather the outputs back flat (padding slots
+            # read zeros/junk that never reaches pages or logits)
+            q_pad = jnp.zeros((B, Q, H, hd), q.dtype) \
+                .at[scat_row, scat_slot].set(q, mode="drop")
+            attn = attend(q_pad, kp, vp, page_tables, query_lens,
+                          context_lens)
+            attn = attn[row_c, scat_slot].reshape(T, D).astype(x.dtype)
+            x = x + jnp.einsum("td,de->te", attn, bp["proj_w"]) \
+                + bp["proj_b"]
 
-        h = _layer_norm(x, bp["ln2_g"], bp["ln2_b"])
-        if cfg.moe_experts:
-            from ..distributed.moe import moe_layer
+        with jax.named_scope("mlp"):
+            h = _layer_norm(x, bp["ln2_g"], bp["ln2_b"])
+            if cfg.moe_experts:
+                from ..distributed.moe import moe_layer
 
-            y, _ = moe_layer(
-                {"gate_w": bp["gate_w"], "up_w": bp["up_w"],
-                 "up_b": bp["up_b"], "down_w": bp["down_w"],
-                 "down_b": bp["down_b"]},
-                h[None], top_k=cfg.moe_top_k,
-                capacity_factor=cfg.moe_capacity_factor)
-            return x + y[0], (kp, vp)
-        h = jnp.einsum("td,df->tf", h, bp["up_w"]) + bp["up_b"]
-        h = jax.nn.gelu(h, approximate=True)
-        h = jnp.einsum("tf,fd->td", h, bp["down_w"]) + bp["down_b"]
-        return x + h, (kp, vp)
+                y, _ = moe_layer(
+                    {"gate_w": bp["gate_w"], "up_w": bp["up_w"],
+                     "up_b": bp["up_b"], "down_w": bp["down_w"],
+                     "down_b": bp["down_b"]},
+                    h[None], top_k=cfg.moe_top_k,
+                    capacity_factor=cfg.moe_capacity_factor)
+                return x + y[0], (kp, vp)
+            h = jnp.einsum("td,df->tf", h, bp["up_w"]) + bp["up_b"]
+            h = jax.nn.gelu(h, approximate=True)
+            h = jnp.einsum("tf,fd->td", h, bp["down_w"]) + bp["down_b"]
+            return x + h, (kp, vp)
 
     x, (k_pages, v_pages) = jax.lax.scan(
         body, x, (params["blocks"], k_pages, v_pages))
-    x = _layer_norm(x, params["lnf_g"], params["lnf_b"])
-    # row b's last packed token sits at cumsum(query_lens)[b] - 1
-    last = jnp.clip(jnp.cumsum(query_lens) - 1, 0, T - 1)
-    x_last = jnp.take(x, last, axis=0)                             # [B, D]
-    if cfg.tie_embeddings:
-        logits = jnp.einsum("bd,vd->bv", x_last, params["wte"])
-    else:
-        logits = jnp.einsum("bd,dv->bv", x_last, params["lm_head"])
+    with jax.named_scope("lm_head"):
+        x = _layer_norm(x, params["lnf_g"], params["lnf_b"])
+        # row b's last packed token sits at cumsum(query_lens)[b] - 1
+        last = jnp.clip(jnp.cumsum(query_lens) - 1, 0, T - 1)
+        x_last = jnp.take(x, last, axis=0)                         # [B, D]
+        if cfg.tie_embeddings:
+            logits = jnp.einsum("bd,vd->bv", x_last, params["wte"])
+        else:
+            logits = jnp.einsum("bd,dv->bv", x_last, params["lm_head"])
     return logits, k_pages, v_pages
 
 

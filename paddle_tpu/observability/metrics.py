@@ -223,6 +223,12 @@ class Histogram(_Metric):
         idx = min(n - 1, max(0, math.ceil(p / 100.0 * n) - 1))
         return sorted_samples[idx]
 
+    def samples(self):
+        """The kept reservoir as a list, oldest first: the newest
+        ``reservoir`` observations in the order they were made."""
+        with self._lock:
+            return list(self._samples)
+
     def percentile(self, p):
         """Exact percentile over the reservoir (p in 0..100); ``None``
         on an empty series — a fresh process's exporter scrape must not

@@ -73,6 +73,22 @@ PROFILING_SERIES = (
 _PHASES = {}
 
 
+def push_phase(name):
+    """Mark the calling thread as in ``name`` until the matching
+    :func:`pop_phase`: :func:`phase` without the context manager, for a
+    caller that is one itself (the serving engine's step phases)."""
+    _PHASES.setdefault(threading.get_ident(), []).append(str(name))
+
+
+def pop_phase():
+    """Leave the calling thread's innermost :func:`push_phase` marker."""
+    tid = threading.get_ident()
+    stack = _PHASES[tid]
+    stack.pop()
+    if not stack:
+        _PHASES.pop(tid, None)
+
+
 @contextlib.contextmanager
 def phase(name):
     """Mark the calling thread as spending the block in ``name``.
@@ -80,17 +96,11 @@ def phase(name):
     Nesting is innermost-wins; the marker costs two dict ops, so it is
     cheap enough for per-step hot paths.  Sampler threads read it
     cross-thread to attribute samples."""
-    tid = threading.get_ident()
-    stack = _PHASES.get(tid)
-    if stack is None:
-        stack = _PHASES[tid] = []
-    stack.append(str(name))
+    push_phase(name)
     try:
         yield
     finally:
-        stack.pop()
-        if not stack:
-            _PHASES.pop(tid, None)
+        pop_phase()
 
 
 def current_phase(tid=None):

@@ -6,7 +6,11 @@ Parity: platform/profiler/profiler.h:43 ``Profiler`` (HostTracer + CudaTracer
 TPU design: host events are recorded in a ring buffer (HostEventRecorder
 analog); device-side activity is captured by jax.profiler (XLA's tracer —
 the CUPTI analog), exported as TensorBoard trace.  ``export_chrome_tracing``
-writes the host events in chrome-trace JSON.
+writes the host events in chrome-trace JSON.  Every ``RecordEvent`` also
+enters a ``jax.profiler.TraceAnnotation`` of its name, so the program's
+host spans sit in any ``.xplane.pb`` that ``jax.profiler`` writes, on the
+clock of the device's operations, whether or not a ``Profiler`` session
+is running (outside a trace the annotation costs a flag check).
 
 Step-aware profiling (reference ``make_scheduler``,
 python/paddle/profiler/profiler.py:115): ``Profiler.step()`` marks batch
@@ -27,6 +31,8 @@ import json
 import os
 import threading
 import time
+
+from jax.profiler import TraceAnnotation
 
 __all__ = ["Profiler", "ProfilerState", "RecordEvent",
            "export_chrome_tracing", "make_scheduler", "ProfilerTarget"]
@@ -133,11 +139,19 @@ class RecordEvent:
 
         @RecordEvent("my_op")
         def my_op(...): ...
+
+    Two sinks: the host ring (kept only while a ``Profiler`` records)
+    and a ``jax.profiler.TraceAnnotation`` of the same name (kept
+    whenever a jax trace is running).  ``elapsed_ns`` holds the last
+    begin()..end() duration on ``time.perf_counter_ns``, so a caller
+    that also wants the time needs no clock reads of its own.
     """
 
     def __init__(self, name, event_type="UserDefined"):
         self.name = name
+        self.elapsed_ns = 0
         self._start = None
+        self._annotation = None
 
     def __enter__(self):
         self.begin()
@@ -160,13 +174,20 @@ class RecordEvent:
         return wrapper
 
     def begin(self):
+        self._annotation = TraceAnnotation(self.name)
+        self._annotation.__enter__()
         self._start = time.perf_counter_ns()
 
     def end(self):
         if self._start is None:
             return
-        _recorder.record(self.name, self._start, time.perf_counter_ns(),
-                         threading.get_ident())
+        end_ns = time.perf_counter_ns()
+        self._annotation.__exit__(None, None, None)
+        self._annotation = None
+        self.elapsed_ns = end_ns - self._start
+        if _recorder.enabled:
+            _recorder.record(self.name, self._start, end_ns,
+                             threading.get_ident())
         self._start = None
 
 

@@ -94,12 +94,12 @@ import jax.numpy as jnp
 
 from ..models.gpt import GPTConfig, gpt_init, gpt_ragged_step
 from ..observability.compile_watchdog import watch
-from ..observability.profiling import phase as profiling_phase
+from ..observability.profiling import pop_phase, push_phase
 from ..observability.tracing import Tracer, default_tracer
 from ..profiler.profiler import RecordEvent
 from ..resilience.faults import fault_point
 from .kv_cache import PagedKVCache
-from .metrics import ServingMetrics
+from .metrics import STEP_PHASES, ServingMetrics
 
 __all__ = ["SamplingParams", "Request", "RequestState", "Engine"]
 
@@ -168,6 +168,51 @@ class Request:
         self._chunks_done = 0
         self.state = RequestState.QUEUED
         self._rng = np.random.default_rng(self.sampling.seed)
+
+
+class _StepPhases:
+    """One ``Engine.step()`` call cut into ``STEP_PHASES``.
+
+    ``with phases.phase(name):`` is the one way the engine opens a
+    phase.  It feeds the three sinks the repo already has: a
+    ``RecordEvent`` named ``serving::step/<name>`` (the profiler's ring
+    while a ``Profiler`` records, and a ``jax.profiler`` trace whenever
+    one runs), the ``serving_step_phase_seconds{phase}`` histogram, and
+    — with ``tag`` — the ``StackSampler``'s phase marker.  Time is
+    ``RecordEvent``'s (``time.perf_counter_ns``), never the engine's
+    injectable clock.  Phases follow one another and never nest, so the
+    object is its own context manager.  ``close()`` observes every
+    phase exactly once, 0 for one the call did not reach, so the n-th
+    sample of every phase is the n-th call's.
+    """
+
+    _NAMES = {p: f"serving::step/{p}" for p in STEP_PHASES}
+
+    def __init__(self, series):
+        self._series = series
+        self._ns = dict.fromkeys(STEP_PHASES, 0)
+        self._name = self._event = self._tag = None
+
+    def phase(self, name, tag=None):
+        self._name, self._tag = name, tag
+        return self
+
+    def __enter__(self):
+        if self._tag is not None:
+            push_phase(self._tag)
+        self._event = RecordEvent(self._NAMES[self._name])
+        self._event.begin()
+
+    def __exit__(self, *exc):
+        self._event.end()
+        self._ns[self._name] += self._event.elapsed_ns
+        if self._tag is not None:
+            pop_phase()
+        return False
+
+    def close(self):
+        for name, ns in self._ns.items():
+            self._series[name].observe(ns * 1e-9)
 
 
 class Engine:
@@ -526,10 +571,6 @@ class Engine:
         return None
 
     def _try_admit(self):
-        with profiling_phase("admission"):
-            self._try_admit_inner()
-
-    def _try_admit_inner(self):
         while self._queue:
             slot = self._free_slot()
             if slot is None:
@@ -647,11 +688,44 @@ class Engine:
             if stable:
                 return plan
 
-    def _unified_step_once(self, plan):
+    def _unified_step_once(self, plan, phases):
         """Run the one jitted program over the planned ragged batch and
-        fold the results back into each request's lifecycle."""
+        sample every row that is owed a token: the phases pack, dispatch,
+        device_wait, fetch and sample of the call's ``_StepPhases``.
+        Returns ``_commit``'s arguments, or None when nothing ran."""
         if not plan:
-            return
+            return None
+        with phases.phase("pack"):
+            packed = self._pack(plan)
+        if packed is None:
+            return None
+        arrays, sched = packed
+        # phase attribution for the sampling profiler: a step with any
+        # mid-prefill row is a prefill chunk, else pure decode
+        step_phase = "prefill_chunk" if any(
+            req.prompt_pos < len(req.prompt)
+            for _, req, _, _ in sched) else "decode"
+        t0 = self._clock()
+        with RecordEvent("serving::unified_step"):
+            with phases.phase("dispatch", step_phase):
+                logits, k, v = self._step_fn(
+                    self.params, self.cache.k_pages, self.cache.v_pages,
+                    *(jnp.asarray(a) for a in arrays))
+            with phases.phase("device_wait", step_phase):
+                logits.block_until_ready()
+            with phases.phase("fetch", step_phase):
+                logits = np.asarray(logits)
+        self.cache.k_pages, self.cache.v_pages = k, v
+        t1 = self._clock()
+        with phases.phase("sample"):
+            sampled = self._sample_rows(logits, sched)
+        return sched, sampled, t0, t1
+
+    def _pack(self, plan):
+        """The planned rows packed row-major into the step's six host
+        arrays: ``((tokens, rows, slots, qlens, ctxs, tables), sched)``
+        with ``sched`` the ``(slot, req, q, new ctx)`` of every packed
+        row, or None when no row is left to run."""
         B, T = self.max_batch_size, self.token_budget
         tokens = np.zeros((T,), np.int32)
         rows = np.full((T,), B, np.int32)        # B marks padding slots
@@ -687,29 +761,35 @@ class Engine:
             sched.append((i, req, q, ctx))
             off += q
         if not sched:
-            return
-        # phase attribution for the sampling profiler: a step with any
-        # mid-prefill row is a prefill chunk, else pure decode
-        step_phase = "prefill_chunk" if any(
-            req.prompt_pos < len(req.prompt)
-            for _, req, _, _ in sched) else "decode"
-        t0 = self._clock()
-        with profiling_phase(step_phase), \
-                RecordEvent("serving::unified_step"):
-            logits, k, v = self._step_fn(
-                self.params, self.cache.k_pages, self.cache.v_pages,
-                jnp.asarray(tokens), jnp.asarray(rows),
-                jnp.asarray(slots), jnp.asarray(qlens),
-                jnp.asarray(ctxs), jnp.asarray(tables))
-            logits = np.asarray(logits)
-        self.cache.k_pages, self.cache.v_pages = k, v
-        t1 = self._clock()
+            return None
+        return (tokens, rows, slots, qlens, ctxs, tables), sched
+
+    def _sample_rows(self, logits, sched):
+        """{batch slot: next token} for every row whose context now
+        covers its prompt (a decode row, or the chunk that completed a
+        prompt).  A row whose sampling raises is retired FAILED here and
+        has no entry, like any other row-attributable failure."""
+        sampled = {}
+        for i, req, _, ctx in sched:
+            if ctx < len(req.prompt):
+                continue                     # more chunks to go
+            try:
+                sampled[i] = self._sample_token(logits[i], req)
+            except Exception as e:
+                self._fail(req, e)
+        return sampled
+
+    def _commit(self, sched, sampled, t0, t1):
+        """Fold one step's results into each request's lifecycle:
+        counters, flight-recorder spans, the sampled token, finish."""
         dt = t1 - t0
         occ = round(self.cache.occupancy(), 4)
         n_rows = len(sched)
-        sampled = 0
+        committed = 0
         for i, req, q, ctx in sched:
-            # per-row commit isolation: anything this row's sampling /
+            if req.state != RequestState.RUNNING:
+                continue                     # failed while sampling
+            # per-row commit isolation: anything this row's
             # bookkeeping raises is ITS failure — the row retires
             # FAILED, every other row in the batch commits normally
             try:
@@ -737,10 +817,9 @@ class Engine:
                     if self.prefix_cache:
                         self.cache.insert_prefix(req.id, req.prompt)
                     # the chunk that completed the prompt falls through
-                    # and samples the request's first token — TTFT
-                tok = self._sample_token(logits[i], req)
-                req.tokens.append(tok)
-                sampled += 1
+                    # and commits the request's first token — TTFT
+                req.tokens.append(sampled[i])
+                committed += 1
                 self.metrics.tokens_generated.inc()
                 if req.t_first_token is None:
                     # time-to-first-SAMPLED-token: stamped when the last
@@ -765,9 +844,9 @@ class Engine:
                 self._maybe_finish(req)
             except Exception as e:
                 self._fail(req, e)
-        if dt > 0 and sampled:
+        if dt > 0 and committed:
             # EWMA decode throughput feeds the drain/retry-after hint
-            inst = sampled / dt
+            inst = committed / dt
             a = self._ewma_alpha
             self._decode_rate_ewma = (
                 inst if self._decode_rate_ewma is None
@@ -826,30 +905,49 @@ class Engine:
         """One scheduler iteration: evict past-deadline requests, admit,
         run the unified ragged step (prompt chunks + decode rows in one
         batch), update gauges.  Returns requests that finished (or were
-        evicted) this step."""
-        # fault site: an io_error here is the whole step failing the way
-        # a crashed replica's RPC would — before any request state
-        # mutates, so a router can re-dispatch losslessly.  tree=
-        # exposes the live KV page pool to the bitflip kind (silent
-        # corruption of serving state) and tokens= exposes every
-        # in-flight request's stream to poison_request (the
-        # query-of-death: a seed-chosen pattern that kills whichever
-        # replica it is aboard — deliberately NOT row-attributable)
-        kv = {"k_pages": self.cache.k_pages, "v_pages": self.cache.v_pages}
-        fault_point("serving.step", tree=kv,
-                    tokens=[r.tokens for r in self._running()]
-                    + [r.tokens for r in self._queue])
-        self.cache.k_pages, self.cache.v_pages = kv["k_pages"], \
-            kv["v_pages"]
-        self._evict_expired()
-        self._try_admit()
-        self._unified_step_once(self._ensure_capacity())
-        self._update_shedding()
-        self.metrics.page_occupancy.set(self.cache.occupancy())
-        self.metrics.queue_depth.set(len(self._queue))
-        self.metrics.estimated_drain_s.set(self.estimated_drain_s())
-        self._sync_prefix_metrics()
-        done, self._just_finished = self._just_finished, []
+        evicted) this step.  The call is cut into ``STEP_PHASES``
+        (``_StepPhases``): one ``serving::step`` event around one
+        ``serving::step/<phase>`` event per phase, and one observation
+        of ``serving_step_phase_seconds`` per phase, also when it
+        raises."""
+        phases = _StepPhases(self.metrics.step_phases)
+        with RecordEvent("serving::step"):
+            try:
+                return self._step(phases)
+            finally:
+                phases.close()
+
+    def _step(self, phases):
+        with phases.phase("admit", "admission"):
+            # fault site: an io_error here is the whole step failing the
+            # way a crashed replica's RPC would — before any request
+            # state mutates, so a router can re-dispatch losslessly.
+            # tree= exposes the live KV page pool to the bitflip kind
+            # (silent corruption of serving state) and tokens= exposes
+            # every in-flight request's stream to poison_request (the
+            # query-of-death: a seed-chosen pattern that kills whichever
+            # replica it is aboard — deliberately NOT row-attributable)
+            kv = {"k_pages": self.cache.k_pages,
+                  "v_pages": self.cache.v_pages}
+            fault_point("serving.step", tree=kv,
+                        tokens=[r.tokens for r in self._running()]
+                        + [r.tokens for r in self._queue])
+            self.cache.k_pages, self.cache.v_pages = kv["k_pages"], \
+                kv["v_pages"]
+            self._evict_expired()
+            self._try_admit()
+        with phases.phase("plan", "admission"):
+            plan = self._ensure_capacity()
+        ran = self._unified_step_once(plan, phases)
+        with phases.phase("commit"):
+            if ran is not None:
+                self._commit(*ran)
+            self._update_shedding()
+            self.metrics.page_occupancy.set(self.cache.occupancy())
+            self.metrics.queue_depth.set(len(self._queue))
+            self.metrics.estimated_drain_s.set(self.estimated_drain_s())
+            self._sync_prefix_metrics()
+            done, self._just_finished = self._just_finished, []
         return done
 
     def _sync_prefix_metrics(self):

@@ -16,6 +16,11 @@ process-wide registry); this module keeps the serving-shaped facade:
   prefix_cache_* — radix prefix-cache hits / hit tokens / LRU
                  evictions (counters) + cached pages (gauge): every
                  hit token is prefill FLOPs the pool skipped
+  step_phase   — ``serving_step_phase_seconds{phase}``: every
+                 ``Engine.step()`` call cut into ``STEP_PHASES``, one
+                 observation per phase per call (0 for a phase the call
+                 did not reach), so the n-th sample of every phase
+                 belongs to the n-th call
 
 Every metric is registered (serving_-prefixed) into the default
 MetricsRegistry with replace semantics, so rebuilding ``ServingMetrics``
@@ -36,7 +41,12 @@ from ..observability.metrics import (  # noqa: F401  (re-export compat)
 )
 
 __all__ = ["Counter", "Gauge", "Histogram", "ServingMetrics",
-           "RouterMetrics", "AutoscalerMetrics"]
+           "RouterMetrics", "AutoscalerMetrics", "STEP_PHASES"]
+
+#: the phases of one ``Engine.step()`` call, in the order they run;
+#: contiguous, and together they cover the call
+STEP_PHASES = ("admit", "plan", "pack", "dispatch", "device_wait", "fetch",
+               "sample", "commit")
 
 
 class ServingMetrics:
@@ -109,6 +119,16 @@ class ServingMetrics:
         self.queue_wait = add(Histogram("serving_queue_wait_seconds"))
         self.ttft = add(Histogram("serving_ttft_seconds"))
         self.decode_token = add(Histogram("serving_decode_token_seconds"))
+        # buckets from a microsecond: most phases of a step are host
+        # code that takes tens of them
+        self.step_phase = add(Histogram(
+            "serving_step_phase_seconds", labelnames=("phase",),
+            start=1e-6, count=24,
+            help="seconds of one Engine.step() call spent in each of "
+                 "its phases: one observation per phase per call, 0 "
+                 "for a phase the call did not reach"))
+        self.step_phases = {p: self.step_phase.labels(phase=p)
+                            for p in STEP_PHASES}
         self.page_occupancy = add(Gauge("serving_page_occupancy"))
         self.queue_depth = add(Gauge(
             "serving_queue_depth",
@@ -145,6 +165,8 @@ class ServingMetrics:
             "queue_wait_s": self.queue_wait.summary(),
             "ttft_s": self.ttft.summary(),
             "decode_token_s": self.decode_token.summary(),
+            "step_phase_s": {p: h.summary()
+                             for p, h in self.step_phases.items()},
             "page_occupancy": {"current": self.page_occupancy.value,
                                "peak": self.page_occupancy.peak},
             "queue_depth": self.queue_depth.value,

@@ -414,7 +414,7 @@ class TestServingMetricsThinClient:
         assert m2.snapshot()["requests"]["submitted"] == 0
 
     def test_isolated_registry(self):
-        from paddle_tpu.serving.metrics import ServingMetrics
+        from paddle_tpu.serving.metrics import STEP_PHASES, ServingMetrics
 
         reg = MetricsRegistry()
         m = ServingMetrics(registry=reg)
@@ -423,10 +423,12 @@ class TestServingMetricsThinClient:
             "serving_tokens_generated_total"]["value"] == 5
         snap = m.snapshot()
         assert snap["tokens"]["generated"] == 5
-        assert set(snap) == {"requests", "tokens", "queue_wait_s",
-                             "ttft_s", "decode_token_s", "page_occupancy",
+        assert set(snap) == {"requests", "tokens", "prefix_cache",
+                             "queue_wait_s", "ttft_s", "decode_token_s",
+                             "step_phase_s", "page_occupancy",
                              "engine_healthy", "queue_depth",
                              "estimated_drain_s"}
+        assert tuple(snap["step_phase_s"]) == STEP_PHASES
 
 
 # ------------------------------------------------------------------- bench
