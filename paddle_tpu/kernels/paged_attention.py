@@ -24,17 +24,23 @@ elsewhere):
 
 - ``_ragged_attention_ref`` — pure-jnp gather + fp32 softmax.  Serves CPU
   tests and is the numerics oracle.
-- the Pallas kernel — grid (batch, pages_per_seq); the page table and the
-  two length vectors ride in scalar-prefetch (PrefetchScalarGridSpec) so
-  the BlockSpec index_map DMAs exactly the pages each row owns.  Page
-  steps are the innermost (sequential) grid axis; VMEM scratch carries
-  the online-softmax state (per query token × head) across them,
-  flash-attention style, with the causal mask applied relative to each
-  row's context offset.
+- the Pallas kernel — grid (batch, pages_per_seq); the page table, the
+  two length vectors and the layer ride in scalar-prefetch
+  (PrefetchScalarGridSpec) so the BlockSpec index_map DMAs exactly the
+  pages each row owns.  Page steps are the innermost (sequential) grid
+  axis; VMEM scratch carries the online-softmax state (per query token ×
+  head) across them, flash-attention style, with the causal mask applied
+  relative to each row's context offset.
 
 Layouts:
   q            [B, Q, H, hd]        Q = max query tokens per row, padded
-  k/v_pages    [P, page_size, H, hd] the shared page pool (one layer)
+  k/v_pages    [L, P, page_size, H, hd] every layer's page pool, stacked,
+               with ``layer`` (a traced int32 scalar) naming the one to
+               read: the serving step hands the kernel its whole donated
+               pool and the index_map picks ``[layer, page]`` blocks out
+               of HBM, so no layer's pages are ever sliced out or copied.
+               [P, page_size, H, hd] (one layer's pool, ``layer`` left
+               out) is the same kernel under a free ``[None]``.
   page_tables  [B, max_pages] int32  physical page id per logical page
   query_lens   [B] int32             valid query tokens (0 = idle row)
   context_lens [B] int32             kv tokens incl. this chunk
@@ -62,19 +68,30 @@ __all__ = ["paged_attention", "ragged_paged_attention"]
 _NEG_INF = -1e30
 
 
+def _stacked(k_pages, v_pages, layer):
+    """(k_pages, v_pages, layer) with the pool in its stacked layout: one
+    layer's pool is a stack of one, read at layer 0."""
+    if k_pages.ndim == 4:
+        return k_pages[None], v_pages[None], 0
+    return k_pages, v_pages, layer
+
+
 # ---------------------------------------------------------------- reference
 
 
 def _ragged_attention_ref(q, k_pages, v_pages, page_tables, query_lens,
-                          context_lens, scale):
+                          context_lens, scale, layer=None):
     """Gather-then-mask oracle: [B, max_kv] dense view of the pages with
     the per-row causal mask applied at each query token's absolute
     position."""
     B, Q, H, hd = q.shape
-    _, page_size, _, _ = k_pages.shape
+    k_pages, v_pages, layer = _stacked(k_pages, v_pages, layer)
+    page_size = k_pages.shape[2]
     max_pages = page_tables.shape[1]
-    k = jnp.take(k_pages, page_tables, axis=0)      # [B, M, ps, H, hd]
-    v = jnp.take(v_pages, page_tables, axis=0)
+    # one gather of the rows' pages out of the stacked pool: taking
+    # pool[layer] first would materialize a whole layer's pages
+    k = k_pages[layer, page_tables]                 # [B, M, ps, H, hd]
+    v = v_pages[layer, page_tables]
     k = k.reshape(B, max_pages * page_size, H, hd)
     v = v.reshape(B, max_pages * page_size, H, hd)
     s = jnp.einsum("bqhd,bthd->bhqt", q.astype(jnp.float32),
@@ -98,8 +115,10 @@ def _ragged_attention_ref(q, k_pages, v_pages, page_tables, query_lens,
 # ------------------------------------------------------------------- kernel
 
 
-def _ragged_kernel(tbl_ref, qlen_ref, ctx_ref, q_ref, kp_ref, vp_ref, o_ref,
-                   acc_ref, m_ref, l_ref, *, scale, page_size, num_pages):
+def _ragged_kernel(tbl_ref, qlen_ref, ctx_ref, layer_ref, q_ref, kp_ref,
+                   vp_ref, o_ref, acc_ref, m_ref, l_ref, *, scale, page_size,
+                   num_pages):
+    del layer_ref                     # only the page index_maps read it
     b = pl.program_id(0)
     j = pl.program_id(1)
 
@@ -116,8 +135,8 @@ def _ragged_kernel(tbl_ref, qlen_ref, ctx_ref, q_ref, kp_ref, vp_ref, o_ref,
     @pl.when((start < ctx) & (q_len > 0))
     def _body():
         q = q_ref[0].astype(jnp.float32)            # [Q, H, hd]
-        k = kp_ref[0].astype(jnp.float32)           # [ps, H, hd]
-        v = vp_ref[0].astype(jnp.float32)
+        k = kp_ref[0, 0].astype(jnp.float32)        # [ps, H, hd]
+        v = vp_ref[0, 0].astype(jnp.float32)
         Q = q.shape[0]
         # s[h, tq, t] = q[tq, h, :] . k[t, h, :]  (batch over heads)
         s = jax.lax.dot_general(
@@ -155,24 +174,28 @@ def _ragged_kernel(tbl_ref, qlen_ref, ctx_ref, q_ref, kp_ref, vp_ref, o_ref,
 
 
 def _ragged_attention_kernel(q, k_pages, v_pages, page_tables, query_lens,
-                             context_lens, scale, interpret):
+                             context_lens, scale, interpret, layer=None):
     B, Q, H, hd = q.shape
-    _, page_size, _, _ = k_pages.shape
+    k_pages, v_pages, layer = _stacked(k_pages, v_pages, layer)
+    page_size = k_pages.shape[2]
     max_pages = page_tables.shape[1]
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
+
+    def q_block(b, j, tbl, ql, cl, lyr):
+        return (b, 0, 0, 0)
+
+    def page_block(b, j, tbl, ql, cl, lyr):
+        return (lyr[0], tbl[b, j], 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(B, max_pages),
         in_specs=[
-            pl.BlockSpec((1, Q, H, hd),
-                         lambda b, j, tbl, ql, cl: (b, 0, 0, 0)),
-            pl.BlockSpec((1, page_size, H, hd),
-                         lambda b, j, tbl, ql, cl: (tbl[b, j], 0, 0, 0)),
-            pl.BlockSpec((1, page_size, H, hd),
-                         lambda b, j, tbl, ql, cl: (tbl[b, j], 0, 0, 0)),
+            pl.BlockSpec((1, Q, H, hd), q_block),
+            pl.BlockSpec((1, 1, page_size, H, hd), page_block),
+            pl.BlockSpec((1, 1, page_size, H, hd), page_block),
         ],
-        out_specs=pl.BlockSpec((1, Q, H, hd),
-                               lambda b, j, tbl, ql, cl: (b, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, Q, H, hd), q_block),
         scratch_shapes=[
             pltpu.VMEM((H, Q, hd), jnp.float32),
             pltpu.VMEM((H, Q, 1), jnp.float32),
@@ -186,19 +209,24 @@ def _ragged_attention_kernel(q, k_pages, v_pages, page_tables, query_lens,
         out_shape=jax.ShapeDtypeStruct((B, Q, H, hd), q.dtype),
         interpret=interpret,
         name="ragged_paged_attention",
-    )(page_tables, query_lens, context_lens, q, k_pages, v_pages)
+    )(page_tables, query_lens, context_lens, layer, q, k_pages, v_pages)
 
 
 # -------------------------------------------------------------- public API
 
 
 def ragged_paged_attention(q, k_pages, v_pages, page_tables, query_lens,
-                           context_lens, scale=None, path=None):
+                           context_lens, scale=None, path=None, layer=None):
     """Fused prefill+decode attention over a paged KV cache (see module
-    docstring for layouts).  ``path`` is one of ``dispatch.MOSAIC`` /
+    docstring for layouts).  ``layer`` (a traced int32 scalar) comes with
+    a stacked ``[L, P, page_size, H, hd]`` pool and names the layer whose
+    pages are read.  ``path`` is one of ``dispatch.MOSAIC`` /
     ``INTERPRET`` / ``REFERENCE``; ``None`` takes the Mosaic kernel on a
     TPU and the jnp gather reference elsewhere (identical contract, fp32
     softmax in both)."""
+    if (k_pages.ndim == 5) != (layer is not None):
+        raise ValueError("a stacked [L, P, page_size, H, hd] pool comes "
+                         "with its `layer`, a one-layer pool without")
     path = dispatch.resolve_path(path, off_tpu=dispatch.REFERENCE)
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
@@ -207,10 +235,11 @@ def ragged_paged_attention(q, k_pages, v_pages, page_tables, query_lens,
     context_lens = context_lens.astype(jnp.int32)
     if path == dispatch.REFERENCE:
         return _ragged_attention_ref(q, k_pages, v_pages, page_tables,
-                                     query_lens, context_lens, scale)
+                                     query_lens, context_lens, scale, layer)
     return _ragged_attention_kernel(q, k_pages, v_pages, page_tables,
                                     query_lens, context_lens, scale,
-                                    interpret=(path == dispatch.INTERPRET))
+                                    interpret=(path == dispatch.INTERPRET),
+                                    layer=layer)
 
 
 def paged_attention(q, k_pages, v_pages, page_tables, seq_lens, scale=None,

@@ -336,16 +336,12 @@ def gpt_loss(cfg: GPTConfig, params, tokens, labels=None, dropout_key=None):
 # This is what kills the prefill/decode phase split: a prompt is N
 # bounded-size chunk rows interleaved with decode rows, not one
 # batch-stalling full-sequence pass.  Pages are stacked
-# [L, P, page_size, H, hd] so the layer loop stays a lax.scan (pages
-# ride as per-layer xs/ys), mirroring gpt_forward.
-
-
-def _paged_write(pages, page_idx, slot_idx, vals):
-    """Scatter vals [..., H, hd] into pages [P, ps, H, hd] at
-    (page_idx, slot_idx); indices already routed out-of-bounds for
-    masked-out positions, which mode="drop" discards."""
-    return pages.at[page_idx, slot_idx].set(vals.astype(pages.dtype),
-                                            mode="drop")
+# [L, P, page_size, H, hd] and the layer loop is a lax.scan over
+# (blocks, layer index) that CARRIES both pools: a layer scatters its
+# tokens' K/V into [layer, page, slot] and the kernel reads [layer, page]
+# blocks, so the donated pools stay where they are from entry to return.
+# Carried, not xs/ys: as xs/ys XLA slices every layer's pages out,
+# restacks them and copies both pools, whole, every step.
 
 
 def gpt_ragged_step(cfg: GPTConfig, params, tokens, row_of_token,
@@ -405,34 +401,44 @@ def gpt_ragged_step(cfg: GPTConfig, params, tokens, row_of_token,
 
     from ..kernels.paged_attention import ragged_paged_attention
 
-    attend = functools.partial(ragged_paged_attention, path=attn_path)
+    def attend(q, kp, vp, tables, q_lens, ctx_lens, layer):
+        return ragged_paged_attention(q, kp, vp, tables, q_lens, ctx_lens,
+                                      path=attn_path, layer=layer)
+
     if mesh is not None:
         # heads that do not divide stay whole on every shard, as
         # mesh.resolve_spec leaves the page pool
-        heads = jax.sharding.PartitionSpec(
-            None, None, "mp" if H % mesh.shape["mp"] == 0 else None, None)
+        mp = "mp" if H % mesh.shape["mp"] == 0 else None
+        heads = jax.sharding.PartitionSpec(None, None, mp, None)
+        pool = jax.sharding.PartitionSpec(None, None, None, mp, None)
         rep = jax.sharding.PartitionSpec()
         attend = jax.shard_map(
-            attend, mesh=mesh, in_specs=(heads, heads, heads, rep, rep, rep),
+            attend, mesh=mesh,
+            in_specs=(heads, pool, pool, rep, rep, rep, rep),
             out_specs=heads, check_vma=False)
 
-    def body(x, xs):
-        bp, kp, vp = xs
+    def body(carry, xs):
+        x, kp, vp = carry
+        bp, layer = xs
         with jax.named_scope("attn"):
             h = _layer_norm(x, bp["ln1_g"], bp["ln1_b"])
             qkv = jnp.einsum("td,de->te", h, bp["qkv_w"]) + bp["qkv_b"]
             qkv = qkv.reshape(T, H, 3, hd)
             q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]  # [T, H, hd]
             with jax.named_scope("kv_write"):
-                kp = _paged_write(kp, safe_page, slot_in_page, k)
-                vp = _paged_write(vp, safe_page, slot_in_page, v)
+                # masked-out tokens were routed to page P: out of bounds,
+                # which mode="drop" discards
+                kp = kp.at[layer, safe_page, slot_in_page].set(
+                    k.astype(kp.dtype), mode="drop")
+                vp = vp.at[layer, safe_page, slot_in_page].set(
+                    v.astype(vp.dtype), mode="drop")
             # the kernel wants per-row padded queries; scatter the packed
             # tokens out, gather the outputs back flat (padding slots
             # read zeros/junk that never reaches pages or logits)
             q_pad = jnp.zeros((B, Q, H, hd), q.dtype) \
                 .at[scat_row, scat_slot].set(q, mode="drop")
             attn = attend(q_pad, kp, vp, page_tables, query_lens,
-                          context_lens)
+                          context_lens, layer)
             attn = attn[row_c, scat_slot].reshape(T, D).astype(x.dtype)
             x = x + jnp.einsum("td,de->te", attn, bp["proj_w"]) \
                 + bp["proj_b"]
@@ -448,14 +454,15 @@ def gpt_ragged_step(cfg: GPTConfig, params, tokens, row_of_token,
                      "down_b": bp["down_b"]},
                     h[None], top_k=cfg.moe_top_k,
                     capacity_factor=cfg.moe_capacity_factor)
-                return x + y[0], (kp, vp)
+                return (x + y[0], kp, vp), None
             h = jnp.einsum("td,df->tf", h, bp["up_w"]) + bp["up_b"]
             h = jax.nn.gelu(h, approximate=True)
             h = jnp.einsum("tf,fd->td", h, bp["down_w"]) + bp["down_b"]
-            return x + h, (kp, vp)
+            return (x + h, kp, vp), None
 
-    x, (k_pages, v_pages) = jax.lax.scan(
-        body, x, (params["blocks"], k_pages, v_pages))
+    (x, k_pages, v_pages), _ = jax.lax.scan(
+        body, (x, k_pages, v_pages),
+        (params["blocks"], jnp.arange(cfg.num_layers, dtype=jnp.int32)))
     with jax.named_scope("lm_head"):
         x = _layer_norm(x, params["lnf_g"], params["lnf_b"])
         # row b's last packed token sits at cumsum(query_lens)[b] - 1

@@ -6,8 +6,9 @@ fixed-size pages drawn from one shared pool, a per-sequence page table
 maps logical token positions to physical pages, and sequences of wildly
 different lengths share the pool with at most page_size-1 slots of waste
 each.  The pool is a single stacked array [L, P, page_size, H, hd]
-(layer-major so the model's lax.scan over layers consumes it as per-layer
-xs/ys), bf16 by default.
+(layer-major: the model's lax.scan over layers carries it whole and each
+layer writes and reads its own [layer, page] blocks in place), bf16 by
+default.
 
 Allocation is chunk-granular: the engine's chunked-prefill scheduler
 ``allocate``s only a prompt's first chunk at admission and ``extend``s

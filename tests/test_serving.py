@@ -13,8 +13,10 @@ import jax.numpy as jnp
 from paddle_tpu.kernels import dispatch
 from paddle_tpu.kernels.paged_attention import (_ragged_attention_kernel,
                                                 _ragged_attention_ref,
-                                                paged_attention)
-from paddle_tpu.models.gpt import GPT_CONFIGS, gpt_forward, gpt_init
+                                                paged_attention,
+                                                ragged_paged_attention)
+from paddle_tpu.models.gpt import (GPT_CONFIGS, _layer_norm, gpt_forward,
+                                   gpt_init, gpt_ragged_step)
 from paddle_tpu.serving import (Engine, PagedKVCache, RequestState,
                                 SamplingParams)
 
@@ -247,6 +249,132 @@ class TestRaggedAttention:
                                        scale, interpret=True)[:, 0]
         np.testing.assert_allclose(np.asarray(dec), np.asarray(rag),
                                    rtol=1e-6, atol=1e-6)
+
+    @pytest.mark.parametrize("path", [dispatch.REFERENCE, dispatch.INTERPRET])
+    @pytest.mark.parametrize("qlens,ctxs", [((5, 1, 3, 0), (14, 6, 3, 0)),
+                                            ((6, 6, 1, 1), (7, 8, 9, 24)),
+                                            ((1, 1, 1, 1), (4, 5, 16, 17))])
+    def test_stacked_pool_layer_equals_one_layer_call(self, path, qlens,
+                                                      ctxs):
+        """A stacked [L, P, ps, H, hd] pool read at ``layer`` (the serving
+        step's call: the pool is never sliced) is bit for bit the
+        one-layer call on ``pool[layer]``, and the kernel still matches
+        the reference there."""
+        q, kp, vp, tables, ql, cl = self._case(list(qlens), list(ctxs))
+        ks = jax.random.split(jax.random.key(3), 2)
+        kp5 = jnp.stack([kp, *jax.random.normal(ks[0], (2, *kp.shape))])
+        vp5 = jnp.stack([vp, *jax.random.normal(ks[1], (2, *vp.shape))])
+        stacked = jax.jit(lambda l: ragged_paged_attention(
+            q, kp5, vp5, tables, ql, cl, path=path, layer=l))
+        outs = []
+        for l in range(3):
+            one = ragged_paged_attention(q, kp5[l], vp5[l], tables, ql, cl,
+                                         path=path)
+            outs.append(np.asarray(stacked(jnp.int32(l))))
+            np.testing.assert_array_equal(outs[-1], np.asarray(one))
+            np.testing.assert_allclose(
+                outs[-1], np.asarray(_ragged_attention_ref(
+                    q, kp5[l], vp5[l], tables, ql, cl,
+                    1.0 / np.sqrt(q.shape[-1]))), rtol=2e-5, atol=2e-5)
+        assert not np.array_equal(outs[0], outs[1])  # the layer is read
+
+    def test_pool_and_layer_come_together(self):
+        q, kp, vp, tables, ql, cl = self._case([1, 1], [4, 5])
+        with pytest.raises(ValueError, match="layer"):
+            ragged_paged_attention(q, kp[None], vp[None], tables, ql, cl)
+        with pytest.raises(ValueError, match="layer"):
+            ragged_paged_attention(q, kp, vp, tables, ql, cl, layer=0)
+
+
+# ------------------------------------------ the step against its oracle
+
+
+def _ragged_step_oracle(cfg, params, tokens, row_of_token, slot_of_token,
+                        query_lens, context_lens, k_pages, v_pages,
+                        page_tables, max_q, path):
+    """``gpt_ragged_step`` (dense branch) written the plain way: a Python
+    loop over layers, each writing its tokens into its own
+    [P, ps, H, hd] pool and attending on it through the public one-layer
+    API, the pools restacked at the end."""
+    T, B = tokens.shape[0], query_lens.shape[0]
+    H, hd, D = cfg.num_heads, cfg.head_dim, cfg.hidden
+    P, page_size = k_pages.shape[1], k_pages.shape[2]
+    row_c = jnp.minimum(row_of_token, B - 1)
+    valid = (row_of_token < B) & (slot_of_token < query_lens[row_c])
+    pos = jnp.clip((context_lens - query_lens)[row_c] + slot_of_token, 0,
+                   cfg.max_seq_len - 1)
+    x = (params["wte"][tokens] + params["wpe"][pos]).astype(cfg.jdtype())
+    page = jnp.where(valid, page_tables[row_c, pos // page_size], P)
+    slot = pos % page_size
+    scat_row = jnp.where(valid, row_c, B)
+    scat_slot = jnp.minimum(slot_of_token, max_q - 1)
+    k_out, v_out = [], []
+    for l in range(cfg.num_layers):
+        bp = jax.tree_util.tree_map(lambda a: a[l], params["blocks"])
+        h = _layer_norm(x, bp["ln1_g"], bp["ln1_b"])
+        qkv = (jnp.einsum("td,de->te", h, bp["qkv_w"])
+               + bp["qkv_b"]).reshape(T, H, 3, hd)
+        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+        kp = k_pages[l].at[page, slot].set(k, mode="drop")
+        vp = v_pages[l].at[page, slot].set(v, mode="drop")
+        k_out.append(kp)
+        v_out.append(vp)
+        q_pad = jnp.zeros((B, max_q, H, hd), q.dtype) \
+            .at[scat_row, scat_slot].set(q, mode="drop")
+        attn = ragged_paged_attention(q_pad, kp, vp, page_tables, query_lens,
+                                      context_lens, path=path)
+        attn = attn[row_c, scat_slot].reshape(T, D)
+        x = x + jnp.einsum("td,de->te", attn, bp["proj_w"]) + bp["proj_b"]
+        h = _layer_norm(x, bp["ln2_g"], bp["ln2_b"])
+        h = jnp.einsum("td,df->tf", h, bp["up_w"]) + bp["up_b"]
+        h = jax.nn.gelu(h, approximate=True)
+        x = x + jnp.einsum("tf,fd->td", h, bp["down_w"]) + bp["down_b"]
+    x = _layer_norm(x, params["lnf_g"], params["lnf_b"])
+    last = jnp.clip(jnp.cumsum(query_lens) - 1, 0, T - 1)
+    logits = jnp.einsum("bd,vd->bv", x[last], params["wte"])
+    return logits, jnp.stack(k_out), jnp.stack(v_out)
+
+
+@pytest.mark.parametrize("path", [dispatch.REFERENCE, dispatch.INTERPRET])
+def test_ragged_step_equals_per_layer_oracle(tiny_model, path):
+    """The step that carries the stacked pools through its layer scan
+    (scatter into [layer, page, slot], the kernel on [layer, page]
+    blocks) returns logits and both pools bit for bit equal to the
+    per-layer oracle.  Rows: a mid-prompt chunk, a decode row, an idle
+    row, and a chunk that crosses a page boundary."""
+    cfg, params = tiny_model
+    assert cfg.tie_embeddings and not cfg.moe_experts
+    B, P, ps, M, Q, T = 4, 16, 4, 4, 6, 14
+    qlens = np.array([5, 1, 0, 6], np.int32)
+    ctxs = np.array([9, 7, 0, 10], np.int32)   # row 3: positions 4..9
+    rng = np.random.RandomState(1)
+    tables = rng.permutation(P).reshape(B, M).astype(np.int32)
+    tokens = np.zeros(T, np.int32)
+    rows = np.full(T, B, np.int32)             # == B marks a padding slot
+    slots = np.zeros(T, np.int32)
+    n = int(qlens.sum())
+    tokens[:n] = rng.randint(0, cfg.vocab_size, n)
+    rows[:n] = np.repeat(np.arange(B), qlens)
+    slots[:n] = np.concatenate([np.arange(q) for q in qlens])
+    pool = (cfg.num_layers, P, ps, cfg.num_heads, cfg.head_dim)
+    ks = jax.random.split(jax.random.key(5), 2)
+    k_pages = jax.random.normal(ks[0], pool, jnp.float32)
+    v_pages = jax.random.normal(ks[1], pool, jnp.float32)
+    args = [jnp.asarray(a) for a in (tokens, rows, slots, qlens, ctxs)] \
+        + [k_pages, v_pages, jnp.asarray(tables)]
+
+    got = jax.jit(lambda p, *a: gpt_ragged_step(
+        cfg, p, *a, max_q=Q, attn_path=path))(params, *args)
+    want = jax.jit(lambda p, *a: _ragged_step_oracle(
+        cfg, p, *a, max_q=Q, path=path))(params, *args)
+    live = qlens > 0                # an idle row's logits are garbage
+    np.testing.assert_array_equal(np.asarray(got[0])[live],
+                                  np.asarray(want[0])[live])
+    for new, ref, old in zip(got[1:], want[1:], (k_pages, v_pages)):
+        np.testing.assert_array_equal(np.asarray(new), np.asarray(ref))
+        # every layer wrote its 12 tokens and nothing else
+        changed = np.any(np.asarray(new) != np.asarray(old), axis=(3, 4))
+        assert changed.sum(axis=(1, 2)).tolist() == [n] * cfg.num_layers
 
 
 # ------------------------------------------------- continuous batching

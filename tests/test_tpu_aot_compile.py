@@ -1,12 +1,16 @@
-"""The Pallas kernels compile for a TPU v5e — checked on the CPU host.
+"""The Pallas kernels and the serving step compile for a TPU v5e — checked
+on the CPU host.
 
 No chip is needed: ``tools.tpu_aot`` lowers against
 ``jax.experimental.topologies``' description of a v5e host and runs the
 installed libtpu's compiler, Mosaic included, with the kernel path forced
 to Mosaic through the kernels' explicit ``path=`` argument.  This keeps
-"the kernels compile at real shapes" true on every PR at no chip cost;
-that they *compute* the right thing on the chip is ``chip_smoke.py``.
+"the kernels compile at real shapes" and "the serving step leaves its page
+pools where they are" true on every PR at no chip cost; that they
+*compute* the right thing on the chip is ``chip_smoke.py``.
 """
+import re
+
 import pytest
 
 pytest.importorskip("libtpu")
@@ -14,12 +18,71 @@ pytest.importorskip("libtpu")
 from tools import tpu_aot  # noqa: E402
 
 
-def test_kernels_compile_for_v5e():
-    device = tpu_aot.topology_devices()[0]
+@pytest.fixture(scope="module")
+def devices():
+    return tpu_aot.topology_devices()
+
+
+def test_kernels_compile_for_v5e(devices):
+    device = devices[0]
     assert (device.platform, device.device_kind) == ("tpu", "TPU v5 lite")
     compiled = tpu_aot.compile_kernels(device)
-    assert set(compiled) == {"ragged_q64", "ragged_q1", "flash_hd64",
-                             "flash_hd128"}
+    assert set(compiled) == {"ragged_q64", "ragged_q1", "ragged_stacked",
+                             "flash_hd64", "flash_hd128"}
     for name, c in compiled.items():
         assert "tpu_custom_call" in c.as_text(), \
             f"{name}: no Mosaic kernel in the compiled program"
+
+
+def test_serve_step_moves_no_page_pool(devices):
+    """The compiled serving step touches its two donated page pools with
+    one in-place scatter each and the kernel, and nothing else: no copy,
+    slice, update-slice or fusion produces a pool, or one layer's pages,
+    alone or inside a tuple-shaped result (as the layer scan's xs/ys did:
+    4 x 64 MiB a layer and two 1.5 GiB copies a step at the benchmark's
+    1024 pages)."""
+    pages = 64
+    compiled = tpu_aot.lower_serve_step(
+        devices, num_pages=pages, max_batch_size=16, chunk_len=128).compile()
+    text = compiled.as_text()
+    from paddle_tpu.models.gpt import GPT_CONFIGS
+
+    cfg = GPT_CONFIGS["gpt3-1.3b"]              # pages of 16 positions
+    L, H, hd = cfg.num_layers, cfg.num_heads, cfg.head_dim
+    pool_like = {f"[{L},{pages},16,{H},{hd}]", f"[1,{pages},16,{H},{hd}]",
+                 f"[{pages},16,{H},{hd}]"}
+
+    # fused computation name -> the opcode of its ROOT
+    roots, current = {}, None
+    for line in text.splitlines():
+        head = re.match(r"%(\S+) \(.*\) -> .* \{$", line)
+        if head:
+            current = head.group(1)
+        root = re.match(r"\s+ROOT %\S+ = \S+ ([\w-]+)\(", line)
+        if root and current:
+            roots[current] = root.group(1)
+
+    # opcodes that hand a buffer on without moving it (the layer loop and
+    # its tuples carry the pools), and the scatter inside the two fusions
+    free = {"parameter", "get-tuple-element", "bitcast", "tuple", "while",
+            "scatter"}
+    movers, scatter_fusions = [], []
+    for line in text.splitlines():
+        # the whole result type, so that a pool inside a tuple-shaped
+        # result (a multi-output fusion) is seen too
+        m = re.match(r"\s+(?:ROOT )?%(\S+) = (.*?) ([\w-]+)\(", line)
+        if (not m or m.group(3) in free
+                or not any(shape in m.group(2) for shape in pool_like)):
+            continue
+        called = re.search(r"calls=%([^\s,]+)", line)
+        if (m.group(3) == "fusion" and called
+                and roots.get(called.group(1)) == "scatter"):
+            scatter_fusions.append(m.group(1))
+        else:
+            movers.append(line.strip()[:160])
+    assert not movers, "pool-sized movers:\n" + "\n".join(movers)
+    assert len(scatter_fusions) == 2, scatter_fusions
+
+    one_layer = pages * 16 * H * hd * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < one_layer
+    assert text.count('custom_call_target="tpu_custom_call"') == 1

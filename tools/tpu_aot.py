@@ -8,7 +8,9 @@ cost; whether it *executes* correctly only ``chip_smoke.py`` can say.
 
 - ``tests/test_tpu_aot_compile.py`` (tier-1) compiles the Pallas kernels
   through :func:`compile_kernels`, the kernel path forced to Mosaic by the
-  kernels' explicit ``path=`` argument.
+  kernels' explicit ``path=`` argument, and the serving step at a small
+  pool through :func:`lower_serve_step`, to see that nothing in it moves
+  a page pool.
 - ``python -m tools.tpu_aot`` also compiles the two whole steps
   ``chip_smoke.py`` runs, at its shapes, and prints XLA's memory analysis:
   the check to make before spending a chip call.
@@ -42,8 +44,10 @@ def _on(sharding, *shape_dtypes):
 
 def compile_kernels(device):
     """{name: Compiled} for the ragged kernel at H16 x hd128 (a prefill
-    chunk of 64 and a decode row) and flash forward+backward at head dims
-    64 and 128 — the shapes ``gpt3-1.3b``/``gpt2-medium`` give them."""
+    chunk of 64 and a decode row on one layer's pool, and the serving
+    step's call: a layer of a stacked pool) and flash forward+backward at
+    head dims 64 and 128 — the shapes ``gpt3-1.3b``/``gpt2-medium`` give
+    them."""
     from paddle_tpu.kernels import dispatch
     from paddle_tpu.kernels.flash_attention import flash_attention
     from paddle_tpu.kernels.paged_attention import ragged_paged_attention
@@ -53,13 +57,18 @@ def compile_kernels(device):
     out = {}
 
     B, H, hd, pages, ps, max_pages = 8, 16, 128, 256, 16, 128
-    ragged = jax.jit(lambda *a: ragged_paged_attention(
-        *a, path=dispatch.MOSAIC))
+    ragged = jax.jit(lambda *a, layer=None: ragged_paged_attention(
+        *a, path=dispatch.MOSAIC, layer=layer))
+
+    def ragged_args(Q, *stack):
+        pool = ((*stack, pages, ps, H, hd), bf16)
+        return _on(one, ((B, Q, H, hd), bf16), pool, pool,
+                   ((B, max_pages), i32), ((B,), i32), ((B,), i32))
+
     for Q in (64, 1):
-        out[f"ragged_q{Q}"] = ragged.lower(*_on(
-            one, ((B, Q, H, hd), bf16), ((pages, ps, H, hd), bf16),
-            ((pages, ps, H, hd), bf16), ((B, max_pages), i32), ((B,), i32),
-            ((B,), i32))).compile()
+        out[f"ragged_q{Q}"] = ragged.lower(*ragged_args(Q)).compile()
+    out["ragged_stacked"] = ragged.lower(
+        *ragged_args(64, 4), layer=_on(one, ((), i32))[0]).compile()
 
     def flash_loss(q, k, v):
         return flash_attention(q, k, v, causal=True,
@@ -172,13 +181,14 @@ def _report(name, compile_fn):
 def main(argv):
     devices = topology_devices()
     ok = True
-    ok &= _report("kernels (ragged q64/q1, flash hd64/hd128; memory is "
-                  "the last one's)",
+    ok &= _report("kernels (ragged q64/q1/stacked, flash hd64/hd128; memory "
+                  "is the last one's)",
                   lambda: list(compile_kernels(devices[0]).values())[-1])
     ok &= _report("train 1.3b b8xs2048 one chip",
                   lambda: lower_train_step(devices[:1]).compile())
-    ok &= _report("serve 1.3b B8 chunk128 1024 pages",
-                  lambda: lower_serve_step(devices).compile())
+    ok &= _report("serve 1.3b B16 chunk128 1024 pages",
+                  lambda: lower_serve_step(devices,
+                                           max_batch_size=16).compile())
     if "--four" in argv:
         ok &= _report("train pp=2 x mp=2",
                       lambda: lower_train_step(devices, pp=2, mp=2).compile())
