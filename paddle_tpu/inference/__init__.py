@@ -87,18 +87,22 @@ class Config:
     # ---- autoregressive generation (serving engine) ----
     def enable_generation(self, model_config, params=None, *, page_size=16,
                           num_pages=256, max_batch_size=4, chunk_len=None,
-                          prefill_len=None, prefix_cache=True):
+                          prefill_len=None, prefix_cache=None):
         """Switch create_predictor to a GenerationPredictor: a
-        continuous-batching, paged-KV-cache generation engine
-        (paddle_tpu.serving) over the given GPTConfig.  params defaults
-        to fresh gpt_init weights; page_size/num_pages size the KV page
-        pool, max_batch_size the in-flight batch.  chunk_len bounds the
+        continuous-batching, paged-cache generation engine
+        (paddle_tpu.serving) over the given model — a served-model
+        object (``paddle_tpu.serving.model``) or a config it can wrap
+        (``GPTConfig``, ``HybridConfig``).  params defaults to the
+        model's own fresh weights; page_size/num_pages size the page
+        pools, max_batch_size the in-flight batch.  chunk_len bounds the
         prompt tokens any request contributes to one unified step
         (chunked prefill — prompts of any admissible length are split
         into chunk_len-token rows scheduled next to decode rows;
         prefill_len is the accepted legacy alias).  prefix_cache
-        (default on) enables radix prefix reuse: a prompt sharing a
-        cached prefix skips that prefill entirely, token-identically."""
+        enables radix prefix reuse: a prompt sharing a cached prefix
+        skips that prefill entirely, token-identically.  It defaults to
+        on, except for a model with recurrent layers, which is served
+        cold and for which ``True`` is refused."""
         self.generation = {
             "config": model_config, "params": params,
             "knobs": {"page_size": page_size, "num_pages": num_pages,

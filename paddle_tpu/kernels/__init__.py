@@ -4,8 +4,11 @@ Role parity: the reference's hand-fused CUDA ops (paddle/fluid/operators/
 fused/ — fused_attention_op.cu, fused_multi_transformer_op.cu) and its
 jit'ed CPU math (operators/math/jit).  On TPU, XLA already fuses elementwise
 chains into matmuls, so only genuinely structured kernels live here:
-flash attention (+ring variant for sequence parallelism) and the
-paged-attention decode kernel behind the serving engine's KV cache.
+flash attention (+ring variant for sequence parallelism), the ragged
+paged-attention kernel behind the serving engine's KV cache (equal heads
+over every page, or grouped heads over selected pages) and the lightning
+(decayed linear) attention kernel over a per-row recurrent state.
 """
 from .flash_attention import flash_attention, flash_attention_available  # noqa: F401
+from .lightning_attention import lightning_attention  # noqa: F401
 from .paged_attention import paged_attention, ragged_paged_attention  # noqa: F401

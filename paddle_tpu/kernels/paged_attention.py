@@ -50,6 +50,30 @@ return zeros.
 ``paged_attention`` (the original decode-only entry: one query token per
 row, ``seq_lens`` masking) is the Q == 1 degenerate case of the same
 entry.
+
+Grouped heads and selected pages (``selected=``, block-sparse attention
+with grouped-query heads).  The pool is then head-major,
+``[L, P, Hkv, page_size, hd]`` with ``Hkv`` dividing ``H``: one page of
+one key/value head is one contiguous ``[page_size, hd]`` block, shared by
+the ``H / Hkv`` query heads of its group.  ``selected = (sel_blocks,
+dense_len)``: ``sel_blocks [B, Hkv, Q, K]`` int32 names, for every query
+token and group, the logical pages (blocks of ``page_size`` positions)
+that token attends over, -1 for none; a token whose context
+(position + 1) is at most ``dense_len`` ignores its list and attends
+causally over every position (``sel_blocks=None``: every token does).
+A third entry, ``sel_mask [B, Hkv, Q, W]`` bool, may give the same lists
+as flags over the ``W`` logical pages (a superset is fine: it only decides
+which pages are fetched, the lists decide what a token reads); without it
+the flags are scattered from the lists.
+So one call serves dense rows, sparse decode rows and sparse prefill
+chunks, each chunk token with its own blocks.  The kernel walks a flat
+list of (row, group, 128-token query tile, page) items built from the
+lists with plain ``jnp``: a page that no token of a tile selected is
+never fetched, the rest are masked per token inside the kernel, and the
+grid is as long as the list (a dynamic grid bound), so a decode row
+costs its K pages and not the width of the page table.  This path is
+chosen statically by ``selected is not None``; the equal-heads,
+full-page-table kernel above is untouched by it.
 """
 from __future__ import annotations
 
@@ -212,18 +236,239 @@ def _ragged_attention_kernel(q, k_pages, v_pages, page_tables, query_lens,
     )(page_tables, query_lens, context_lens, layer, q, k_pages, v_pages)
 
 
+# ------------------------------------------ grouped heads, selected pages
+
+
+def _token_positions(Q, query_lens, context_lens):
+    """(pos [B, Q] absolute position of each padded query slot, valid)."""
+    tq = jnp.arange(Q)
+    pos = (context_lens - query_lens)[:, None] + tq[None, :]
+    return pos, tq[None, :] < query_lens[:, None]
+
+
+def _selected_onehot(sel_blocks, W):
+    """[B, Hkv, Q, W] bool: logical page w is in the token's list."""
+    B, Hkv, Q, K = sel_blocks.shape
+    idx = jnp.where(sel_blocks < 0, W, sel_blocks)       # -1 => dropped
+    b, h, t = jnp.meshgrid(jnp.arange(B), jnp.arange(Hkv), jnp.arange(Q),
+                           indexing="ij")
+    return jnp.zeros((B, Hkv, Q, W), bool).at[
+        b[..., None], h[..., None], t[..., None], idx].set(True, mode="drop")
+
+
+def _listed_attention_ref(q, k_pages, v_pages, page_tables, query_lens,
+                          context_lens, scale, layer, sel_blocks, dense_len):
+    """Gather-then-mask oracle of the grouped / selected mode."""
+    B, Q, H, hd = q.shape
+    Hkv, page_size = k_pages.shape[2], k_pages.shape[3]
+    G, W = H // Hkv, page_tables.shape[1]
+    T = W * page_size
+    gather = lambda pool: pool[layer, page_tables].transpose(
+        0, 2, 1, 3, 4).reshape(B, Hkv, T, hd).astype(jnp.float32)
+    k, v = gather(k_pages), gather(v_pages)
+    qg = q.astype(jnp.float32).reshape(B, Q, Hkv, G, hd)
+    s = jnp.einsum("bqhgd,bhtd->bhgqt", qg, k) * scale
+    pos, valid = _token_positions(Q, query_lens, context_lens)
+    t = jnp.arange(T)
+    ok = (t[None, None, :] <= pos[:, :, None]) & valid[:, :, None]
+    ok = jnp.broadcast_to(ok[:, None], (B, Hkv, Q, T))
+    if sel_blocks is not None:
+        allowed = (_selected_onehot(sel_blocks, W)
+                   | (pos + 1 <= dense_len)[:, None, :, None])
+        ok = ok & jnp.repeat(allowed, page_size, axis=-1)
+    s = jnp.where(ok[:, :, None], s, _NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    out = jnp.einsum("bhgqt,bhtd->bqhgd", p, v).reshape(B, Q, H, hd)
+    return jnp.where(valid[:, :, None, None], out, 0.0).astype(q.dtype)
+
+
+def _work_items(visit, n_max):
+    """The flat list of the ``True`` entries of ``visit [S, W]``, segment
+    by segment and in ascending order within one: ``(segment, w, n)``,
+    the first two ``[n_max]`` int32 with the first ``n`` in use."""
+    S, W = visit.shape
+    counts = visit.sum(-1).astype(jnp.int32)
+    ends = jnp.cumsum(counts)
+    i = jnp.arange(n_max, dtype=jnp.int32)
+    # the segment of item i: how many segments end at or before it (a
+    # compare and a sum: a binary search was 8 passes, 1.35 ms a call)
+    seg = jnp.minimum(jnp.sum(i[:, None] >= ends[None, :], axis=1),
+                      S - 1).astype(jnp.int32)
+    rank = i - (ends - counts)[seg]
+    # the True columns of each segment first, in ascending order
+    order = jnp.argsort(~visit, axis=-1, stable=True).astype(jnp.int32)
+    w = order[seg, jnp.clip(rank, 0, W - 1)]
+    return seg, w, ends[-1]
+
+
+def _listed_kernel(seg_ref, lp_ref, n_ref, tbl_ref, qlen_ref, ctx_ref,
+                   layer_ref, q_ref, sel_ref, kp_ref, vp_ref, o_ref, acc_ref,
+                   m_ref, l_ref, *, scale, page_size, groups, tiles, tile,
+                   small, dense_len, n_max):
+    del tbl_ref, layer_ref            # only the page index_maps read them
+    i = pl.program_id(0)
+    seg = seg_ref[i]
+    first = (i == 0) | (seg_ref[jnp.maximum(i - 1, 0)] != seg)
+    last = (i == n_ref[0] - 1) | (seg_ref[jnp.minimum(i + 1, n_max - 1)]
+                                  != seg)
+    b = seg // (groups * tiles)
+    qt = seg % tiles
+    lpage = lp_ref[i]
+    q_len, ctx = qlen_ref[b], ctx_ref[b]
+    start = lpage * page_size
+    in_tile = q_len - qt * tile       # query tokens of the row in this tile
+
+    @pl.when(first)
+    def _init():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+
+    def update(R):
+        """The page against the tile's first ``R`` query slots (static):
+        a decode row pays for ``small`` slots, not for the tile."""
+        G = q_ref.shape[2]
+        hd = q_ref.shape[4]
+        q = q_ref[0, 0, :, :R, :].reshape(G * R, hd)
+        k = kp_ref[0, 0, 0]                          # [ps, hd]
+        v = vp_ref[0, 0, 0]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        s = s.reshape(G, R, page_size)
+        tq = qt * tile + jax.lax.broadcasted_iota(jnp.int32, (1, R, 1), 1)
+        kv = start + jax.lax.broadcasted_iota(jnp.int32, (1, 1, page_size),
+                                              2)
+        at = ctx - q_len + tq                        # absolute position
+        member = jnp.any(sel_ref[0, 0, :R, :] == lpage, axis=-1,
+                         keepdims=True)[None]        # [1, R, 1]
+        ok = ((kv <= at) & (tq < q_len)
+              & ((at + 1 <= dense_len) | member))
+        s = jnp.where(ok, s, _NEG_INF)
+        m_prev = m_ref[:, :R]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        # a slot with nothing allowed yet keeps m at -inf: exp(s - m)
+        # would be 1 there, so mask the probabilities too
+        p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[:, :R] = l_ref[:, :R] * corr + jnp.sum(p, axis=-1,
+                                                     keepdims=True)
+        pv = jnp.dot(p.reshape(G * R, page_size).astype(v.dtype), v,
+                     preferred_element_type=jnp.float32)
+        acc_ref[:, :R] = acc_ref[:, :R] * corr + pv.reshape(G, R, hd)
+        m_ref[:, :R] = m_new
+
+    if small < tile:
+        pl.when(in_tile <= small)(lambda: update(small))
+        pl.when(in_tile > small)(lambda: update(tile))
+    else:
+        update(tile)
+
+    @pl.when(last)
+    def _final():
+        l = l_ref[:]
+        o = acc_ref[:] / jnp.where(l == 0.0, 1.0, l)
+        o_ref[0, 0] = o.astype(o_ref.dtype)
+
+
+def _listed_attention_kernel(q, k_pages, v_pages, page_tables, query_lens,
+                             context_lens, scale, layer, sel_blocks,
+                             dense_len, total_q, interpret, sel_mask=None):
+    B, Q, H, hd = q.shape
+    Hkv, page_size = k_pages.shape[2], k_pages.shape[3]
+    G, W = H // Hkv, page_tables.shape[1]
+    tile = min(128, Q)
+    if Q % tile:
+        raise ValueError(f"chunk width {Q} is not a multiple of {tile}")
+    tiles, small = Q // tile, min(16, tile)
+    pos, valid = _token_positions(Q, query_lens, context_lens)
+    if sel_blocks is None:
+        sel_blocks = jnp.full((B, Hkv, Q, 1), -1, jnp.int32)
+        dense_len = 2 ** 30
+    # the pages a token reads: its list, or (a dense token) all up to its own
+    w = jnp.arange(W)
+    dense_tok = pos + 1 <= dense_len
+    # (the caller's mask where it has one: scattering K indices a token
+    # into W flags took 5.6 ms a call at 16 x 2 x 512 x 64 into 544)
+    listed = sel_mask if sel_mask is not None \
+        else _selected_onehot(sel_blocks, W)
+    reads = (listed
+             | (dense_tok[:, :, None]
+                & (w[None, None, :] <= pos[:, :, None] // page_size)
+                )[:, None])
+    reads = reads & valid[:, None, :, None]
+    visit = reads.reshape(B, Hkv, tiles, tile, W).any(3)
+    # tiles that hold a token: one a row and one more per `tile` tokens
+    n_max = Hkv * (B + -(-(total_q or B * Q) // tile)) * W
+    n_max = min(n_max, B * Hkv * tiles * W)
+    seg, lpage, n = _work_items(visit.reshape(B * Hkv * tiles, W), n_max)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
+
+    def row_of(s):
+        return s // (Hkv * tiles), (s // tiles) % Hkv, s % tiles
+
+    def q_block(i, seg, lp, n, tbl, ql, cl, lyr):
+        b, g, qt = row_of(seg[i])
+        return (b, g, 0, qt, 0)
+
+    def sel_block(i, seg, lp, n, tbl, ql, cl, lyr):
+        b, g, qt = row_of(seg[i])
+        return (b, g, qt, 0)
+
+    def page_block(i, seg, lp, n, tbl, ql, cl, lyr):
+        b, g, _ = row_of(seg[i])
+        return (lyr[0], tbl[b, lp[i]], g, 0, 0)
+
+    K = sel_blocks.shape[-1]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=7,
+        grid=(n,),
+        in_specs=[
+            pl.BlockSpec((1, 1, G, tile, hd), q_block),
+            pl.BlockSpec((1, 1, tile, K), sel_block),
+            pl.BlockSpec((1, 1, 1, page_size, hd), page_block),
+            pl.BlockSpec((1, 1, 1, page_size, hd), page_block),
+        ],
+        out_specs=pl.BlockSpec((1, 1, G, tile, hd), q_block),
+        scratch_shapes=[
+            pltpu.VMEM((G, tile, hd), jnp.float32),
+            pltpu.VMEM((G, tile, 1), jnp.float32),
+            pltpu.VMEM((G, tile, 1), jnp.float32),
+        ],
+    )
+    kernel = functools.partial(
+        _listed_kernel, scale=scale, page_size=page_size, groups=Hkv,
+        tiles=tiles, tile=tile, small=small, dense_len=dense_len,
+        n_max=n_max)
+    q5 = q.reshape(B, Q, Hkv, G, hd).transpose(0, 2, 3, 1, 4)
+    out = pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(q5.shape, q.dtype),
+        interpret=interpret,
+        name="ragged_paged_attention",
+    )(seg, lpage, n.reshape(1).astype(jnp.int32), page_tables, query_lens,
+      context_lens, layer, q5, sel_blocks, k_pages, v_pages)
+    out = out.transpose(0, 3, 1, 2, 4).reshape(B, Q, H, hd)
+    # tiles that no item visited were never written
+    return jnp.where(valid[:, :, None, None], out, jnp.zeros_like(out))
+
+
 # -------------------------------------------------------------- public API
 
 
 def ragged_paged_attention(q, k_pages, v_pages, page_tables, query_lens,
-                           context_lens, scale=None, path=None, layer=None):
+                           context_lens, scale=None, path=None, layer=None,
+                           selected=None, total_q=None):
     """Fused prefill+decode attention over a paged KV cache (see module
     docstring for layouts).  ``layer`` (a traced int32 scalar) comes with
     a stacked ``[L, P, page_size, H, hd]`` pool and names the layer whose
     pages are read.  ``path`` is one of ``dispatch.MOSAIC`` /
     ``INTERPRET`` / ``REFERENCE``; ``None`` takes the Mosaic kernel on a
     TPU and the jnp gather reference elsewhere (identical contract, fp32
-    softmax in both)."""
+    softmax in both).  ``selected=(sel_blocks, dense_len)`` takes the
+    grouped-heads / selected-pages mode over a head-major stacked pool
+    ``[L, P, Hkv, page_size, hd]``; ``total_q`` (static) then bounds the
+    query tokens of all rows together, which bounds the work list."""
     if (k_pages.ndim == 5) != (layer is not None):
         raise ValueError("a stacked [L, P, page_size, H, hd] pool comes "
                          "with its `layer`, a one-layer pool without")
@@ -233,6 +478,19 @@ def ragged_paged_attention(q, k_pages, v_pages, page_tables, query_lens,
     page_tables = page_tables.astype(jnp.int32)
     query_lens = query_lens.astype(jnp.int32)
     context_lens = context_lens.astype(jnp.int32)
+    if selected is not None:
+        sel_blocks, dense_len, *sel_mask = selected
+        if layer is None:
+            k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
+        if path == dispatch.REFERENCE:
+            return _listed_attention_ref(
+                q, k_pages, v_pages, page_tables, query_lens, context_lens,
+                scale, layer, sel_blocks, dense_len)
+        return _listed_attention_kernel(
+            q, k_pages, v_pages, page_tables, query_lens, context_lens,
+            scale, layer, sel_blocks, dense_len, total_q,
+            interpret=(path == dispatch.INTERPRET),
+            sel_mask=sel_mask[0] if sel_mask else None)
     if path == dispatch.REFERENCE:
         return _ragged_attention_ref(q, k_pages, v_pages, page_tables,
                                      query_lens, context_lens, scale, layer)

@@ -273,6 +273,7 @@ event visible as a ``soak::*`` record in ``/flight``.
 """
 from .engine import Engine, Request, RequestState, SamplingParams  # noqa: F401
 from .kv_cache import PagedKVCache, prefix_hashes  # noqa: F401
+from .model import GPTServed, HybridServed, as_served  # noqa: F401
 from .prefix_gossip import (  # noqa: F401
     PrefixSummaryPublisher,
     collect_prefix_summaries,

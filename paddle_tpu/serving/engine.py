@@ -65,7 +65,8 @@ engine shares the process-wide tracer by default; with an injected
 ``clock`` it gets a private Tracer on that clock so tests drive span
 timestamps deterministically.
 
-Prefix reuse (``prefix_cache=True``, the default): admission walks the
+Prefix reuse (``prefix_cache=True``, the default for a model without
+recurrent layers, and refused for one with them): admission walks the
 page pool's radix tree for the longest cached page-aligned prefix of
 the prompt, maps those pages in read-only (a refcount bump instead of
 prefill FLOPs) and starts chunked prefill at the first uncached token —
@@ -92,7 +93,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..models.gpt import GPTConfig, gpt_init, gpt_ragged_step
 from ..observability.compile_watchdog import watch
 from ..observability.profiling import pop_phase, push_phase
 from ..observability.tracing import Tracer, default_tracer
@@ -100,6 +100,7 @@ from ..profiler.profiler import RecordEvent
 from ..resilience.faults import fault_point
 from .kv_cache import PagedKVCache
 from .metrics import STEP_PHASES, ServingMetrics
+from .model import as_served
 
 __all__ = ["SamplingParams", "Request", "RequestState", "Engine"]
 
@@ -219,8 +220,13 @@ class Engine:
     """Continuous-batching generation over a paged KV cache with a
     unified (chunked-prefill) step scheduler.
 
-    cfg/params: the GPT model (params default to gpt_init — useful for
-    benches and tests).  page_size/num_pages size the KV pool;
+    model/params: what is served, as an object with the interface of
+    ``serving/model.py`` — its ragged step and the state that step
+    carries — or a config object that file knows how to wrap (a
+    ``GPTConfig``, a ``HybridConfig``); params default to the model's own
+    initializer (useful for benches and tests).  The engine names no
+    model: scheduler, phases, metrics and allocator are the same for all.
+    page_size/num_pages size the page pools;
     max_batch_size fixes the in-flight row count (static shape).
     ``chunk_len`` bounds the prompt tokens any single row contributes
     per step — the knob that trades TTFT of the chunked prompt against
@@ -259,14 +265,24 @@ class Engine:
     #: the decode-rate EWMA has no sample yet
     DRAIN_FLOOR_S = 0.5
 
-    def __init__(self, cfg: GPTConfig, params=None, *, page_size=16,
+    def __init__(self, model, params=None, *, page_size=16,
                  num_pages=256, max_batch_size=4, chunk_len=None,
                  token_budget=None, prefill_len=None,
                  default_ttl_s=None, shed_occupancy_high=None,
                  shed_occupancy_low=None, shed_queue_high=None,
                  shed_queue_low=None, drain_floor_s=None,
-                 prefix_cache=True, clock=None, tracer=None, mesh=None):
-        self.cfg = cfg
+                 prefix_cache=None, clock=None, tracer=None, mesh=None):
+        self.model = model = as_served(model)
+        self.cfg = cfg = model.cfg
+        if prefix_cache and model.recurrent:
+            raise ValueError(
+                "prefix_cache=True with a model that has recurrent layers: "
+                "a cached prefix is pages of keys and values, and the "
+                "row's recurrent state never saw the tokens behind them. "
+                "Such a model is served cold (prefix_cache=False, the "
+                "default for it) until a prefix can carry a state snapshot")
+        if prefix_cache is None:
+            prefix_cache = not model.recurrent
         self._clock = clock or time.perf_counter
         if tracer is None:
             tracer = (default_tracer() if clock is None
@@ -288,7 +304,7 @@ class Engine:
             else (None if shed_queue_high is None
                   else max(0, int(0.75 * shed_queue_high))))
         self._shedding = False
-        self.params = params if params is not None else gpt_init(cfg)
+        self.params = params if params is not None else model.init_params()
         self.page_size = page_size
         self.max_batch_size = max_batch_size
         # prefill_len kept as a legacy alias for the chunk size; prompts
@@ -299,9 +315,10 @@ class Engine:
             token_budget or (self.chunk_len + max_batch_size - 1),
             max_batch_size)
         self.cache = PagedKVCache(
-            num_layers=cfg.num_layers, num_heads=cfg.num_heads,
-            head_dim=cfg.head_dim, num_pages=num_pages, page_size=page_size,
-            max_seq_len=cfg.max_seq_len, dtype=cfg.jdtype())
+            num_pages=num_pages, page_size=page_size,
+            max_seq_len=cfg.max_seq_len,
+            state=model.state_spec(num_pages=num_pages, page_size=page_size,
+                                   max_batch_size=max_batch_size))
         # prefix/radix reuse: admission walks the radix tree so a shared
         # system prompt is a refcount bump instead of prefill FLOPs;
         # completed prompts are inserted back.  Off = always-cold
@@ -309,30 +326,36 @@ class Engine:
         self.prefix_cache = bool(prefix_cache)
         self._prefix_seen = {"hits": 0, "hit_tokens": 0, "evictions": 0}
         self.metrics = ServingMetrics()
+        self.metrics.recurrent_state_bytes.set(
+            self.cache.recurrent_state_bytes())
         self._queue = deque()
         self._slots = [None] * max_batch_size
         self._just_finished = []
         self._admit_seq = 0                 # admission order, for preemption
         self._next_id = 0
-        # donation chains the page buffers through steps; XLA:CPU can't
+        # donation chains every state pool through steps; XLA:CPU can't
         # donate and warns, so only donate on accelerators
-        donate = (1, 2) if jax.default_backend() != "cpu" else ()
-        cfg_, max_q = cfg, self.chunk_len
+        n_state = len(self.cache.arrays)
+        donate = tuple(range(1, 1 + n_state)) \
+            if jax.default_backend() != "cpu" else ()
+        model_step = model.make_step(max_q=self.chunk_len, mesh=mesh)
 
-        def _step(params, k_pages, v_pages, tokens, rows, slots, qlens,
-                  ctxs, tables):
-            return gpt_ragged_step(cfg_, params, tokens, rows, slots,
-                                   qlens, ctxs, k_pages, v_pages, tables,
-                                   max_q=max_q, mesh=mesh)
+        def _step(params, *args):
+            # (params, every state pool, the six packed host arrays): flat,
+            # so that the pools are donated one by one
+            logits, state = model_step(params, args[:n_state],
+                                       *args[n_state:])
+            return (logits, *state)
 
-        # GSPMD serving (prepare(mesh=...) analogue): params follow the
-        # mesh.py GPT rule table and the KV page pool [L, P, ps, H, hd]
-        # shards its HEAD axis along "mp" — each model-parallel shard
-        # owns its head group's pages, so page writes are local and the
-        # only cross-shard traffic is the per-layer psum GSPMD inserts
-        # at the residual write plus ONE logits gather per step
-        # (out_shardings pins logits replicated; pages stay sharded
-        # end-to-end, never gathered).
+        # GSPMD serving (prepare(mesh=...) analogue): the model shards its
+        # params and names the page pools' spec (the dense family: the
+        # mesh.py GPT rule table, and the pools [L, P, ps, H, hd] on their
+        # HEAD axis along "mp") — each model-parallel shard owns its head
+        # group's pages, so page writes are local and the only
+        # cross-shard traffic is the per-layer psum GSPMD inserts at the
+        # residual write plus ONE logits gather per step (out_shardings
+        # pins logits replicated; pages stay sharded end-to-end, never
+        # gathered).
         self.mesh = mesh
         self._page_sharding = None
         jit_kw = {"donate_argnums": donate}
@@ -341,19 +364,16 @@ class Engine:
 
             from ..distributed import mesh as mesh_mod
 
-            self.params = mesh_mod.shard_params(self.params, mesh)
-            page_spec = mesh_mod.resolve_spec(
-                P(None, None, None, "mp"), self.cache.k_pages.shape,
-                mesh)
-            psh = NamedSharding(mesh, page_spec)
-            self.cache.k_pages = jax.device_put(self.cache.k_pages, psh)
-            self.cache.v_pages = jax.device_put(self.cache.v_pages, psh)
+            self.params, p_sh, page_spec = model.shard(self.params, mesh)
+            psh = NamedSharding(mesh, mesh_mod.resolve_spec(
+                page_spec, self.cache.k_pages.shape, mesh))
+            for name, a in self.cache.arrays.items():
+                self.cache.arrays[name] = jax.device_put(a, psh)
             self._page_sharding = psh
             rep = NamedSharding(mesh, P())
-            p_sh = mesh_mod.sharding_tree(self.params, mesh)
             jit_kw.update(
-                in_shardings=(p_sh, psh, psh) + (rep,) * 6,
-                out_shardings=(rep, psh, psh))
+                in_shardings=(p_sh,) + (psh,) * n_state + (rep,) * 6,
+                out_shardings=(rep,) + (psh,) * n_state)
         # watchdog-wrapped: the ONE statically-shaped program — prompt
         # chunks and decode rows share it — must compile exactly once;
         # any recompile here is a serving bug the watchdog flags with
@@ -584,13 +604,13 @@ class Engine:
             # the first uncached token
             if self.prefix_cache:
                 matched = self.cache.allocate_prefixed(
-                    req.id, req.prompt, self.chunk_len)
+                    req.id, req.prompt, self.chunk_len, slot=slot)
                 if matched is None:
                     return                   # FIFO: no queue-jumping
             else:
                 matched = 0
                 first = min(self.chunk_len, len(req.prompt))
-                if not self.cache.allocate(req.id, first):
+                if not self.cache.allocate(req.id, first, slot=slot):
                     return                   # FIFO: no queue-jumping
             req.prompt_pos = matched
             self._queue.popleft()
@@ -708,14 +728,14 @@ class Engine:
         t0 = self._clock()
         with RecordEvent("serving::unified_step"):
             with phases.phase("dispatch", step_phase):
-                logits, k, v = self._step_fn(
-                    self.params, self.cache.k_pages, self.cache.v_pages,
+                logits, *state = self._step_fn(
+                    self.params, *self.cache.state_arrays(),
                     *(jnp.asarray(a) for a in arrays))
             with phases.phase("device_wait", step_phase):
                 logits.block_until_ready()
             with phases.phase("fetch", step_phase):
                 logits = np.asarray(logits)
-        self.cache.k_pages, self.cache.v_pages = k, v
+        self.cache.set_state(state)
         t1 = self._clock()
         with phases.phase("sample"):
             sampled = self._sample_rows(logits, sched)
@@ -786,7 +806,14 @@ class Engine:
         occ = round(self.cache.occupancy(), 4)
         n_rows = len(sched)
         committed = 0
+        in_context = read = resets = 0
         for i, req, q, ctx in sched:
+            # what the step's attention layers had to read for this row,
+            # from the lengths alone (no device read); a row that began
+            # at position 0 had its recurrent state zeroed in the step
+            a, b = self.model.attention_positions(ctx, q)
+            in_context, read = in_context + a, read + b
+            resets += self.model.recurrent and ctx == q
             if req.state != RequestState.RUNNING:
                 continue                     # failed while sampling
             # per-row commit isolation: anything this row's
@@ -844,6 +871,10 @@ class Engine:
                 self._maybe_finish(req)
             except Exception as e:
                 self._fail(req, e)
+        self.metrics.attention_context.inc(in_context)
+        self.metrics.attention_selected.inc(read)
+        if resets:
+            self.metrics.state_resets.inc(resets)
         if dt > 0 and committed:
             # EWMA decode throughput feeds the drain/retry-after hint
             inst = committed / dt
@@ -927,13 +958,11 @@ class Engine:
             # every in-flight request's stream to poison_request (the
             # query-of-death: a seed-chosen pattern that kills whichever
             # replica it is aboard — deliberately NOT row-attributable)
-            kv = {"k_pages": self.cache.k_pages,
-                  "v_pages": self.cache.v_pages}
+            kv = dict(self.cache.arrays)
             fault_point("serving.step", tree=kv,
                         tokens=[r.tokens for r in self._running()]
                         + [r.tokens for r in self._queue])
-            self.cache.k_pages, self.cache.v_pages = kv["k_pages"], \
-                kv["v_pages"]
+            self.cache.arrays.update(kv)
             self._evict_expired()
             self._try_admit()
         with phases.phase("plan", "admission"):

@@ -10,6 +10,17 @@ each.  The pool is a single stacked array [L, P, page_size, H, hd]
 layer writes and reads its own [layer, page] blocks in place), bf16 by
 default.
 
+Which arrays there are is the served model's to say (``state=``, a list
+of ``(name, shape, dtype, kind)``): kind ``"pages"`` has the physical
+page on axis 1 and follows the one allocator — every such array is
+indexed by the same page ids, so keys, values and whatever a model keeps
+beside them (compressed keys for block selection) are allocated, shared,
+copied on write, compacted and freed together; kind ``"slots"`` has the
+batch row on axis 1 and holds one fixed state per in-flight sequence (a
+recurrent layer's state).  A sequence is bound to its row slot when it is
+allocated and released with its pages.  Without ``state=`` the manager
+holds the two pools of a model with ``num_heads`` equal heads.
+
 Allocation is chunk-granular: the engine's chunked-prefill scheduler
 ``allocate``s only a prompt's first chunk at admission and ``extend``s
 the table as later chunks (and decode tokens) land, so a long prompt
@@ -118,23 +129,42 @@ class PagedKVCache:
     """Page pool + per-sequence page tables with alloc/free/defrag,
     per-page refcounts and a radix prefix cache.
 
-    The arrays (`k_pages`/`v_pages`) are functional: jitted model steps
-    take them as inputs and return updated copies; the engine assigns the
-    results back.  Bookkeeping methods never touch the arrays except
+    The arrays (``arrays``, in the model's order; ``k_pages``/``v_pages``
+    name the first two) are functional: jitted model steps take them as
+    inputs and return updated copies; the engine assigns the results
+    back.  Bookkeeping methods never touch the arrays except
     ``defrag`` (a gather), the copy-on-write path of
     ``allocate_prefixed`` (one page copy) and ``reset`` (a fill).
     """
 
-    def __init__(self, *, num_layers, num_heads, head_dim, num_pages,
-                 page_size, max_seq_len, dtype=jnp.bfloat16):
+    def __init__(self, *, num_pages, page_size, max_seq_len,
+                 num_layers=None, num_heads=None, head_dim=None,
+                 dtype=jnp.bfloat16, state=None):
         if num_pages <= 0 or page_size <= 0:
             raise ValueError("num_pages and page_size must be positive")
         self.num_pages = int(num_pages)
         self.page_size = int(page_size)
         self.max_pages_per_seq = math.ceil(max_seq_len / page_size)
-        shape = (num_layers, num_pages, page_size, num_heads, head_dim)
-        self.k_pages = jnp.zeros(shape, dtype)
-        self.v_pages = jnp.zeros(shape, dtype)
+        if state is None:
+            shape = (num_layers, num_pages, page_size, num_heads, head_dim)
+            state = [("k_pages", shape, dtype, "pages"),
+                     ("v_pages", shape, dtype, "pages")]
+        # name -> array, in the order the model's step takes them
+        self.arrays = {}
+        self._kinds = {}
+        for name, shape, dt, kind in state:
+            if kind not in ("pages", "slots"):
+                raise ValueError(f"state {name!r}: kind {kind!r}")
+            if kind == "pages" and shape[1] != self.num_pages:
+                raise ValueError(f"state {name!r}: axis 1 of {shape} is "
+                                 f"not the {self.num_pages} pages")
+            self.arrays[name] = jnp.zeros(shape, dt)
+            self._kinds[name] = kind
+        slots = {self.arrays[n].shape[1] for n in self._of_kind("slots")}
+        if len(slots) > 1:
+            raise ValueError(f"slot states disagree on the rows: {slots}")
+        self.num_slots = slots.pop() if slots else None
+        self._slot_of = {}         # seq_id -> batch row, where bound
         # LIFO free list: recently-freed (still-warm) pages are reused first
         self._free = list(range(num_pages - 1, -1, -1))
         self._tables = {}          # seq_id -> [physical page ids]
@@ -161,6 +191,52 @@ class PagedKVCache:
                               "evictions": 0,
                               "inserted_pages": 0}  # guarded-by: self._lock
         self._tick = 0             # logical LRU clock
+
+    # ------------------------------------------------------------- arrays
+    def _of_kind(self, kind):
+        return [n for n, k in self._kinds.items() if k == kind]
+
+    @property
+    def k_pages(self):
+        return self.arrays["k_pages"]
+
+    @k_pages.setter
+    def k_pages(self, value):
+        self.arrays["k_pages"] = value
+
+    @property
+    def v_pages(self):
+        return self.arrays["v_pages"]
+
+    @v_pages.setter
+    def v_pages(self, value):
+        self.arrays["v_pages"] = value
+
+    def state_arrays(self):
+        """Every pool, in the order the model's step takes them."""
+        return tuple(self.arrays.values())
+
+    def set_state(self, arrays):
+        """The step's results, in the same order."""
+        for name, a in zip(self.arrays, arrays, strict=True):
+            self.arrays[name] = a
+
+    def recurrent_state_bytes(self):
+        """Bytes of the per-row (``"slots"``) state."""
+        return sum(self.arrays[n].nbytes for n in self._of_kind("slots"))
+
+    def slot_of(self, seq_id):
+        return self._slot_of.get(seq_id)
+
+    def _check_slot(self, seq_id, slot):
+        """Before any page is taken: the row slot is there and unbound."""
+        if slot is None:
+            return
+        if self.num_slots is not None and not 0 <= slot < self.num_slots:
+            raise ValueError(f"seq {seq_id!r}: row slot {slot} outside the "
+                             f"{self.num_slots} rows of state")
+        if slot in self._slot_of.values():
+            raise ValueError(f"seq {seq_id!r}: row slot {slot} is bound")
 
     # ------------------------------------------------------------ queries
     @property
@@ -192,12 +268,15 @@ class PagedKVCache:
         return list(self._tables)
 
     # ------------------------------------------------------- alloc / free
-    def allocate(self, seq_id, num_tokens):
-        """Reserve pages for a new sequence of num_tokens.  Returns True
-        on success; False (allocating nothing) when the pool can't cover
+    def allocate(self, seq_id, num_tokens, slot=None):
+        """Reserve pages for a new sequence of num_tokens and bind it to
+        batch row ``slot`` (whose per-row state the model's step zeroes
+        when the sequence runs its first token).  Returns True on
+        success; False (allocating nothing) when the pool can't cover
         the request — the engine's admission gate."""
         if seq_id in self._tables:
             raise ValueError(f"seq {seq_id!r} already allocated")
+        self._check_slot(seq_id, slot)
         need = self.pages_for(num_tokens)
         if need > self.max_pages_per_seq:
             raise ValueError(
@@ -207,6 +286,8 @@ class PagedKVCache:
             pages = self._take_pages_locked(need)
             if pages is None:
                 return False
+            if slot is not None:
+                self._slot_of[seq_id] = slot
             self._tables[seq_id] = pages
         return True
 
@@ -239,6 +320,7 @@ class PagedKVCache:
         with self._lock:
             for p in self._tables.pop(seq_id):
                 self._release_page_locked(p)
+            self._slot_of.pop(seq_id, None)
 
     def reset(self):
         """Free everything — tables, prefix cache, refcounts — and zero
@@ -252,8 +334,9 @@ class PagedKVCache:
             self._tree_pages = {}
             self._evictable = 0
             self._evict_heap = []
-            self.k_pages = jnp.zeros_like(self.k_pages)
-            self.v_pages = jnp.zeros_like(self.v_pages)
+            self._slot_of.clear()
+            for name, a in self.arrays.items():
+                self.arrays[name] = jnp.zeros_like(a)
 
     # --------------------------------------------------- locked internals
     def _release_page_locked(self, page):
@@ -366,7 +449,7 @@ class PagedKVCache:
         return pages
 
     # ------------------------------------------------------- prefix reuse
-    def allocate_prefixed(self, seq_id, token_ids, chunk_tokens):
+    def allocate_prefixed(self, seq_id, token_ids, chunk_tokens, slot=None):
         """Admission with prefix reuse.
 
         Walks the radix tree for the longest cached page-aligned prefix
@@ -393,6 +476,7 @@ class PagedKVCache:
         zero-ref cached page."""
         if seq_id in self._tables:
             raise ValueError(f"seq {seq_id!r} already allocated")
+        self._check_slot(seq_id, slot)
         n = len(token_ids)
         with self._lock:
             full_match = self._match_locked(token_ids)
@@ -444,12 +528,13 @@ class PagedKVCache:
             if cow_src is not None:
                 # one-page copy-on-write; cow page is fresh[0] (owned)
                 dst = fresh[0]
-                self.k_pages = self.k_pages.at[:, dst].set(
-                    self.k_pages[:, cow_src])
-                self.v_pages = self.v_pages.at[:, dst].set(
-                    self.v_pages[:, cow_src])
+                for name in self._of_kind("pages"):
+                    a = self.arrays[name]
+                    self.arrays[name] = a.at[:, dst].set(a[:, cow_src])
                 # copy landed; the source keeps only its tree/table refs
                 self._release_page_locked(cow_src)
+            if slot is not None:
+                self._slot_of[seq_id] = slot
             self._tables[seq_id] = shared + fresh
             if matched:
                 self._prefix_stats["hits"] += 1
@@ -535,7 +620,11 @@ class PagedKVCache:
     def check_integrity(self):
         """Debug invariant sweep (tests): every page is exactly one of
         free/referenced, refcounts equal table + tree occurrences, the
-        free list holds no duplicates, the incremental evictable
+        free list holds no duplicates, every page-indexed pool still has
+        the allocator's pages and every per-row pool its rows, row slots
+        are bound one to a sequence and released with its pages (and,
+        where the model keeps per-row state, every sequence has one), the
+        incremental evictable
         counter matches a full rescan, and every evictable leaf has a
         live entry in the eviction heap.  Raises AssertionError."""
         with self._lock:
@@ -553,6 +642,21 @@ class PagedKVCache:
                 "page both free and referenced"
             assert len(self._free) + len(counts) == self.num_pages, \
                 "pages leaked: free + referenced != pool"
+            for name in self._of_kind("pages"):
+                assert self.arrays[name].shape[1] == self.num_pages, \
+                    f"{name}: {self.arrays[name].shape} lost the page axis"
+            slots = list(self._slot_of.values())
+            assert len(slots) == len(set(slots)), \
+                f"two sequences bound to one row slot: {self._slot_of}"
+            assert set(self._slot_of) <= set(self._tables), \
+                "a row slot outlived its sequence's pages"
+            if self.num_slots is not None:
+                for name in self._of_kind("slots"):
+                    assert self.arrays[name].shape[1] == self.num_slots, \
+                        f"{name}: {self.arrays[name].shape} lost its rows"
+                assert set(self._slot_of) == set(self._tables), \
+                    (f"sequences without a row of state: "
+                     f"{set(self._tables) - set(self._slot_of)}")
             for page, node in self._tree_pages.items():
                 assert node.page == page, \
                     f"tree-page map drift: {page} -> node.page {node.page}"
@@ -611,8 +715,8 @@ class PagedKVCache:
                 return 0
             order += [p for p in range(self.num_pages) if p not in remap]
             idx = jnp.asarray(order, jnp.int32)
-            self.k_pages = jnp.take(self.k_pages, idx, axis=1)
-            self.v_pages = jnp.take(self.v_pages, idx, axis=1)
+            for name in self._of_kind("pages"):
+                self.arrays[name] = jnp.take(self.arrays[name], idx, axis=1)
             self._tables = {sid: [remap[p] for p in t]
                             for sid, t in self._tables.items()}
             for node in self._iter_nodes_locked():

@@ -129,6 +129,24 @@ class ServingMetrics:
                  "for a phase the call did not reach"))
         self.step_phases = {p: self.step_phase.labels(phase=p)
                             for p in STEP_PHASES}
+        self.attention_positions = add(Counter(
+            "serving_attention_positions_total", labelnames=("kind",),
+            help="per attention layer, the positions the processed "
+                 "tokens had in context (kind=context) and the positions "
+                 "the model read for them (kind=selected: fewer, where "
+                 "it selects blocks); from the lengths, on the host"))
+        self.attention_context = self.attention_positions.labels(
+            kind="context")
+        self.attention_selected = self.attention_positions.labels(
+            kind="selected")
+        self.state_resets = add(Counter(
+            "serving_state_resets_total",
+            help="rows whose recurrent state the step zeroed: an "
+                 "admitted or recomputed request's first chunk"))
+        self.recurrent_state_bytes = add(Gauge(
+            "serving_recurrent_state_bytes",
+            help="bytes of per-row recurrent state the cache manager "
+                 "holds (0 for a model that keeps none)"))
         self.page_occupancy = add(Gauge("serving_page_occupancy"))
         self.queue_depth = add(Gauge(
             "serving_queue_depth",

@@ -1,0 +1,155 @@
+"""What the serving engine needs to know of a model: its ragged step and
+the state that step carries.
+
+``Engine`` serves any object with this interface; it never names a model.
+
+- ``cfg``: ``max_seq_len``, ``vocab_size`` and ``jdtype()`` are read.
+- ``init_params()``: fresh parameters (tests, benches).
+- ``state_spec(num_pages=, page_size=, max_batch_size=)``: the pools the
+  cache manager builds, in the order the step takes and returns them, as
+  ``(name, shape, dtype, kind)``; kind ``"pages"`` has the physical page
+  on axis 1 (allocated, shared, copied and compacted page by page), kind
+  ``"slots"`` the batch row (one fixed state per in-flight request).
+- ``make_step(max_q=, mesh=)``: ``step(params, state, tokens,
+  row_of_token, slot_of_token, query_lens, context_lens, page_tables) ->
+  (logits [B, V], state)`` with ``state`` the tuple of pools; jitted by
+  the engine with every pool donated.
+- ``recurrent``: the model keeps per-row state that is a function of
+  every token the row has seen.  Pages of a cached prefix say nothing of
+  that state, so the engine serves such a model cold (no prefix reuse).
+- ``attention_positions(ctx, q)``: for a row that ran ``q`` tokens and
+  now holds ``ctx``, the positions those tokens had in context and the
+  positions the model's attention layers read for them (fewer, where it
+  selects), per attention layer: host arithmetic for the
+  ``serving_attention_positions_total`` counter.
+- ``shard(params, mesh)``: GSPMD serving, where the model has it
+  (``make_step(mesh=...)`` raises ``NotImplementedError`` where not).
+"""
+from __future__ import annotations
+
+import jax
+import numpy as np
+
+__all__ = ["GPTServed", "HybridServed", "as_served"]
+
+
+class GPTServed:
+    """The dense GPT family behind the engine's interface."""
+
+    recurrent = False
+
+    def __init__(self, cfg):
+        self.cfg = cfg
+
+    def init_params(self):
+        from ..models.gpt import gpt_init
+
+        return gpt_init(self.cfg)
+
+    def state_spec(self, *, num_pages, page_size, max_batch_size):
+        cfg = self.cfg
+        pool = (cfg.num_layers, num_pages, page_size, cfg.num_heads,
+                cfg.head_dim)
+        return [("k_pages", pool, cfg.jdtype(), "pages"),
+                ("v_pages", pool, cfg.jdtype(), "pages")]
+
+    def make_step(self, *, max_q, mesh=None):
+        from ..models.gpt import gpt_ragged_step
+
+        cfg = self.cfg
+
+        def step(params, state, tokens, rows, slots, qlens, ctxs, tables):
+            k_pages, v_pages = state
+            logits, k_pages, v_pages = gpt_ragged_step(
+                cfg, params, tokens, rows, slots, qlens, ctxs, k_pages,
+                v_pages, tables, max_q=max_q, mesh=mesh)
+            return logits, (k_pages, v_pages)
+
+        # jitted here, where the step is named: the engine's own jit
+        # inlines it, and the trace-purity pass (tools/analysis) follows
+        # jit entry points, not a callable handed over at run time
+        return jax.jit(step)
+
+    def attention_positions(self, ctx, q):
+        # token at position p attends over p + 1 positions, all of them
+        n = q * ctx - q * (q - 1) // 2
+        return n, n
+
+    def shard(self, params, mesh):
+        """``(sharded params, their sharding tree, the PartitionSpec of a
+        page pool)``: params by the mesh.py GPT rule table, the pools
+        ``[L, P, ps, H, hd]`` on their head axis along "mp"."""
+        from jax.sharding import PartitionSpec as P
+
+        from ..distributed import mesh as mesh_mod
+
+        params = mesh_mod.shard_params(params, mesh)
+        return (params, mesh_mod.sharding_tree(params, mesh),
+                P(None, None, None, "mp"))
+
+
+class HybridServed:
+    """The sparse-plus-lightning decoder (``models/hybrid.py``)."""
+
+    recurrent = True
+
+    def __init__(self, cfg, *, dense_only=False):
+        self.cfg = cfg
+        self.dense_only = dense_only
+
+    def init_params(self):
+        from ..models.hybrid import hybrid_init
+
+        return hybrid_init(self.cfg)
+
+    def state_spec(self, **sizes):
+        from ..models.hybrid import hybrid_state_spec
+
+        return hybrid_state_spec(self.cfg, **sizes)
+
+    def make_step(self, *, max_q, mesh=None):
+        from ..models.hybrid import hybrid_ragged_step
+
+        if mesh is not None:
+            raise NotImplementedError(
+                "Engine(mesh=...) with a model of two layer stacks: the "
+                "sparse layers' pools shard by key/value head, the "
+                "lightning layers' state by head, and neither has a rule "
+                "table or a shard_map around its kernel yet (PERF.md, open "
+                "questions)")
+        cfg, dense_only = self.cfg, self.dense_only
+
+        def step(params, state, tokens, rows, slots, qlens, ctxs, tables):
+            logits, *state = hybrid_ragged_step(
+                cfg, params, tokens, rows, slots, qlens, ctxs, *state,
+                tables, max_q=max_q, dense_only=dense_only)
+            return logits, tuple(state)
+
+        return jax.jit(step)       # as GPTServed.make_step
+
+    def attention_positions(self, ctx, q):
+        cfg = self.cfg
+        n = np.arange(ctx - q + 1, ctx + 1, dtype=np.int64)   # contexts
+        context = int(n.sum())
+        if self.dense_only:
+            return context, context
+        # past dense_len: topk blocks, the token's own one read up to it
+        bs = cfg.block_size
+        sparse = np.minimum(n, (cfg.topk - 1) * bs + (n - 1) % bs + 1)
+        return context, int(np.where(n <= cfg.dense_len, n, sparse).sum())
+
+
+def as_served(model):
+    """``model`` if it already has the interface, else the served form of
+    a known config object."""
+    if hasattr(model, "make_step"):
+        return model
+    from ..models.gpt import GPTConfig
+    from ..models.hybrid import HybridConfig
+
+    if isinstance(model, GPTConfig):
+        return GPTServed(model)
+    if isinstance(model, HybridConfig):
+        return HybridServed(model)
+    raise TypeError(f"Engine cannot serve a {type(model).__name__}: pass a "
+                    f"served-model object (paddle_tpu.serving.model)")

@@ -86,3 +86,45 @@ def test_serve_step_moves_no_page_pool(devices):
     one_layer = pages * 16 * H * hd * 2
     assert compiled.memory_analysis().temp_size_in_bytes < one_layer
     assert text.count('custom_call_target="tpu_custom_call"') == 1
+
+
+def test_gpt_serve_step_keeps_its_temporaries_behind_the_model_interface(
+        devices):
+    """The dense family is served through the engine's model interface
+    (``serving/model.py``) and its compiled step is the one it was: at the
+    benchmark's 1024 pages and 16 rows XLA plans 1,032,192 B of
+    temporaries (PERF.md, PR 27) and the program holds one Mosaic call —
+    the equal-heads kernel, which the grouped / selected mode must not
+    reach."""
+    compiled = tpu_aot.lower_serve_step(
+        devices, num_pages=1024, max_batch_size=16, chunk_len=128).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes == 1032192
+    assert mem.argument_size_in_bytes == 5852876800
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 1
+
+
+def test_hybrid_serve_step_compiles_for_v5e_and_moves_no_pool(devices):
+    """The sparse-plus-lightning step at the benchmark cell's shapes
+    (published widths, 8 layers, 16 rows, chunks of 512, 8192 pages of
+    64): Mosaic accepts both new kernels, every state pool is donated and
+    aliased, nothing re-lays or copies a key/value pool, and the plan
+    fits the chip."""
+    from paddle_tpu.models.hybrid import HYBRID_CONFIGS
+
+    compiled = tpu_aot.lower_hybrid_serve_step(devices).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    cfg = HYBRID_CONFIGS["minicpm-sala-8l"]
+    # one Mosaic call a layer: 2 sparse, 6 lightning
+    assert text.count('custom_call_target="tpu_custom_call"') == \
+        cfg.num_layers
+    pools = 2 * 2 * 8192 * 2 * 64 * 128 * 2 + 2 * 8192 * 4 * 2 * 128 * 2 \
+        + 6 * 16 * 32 * 128 * 128 * 4
+    assert mem.alias_size_in_bytes >= pools
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12e9
+    pool = "bf16[2,8192,2,64,128]"
+    movers = [line.strip()[:160] for line in text.splitlines()
+              if re.match(rf"\s+(?:ROOT )?%\S+ = {re.escape(pool)}\S* "
+                          rf"(copy|transpose|dynamic-update-slice)\(", line)]
+    assert not movers, "key/value pool movers:\n" + "\n".join(movers)

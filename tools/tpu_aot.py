@@ -163,6 +163,36 @@ def lower_serve_step(devices, num_pages=1024, max_batch_size=8,
                  ((B, eng.cache.max_pages_per_seq), jnp.int32)))
 
 
+def lower_hybrid_serve_step(devices, num_pages=8192, max_batch_size=16,
+                            chunk_len=512, page_size=64,
+                            config="minicpm-sala-8l"):
+    """The unified step of the sparse-plus-lightning decoder at the
+    benchmark cell's knobs, on one device: its four state pools donated."""
+    from paddle_tpu.models.hybrid import HYBRID_CONFIGS, hybrid_init
+    from paddle_tpu.serving import Engine
+
+    cfg = HYBRID_CONFIGS[config]
+    params = jax.eval_shape(lambda: hybrid_init(cfg))
+    one = SingleDeviceSharding(devices[0])
+    with as_if_on_tpu():
+        # 1 page and 1 row held here; the lowered shapes are the real ones
+        eng = Engine(cfg, params, page_size=page_size, num_pages=1,
+                     max_batch_size=max_batch_size, chunk_len=chunk_len)
+        B, T = eng.max_batch_size, eng.token_budget
+        state = [(shape, dtype) for _, shape, dtype, _ in
+                 eng.model.state_spec(num_pages=num_pages,
+                                      page_size=page_size,
+                                      max_batch_size=max_batch_size)]
+        return eng._step_fn.lower(
+            jax.tree_util.tree_map(
+                lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                               sharding=one), params),
+            *_on(one, *state),
+            *_on(one, ((T,), jnp.int32), ((T,), jnp.int32),
+                 ((T,), jnp.int32), ((B,), jnp.int32), ((B,), jnp.int32),
+                 ((B, eng.cache.max_pages_per_seq), jnp.int32)))
+
+
 def _report(name, compile_fn):
     t0 = time.perf_counter()
     compiled = compile_fn()
@@ -189,6 +219,8 @@ def main(argv):
     ok &= _report("serve 1.3b B16 chunk128 1024 pages",
                   lambda: lower_serve_step(devices,
                                            max_batch_size=16).compile())
+    ok &= _report("serve hybrid 8l B16 chunk512 8192 pages of 64",
+                  lambda: lower_hybrid_serve_step(devices).compile())
     if "--four" in argv:
         ok &= _report("train pp=2 x mp=2",
                       lambda: lower_train_step(devices, pp=2, mp=2).compile())
