@@ -24,21 +24,37 @@ elsewhere):
 
 - ``_ragged_attention_ref`` — pure-jnp gather + fp32 softmax.  Serves CPU
   tests and is the numerics oracle.
-- the Pallas kernel — grid (batch, pages_per_seq); the page table, the
-  two length vectors and the layer ride in scalar-prefetch
-  (PrefetchScalarGridSpec) so the BlockSpec index_map DMAs exactly the
-  pages each row owns.  Page steps are the innermost (sequential) grid
-  axis; VMEM scratch carries the online-softmax state (per query token ×
-  head) across them, flash-attention style, with the causal mask applied
-  relative to each row's context offset.
+- the Pallas kernel — a grid as long as the work.  A *work item* is one
+  tile of a live row's context: (batch row, tile of ``_pages_per_item``
+  pages), at least ``_KEY_TILE`` key positions.  ``ragged_work_items``
+  lists them from the two length vectors (row by row, a row's tiles
+  ascending, nothing for an idle row), the list rides in scalar prefetch
+  with the page table, the lengths and the layer, and the grid is
+  ``(n,)``, a dynamic bound: no grid step is spent on a page that does
+  not exist.  The pools stay in HBM (``memory_space=pl.ANY``); an item's
+  pages are copied ``[layer, table[row, j]] -> VMEM`` by
+  ``make_async_copy`` into one of two buffers, the next item's pages in
+  flight while this one is multiplied, and pages past the row's context
+  are neither fetched nor waited for (their positions are masked).  The
+  tiles arrive token-major and are turned head-major in VMEM
+  (``swapaxes``: the heads are the products' batch).  Both products take
+  their operands as stored (bf16 into the unit, float32 out of it: a
+  bf16 product is exact in float32); the softmax, its
+  running maximum and sum and the accumulator are float32 scratch carried
+  across a row's items, flash-attention style, with the causal mask
+  applied relative to the row's context offset.  Two bodies are compiled
+  and a row's query count picks one per item: the first ``_SMALL_Q``
+  query slots (every decode row) or the chunk's full width.
 
 Layouts:
   q            [B, Q, H, hd]        Q = max query tokens per row, padded
-  k/v_pages    [L, P, page_size, H, hd] every layer's page pool, stacked,
-               with ``layer`` (a traced int32 scalar) naming the one to
-               read: the serving step hands the kernel its whole donated
-               pool and the index_map picks ``[layer, page]`` blocks out
-               of HBM, so no layer's pages are ever sliced out or copied.
+  k/v_pages    [L, P, page_size, H, hd] every layer's page pool, stacked
+               and token-major (one position's heads are contiguous: the
+               model writes a token's keys as one 4 KiB row), with
+               ``layer`` (a traced int32 scalar) naming the one to read:
+               the serving step hands the kernel its whole donated pool
+               and the copies take ``[layer, page]`` out of HBM, so no
+               layer's pages are ever sliced out or copied.
                [P, page_size, H, hd] (one layer's pool, ``layer`` left
                out) is the same kernel under a free ``[None]``.
   page_tables  [B, max_pages] int32  physical page id per logical page
@@ -72,8 +88,9 @@ lists with plain ``jnp``: a page that no token of a tile selected is
 never fetched, the rest are masked per token inside the kernel, and the
 grid is as long as the list (a dynamic grid bound), so a decode row
 costs its K pages and not the width of the page table.  This path is
-chosen statically by ``selected is not None``; the equal-heads,
-full-page-table kernel above is untouched by it.
+chosen statically by ``selected is not None``; it takes one page an item
+through a ``BlockSpec``, and shares no code with the equal-heads kernel
+above.
 """
 from __future__ import annotations
 
@@ -85,11 +102,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..ops.linalg import mxu_precision
 from . import dispatch
 
-__all__ = ["paged_attention", "ragged_paged_attention"]
+__all__ = ["paged_attention", "ragged_paged_attention",
+           "ragged_work_items"]
 
 _NEG_INF = -1e30
+
+
+# key positions one work item of the equal-heads kernel covers (whole
+# pages: at least this many), and the query slots its narrow body computes
+_KEY_TILE = 128
+_SMALL_Q = 16
 
 
 def _stacked(k_pages, v_pages, layer):
@@ -139,101 +164,191 @@ def _ragged_attention_ref(q, k_pages, v_pages, page_tables, query_lens,
 # ------------------------------------------------------------------- kernel
 
 
-def _ragged_kernel(tbl_ref, qlen_ref, ctx_ref, layer_ref, q_ref, kp_ref,
-                   vp_ref, o_ref, acc_ref, m_ref, l_ref, *, scale, page_size,
-                   num_pages):
-    del layer_ref                     # only the page index_maps read it
-    b = pl.program_id(0)
-    j = pl.program_id(1)
+def _pages_per_item(page_size, max_pages):
+    """Pages in one work item: whole pages covering ``_KEY_TILE`` key
+    positions, and never more than a row's page table holds."""
+    return max(1, min(max_pages, -(-_KEY_TILE // page_size)))
 
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
 
-    q_len = qlen_ref[b]
-    ctx = ctx_ref[b]
-    start = j * page_size
+def ragged_work_items(query_lens, context_lens, page_size, max_pages):
+    """The equal-heads kernel's grid, as a list: one item for every tile of
+    ``_pages_per_item`` pages that a live row's context reaches, row by
+    row and in ascending order within a row; an idle row has none.
+    Returns ``(rows, tiles, n)``: the item's batch row and its tile within
+    that row, both ``[B * tiles_per_row]`` int32 with the first ``n [1]``
+    in use.  It depends on the two length vectors alone, so a step builds
+    it once and hands it to every layer's call (``items=``)."""
+    B = query_lens.shape[0]
+    tile = _pages_per_item(page_size, max_pages) * page_size
+    per_row = -(-max_pages * page_size // tile)
+    # a live row always has an item: the one that writes its output
+    counts = jnp.where(query_lens > 0,
+                       jnp.clip(-(-context_lens // tile), 1, per_row), 0)
+    ends = jnp.cumsum(counts)
+    i = jnp.arange(B * per_row, dtype=jnp.int32)
+    # the row of item i: how many rows end at or before it
+    rows = jnp.minimum(jnp.sum(i[:, None] >= ends[None, :], axis=1), B - 1)
+    tiles = i - (ends - counts)[rows]
+    return (rows.astype(jnp.int32), tiles.astype(jnp.int32),
+            ends[-1:].astype(jnp.int32))
 
-    @pl.when((start < ctx) & (q_len > 0))
-    def _body():
-        q = q_ref[0].astype(jnp.float32)            # [Q, H, hd]
-        k = kp_ref[0, 0].astype(jnp.float32)        # [ps, H, hd]
-        v = vp_ref[0, 0].astype(jnp.float32)
-        Q = q.shape[0]
-        # s[h, tq, t] = q[tq, h, :] . k[t, h, :]  (batch over heads)
+
+def _ragged_kernel(row_ref, tile_ref, n_ref, tbl_ref, qlen_ref, ctx_ref,
+                   layer_ref, q_ref, kp_hbm, vp_hbm, o_ref, k_buf, v_buf,
+                   sem, acc_ref, m_ref, l_ref, *, scale, page_size, pages,
+                   small):
+    i = pl.program_id(0)
+    n = n_ref[0]
+    layer = layer_ref[0]
+    n_max, max_pages = row_ref.shape[0], tbl_ref.shape[1]
+    Q = q_ref.shape[1]
+    row = row_ref[i]
+    first = (i == 0) | (row_ref[jnp.maximum(i - 1, 0)] != row)
+    last = (i == n - 1) | (row_ref[jnp.minimum(i + 1, n_max - 1)] != row)
+    q_len, ctx = qlen_ref[row], ctx_ref[row]
+    start = tile_ref[i] * (pages * page_size)    # the item's first position
+    slot = i % 2
+
+    def fetch(item, slot, wait):
+        """Start (or wait for) the copies of ``item``'s pages, keys and
+        values, out of HBM into buffer ``slot``: the pages its row's
+        context reaches and no others."""
+        r = row_ref[item]
+        page0 = tile_ref[item] * pages
+        live = (ctx_ref[r] + page_size - 1) // page_size - page0
+        for j in range(pages):
+            @pl.when(j < live)
+            def _():
+                page = tbl_ref[r, jnp.minimum(page0 + j, max_pages - 1)]
+                at = pl.ds(j * page_size, page_size)
+                for p, (hbm, buf) in enumerate(((kp_hbm, k_buf),
+                                                (vp_hbm, v_buf))):
+                    copy = pltpu.make_async_copy(
+                        hbm.at[layer, page], buf.at[slot, at],
+                        sem.at[p, slot])
+                    copy.wait() if wait else copy.start()
+
+    @pl.when(i == 0)
+    def _prime():
+        # positions no page was fetched for are masked out of the scores,
+        # but 0 x (whatever the buffer held) has to be 0 in the second
+        # product: after this the buffers only ever hold pool values
+        v_buf[:] = jnp.zeros_like(v_buf)
+        fetch(0, 0, wait=False)
+
+    @pl.when(i + 1 < n)
+    def _next():
+        fetch(jnp.minimum(i + 1, n_max - 1), 1 - slot, wait=False)
+
+    fetch(i, slot, wait=True)
+
+    def item(R):
+        """The item against the row's first ``R`` query slots (static): a
+        decode row pays for ``small`` slots, not for the chunk's width."""
+        @pl.when(first)
+        def _init():
+            acc_ref[:, :R] = jnp.zeros_like(acc_ref[:, :R])
+            m_ref[:, :R] = jnp.full_like(m_ref[:, :R], _NEG_INF)
+            l_ref[:, :R] = jnp.zeros_like(l_ref[:, :R])
+
+        # queries, keys and values are stored token-major, [.., H, hd],
+        # as the model's projection and its page scatter write them; the
+        # unit wants the heads as the batch, so the tiles are turned in
+        # VMEM (keys and values: 0.2 us an item)
+        q = jnp.swapaxes(q_ref[0, :R], 0, 1)         # [H, R, hd]
+        k = jnp.swapaxes(k_buf[slot], 0, 1)          # [H, T, hd]
+        v = jnp.swapaxes(v_buf[slot], 0, 1)
+        T = k.shape[1]
+        # operands as they are stored (a bf16 product is exact in
+        # float32), float32 out of the unit and through the softmax;
+        # float32 operands get the unit's true-float32 passes, the
+        # framework's rule (ops/linalg.py mxu_precision)
+        precision = mxu_precision(q, k)
         s = jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((1,), (1,))),
-            preferred_element_type=jnp.float32) * scale  # [H, Q, ps]
-        tq = jax.lax.broadcasted_iota(jnp.int32, (1, Q, page_size), 1)
-        kv = start + jax.lax.broadcasted_iota(jnp.int32, (1, Q, page_size),
-                                              2)
+            q, k, (((2,), (2,)), ((0,), (0,))), precision=precision,
+            preferred_element_type=jnp.float32) * scale      # [H, R, T]
+        tq = jax.lax.broadcasted_iota(jnp.int32, (1, R, T), 1)
+        kv = start + jax.lax.broadcasted_iota(jnp.int32, (1, R, T), 2)
         # causal relative to the row's context offset: query tq sits at
         # absolute position ctx - q_len + tq
         ok = (kv <= ctx - q_len + tq) & (tq < q_len)
         s = jnp.where(ok, s, _NEG_INF)
-        m_prev = m_ref[:]                            # [H, Q, 1]
+        m_prev = m_ref[:, :R]                        # [H, R, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)                       # [H, Q, ps]
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_ref[:] = l_ref[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        # acc[h, tq, d] += p[h, tq, :] . v[:, h, d]
-        acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
-            p, v, (((2,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)
-        m_ref[:] = m_new
+        l_ref[:, :R] = l_ref[:, :R] * corr + jnp.sum(p, axis=-1,
+                                                     keepdims=True)
+        acc_ref[:, :R] = acc_ref[:, :R] * corr + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+            precision=precision, preferred_element_type=jnp.float32)
+        m_ref[:, :R] = m_new
 
-    @pl.when(j == num_pages - 1)
-    def _final():
-        Q = acc_ref.shape[1]
-        l = l_ref[:]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o = acc_ref[:] / l_safe                      # [H, Q, hd]
-        # padded query slots accumulated garbage behind the mask with
-        # m == -inf; zero them so the kernel matches the ref everywhere
-        tq = jax.lax.broadcasted_iota(jnp.int32, (1, Q, 1), 1)
-        o = jnp.where(tq < q_len, o, 0.0)
-        o_ref[0] = o.transpose(1, 0, 2).astype(o_ref.dtype)
+        @pl.when(last)
+        def _final():
+            l = l_ref[:, :R]
+            o = acc_ref[:, :R] / jnp.where(l == 0.0, 1.0, l)
+            # padded query slots accumulated garbage behind the mask with
+            # m == -inf; zero them so the kernel matches the ref everywhere
+            tq = jax.lax.broadcasted_iota(jnp.int32, (1, R, 1), 1)
+            o = jnp.where(tq < q_len, o, 0.0).astype(o_ref.dtype)
+            o_ref[0, :R] = jnp.swapaxes(o, 0, 1)
+            if R < Q:
+                o_ref[0, R:] = jnp.zeros_like(o_ref[0, R:])
+
+    if small < Q:
+        pl.when(q_len <= small)(lambda: item(small))
+        pl.when(q_len > small)(lambda: item(Q))
+    else:
+        item(Q)
 
 
 def _ragged_attention_kernel(q, k_pages, v_pages, page_tables, query_lens,
-                             context_lens, scale, interpret, layer=None):
+                             context_lens, scale, interpret, layer=None,
+                             items=None):
     B, Q, H, hd = q.shape
     k_pages, v_pages, layer = _stacked(k_pages, v_pages, layer)
     page_size = k_pages.shape[2]
     max_pages = page_tables.shape[1]
+    pages = _pages_per_item(page_size, max_pages)
+    if items is None:
+        items = ragged_work_items(query_lens, context_lens, page_size,
+                                  max_pages)
+    rows, tiles, n = items
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
 
-    def q_block(b, j, tbl, ql, cl, lyr):
-        return (b, 0, 0, 0)
+    def q_block(i, rows, *_):
+        return (rows[i], 0, 0, 0)
 
-    def page_block(b, j, tbl, ql, cl, lyr):
-        return (lyr[0], tbl[b, j], 0, 0, 0)
-
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    tile = (2, pages * page_size, H, hd)         # two buffers of one item
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(B, max_pages),
-        in_specs=[
-            pl.BlockSpec((1, Q, H, hd), q_block),
-            pl.BlockSpec((1, 1, page_size, H, hd), page_block),
-            pl.BlockSpec((1, 1, page_size, H, hd), page_block),
-        ],
+        num_scalar_prefetch=7,
+        grid=(n[0],),
+        in_specs=[pl.BlockSpec((1, Q, H, hd), q_block), in_hbm, in_hbm],
         out_specs=pl.BlockSpec((1, Q, H, hd), q_block),
         scratch_shapes=[
+            pltpu.VMEM(tile, k_pages.dtype),
+            pltpu.VMEM(tile, v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.VMEM((H, Q, hd), jnp.float32),
             pltpu.VMEM((H, Q, 1), jnp.float32),
             pltpu.VMEM((H, Q, 1), jnp.float32),
         ],
     )
     kernel = functools.partial(_ragged_kernel, scale=scale,
-                               page_size=page_size, num_pages=max_pages)
-    return pl.pallas_call(
+                               page_size=page_size, pages=pages,
+                               small=min(_SMALL_Q, Q))
+    out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Q, H, hd), q.dtype),
         interpret=interpret,
         name="ragged_paged_attention",
-    )(page_tables, query_lens, context_lens, layer, q, k_pages, v_pages)
+    )(rows, tiles, n, page_tables, query_lens, context_lens, layer, q,
+      k_pages, v_pages)
+    # an idle row has no item: nothing wrote its block
+    return jnp.where((query_lens > 0)[:, None, None, None], out,
+                     jnp.zeros((), q.dtype))
 
 
 # ------------------------------------------ grouped heads, selected pages
@@ -458,17 +573,20 @@ def _listed_attention_kernel(q, k_pages, v_pages, page_tables, query_lens,
 
 def ragged_paged_attention(q, k_pages, v_pages, page_tables, query_lens,
                            context_lens, scale=None, path=None, layer=None,
-                           selected=None, total_q=None):
+                           selected=None, total_q=None, items=None):
     """Fused prefill+decode attention over a paged KV cache (see module
     docstring for layouts).  ``layer`` (a traced int32 scalar) comes with
     a stacked ``[L, P, page_size, H, hd]`` pool and names the layer whose
     pages are read.  ``path`` is one of ``dispatch.MOSAIC`` /
     ``INTERPRET`` / ``REFERENCE``; ``None`` takes the Mosaic kernel on a
     TPU and the jnp gather reference elsewhere (identical contract, fp32
-    softmax in both).  ``selected=(sel_blocks, dense_len)`` takes the
-    grouped-heads / selected-pages mode over a head-major stacked pool
-    ``[L, P, Hkv, page_size, hd]``; ``total_q`` (static) then bounds the
-    query tokens of all rows together, which bounds the work list."""
+    softmax in both).  ``items`` is ``ragged_work_items`` of the same
+    lengths, for a caller that makes several calls on them (a step's
+    layers); left out, the kernel builds it.  ``selected=(sel_blocks,
+    dense_len)`` takes the grouped-heads / selected-pages mode over a
+    head-major stacked pool ``[L, P, Hkv, page_size, hd]``; ``total_q``
+    (static) then bounds the query tokens of all rows together, which
+    bounds its work list."""
     if (k_pages.ndim == 5) != (layer is not None):
         raise ValueError("a stacked [L, P, page_size, H, hd] pool comes "
                          "with its `layer`, a one-layer pool without")
@@ -497,7 +615,7 @@ def ragged_paged_attention(q, k_pages, v_pages, page_tables, query_lens,
     return _ragged_attention_kernel(q, k_pages, v_pages, page_tables,
                                     query_lens, context_lens, scale,
                                     interpret=(path == dispatch.INTERPRET),
-                                    layer=layer)
+                                    layer=layer, items=items)
 
 
 def paged_attention(q, k_pages, v_pages, page_tables, seq_lens, scale=None,

@@ -341,7 +341,9 @@ def gpt_loss(cfg: GPTConfig, params, tokens, labels=None, dropout_key=None):
 # tokens' K/V into [layer, page, slot] and the kernel reads [layer, page]
 # blocks, so the donated pools stay where they are from entry to return.
 # Carried, not xs/ys: as xs/ys XLA slices every layer's pages out,
-# restacks them and copies both pools, whole, every step.
+# restacks them and copies both pools, whole, every step.  The kernel's
+# work list depends on the rows' lengths alone: it is built once, outside
+# the scan, and handed to every layer's call.
 
 
 def gpt_ragged_step(cfg: GPTConfig, params, tokens, row_of_token,
@@ -399,11 +401,16 @@ def gpt_ragged_step(cfg: GPTConfig, params, tokens, row_of_token,
     scat_row = jnp.where(valid, row_c, B)              # OOB => dropped
     scat_slot = jnp.minimum(slot_of_token, Q - 1)
 
-    from ..kernels.paged_attention import ragged_paged_attention
+    from ..kernels.paged_attention import (ragged_paged_attention,
+                                           ragged_work_items)
 
-    def attend(q, kp, vp, tables, q_lens, ctx_lens, layer):
+    items = ragged_work_items(query_lens, context_lens, page_size,
+                              page_tables.shape[1])
+
+    def attend(q, kp, vp, tables, q_lens, ctx_lens, layer, items):
         return ragged_paged_attention(q, kp, vp, tables, q_lens, ctx_lens,
-                                      path=attn_path, layer=layer)
+                                      path=attn_path, layer=layer,
+                                      items=items)
 
     if mesh is not None:
         # heads that do not divide stay whole on every shard, as
@@ -414,7 +421,7 @@ def gpt_ragged_step(cfg: GPTConfig, params, tokens, row_of_token,
         rep = jax.sharding.PartitionSpec()
         attend = jax.shard_map(
             attend, mesh=mesh,
-            in_specs=(heads, pool, pool, rep, rep, rep, rep),
+            in_specs=(heads, pool, pool, rep, rep, rep, rep, rep),
             out_specs=heads, check_vma=False)
 
     def body(carry, xs):
@@ -438,7 +445,7 @@ def gpt_ragged_step(cfg: GPTConfig, params, tokens, row_of_token,
             q_pad = jnp.zeros((B, Q, H, hd), q.dtype) \
                 .at[scat_row, scat_slot].set(q, mode="drop")
             attn = attend(q_pad, kp, vp, page_tables, query_lens,
-                          context_lens, layer)
+                          context_lens, layer, items)
             attn = attn[row_c, scat_slot].reshape(T, D).astype(x.dtype)
             x = x + jnp.einsum("td,de->te", attn, bp["proj_w"]) \
                 + bp["proj_b"]

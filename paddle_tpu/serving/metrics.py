@@ -120,10 +120,12 @@ class ServingMetrics:
         self.ttft = add(Histogram("serving_ttft_seconds"))
         self.decode_token = add(Histogram("serving_decode_token_seconds"))
         # buckets from a microsecond: most phases of a step are host
-        # code that takes tens of them
+        # code that takes tens of them.  The newest 8192 steps are kept:
+        # a reader cuts a 30 s window out of them by count, and a step
+        # takes under 15 ms
         self.step_phase = add(Histogram(
             "serving_step_phase_seconds", labelnames=("phase",),
-            start=1e-6, count=24,
+            start=1e-6, count=24, reservoir=8192,
             help="seconds of one Engine.step() call spent in each of "
                  "its phases: one observation per phase per call, 0 "
                  "for a phase the call did not reach"))

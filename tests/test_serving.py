@@ -11,10 +11,12 @@ import jax
 import jax.numpy as jnp
 
 from paddle_tpu.kernels import dispatch
-from paddle_tpu.kernels.paged_attention import (_ragged_attention_kernel,
+from paddle_tpu.kernels.paged_attention import (_KEY_TILE, _SMALL_Q,
+                                                _ragged_attention_kernel,
                                                 _ragged_attention_ref,
                                                 paged_attention,
-                                                ragged_paged_attention)
+                                                ragged_paged_attention,
+                                                ragged_work_items)
 from paddle_tpu.models.gpt import (GPT_CONFIGS, _layer_norm, gpt_forward,
                                    gpt_init, gpt_ragged_step)
 from paddle_tpu.serving import (Engine, PagedKVCache, RequestState,
@@ -184,9 +186,11 @@ class TestRaggedAttention:
     """The unified kernel: every batch row at an arbitrary position —
     mid-prefill chunk, decode step, or idle."""
 
-    def _case(self, qlens, ctxs, Q=6, dtype=jnp.float32):
+    def _case(self, qlens, ctxs, Q=6, dtype=jnp.float32, P=12, M=6, ps=4):
+        """Every row's table is its own draw of ``M`` of the ``P`` pages,
+        scattered and out of order."""
         B = len(qlens)
-        H, hd, P, ps, M = 2, 8, 12, 4, 6
+        H, hd = 2, 8
         ks = jax.random.split(jax.random.key(2), 3)
         q = jax.random.normal(ks[0], (B, Q, H, hd), dtype)
         kp = jax.random.normal(ks[1], (P, ps, H, hd), dtype)
@@ -196,6 +200,12 @@ class TestRaggedAttention:
             np.stack([rng.permutation(P)[:M] for _ in range(B)]), jnp.int32)
         return (q, kp, vp, tables, jnp.asarray(qlens, jnp.int32),
                 jnp.asarray(ctxs, jnp.int32))
+
+    # the kernel's key tile in pages of 16, and tables wide enough for
+    # contexts of three tiles: the walk below is over real item lists
+    T = -(-_KEY_TILE // 16) * 16
+    S = _SMALL_Q
+    WIDE = dict(Q=S + 4, ps=16, P=3 * T // 16 + 8, M=3 * T // 16)
 
     def test_ref_matches_dense_causal_oracle(self):
         """Each query token must equal dense softmax attention over the
@@ -222,20 +232,78 @@ class TestRaggedAttention:
                                            np.asarray(ref),
                                            rtol=1e-5, atol=1e-5)
 
-    def test_kernel_matches_ref_mixed_rows(self):
-        """Interpret-mode kernel == ref for a batch mixing a mid-prefill
-        chunk, a prompt-completing chunk, a decode row, and an idle row,
-        with context lengths straddling page boundaries."""
-        for qlens, ctxs in ([(5, 1, 3, 0), (14, 6, 3, 0)],
-                            [(6, 6, 1, 1), (7, 8, 9, 24)],
-                            [(1, 1, 1, 1), (4, 5, 16, 17)]):
-            q, kp, vp, tables, ql, cl = self._case(list(qlens), list(ctxs))
-            scale = 1.0 / np.sqrt(q.shape[-1])
-            ref = _ragged_attention_ref(q, kp, vp, tables, ql, cl, scale)
-            ker = _ragged_attention_kernel(q, kp, vp, tables, ql, cl,
-                                           scale, interpret=True)
-            np.testing.assert_allclose(np.asarray(ker), np.asarray(ref),
-                                       rtol=2e-5, atol=2e-5)
+    WALK = [
+        # the three mixes the kernel has always been held to (one tile)
+        ("mixed", (5, 1, 3, 0), (14, 6, 3, 0), {}),
+        ("page_edges", (6, 6, 1, 1), (7, 8, 9, 24), {}),
+        ("decode", (1, 1, 1, 1), (4, 5, 16, 17), {}),
+        # contexts at tile - 1, tile, tile + 1 and several tiles
+        ("tile_edges", (1, 1, 1, 1), (T - 1, T, T + 1, 3 * T), WIDE),
+        ("chunk_across_tiles", (S + 4, S + 4, 3, 1),
+         (T + 2, 2 * T + 1, T, 1), WIDE),
+        # query_len at, under and over the narrow body's width in one call
+        ("slot_widths", (S, S - 1, S + 1, 1), (T + S, S - 1, 2 * T, 2 * T),
+         WIDE),
+        # idle rows first, last and in the middle: a row's run of items
+        # starts and ends on its own
+        ("idle_first", (0, 0, 1, S + 2), (0, 0, 2 * T + 3, T + 1), WIDE),
+        ("idle_last", (S + 2, 1, 0, 0), (2 * T, T - 1, 0, 0), WIDE),
+        ("idle_middle", (1, 0, 0, S + 1), (T + 1, 0, 0, 3 * T), WIDE),
+        ("all_idle", (0, 0, 0, 0), (0, 0, 0, 0), WIDE),
+    ]
+
+    # every mix has 4 rows, so the interpreted kernel compiles once per
+    # table width and dtype, not once per case (a compile is seconds)
+    _kernel = staticmethod(jax.jit(
+        lambda q, kp, vp, tables, ql, cl, items=None: ragged_paged_attention(
+            q, kp, vp, tables, ql, cl, path=dispatch.INTERPRET, items=items)))
+
+    @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                           (jnp.bfloat16, 2e-2)])
+    @pytest.mark.parametrize("name,qlens,ctxs,kw", WALK,
+                             ids=[w[0] for w in WALK])
+    def test_kernel_matches_ref_mixed_rows(self, name, qlens, ctxs, kw,
+                                           dtype, tol):
+        """Interpret-mode kernel == ref for batches mixing mid-prefill
+        chunks, prompt-completing chunks, decode rows and idle rows, with
+        contexts straddling page and tile boundaries, over page tables
+        whose pages are scattered and out of order.  float32 within 2e-5;
+        bf16 within 2e-2 (the outputs are O(1) and rounded to 8 bits, and
+        the kernel rounds the probabilities to bf16 for the second
+        product where the reference keeps float32)."""
+        q, kp, vp, tables, ql, cl = self._case(list(qlens), list(ctxs),
+                                               dtype=dtype, **kw)
+        ref = _ragged_attention_ref(q, kp, vp, tables, ql, cl,
+                                    1.0 / np.sqrt(q.shape[-1]))
+        ker = self._kernel(q, kp, vp, tables, ql, cl)
+        assert ker.dtype == dtype
+        np.testing.assert_allclose(np.asarray(ker, np.float32),
+                                   np.asarray(ref, np.float32),
+                                   rtol=tol, atol=tol)
+
+    @pytest.mark.parametrize("name,qlens,ctxs,kw", WALK,
+                             ids=[w[0] for w in WALK])
+    def test_work_items_cover_each_live_row_once(self, name, qlens, ctxs,
+                                                 kw):
+        """The list: one item per tile a live row's context reaches, rows
+        in order and a row's tiles ascending, nothing for an idle row; and
+        the list handed in is the list the kernel builds itself."""
+        q, kp, vp, tables, ql, cl = self._case(list(qlens), list(ctxs),
+                                               **kw)
+        ps, M = kp.shape[1], tables.shape[1]
+        rows, tiles, n = ragged_work_items(ql, cl, ps, M)
+        tile = min(M, -(-_KEY_TILE // ps)) * ps
+        want = [(b, t) for b, (ql_b, cl_b) in enumerate(zip(qlens, ctxs))
+                if ql_b for t in range(max(1, -(-cl_b // tile)))]
+        assert int(n[0]) == len(want)
+        assert rows.shape == tiles.shape == (len(qlens) * -(-M * ps // tile),)
+        got = list(zip(np.asarray(rows)[:len(want)].tolist(),
+                       np.asarray(tiles)[:len(want)].tolist()))
+        assert got == want
+        inside = self._kernel(q, kp, vp, tables, ql, cl)
+        handed = self._kernel(q, kp, vp, tables, ql, cl, (rows, tiles, n))
+        np.testing.assert_array_equal(np.asarray(inside),
+                                      np.asarray(handed))
 
     def test_decode_entry_is_qlen1_degenerate_row(self):
         """The legacy decode entry must equal a Q=1 ragged call."""

@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import sys
 import time
 from unittest import mock
@@ -43,32 +44,44 @@ def _on(sharding, *shape_dtypes):
 
 
 def compile_kernels(device):
-    """{name: Compiled} for the ragged kernel at H16 x hd128 (a prefill
-    chunk of 64 and a decode row on one layer's pool, and the serving
-    step's call: a layer of a stacked pool) and flash forward+backward at
-    head dims 64 and 128 — the shapes ``gpt3-1.3b``/``gpt2-medium`` give
-    them."""
+    """{name: Compiled} for the equal-heads ragged kernel at the GPT
+    serving cell's shapes (16 rows, 16 heads of 128, pages of 16, a table
+    128 wide) — a 128-token chunk on one layer's pool with the work list
+    built inside (``ragged_q128``: the wide and the narrow body), the
+    decode entry's one query slot (``ragged_q1``), the serving step's
+    call, a layer of a stacked pool with the list handed in
+    (``ragged_stacked``), the 4 heads a chip holds under ``mp=4``
+    (``ragged_h4``) — and flash forward+backward at head dims 64 and 128,
+    the shapes ``gpt3-1.3b``/``gpt2-medium`` give them."""
     from paddle_tpu.kernels import dispatch
     from paddle_tpu.kernels.flash_attention import flash_attention
-    from paddle_tpu.kernels.paged_attention import ragged_paged_attention
+    from paddle_tpu.kernels.paged_attention import (ragged_paged_attention,
+                                                    ragged_work_items)
 
     one = SingleDeviceSharding(device)
     bf16, i32 = jnp.bfloat16, jnp.int32
     out = {}
 
-    B, H, hd, pages, ps, max_pages = 8, 16, 128, 256, 16, 128
-    ragged = jax.jit(lambda *a, layer=None: ragged_paged_attention(
-        *a, path=dispatch.MOSAIC, layer=layer))
+    B, hd, pages, ps, max_pages = 16, 128, 256, 16, 128
 
-    def ragged_args(Q, *stack):
+    @functools.partial(jax.jit, static_argnames="listed")
+    def ragged(*a, layer=None, listed=False):
+        items = ragged_work_items(a[4], a[5], ps, max_pages) if listed \
+            else None
+        return ragged_paged_attention(*a, path=dispatch.MOSAIC, layer=layer,
+                                      items=items)
+
+    def ragged_args(Q, *stack, H=16):
         pool = ((*stack, pages, ps, H, hd), bf16)
         return _on(one, ((B, Q, H, hd), bf16), pool, pool,
                    ((B, max_pages), i32), ((B,), i32), ((B,), i32))
 
-    for Q in (64, 1):
+    for Q in (128, 1):
         out[f"ragged_q{Q}"] = ragged.lower(*ragged_args(Q)).compile()
+    out["ragged_h4"] = ragged.lower(*ragged_args(128, H=4)).compile()
     out["ragged_stacked"] = ragged.lower(
-        *ragged_args(64, 4), layer=_on(one, ((), i32))[0]).compile()
+        *ragged_args(128, 4), layer=_on(one, ((), i32))[0],
+        listed=True).compile()
 
     def flash_loss(q, k, v):
         return flash_attention(q, k, v, causal=True,
@@ -211,8 +224,8 @@ def _report(name, compile_fn):
 def main(argv):
     devices = topology_devices()
     ok = True
-    ok &= _report("kernels (ragged q64/q1/stacked, flash hd64/hd128; memory "
-                  "is the last one's)",
+    ok &= _report("kernels (ragged q128/q1/h4/stacked, flash hd64/hd128; "
+                  "memory is the last one's)",
                   lambda: list(compile_kernels(devices[0]).values())[-1])
     ok &= _report("train 1.3b b8xs2048 one chip",
                   lambda: lower_train_step(devices[:1]).compile())
