@@ -469,14 +469,11 @@ def check_requests(reqs, new_tokens):
 
 
 def lowered_serve_step(engine):
-    import jax.numpy as jnp
+    from paddle_tpu.models.ragged import batch_shapes
 
-    B, T = engine.max_batch_size, engine.token_budget
-    i32 = lambda *shape: jnp.zeros(shape, jnp.int32)
     return engine._step_fn.lower(
-        engine.params, engine.cache.k_pages, engine.cache.v_pages,
-        i32(T), i32(T), i32(T), i32(B), i32(B),
-        i32(B, engine.cache.max_pages_per_seq))
+        engine.params, *engine.cache.state_arrays(),
+        batch_shapes(*engine.batch_dims))
 
 
 def leg_serve(args):
@@ -555,6 +552,7 @@ def ragged_logits_diff(sz, kernel_path):
 
     from paddle_tpu.kernels import dispatch
     from paddle_tpu.models.gpt import GPT_CONFIGS, gpt_ragged_step
+    from paddle_tpu.models.ragged import empty_batch
 
     cfg = GPT_CONFIGS[sz["model"]]
     params = init_params(cfg)
@@ -563,10 +561,8 @@ def ragged_logits_diff(sz, kernel_path):
     T = Q + B - 1                                # the engine's token budget
     max_pages = math.ceil(cfg.max_seq_len / ps)
     rng = np.random.RandomState(2)
-    tokens = np.zeros(T, np.int32)
-    row_of = np.full(T, B, np.int32)             # B marks padding slots
-    slot_of = np.zeros(T, np.int32)
-    tables = np.zeros((B, max_pages), np.int32)
+    batch = empty_batch(B, T, max_pages)
+    tokens, row_of, slot_of, qlens, ctxs, tables = batch
     off = page = 0
     for b, (qlen, ctx) in enumerate(rows):
         n = math.ceil(ctx / ps)
@@ -575,9 +571,8 @@ def ragged_logits_diff(sz, kernel_path):
         tokens[off:off + qlen] = rng.randint(0, cfg.vocab_size, qlen)
         row_of[off:off + qlen] = b
         slot_of[off:off + qlen] = np.arange(qlen)
+        qlens[b], ctxs[b] = qlen, ctx
         off += qlen
-    qlens = np.asarray([r[0] for r in rows], np.int32)
-    ctxs = np.asarray([r[1] for r in rows], np.int32)
     pool = (cfg.num_layers, page + 8, ps, cfg.num_heads, cfg.head_dim)
     kk, kv = jax.random.split(jax.random.key(3))
     k_pages = jax.random.normal(kk, pool, cfg.jdtype())
@@ -586,8 +581,7 @@ def ragged_logits_diff(sz, kernel_path):
     def logits(path):
         step = jax.jit(functools.partial(gpt_ragged_step, cfg, max_q=Q,
                                          attn_path=path))
-        out, _, _ = step(params, tokens, row_of, slot_of, qlens, ctxs,
-                         k_pages, v_pages, tables)
+        out, _, _ = step(params, batch, k_pages, v_pages)
         return np.asarray(out, np.float32)[qlens > 0]
 
     ker, ref = logits(kernel_path), logits(dispatch.REFERENCE)

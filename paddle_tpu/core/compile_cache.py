@@ -11,8 +11,8 @@ the 1.3B train step and the serving step compile in 2-8 s, jax's own
 default of 1 s sits too close under them — and skips the eager primitives,
 which compile in tens of milliseconds and would only bloat the directory.
 
-Callers: ``chip_smoke.py``'s legs, ``bench.py`` and ``tests/conftest.py``,
-each before its first compile.
+Callers: ``chip_smoke.py``'s legs, ``benchmark/run.py`` and
+``tests/conftest.py``, each before its first compile.
 """
 from __future__ import annotations
 

@@ -31,7 +31,8 @@ module owning mesh construction + PartitionSpec rules"):
   semantics: params replicated, optimizer state sharded).
 - :func:`assert_placement` / :func:`placement_report` — verify via
   ``addressable_shards`` that an array is ACTUALLY laid out as the
-  spec intends (the bench's non-dry-run proof of placement).
+  spec intends (a spec proves nothing: ``resolve_spec`` degrades
+  silently to replication).
 - :func:`replica_peers` — which ranks of a (dp, mp, pp, sharding)
   process grid hold bitwise-identical state (same non-dp coordinates):
   the peer set the integrity sentinel's cross-rank fingerprint compare
@@ -41,7 +42,7 @@ Consumers: ``hapi/model.py`` (train/eval steps jitted with
 ``in_shardings``/``out_shardings``, donated params),
 ``serving/engine.py`` (KV page pool sharded along ``mp``),
 ``distributed/checkpoint.py`` (per-rank addressable-shard saves under
-the commit barrier), and ``bench.py --section multichip``.
+the commit barrier).
 """
 from __future__ import annotations
 
@@ -291,8 +292,7 @@ def zero_opt_specs(param_spec_tree, state_like, mesh, axis="sharding"):
 def placement_report(tree, prefix=""):
     """{leaf path: {spec, devices, distinct_windows, shard_shape}} from
     each leaf's LIVE ``addressable_shards`` — what is actually on the
-    devices, not what was requested.  The bench embeds this as its
-    non-dry-run placement proof."""
+    devices, not what was requested."""
     flat, _, paths = _leaf_paths(tree)
     out = {}
     for path, arr in zip(paths, flat):

@@ -87,7 +87,7 @@ class Config:
     # ---- autoregressive generation (serving engine) ----
     def enable_generation(self, model_config, params=None, *, page_size=16,
                           num_pages=256, max_batch_size=4, chunk_len=None,
-                          prefill_len=None, prefix_cache=None):
+                          prefix_cache=None):
         """Switch create_predictor to a GenerationPredictor: a
         continuous-batching, paged-cache generation engine
         (paddle_tpu.serving) over the given model — a served-model
@@ -97,18 +97,17 @@ class Config:
         pools, max_batch_size the in-flight batch.  chunk_len bounds the
         prompt tokens any request contributes to one unified step
         (chunked prefill — prompts of any admissible length are split
-        into chunk_len-token rows scheduled next to decode rows;
-        prefill_len is the accepted legacy alias).  prefix_cache
-        enables radix prefix reuse: a prompt sharing a cached prefix
-        skips that prefill entirely, token-identically.  It defaults to
+        into chunk_len-token rows scheduled next to decode rows).
+        prefix_cache enables radix prefix reuse: a prompt sharing a
+        cached prefix skips that prefill entirely, token-identically.  It
+        defaults to
         on, except for a model with recurrent layers, which is served
         cold and for which ``True`` is refused."""
         self.generation = {
             "config": model_config, "params": params,
             "knobs": {"page_size": page_size, "num_pages": num_pages,
                       "max_batch_size": max_batch_size,
-                      "chunk_len": chunk_len, "prefill_len": prefill_len,
-                      "prefix_cache": prefix_cache},
+                      "chunk_len": chunk_len, "prefix_cache": prefix_cache},
         }
         return self
 

@@ -11,4 +11,4 @@ over every page, or grouped heads over selected pages) and the lightning
 """
 from .flash_attention import flash_attention, flash_attention_available  # noqa: F401
 from .lightning_attention import lightning_attention  # noqa: F401
-from .paged_attention import paged_attention, ragged_paged_attention  # noqa: F401
+from .paged_attention import ragged_paged_attention  # noqa: F401

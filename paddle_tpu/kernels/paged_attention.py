@@ -63,9 +63,8 @@ Layouts:
 Returns [B, Q, H, hd] in q.dtype; padded query slots and idle rows
 return zeros.
 
-``paged_attention`` (the original decode-only entry: one query token per
-row, ``seq_lens`` masking) is the Q == 1 degenerate case of the same
-entry.
+A decode-only call (one query token per row) is the Q == 1 case of the
+same entry: ``query_lens = context_lens > 0``.
 
 Grouped heads and selected pages (``selected=``, block-sparse attention
 with grouped-query heads).  The pool is then head-major,
@@ -105,8 +104,7 @@ from jax.experimental.pallas import tpu as pltpu
 from ..ops.linalg import mxu_precision
 from . import dispatch
 
-__all__ = ["paged_attention", "ragged_paged_attention",
-           "ragged_work_items"]
+__all__ = ["ragged_paged_attention", "ragged_work_items"]
 
 _NEG_INF = -1e30
 
@@ -616,16 +614,3 @@ def ragged_paged_attention(q, k_pages, v_pages, page_tables, query_lens,
                                     query_lens, context_lens, scale,
                                     interpret=(path == dispatch.INTERPRET),
                                     layer=layer, items=items)
-
-
-def paged_attention(q, k_pages, v_pages, page_tables, seq_lens, scale=None,
-                    path=None):
-    """Single-token decode attention over a paged KV cache: q [B, H, hd],
-    one query token per sequence attending over its first ``seq_lens``
-    kv tokens (0 marks an inactive slot) — the query_len == 1 degenerate
-    row of ``ragged_paged_attention``, kept as a stable API for
-    decode-only callers and tests."""
-    seq_lens = seq_lens.astype(jnp.int32)
-    return ragged_paged_attention(
-        q[:, None], k_pages, v_pages, page_tables,
-        (seq_lens > 0).astype(jnp.int32), seq_lens, scale, path)[:, 0]

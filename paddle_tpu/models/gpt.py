@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from .ragged import RaggedBatch, RaggedView
+
 __all__ = ["GPTConfig", "gpt_init", "gpt_forward", "gpt_loss",
            "gpt_param_specs", "gpt_ragged_step", "GPT",
            "GPT_CONFIGS"]
@@ -61,7 +63,8 @@ class GPTConfig:
 
 
 GPT_CONFIGS = {
-    # reference benchmark family (BASELINE.json configs)
+    # the reference's benchmark family (BASELINE.json); gpt3-1.3b and
+    # gpt2-medium are the configurations of BENCHMARK.json's GPT cells
     "gpt2-small": GPTConfig(hidden=768, num_layers=12, num_heads=12,
                             ffn_hidden=3072),
     "gpt2-medium": GPTConfig(hidden=1024, num_layers=24, num_heads=16,
@@ -346,24 +349,16 @@ def gpt_loss(cfg: GPTConfig, params, tokens, labels=None, dropout_key=None):
 # the scan, and handed to every layer's call.
 
 
-def gpt_ragged_step(cfg: GPTConfig, params, tokens, row_of_token,
-                    slot_of_token, query_lens, context_lens, k_pages,
-                    v_pages, page_tables, *, max_q=None, attn_path=None,
-                    mesh=None):
+def gpt_ragged_step(cfg: GPTConfig, params, batch: RaggedBatch, k_pages,
+                    v_pages, *, max_q=None, attn_path=None, mesh=None):
     """Unified ragged step over the paged KV cache — the serving
     engine's single jitted program for both prompt chunks and decode.
 
-    Packing contract: ``tokens`` [T] holds every scheduled query token,
-    row-major (row b's ``query_lens[b]`` tokens are contiguous and in
-    order; rows are packed in ascending batch-slot order).
-    ``row_of_token`` [T] names each token's batch row (== B for padding
-    slots, which are dropped everywhere); ``slot_of_token`` [T] is the
-    token's index within its row's chunk.  ``context_lens`` [B] counts
-    the row's total tokens *including* this chunk, so token t of row b
-    sits at absolute position ``context_lens[b] - query_lens[b] + t``.
-    ``max_q`` (static) bounds any single row's chunk — the padded query
-    width handed to the attention kernel.  ``attn_path`` (static) is
-    handed to ``ragged_paged_attention`` as its ``path``: ``None`` lets
+    ``batch`` is the scheduler's ``RaggedBatch``; its packing contract is
+    written in ``models/ragged.py``.  ``max_q`` (static) bounds any
+    single row's chunk — the padded query width handed to the attention
+    kernel.  ``attn_path`` (static) is handed to
+    ``ragged_paged_attention`` as its ``path``: ``None`` lets
     ``kernels.dispatch`` pick from the platform.  ``mesh`` (static) is
     the serving mesh when params and pages are sharded over its "mp"
     axis: attention then runs under a shard_map over the head axis —
@@ -372,34 +367,22 @@ def gpt_ragged_step(cfg: GPTConfig, params, tokens, row_of_token,
 
     Compute is flat [T, D] (a decode row costs one token, not a padded
     chunk); only the attention kernel sees a per-row padded [B, max_q]
-    view, scattered/gathered around the call.  Returns (logits [B, V]
-    at each row's last packed token — the next-token distribution for a
-    decode row or a prompt-completing chunk; rows with query_len 0
-    return garbage the engine ignores — k_pages, v_pages)."""
+    view.  Returns (logits [B, V] at each row's last packed token — the
+    next-token distribution for a decode row or a prompt-completing
+    chunk; rows with query_len 0 return garbage the engine ignores —
+    k_pages, v_pages)."""
+    tokens, query_lens, context_lens, page_tables = (
+        batch.tokens, batch.query_lens, batch.context_lens,
+        batch.page_tables)
     T = tokens.shape[0]
-    B = query_lens.shape[0]
     H, hd, D = cfg.num_heads, cfg.head_dim, cfg.hidden
-    P = k_pages.shape[1]
     page_size = k_pages.shape[2]
-    Q = max_q or T
-
-    row_c = jnp.minimum(row_of_token, B - 1)
-    valid = ((row_of_token < B)
-             & (slot_of_token < jnp.take(query_lens, row_c)))
-    pos = jnp.clip(jnp.take(context_lens - query_lens, row_c)
-                   + slot_of_token, 0, cfg.max_seq_len - 1)        # [T]
+    view = RaggedView(batch, max_q=max_q, max_seq_len=cfg.max_seq_len,
+                      num_pages=k_pages.shape[1], page_size=page_size)
 
     x = jnp.take(params["wte"], tokens, axis=0) + \
-        jnp.take(params["wpe"], pos, axis=0)
+        jnp.take(params["wpe"], view.pos, axis=0)
     x = x.astype(cfg.jdtype())                                     # [T, D]
-
-    page_of_pos = jnp.take_along_axis(
-        jnp.take(page_tables, row_c, axis=0),
-        (pos // page_size)[:, None], axis=1)[:, 0]
-    safe_page = jnp.where(valid, page_of_pos, P)       # OOB => dropped
-    slot_in_page = pos % page_size
-    scat_row = jnp.where(valid, row_c, B)              # OOB => dropped
-    scat_slot = jnp.minimum(slot_of_token, Q - 1)
 
     from ..kernels.paged_attention import (ragged_paged_attention,
                                            ragged_work_items)
@@ -433,20 +416,16 @@ def gpt_ragged_step(cfg: GPTConfig, params, tokens, row_of_token,
             qkv = qkv.reshape(T, H, 3, hd)
             q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]  # [T, H, hd]
             with jax.named_scope("kv_write"):
-                # masked-out tokens were routed to page P: out of bounds,
-                # which mode="drop" discards
-                kp = kp.at[layer, safe_page, slot_in_page].set(
+                # a masked token's page is out of bounds, which
+                # mode="drop" discards
+                kp = kp.at[layer, view.page, view.slot_in_page].set(
                     k.astype(kp.dtype), mode="drop")
-                vp = vp.at[layer, safe_page, slot_in_page].set(
+                vp = vp.at[layer, view.page, view.slot_in_page].set(
                     v.astype(vp.dtype), mode="drop")
-            # the kernel wants per-row padded queries; scatter the packed
-            # tokens out, gather the outputs back flat (padding slots
-            # read zeros/junk that never reaches pages or logits)
-            q_pad = jnp.zeros((B, Q, H, hd), q.dtype) \
-                .at[scat_row, scat_slot].set(q, mode="drop")
-            attn = attend(q_pad, kp, vp, page_tables, query_lens,
+            # the kernel wants one padded row of queries per request
+            attn = attend(view.pad(q), kp, vp, page_tables, query_lens,
                           context_lens, layer, items)
-            attn = attn[row_c, scat_slot].reshape(T, D).astype(x.dtype)
+            attn = view.unpad(attn).reshape(T, D).astype(x.dtype)
             x = x + jnp.einsum("td,de->te", attn, bp["proj_w"]) \
                 + bp["proj_b"]
 
@@ -472,9 +451,7 @@ def gpt_ragged_step(cfg: GPTConfig, params, tokens, row_of_token,
         (params["blocks"], jnp.arange(cfg.num_layers, dtype=jnp.int32)))
     with jax.named_scope("lm_head"):
         x = _layer_norm(x, params["lnf_g"], params["lnf_b"])
-        # row b's last packed token sits at cumsum(query_lens)[b] - 1
-        last = jnp.clip(jnp.cumsum(query_lens) - 1, 0, T - 1)
-        x_last = jnp.take(x, last, axis=0)                         # [B, D]
+        x_last = view.last(x)                                      # [B, D]
         if cfg.tie_embeddings:
             logits = jnp.einsum("bd,vd->bv", x_last, params["wte"])
         else:

@@ -26,8 +26,9 @@ The cache therefore holds pages of keys and values for the sparse layers
 (without which every step would re-read every key), and for each
 lightning layer one ``[heads, hd, hd]`` float32 state per batch row.
 
-``hybrid_ragged_step`` has the packing contract of ``gpt_ragged_step``; all
-four state pools are carried in place.
+``hybrid_ragged_step`` takes the scheduler's ``RaggedBatch``
+(``models/ragged.py``), as ``gpt_ragged_step`` does; all four state pools
+are carried in place.
 """
 from __future__ import annotations
 
@@ -36,6 +37,8 @@ import math
 
 import jax
 import jax.numpy as jnp
+
+from .ragged import RaggedBatch, RaggedView
 
 __all__ = ["HybridConfig", "hybrid_init", "hybrid_ragged_step",
            "hybrid_state_spec", "HYBRID_CONFIGS", "SPARSE", "LIGHTNING"]
@@ -243,17 +246,17 @@ def _write_compressed(cfg, kc, kp, layer, tables, query_lens, context_lens,
     return kc.at[layer, home, j % cfg.spans_per_page].set(kbar, mode="drop")
 
 
-def _select_tiles(B, T, row_c, slot_of_token, valid, query_lens):
+def _select_tiles(view: RaggedView):
     """The packed tokens regrouped into tiles of ``_SELECT_TILE`` tokens
     of one row each, so that a tile is scored against one row's
     compressed keys: ``(number of tiles, tile of token, lane of token)``
     with invalid tokens sent to tile ``NT`` (out of bounds)."""
-    n = _SELECT_TILE
-    nt = B + -(-T // n)
-    per_row = -(-query_lens // n)
+    n, slots = _SELECT_TILE, view.batch.slots
+    nt = view.B + -(-view.T // n)
+    per_row = -(-view.batch.query_lens // n)
     first = jnp.cumsum(per_row) - per_row
-    tile = jnp.take(first, row_c) + slot_of_token // n
-    return nt, jnp.where(valid, tile, nt), slot_of_token % n
+    tile = jnp.take(first, view.row) + slots // n
+    return nt, jnp.where(view.valid, tile, nt), slots % n
 
 
 def _select_blocks(cfg, q, kc, layer, tables, tile_row, tile_pos, tile,
@@ -306,14 +309,14 @@ def _select_blocks(cfg, q, kc, layer, tables, tile_row, tile_pos, tile,
 # --------------------------------------------------------------- the step
 
 
-def hybrid_ragged_step(cfg: HybridConfig, params, tokens, row_of_token,
-                       slot_of_token, query_lens, context_lens, k_pages,
-                       v_pages, kc_pages, lin_state, page_tables, *,
+def hybrid_ragged_step(cfg: HybridConfig, params, batch: RaggedBatch,
+                       k_pages, v_pages, kc_pages, lin_state, *,
                        max_q=None, attn_path=None, dense_only=False):
     """Unified ragged step of the hybrid decoder over its four state
-    pools; the packing contract is ``gpt_ragged_step``'s.  A row whose
-    chunk starts at position 0 (a newly admitted or recomputed request)
-    starts its lightning layers from a zero state, inside the step.
+    pools; ``batch`` is the scheduler's ``RaggedBatch``
+    (``models/ragged.py``).  A row whose chunk starts at position 0 (a
+    newly admitted or recomputed request: ``view.fresh``) starts its
+    lightning layers from a zero state, inside the step.
     ``dense_only`` (static) leaves block selection out: every query
     attends over its whole context (a control for the benchmark).
 
@@ -322,33 +325,23 @@ def hybrid_ragged_step(cfg: HybridConfig, params, tokens, row_of_token,
                                                lightning_slopes)
     from ..kernels.paged_attention import ragged_paged_attention
 
-    T, B = tokens.shape[0], query_lens.shape[0]
-    P, ps = k_pages.shape[1], k_pages.shape[3]
-    Q = max_q or T
+    tokens, query_lens, context_lens, page_tables = (
+        batch.tokens, batch.query_lens, batch.context_lens,
+        batch.page_tables)
+    T = tokens.shape[0]
     H, Hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     Hl, hdl = cfg.lightning_heads, cfg.lightning_head_dim
     c = cfg.residual_scale
-
-    row_c = jnp.minimum(row_of_token, B - 1)
-    valid = ((row_of_token < B)
-             & (slot_of_token < jnp.take(query_lens, row_c)))
-    pos = jnp.clip(jnp.take(context_lens - query_lens, row_c)
-                   + slot_of_token, 0, cfg.max_seq_len - 1)        # [T]
-    page_of_pos = jnp.take_along_axis(
-        jnp.take(page_tables, row_c, axis=0), (pos // ps)[:, None],
-        axis=1)[:, 0]
-    safe_page = jnp.where(valid, page_of_pos, P)       # OOB => dropped
-    slot_in_page = pos % ps
-    scat_row = jnp.where(valid, row_c, B)              # OOB => dropped
-    scat_slot = jnp.minimum(slot_of_token, Q - 1)
-    fresh = (context_lens - query_lens) == 0
+    view = RaggedView(batch, max_q=max_q, max_seq_len=cfg.max_seq_len,
+                      num_pages=k_pages.shape[1], page_size=k_pages.shape[3])
+    pos = view.pos
     slopes = lightning_slopes(Hl) if cfg.lightning_decay \
         else jnp.zeros((Hl,), jnp.float32)
 
     # `select` scores tokens in tiles of one row each
-    nt, tile, lane = _select_tiles(B, T, row_c, slot_of_token, valid,
-                                   query_lens)
-    tile_row = jnp.zeros((nt,), jnp.int32).at[tile].set(row_c, mode="drop")
+    nt, tile, lane = _select_tiles(view)
+    tile_row = jnp.zeros((nt,), jnp.int32).at[tile].set(view.row,
+                                                        mode="drop")
     tile_pos = jnp.full((nt, _SELECT_TILE), -1, jnp.int32).at[
         tile, lane].set(pos, mode="drop")
 
@@ -367,31 +360,28 @@ def hybrid_ragged_step(cfg: HybridConfig, params, tokens, row_of_token,
             with jax.named_scope("kv_write"):
                 # one [hd] row per (token, head): the pool keeps the
                 # head-major layout the kernel reads
-                at = (i, safe_page[:, None], jnp.arange(Hkv)[None, :],
-                      slot_in_page[:, None])
+                at = (i, view.page[:, None], jnp.arange(Hkv)[None, :],
+                      view.slot_in_page[:, None])
                 kp = kp.at[at].set(k.astype(kp.dtype), mode="drop")
                 vp = vp.at[at].set(v.astype(vp.dtype), mode="drop")
                 kc = _write_compressed(cfg, kc, kp, i, page_tables,
-                                       query_lens, context_lens, Q)
+                                       query_lens, context_lens, view.Q)
             selected = (None, cfg.dense_len)
             if not dense_only:
                 with jax.named_scope("select"):
                     sel_tok, flag_tok = _select_blocks(
                         cfg, q, kc, i, page_tables, tile_row, tile_pos,
                         tile, lane, nt)
-                    pad = lambda a, fill: jnp.full(
-                        (B, Q) + a.shape[1:], fill, a.dtype).at[
-                        scat_row, scat_slot].set(a, mode="drop").transpose(
-                        0, 2, 1, 3)
-                    selected = (pad(sel_tok, -1), cfg.dense_len,
-                                pad(flag_tok, False))
-            q_pad = jnp.zeros((B, Q, H, hd), q.dtype) \
-                .at[scat_row, scat_slot].set(q, mode="drop")
+                    # the kernel reads the lists by [row, group, token]
+                    selected = (
+                        view.pad(sel_tok, -1).transpose(0, 2, 1, 3),
+                        cfg.dense_len,
+                        view.pad(flag_tok, False).transpose(0, 2, 1, 3))
             attn = ragged_paged_attention(
-                q_pad, kp, vp, page_tables, query_lens, context_lens,
+                view.pad(q), kp, vp, page_tables, query_lens, context_lens,
                 path=attn_path, layer=jnp.int32(i),
                 selected=selected, total_q=T)
-            attn = attn[row_c, scat_slot].reshape(T, H * hd).astype(x.dtype)
+            attn = view.unpad(attn).reshape(T, H * hd).astype(x.dtype)
             gate = jax.nn.sigmoid(jnp.einsum("td,de->te", h, p["gate_w"][i]))
             x = x + c * jnp.einsum("te,ed->td", gate * attn, p["o_w"][i])
         with jax.named_scope("mlp"):
@@ -410,14 +400,13 @@ def hybrid_ragged_step(cfg: HybridConfig, params, tokens, row_of_token,
                       cfg.rope_theta)
             v = proj("v_w")
             q = (q.astype(jnp.float32) / math.sqrt(hdl)).astype(q.dtype)
-            pad = lambda a: jnp.zeros((B, Q, Hl, hdl), a.dtype).at[
-                scat_row, scat_slot].set(a, mode="drop").transpose(
-                0, 2, 1, 3)
+            # the kernel is head-major: [B, Hl, Q, hd] in and out
+            pad = lambda a: view.pad(a).transpose(0, 2, 1, 3)
             with jax.named_scope("state_write"):
                 o, state = lightning_attention(
                     pad(q), pad(k), pad(v), state, slopes, query_lens,
-                    fresh, layer=jnp.int32(i), path=attn_path)
-            o = o[row_c, :, scat_slot]                       # [T, Hl, hd]
+                    view.fresh, layer=jnp.int32(i), path=attn_path)
+            o = view.unpad(o, q_axis=2)                      # [T, Hl, hd]
             o = _rms(o, p["o_norm"][i], cfg.rms_eps).reshape(T, Hl * hdl)
             gate = jax.nn.sigmoid(jnp.einsum("td,de->te", h, p["gate_w"][i]))
             x = x + c * jnp.einsum("te,ed->td", gate * o.astype(x.dtype),
@@ -438,8 +427,6 @@ def hybrid_ragged_step(cfg: HybridConfig, params, tokens, row_of_token,
 
     with jax.named_scope("lm_head"):
         x = _rms(x, params["norm_f"], cfg.rms_eps)
-        # row b's last packed token sits at cumsum(query_lens)[b] - 1
-        last = jnp.clip(jnp.cumsum(query_lens) - 1, 0, T - 1)
-        logits = jnp.einsum("bd,dv->bv", jnp.take(x, last, axis=0),
+        logits = jnp.einsum("bd,dv->bv", view.last(x),
                             params["lm_head"]) / cfg.logit_divisor
     return logits, k_pages, v_pages, kc_pages, lin_state

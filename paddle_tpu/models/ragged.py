@@ -1,0 +1,142 @@
+"""The ragged batch: the one format between the serving scheduler and every
+served model's step.
+
+A step advances ``B`` batch rows at once, each at its own point in its
+life: a prompt chunk of several tokens, a decode row of one, or idle.  The
+scheduler (``serving/engine.py`` ``Engine._pack``) packs the query tokens
+of all rows into one flat axis of static width ``T`` and hands the model's
+step a :class:`RaggedBatch`; the step (``gpt_ragged_step``,
+``hybrid_ragged_step``) reads it through a :class:`RaggedView`.  What the
+six arrays mean, how padding is marked and how a packed token finds its
+row, its position and its place in the page pool is written here and
+nowhere else: a change of the format (one packed transfer, sampled ids
+coming back) is an edit to this file and to ``Engine._pack``.
+
+Nothing here imports ``paddle_tpu.serving``: this is the lowest layer the
+model steps and, through ``serving/model.py``, the engine both import.
+"""
+from __future__ import annotations
+
+from typing import NamedTuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+__all__ = ["RaggedBatch", "RaggedView", "empty_batch", "batch_shapes"]
+
+
+class RaggedBatch(NamedTuple):
+    """Six int32 arrays, in the order the jitted step receives them (a
+    ``NamedTuple`` is a pytree: the program's operands are these six).
+
+    Packing contract.  ``tokens`` [T] holds every scheduled query token,
+    row-major: row ``b``'s ``query_lens[b]`` tokens are contiguous and in
+    order, and rows are packed in ascending batch-slot order, so row
+    ``b``'s last token sits at ``cumsum(query_lens)[b] - 1``.  ``rows`` [T]
+    names each token's batch row and is ``B`` for a padding slot, which is
+    dropped everywhere; ``slots`` [T] is the token's index within its
+    row's chunk.  ``query_lens`` [B] is 0 for an idle row.
+    ``context_lens`` [B] counts the row's total tokens *including* this
+    chunk, so token ``t`` of row ``b`` sits at absolute position
+    ``context_lens[b] - query_lens[b] + t``.  ``page_tables``
+    [B, max_pages] maps a row's logical page (position // page size) to
+    its physical page in the pools; entries past the row's context are
+    never read.
+    """
+    tokens: object
+    rows: object
+    slots: object
+    query_lens: object
+    context_lens: object
+    page_tables: object
+
+
+def _shapes(B, T, max_pages):
+    return RaggedBatch((T,), (T,), (T,), (B,), (B,), (B, max_pages))
+
+
+def empty_batch(B, T, max_pages):
+    """The host batch (numpy) of ``B`` idle rows and ``T`` padding slots,
+    for the scheduler to fill row by row."""
+    batch = RaggedBatch(*(np.zeros(s, np.int32)
+                          for s in _shapes(B, T, max_pages)))
+    batch.rows[:] = B                            # B marks a padding slot
+    return batch
+
+
+def batch_shapes(B, T, max_pages, sharding=None):
+    """The batch as ``ShapeDtypeStruct``s: what a lowering of the step
+    takes in place of ``empty_batch``'s arrays."""
+    return RaggedBatch(*(jax.ShapeDtypeStruct(s, jnp.int32, sharding=sharding)
+                         for s in _shapes(B, T, max_pages)))
+
+
+class RaggedView:
+    """What a model step derives from a :class:`RaggedBatch`, computed once
+    a step (trace-time Python around a handful of index operations).
+
+    Static sizes: ``max_q`` bounds any single row's chunk and is the padded
+    query width ``Q`` handed to the attention kernels (``None``: ``T``);
+    ``max_seq_len`` clips positions; ``num_pages`` and ``page_size`` are
+    the page pools'.
+
+    Per packed token, ``[T]``: ``row`` (its batch row, clamped into range
+    for a padding slot), ``valid`` (a real token of a live row), ``pos``
+    (its absolute position), and its scatter target in a page pool,
+    ``page`` and ``slot_in_page`` — a masked token's ``page`` is
+    ``num_pages``, out of range, so that a scatter with ``mode="drop"``
+    discards it.  Per row, ``[B]``: ``fresh`` (the chunk starts at
+    position 0: a newly admitted or recomputed request).
+
+    Compute is flat ``[T, ...]`` (a decode row costs one token, not a
+    padded chunk); only a kernel that wants one padded row per request
+    sees ``[B, Q, ...]``, through :meth:`pad` and :meth:`unpad`.
+    """
+
+    def __init__(self, batch: RaggedBatch, *, max_q, max_seq_len, num_pages,
+                 page_size):
+        self.batch = batch
+        self.T = T = batch.tokens.shape[0]
+        self.B = B = batch.query_lens.shape[0]
+        self.Q = Q = max_q or T
+        rows, slots = batch.rows, batch.slots
+        query_lens, context_lens = batch.query_lens, batch.context_lens
+        self.row = row = jnp.minimum(rows, B - 1)
+        self.valid = valid = ((rows < B)
+                              & (slots < jnp.take(query_lens, row)))
+        self.pos = pos = jnp.clip(
+            jnp.take(context_lens - query_lens, row) + slots, 0,
+            max_seq_len - 1)
+        page_of_pos = jnp.take_along_axis(
+            jnp.take(batch.page_tables, row, axis=0),
+            (pos // page_size)[:, None], axis=1)[:, 0]
+        self.page = jnp.where(valid, page_of_pos, num_pages)
+        self.slot_in_page = pos % page_size
+        # the padded [B, Q] place of each token; masked tokens go to row B
+        self._pad_row = jnp.where(valid, row, B)
+        self._pad_slot = jnp.minimum(slots, Q - 1)
+        self.fresh = (context_lens - query_lens) == 0
+
+    def pad(self, a, fill=0):
+        """``a [T, ...]`` as one padded row per request, ``[B, Q, ...]``,
+        ``fill`` wherever no valid token landed."""
+        return jnp.full((self.B, self.Q) + a.shape[1:], fill, a.dtype).at[
+            self._pad_row, self._pad_slot].set(a, mode="drop")
+
+    def unpad(self, a, q_axis=1):
+        """``a [B, Q, ...]`` back to packed order, ``[T, ...]``; a padding
+        slot reads some live slot's junk, which never reaches pages or
+        logits.  ``q_axis`` is where ``a`` has its query slots: 2 for a
+        head-major kernel's ``[B, H, Q, ...]``, read in place (a transpose
+        before the gather is a copy XLA does not fold) into
+        ``[T, H, ...]``."""
+        return a[(self.row, *(slice(None),) * (q_axis - 1), self._pad_slot)]
+
+    def last(self, x):
+        """``x [T, ...]`` at each row's last packed token, ``[B, ...]``:
+        where a decode row or a prompt-completing chunk reads its
+        next-token distribution (an idle row reads garbage the engine
+        ignores)."""
+        at = jnp.clip(jnp.cumsum(self.batch.query_lens) - 1, 0, self.T - 1)
+        return jnp.take(x, at, axis=0)

@@ -6,7 +6,7 @@ platform/monitor.h grown into a production observability stack):
 - :mod:`.metrics` — thread-safe Counter/Gauge/Histogram with label
   support, a process-wide default :class:`MetricsRegistry`, JSON
   ``snapshot()`` and Prometheus text exposition.  ``serving.metrics``
-  is a thin client; bench embeds the snapshot in every section's JSON.
+  is a thin client.
 - :mod:`.compile_watchdog` — opt-in wrapper around the repo's
   ``jax.jit`` entry points (hapi train step, the serving unified step,
   hybrid-engine step, inference predictors, jit.to_static): counts
@@ -84,8 +84,8 @@ platform/monitor.h grown into a production observability stack):
 - :mod:`.profiling` — the continuous sampling profiler:
   :class:`StackSampler` keeps a low-rate ``sys._current_frames`` walk
   always on (collapsed flamegraph stacks in a fixed-budget windowed
-  store; documented <1% overhead bound, gated by ``bench.py --section
-  profiling``), tags every sample with the sampled thread's
+  store; under 1% of wall time at the default rate, a CPU ratio
+  ``tests/test_profiling.py`` asserts), tags every sample with the sampled thread's
   :func:`phase` marker (``admission`` / ``prefill_chunk`` / ``decode``
   / ``checkpoint`` / ``scrape``) or its ambient tracer span — a
   window's phase slices sum exactly to its sampled wall time — and

@@ -76,7 +76,7 @@ enforces that):
   bytes into registry gauges (``process_rss_bytes`` & co.), so memory
   leaks and fd leaks show up on ``/metrics`` long before the OOM
   killer explains them post-mortem.  ``sample_once()`` works without
-  the thread (bench embeds one synchronous sample per section).
+  the thread.
 """
 from __future__ import annotations
 

@@ -398,7 +398,8 @@ def record_collective(op_name):
     opens/closes a :class:`CollectiveRecord` on the active recorder
     (errors are recorded, then re-raised — a failing collective is a
     record, not a blind spot).  The un-instrumented callable stays
-    reachable as ``fn.__wrapped__`` (the bench's bare baseline)."""
+    reachable as ``fn.__wrapped__`` (what
+    ``tests/test_distributed_flight.py`` times the recorder against)."""
     import functools
 
     def deco(fn):

@@ -25,8 +25,8 @@ from the telemetry the stack already records to that answer:
   ``peak_flops=``.
 
 Everything lands in the default :class:`MetricsRegistry` — so ``/varz``,
-``/metrics``, the cross-rank aggregator and bench section JSON all see
-it with no extra wiring — and in :meth:`GoodputMonitor.report`'s
+``/metrics`` and the cross-rank aggregator all see it with no extra
+wiring — and in :meth:`GoodputMonitor.report`'s
 JSON-able dict.
 """
 from __future__ import annotations
@@ -36,7 +36,7 @@ import os
 import time
 
 __all__ = ["PEAK_FLOPS", "device_peak_flops", "mfu", "TrainingCallback",
-           "GoodputMonitor", "last_report"]
+           "GoodputMonitor"]
 
 logger = logging.getLogger("paddle_tpu.observability")
 
@@ -114,15 +114,6 @@ class TrainingCallback:
     def on_eval_end(self, logs=None): ...
     def on_eval_batch_begin(self, step, logs=None): ...
     def on_eval_batch_end(self, step, logs=None): ...
-
-
-_LAST_REPORT = None
-
-
-def last_report():
-    """The most recent :meth:`GoodputMonitor.report` in this process
-    (``None`` before any monitored run) — the bench's embed hook."""
-    return _LAST_REPORT
 
 
 class GoodputMonitor(TrainingCallback):
@@ -303,10 +294,6 @@ class GoodputMonitor(TrainingCallback):
         self._publish(None)
         self._bm.before_reader()
 
-    def on_train_end(self, logs=None):
-        global _LAST_REPORT
-        _LAST_REPORT = self.report()
-
     # ---- publication ----------------------------------------------------
     def _publish(self, step_total):
         reg = self.registry()
@@ -332,7 +319,7 @@ class GoodputMonitor(TrainingCallback):
             ).set(self._mfu)
 
     def report(self):
-        """JSON-able accounting summary — bench sections embed this."""
+        """JSON-able accounting summary."""
         out = {
             "steps": self._steps,
             "total_seconds": self._total_seconds,

@@ -1,7 +1,7 @@
 """Framework-wide metrics: Counter / Gauge / Histogram + MetricsRegistry.
 
 Promoted out of ``serving/metrics.py`` so training (hapi), distributed,
-inference and bench code share one telemetry surface (the reference keeps
+inference and the benchmark's runners share one telemetry surface (the reference keeps
 the same split: platform/monitor.h StatRegistry is process-wide, the
 serving counters are one client of it).  Design points:
 
@@ -16,10 +16,10 @@ serving counters are one client of it).  Design points:
 - **process-wide default registry** (``default_registry()``): named
   singletons with get-or-create semantics (``registry.counter(name)``)
   and replace-on-re-register, so a subsystem that rebuilds its metrics
-  (e.g. bench resetting ``ServingMetrics``) atomically swaps the old
+  (e.g. a rebuilt ``ServingMetrics``) atomically swaps the old
   series out of the snapshot.
-- **two expositions**: ``snapshot()`` → JSON-able dict (bench embeds it
-  per section), ``expose_prometheus()`` → Prometheus text format
+- **two expositions**: ``snapshot()`` → JSON-able dict,
+  ``expose_prometheus()`` → Prometheus text format
   (cumulative ``_bucket{le=...}`` + ``_sum``/``_count`` for histograms).
 """
 from __future__ import annotations

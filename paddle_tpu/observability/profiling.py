@@ -5,8 +5,8 @@ matters*; none of them says **where the time went**.  This module keeps
 a low-rate stack sampler always on and answers exactly that:
 
 - :class:`StackSampler` walks ``sys._current_frames()`` on an injectable
-  clock (default 10 Hz — a documented <1% overhead bound, bench-gated by
-  ``bench.py --section profiling``), collapses each thread's stack into
+  clock (default 10 Hz — under 1% of wall time, a CPU ratio
+  ``tests/test_profiling.py`` asserts), collapses each thread's stack into
   flamegraph form (``thread;outer;...;leaf``) and aggregates samples in
   a fixed-budget store with windowed retention — the same discipline as
   the time-series store: bounded memory, windowed queries, nothing on

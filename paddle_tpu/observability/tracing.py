@@ -35,16 +35,14 @@ sharing a ``trace_id``.  Design points:
   its own clock over, so deadline tests drive spans deterministically
   and span timestamps share the engine's timebase.
 - **zero-cost disable**: ``Tracer(enabled=False)`` returns a shared
-  no-op span from every ``start_*`` call — no lock, no allocation —
-  the bench's "tracing off" baseline.
+  no-op span from every ``start_*`` call — no lock, no allocation.
 - **chrome-trace export**: :meth:`Tracer.export_chrome` renders every
   completed trace as one track (labelled with the root span's name) of
   nested ``"X"`` events via the profiler's exporter — the same
   perf_counter timebase as ``ProfilerStep#N`` instants, so request
   timelines and profiler step marks correlate in one Perfetto view.
 - **JSON export**: :meth:`Tracer.traces` returns completed traces as
-  JSON-able dicts — the telemetry server's ``/traces`` payload and the
-  bench's embedded trace summary.
+  JSON-able dicts — the telemetry server's ``/traces`` payload.
 
 Nothing here starts threads or opens sockets; the process-wide
 :func:`default_tracer` is a plain object created at import.
@@ -455,8 +453,7 @@ class Tracer:
 
     def summary(self):
         """Aggregate over the ring: lifetime completed count plus
-        per-root-name count/total duration — the bench's embedded
-        trace digest."""
+        per-root-name count/total duration."""
         # one locked read: the lifetime count and the ring must come
         # from the same instant, or "completed" can lag a trace that
         # "buffered" already shows (racing _end_span)

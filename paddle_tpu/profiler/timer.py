@@ -64,7 +64,7 @@ class Benchmark:
 
     def step_info(self, unit="samples"):
         """Steady-state reader/step breakdown as a dict — the
-        programmatic surface (goodput accounting and bench consume the
+        programmatic surface (goodput accounting consumes the
         totals; nothing should re-parse a formatted string).  Averages
         are per counted step; ``*_total`` fields are cumulative seconds
         over the counted (post-warmup) window."""

@@ -75,7 +75,7 @@ control loop hiccuping: scaling is delayed, never wrong) and
 ``autoscaler.scale_up`` fires before every spawn attempt (an
 ``io_error`` is a spawn that died mid-flight, retried with bounded
 jittered backoff — the PR 6 supervisor discipline).  The chaos soak
-harness (``bench.py --section soak``) exercises both alongside hard
+harness (``serving.soak.run_soak``) exercises both alongside hard
 replica kills as its standing kill matrix.
 """
 from __future__ import annotations

@@ -8,9 +8,10 @@ the fused ragged paged-attention kernel
 (kernels/paged_attention.py), and the serving facade over the
 framework-wide metrics registry (:mod:`metrics` →
 paddle_tpu.observability).  ``inference.Config.enable_generation()`` +
-``create_predictor`` expose it through the predictor API; ``bench.py
---section serving`` measures tokens/sec, TTFT under a Poisson arrival
-trace, and the long-prompt-interference probe.
+``create_predictor`` expose it through the predictor API; the cells
+``serve-gpt3-1.3b-closed16`` and ``serve-minicpm-sala-8l-closed16-16k``
+(``python3 benchmark/run.py --workload ...``) measure tokens/s and the gap
+between tokens under a closed loop of 16 clients.
 
 Unified-step scheduling (this replaced the prefill/decode phase split):
 there is ONE jitted program, ``serving::unified_step``, and every
@@ -28,9 +29,8 @@ TTFT event (``serving_ttft_seconds``), and each chunk increments
 
 Admission semantics: any prompt with prompt + max_new_tokens ≤
 cfg.max_seq_len (and a page count the pool could ever hold) is
-admissible — there is no prompt-length ceiling below that; the old
-``prefill_len`` gate is gone (the name survives as a legacy alias for
-``chunk_len``).  Pages are allocated chunk-by-chunk: admission reserves
+admissible — there is no prompt-length ceiling below that.  Pages are
+allocated chunk-by-chunk: admission reserves
 only the first chunk, later chunks extend the page table step by step,
 and memory pressure preempts the youngest row — mid-prefill rows
 included, whose already-written chunk pages are freed (likewise on
@@ -260,8 +260,8 @@ state, across processes and across failures.  Semantics it guarantees:
   exemplars (``serving_ttft_seconds`` et al.) link each latency
   bucket to a retained exemplar trace in the OpenMetrics exposition.
 
-Soak exit criteria (:mod:`soak`, ``bench.py --section soak`` and the
-compressed tier-1 variant): replaying a seeded diurnal/bursty trace
+Soak exit criteria (:mod:`soak`; ``tests/test_soak.py`` runs the
+compressed variant): replaying a seeded diurnal/bursty trace
 (:mod:`traffic`) through the autoscaled fleet while the chaos timeline
 fires hard kills, admission stalls, poll stalls, spawn I/O errors,
 KV-page bitflips, and poison storms must end with ``lost_requests ==

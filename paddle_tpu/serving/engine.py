@@ -28,8 +28,8 @@ Per ``step()``:
 Admission control: requests that can NEVER fit (prompt + max_new_tokens
 over the model's max_seq_len, or more pages than the whole pool) are
 rejected at submit with Request.state == REJECTED — the engine's
-graceful-overload contract.  Any prompt up to that bound is admissible;
-chunking removed the old ``prefill_len`` prompt-length ceiling.
+graceful-overload contract.  Any prompt up to that bound is admissible:
+it is chunked, whatever its length.
 Requests that merely can't fit *now* stay queued.  If a sequence
 outgrows the pool mid-flight (admission is optimistic), the youngest
 running sequence — mid-prefill or decoding — is preempted back to the
@@ -93,6 +93,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..models.ragged import RaggedBatch, empty_batch
 from ..observability.compile_watchdog import watch
 from ..observability.profiling import pop_phase, push_phase
 from ..observability.tracing import Tracer, default_tracer
@@ -230,8 +231,8 @@ class Engine:
     max_batch_size fixes the in-flight row count (static shape).
     ``chunk_len`` bounds the prompt tokens any single row contributes
     per step — the knob that trades TTFT of the chunked prompt against
-    the stall it imposes on everyone else (``prefill_len`` is accepted
-    as a legacy alias; it no longer caps admissible prompt length).
+    the stall it imposes on everyone else; it does not cap the
+    admissible prompt length.
     ``token_budget`` is the packed query-token width of the one
     compiled step (default chunk_len + max_batch_size - 1: one full
     chunk plus a decode token for every other row).
@@ -267,11 +268,11 @@ class Engine:
 
     def __init__(self, model, params=None, *, page_size=16,
                  num_pages=256, max_batch_size=4, chunk_len=None,
-                 token_budget=None, prefill_len=None,
-                 default_ttl_s=None, shed_occupancy_high=None,
-                 shed_occupancy_low=None, shed_queue_high=None,
-                 shed_queue_low=None, drain_floor_s=None,
-                 prefix_cache=None, clock=None, tracer=None, mesh=None):
+                 token_budget=None, default_ttl_s=None,
+                 shed_occupancy_high=None, shed_occupancy_low=None,
+                 shed_queue_high=None, shed_queue_low=None,
+                 drain_floor_s=None, prefix_cache=None, clock=None,
+                 tracer=None, mesh=None):
         self.model = model = as_served(model)
         self.cfg = cfg = model.cfg
         if prefix_cache and model.recurrent:
@@ -307,10 +308,8 @@ class Engine:
         self.params = params if params is not None else model.init_params()
         self.page_size = page_size
         self.max_batch_size = max_batch_size
-        # prefill_len kept as a legacy alias for the chunk size; prompts
-        # of ANY admissible length are chunked through it
-        self.chunk_len = max(1, min(chunk_len or prefill_len or 64,
-                                    cfg.max_seq_len))
+        # prompts of ANY admissible length are chunked through it
+        self.chunk_len = max(1, min(chunk_len or 64, cfg.max_seq_len))
         self.token_budget = max(
             token_budget or (self.chunk_len + max_batch_size - 1),
             max_batch_size)
@@ -319,10 +318,14 @@ class Engine:
             max_seq_len=cfg.max_seq_len,
             state=model.state_spec(num_pages=num_pages, page_size=page_size,
                                    max_batch_size=max_batch_size))
+        # the static sizes of the step's ragged batch: rows, packed query
+        # tokens, width of a row's page table
+        self.batch_dims = (max_batch_size, self.token_budget,
+                           self.cache.max_pages_per_seq)
         # prefix/radix reuse: admission walks the radix tree so a shared
         # system prompt is a refcount bump instead of prefill FLOPs;
         # completed prompts are inserted back.  Off = always-cold
-        # admission (the bench's cold-fleet baseline).
+        # admission.
         self.prefix_cache = bool(prefix_cache)
         self._prefix_seen = {"hits": 0, "hit_tokens": 0, "evictions": 0}
         self.metrics = ServingMetrics()
@@ -341,10 +344,10 @@ class Engine:
         model_step = model.make_step(max_q=self.chunk_len, mesh=mesh)
 
         def _step(params, *args):
-            # (params, every state pool, the six packed host arrays): flat,
-            # so that the pools are donated one by one
-            logits, state = model_step(params, args[:n_state],
-                                       *args[n_state:])
+            # (params, every state pool, the ragged batch): the pools
+            # flat, so that they are donated one by one
+            *state, batch = args
+            logits, state = model_step(params, tuple(state), batch)
             return (logits, *state)
 
         # GSPMD serving (prepare(mesh=...) analogue): the model shards its
@@ -372,7 +375,7 @@ class Engine:
             self._page_sharding = psh
             rep = NamedSharding(mesh, P())
             jit_kw.update(
-                in_shardings=(p_sh,) + (psh,) * n_state + (rep,) * 6,
+                in_shardings=(p_sh,) + (psh,) * n_state + (rep,),
                 out_shardings=(rep,) + (psh,) * n_state)
         # watchdog-wrapped: the ONE statically-shaped program — prompt
         # chunks and decode rows share it — must compile exactly once;
@@ -719,7 +722,7 @@ class Engine:
             packed = self._pack(plan)
         if packed is None:
             return None
-        arrays, sched = packed
+        batch, sched = packed
         # phase attribution for the sampling profiler: a step with any
         # mid-prefill row is a prefill chunk, else pure decode
         step_phase = "prefill_chunk" if any(
@@ -730,7 +733,7 @@ class Engine:
             with phases.phase("dispatch", step_phase):
                 logits, *state = self._step_fn(
                     self.params, *self.cache.state_arrays(),
-                    *(jnp.asarray(a) for a in arrays))
+                    RaggedBatch(*(jnp.asarray(a) for a in batch)))
             with phases.phase("device_wait", step_phase):
                 logits.block_until_ready()
             with phases.phase("fetch", step_phase):
@@ -742,20 +745,15 @@ class Engine:
         return sched, sampled, t0, t1
 
     def _pack(self, plan):
-        """The planned rows packed row-major into the step's six host
-        arrays: ``((tokens, rows, slots, qlens, ctxs, tables), sched)``
-        with ``sched`` the ``(slot, req, q, new ctx)`` of every packed
-        row, or None when no row is left to run."""
-        B, T = self.max_batch_size, self.token_budget
-        tokens = np.zeros((T,), np.int32)
-        rows = np.full((T,), B, np.int32)        # B marks padding slots
-        slots = np.zeros((T,), np.int32)
-        qlens = np.zeros((B,), np.int32)
-        ctxs = np.zeros((B,), np.int32)
-        tables = np.zeros((B, self.cache.max_pages_per_seq), np.int32)
+        """The planned rows packed row-major into the step's host
+        ``RaggedBatch`` (``models/ragged.py`` has the contract):
+        ``(batch, sched)`` with ``sched`` the ``(slot, req, q, new ctx)``
+        of every packed row, or None when no row is left to run."""
+        batch = empty_batch(*self.batch_dims)
+        tokens, rows, slots, qlens, ctxs, tables = batch
         sched = []                               # (slot, req, q, new ctx)
         off = 0
-        for i in range(B):                       # packing is row-major
+        for i in range(self.max_batch_size):     # packing is row-major
             req = self._slots[i]
             q = plan.get(i, 0)
             if req is None or q <= 0:
@@ -782,7 +780,7 @@ class Engine:
             off += q
         if not sched:
             return None
-        return (tokens, rows, slots, qlens, ctxs, tables), sched
+        return batch, sched
 
     def _sample_rows(self, logits, sched):
         """{batch slot: next token} for every row whose context now

@@ -24,9 +24,10 @@ process-wide registry); this module keeps the serving-shaped facade:
 
 Every metric is registered (serving_-prefixed) into the default
 MetricsRegistry with replace semantics, so rebuilding ``ServingMetrics``
-(the bench's reset idiom) swaps fresh series into the global snapshot —
-and ``bench.py`` / Prometheus exposition / the profiler's counter events
-all see serving telemetry with no extra wiring.  Engine phases are
+(the reset idiom) swaps fresh series into the global snapshot — and the
+benchmark's readers (``benchmark/program_series.py``), Prometheus
+exposition and the profiler's counter events all see serving telemetry
+with no extra wiring.  Engine phases are
 additionally wrapped in profiler.RecordEvent, so a
 paddle_tpu.profiler.Profiler session captures serving activity in its
 host trace/summary.
@@ -50,7 +51,7 @@ STEP_PHASES = ("admit", "plan", "pack", "dispatch", "device_wait", "fetch",
 
 
 class ServingMetrics:
-    """The engine's metric facade; snapshot() is the bench/ops surface.
+    """The engine's metric facade; snapshot() is the ops surface.
 
     ``registry=None`` publishes into the process-wide default registry
     (pass an explicit MetricsRegistry to isolate, e.g. in tests)."""

@@ -10,10 +10,10 @@ the state that step carries.
   ``(name, shape, dtype, kind)``; kind ``"pages"`` has the physical page
   on axis 1 (allocated, shared, copied and compacted page by page), kind
   ``"slots"`` the batch row (one fixed state per in-flight request).
-- ``make_step(max_q=, mesh=)``: ``step(params, state, tokens,
-  row_of_token, slot_of_token, query_lens, context_lens, page_tables) ->
-  (logits [B, V], state)`` with ``state`` the tuple of pools; jitted by
-  the engine with every pool donated.
+- ``make_step(max_q=, mesh=)``: ``step(params, state, batch) -> (logits
+  [B, V], state)`` with ``state`` the tuple of pools and ``batch`` the
+  scheduler's ``RaggedBatch`` (``models/ragged.py`` owns the format);
+  jitted by the engine with every pool donated.
 - ``recurrent``: the model keeps per-row state that is a function of
   every token the row has seen.  Pages of a cached prefix say nothing of
   that state, so the engine serves such a model cold (no prefix reuse).
@@ -58,12 +58,10 @@ class GPTServed:
 
         cfg = self.cfg
 
-        def step(params, state, tokens, rows, slots, qlens, ctxs, tables):
-            k_pages, v_pages = state
-            logits, k_pages, v_pages = gpt_ragged_step(
-                cfg, params, tokens, rows, slots, qlens, ctxs, k_pages,
-                v_pages, tables, max_q=max_q, mesh=mesh)
-            return logits, (k_pages, v_pages)
+        def step(params, state, batch):
+            logits, *state = gpt_ragged_step(cfg, params, batch, *state,
+                                             max_q=max_q, mesh=mesh)
+            return logits, tuple(state)
 
         # jitted here, where the step is named: the engine's own jit
         # inlines it, and the trace-purity pass (tools/analysis) follows
@@ -119,10 +117,10 @@ class HybridServed:
                 "questions)")
         cfg, dense_only = self.cfg, self.dense_only
 
-        def step(params, state, tokens, rows, slots, qlens, ctxs, tables):
+        def step(params, state, batch):
             logits, *state = hybrid_ragged_step(
-                cfg, params, tokens, rows, slots, qlens, ctxs, *state,
-                tables, max_q=max_q, dense_only=dense_only)
+                cfg, params, batch, *state, max_q=max_q,
+                dense_only=dense_only)
             return logits, tuple(state)
 
         return jax.jit(step)       # as GPTServed.make_step

@@ -959,7 +959,7 @@ class FleetRouter:
         """Emulate a hard replica death (process SIGKILL): the engine
         is replaced by a stub whose every access raises ``OSError``, so
         the normal detection path — failed step, missed probe — finds
-        the corpse on the next tick.  Test/bench/ops hook."""
+        the corpse on the next tick.  Test/ops hook."""
         rep = self._rep(replica_id)
         rep.engine = _DeadEngine(replica_id)
         return rep

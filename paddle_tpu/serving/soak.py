@@ -5,9 +5,8 @@ bursty, shared-prefix* traffic trace (:mod:`.traffic`) through an
 **autoscaled** fleet (:mod:`.autoscaler` over :mod:`.router`) while a
 chaos timeline fires hard replica kills, admission stalls, control-
 loop stalls, and spawn I/O errors — the standing kill matrix.  One
-driver, :func:`run_soak`, backs both ``bench.py --section soak`` (the
-long variant) and the compressed tier-1 test, so the invariants are
-asserted by CI on every run and measured at scale by the bench:
+driver, :func:`run_soak`, at any horizon; the compressed tier-1 test
+(``tests/test_soak.py``) asserts the invariants on every run:
 
 - ``lost_requests == 0`` — every submitted request reaches FINISHED
   despite kills, stalls, drains, and scale events (the router's

@@ -83,7 +83,7 @@ def bridge_to_metrics(stat_registry=None, metrics_registry=None):
     The sync runs *on scrape* (a registry collector fires at the top of
     every ``snapshot()``/``expose_prometheus()``), so legacy
     ``stat_add`` call sites keep their lock-cheap integer registry but
-    their stats still appear on ``/metrics`` and in bench JSON instead
+    their stats still appear on ``/metrics`` and ``/varz`` instead
     of living in a parallel, invisible registry.  Peaks ride the gauge's
     own peak tracking (the peak is replayed before the current value,
     so ``runtime_stat_peak`` is never below the stat's true peak).

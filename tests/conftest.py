@@ -73,3 +73,33 @@ def _seed_everything():
 
     paddle_tpu.seed(1234)
     yield
+
+
+@pytest.fixture
+def in_fresh_process():
+    """``call(test_file, function)``: the named function of a test module
+    (loaded by path, as no test session is) run in a fresh interpreter,
+    and what it returned, through JSON.  For host-time measurements that a
+    mid-suite interpreter's daemon threads would inflate."""
+    import json
+    import subprocess
+    import sys
+
+    code = ("import importlib.util, json, sys\n"
+            "spec = importlib.util.spec_from_file_location("
+            "'measured_mod', sys.argv[1])\n"
+            "mod = importlib.util.module_from_spec(spec)\n"
+            "spec.loader.exec_module(mod)\n"
+            "print(json.dumps(getattr(mod, sys.argv[2])()))\n")
+
+    def call(test_file, function):
+        test_file = os.path.abspath(test_file)
+        proc = subprocess.run(
+            [sys.executable, "-c", code, test_file, function],
+            capture_output=True, text=True, timeout=300,
+            cwd=os.path.dirname(os.path.dirname(test_file)),
+            env=dict(os.environ, JAX_PLATFORMS="cpu"))
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        return json.loads(proc.stdout.strip().splitlines()[-1])
+
+    return call

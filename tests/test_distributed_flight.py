@@ -560,16 +560,40 @@ class TestCollectiveInstrumentedLint:
 # --------------------------------------------------- overhead smoke
 
 
+def _recorder_overhead_s(n=300, reps=3, shape=(1024,)):
+    """Seconds the flight recorder adds to one eager ``all_reduce``: the
+    instrumented call (the shipping path) against the bare one (the
+    decorator's ``__wrapped__``), medians over ``reps`` windows of ``n``
+    calls.  Host clock on the CPU: a cost in Python, not a device time."""
+    x = jnp.ones(shape, jnp.float32)
+    bare = collective.all_reduce.__wrapped__
+    # a private bounded recorder: the measurement pays realistic
+    # ring/metric/span costs without flooding process-wide telemetry
+    rec = FlightRecorder(capacity=512, registry=MetricsRegistry(),
+                         tracer=Tracer(max_traces=64))
+
+    def per_op(fn):
+        t0 = time.perf_counter()
+        for _ in range(n):
+            fn(x)
+        return (time.perf_counter() - t0) / n
+
+    per_op(bare)                             # warm up both paths
+    with use_flight_recorder(rec):
+        per_op(collective.all_reduce)
+        inst_s = float(np.median(
+            [per_op(collective.all_reduce) for _ in range(reps)]))
+    bare_s = float(np.median([per_op(bare) for _ in range(reps)]))
+    return max(0.0, inst_s - bare_s)
+
+
 class TestRecorderOverheadSmoke:
     def test_implied_step_overhead_under_bound(self):
         """Acceptance: the recorder's per-collective cost, scaled to a
-        documented 1.3B-class step (64 collectives, 1.5 s), stays under
-        the 3% bound bench --section distributed publishes."""
-        spec = importlib.util.spec_from_file_location(
-            "bench_mod", os.path.join(REPO, "bench.py"))
-        bench = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(bench)
-        out = bench.bench_distributed(iters=900, reps=3)
-        assert out["implied_step_overhead_ratio"] < out["bound_ratio"], out
+        1.3B-class step (64 grad-sync collectives in 1.5 s: BENCH_r05's
+        throughput), stays under 3% of the step.  A ratio of host times
+        on the CPU; not measured on the chip."""
+        overhead_s = _recorder_overhead_s()
+        assert overhead_s * 64 / 1.5 < 0.03, overhead_s
         # absolute sanity: tens of microseconds per op, not milliseconds
-        assert out["per_op_overhead_us"] < 1000, out
+        assert overhead_s * 1e6 < 1000, overhead_s

@@ -48,7 +48,7 @@ def _tiny_engine(clock=None, **kw):
     kw.setdefault("page_size", 4)
     kw.setdefault("num_pages", 64)
     kw.setdefault("max_batch_size", 2)
-    kw.setdefault("prefill_len", 32)
+    kw.setdefault("chunk_len", 32)
     return Engine(cfg, params, clock=clock, **kw)
 
 
@@ -517,27 +517,50 @@ class TestMetricNamesLint:
 # --------------------------------------------------- tracing overhead smoke
 
 
+def _trace_lifecycle_s(tracer, n=300, reps=3):
+    """Seconds one request-shaped trace lifecycle costs under ``tracer``:
+    a root and queued/dispatch/decode child spans with attributes, all
+    ended; median over ``reps`` windows of ``n``.  Host clock on the CPU."""
+    import time
+
+    def per_request():
+        t0 = time.perf_counter()
+        for i in range(n):
+            now = float(i)
+            root = tracer.start_trace("request#bench", start_s=now,
+                                      attributes={"prompt_len": 32})
+            for name in ("queued", "router::dispatch", "decode"):
+                sp = tracer.start_span(name, root, start_s=now)
+                sp.set_attribute("outcome", "ok")
+                sp.end(now + 0.001)
+            root.end(now + 0.002)
+        return (time.perf_counter() - t0) / n
+
+    per_request()                            # warm-up
+    return float(np.median([per_request() for _ in range(reps)]))
+
+
 class TestTracingOverheadSmoke:
     def test_implied_request_overhead_under_bound(self):
-        """Acceptance: a full request-shaped trace lifecycle, scaled to
-        a documented 50 ms TTFT-class request, stays under the 1% bound
-        ``bench --section tracing`` publishes — with tail retention at
-        full sampling (the default posture)."""
-        import importlib.util
-        import os
+        """Acceptance: a full request-shaped trace lifecycle with tail
+        retention at full sampling (the default posture) costs under 1%
+        of 50 ms.  A ratio of host times on the CPU against a model
+        request of 50 ms; not measured on the chip."""
+        import time
 
-        path = os.path.join(os.path.dirname(__file__), os.pardir,
-                            "bench.py")
-        spec = importlib.util.spec_from_file_location("bench_mod", path)
-        bench = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(bench)
-        out = bench.bench_tracing(iters=900, reps=3)
-        assert out["implied_request_overhead_ratio"] < \
-            out["bound_ratio"], out
+        from paddle_tpu.observability.tracing import TailRetention
+
+        full = _trace_lifecycle_s(
+            Tracer(clock=time.perf_counter, max_traces=256))
+        sampled_tracer = Tracer(clock=time.perf_counter, max_traces=256,
+                                retention=TailRetention(sample_rate=0.01))
+        _trace_lifecycle_s(sampled_tracer)
+        disabled = _trace_lifecycle_s(
+            Tracer(clock=time.perf_counter, enabled=False))
+        assert full / 0.05 < 0.01, full
         # absolute sanity: tens of microseconds per request, not ms
-        assert out["per_request_full_us"] < 1000, out
+        assert full * 1e6 < 1000, full
         # the disabled posture must be dramatically cheaper (null span)
-        assert out["per_request_disabled_us"] < \
-            out["per_request_full_us"], out
+        assert disabled < full, (disabled, full)
         # and sampled retention must actually shed boring traces
-        assert out["ring_sampled"]["dropped"] > 0, out
+        assert sampled_tracer.summary()["dropped"] > 0

@@ -399,8 +399,12 @@ class TestCrossRankDivergence:
 
     def test_detection_names_rank_and_leaf(self, tmp_path):
         """Detect-only (no monitor): the divergent rank knows it
-        diverged, from which leaf, and stays flagged unhealthy."""
-        cbs, _, finals, _ = self._run_ranks(tmp_path, world=2)
+        diverged, from which leaf, and stays flagged unhealthy.  The flip
+        lands on a step whose fingerprint is taken, so the flipped leaf
+        is the only one that differs: one update later every leaf does,
+        and the sentinel names the first in sorted order."""
+        cbs, _, finals, _ = self._run_ranks(tmp_path, world=2,
+                                            occurrence=6)
         assert cbs[0].events == []
         ev = cbs[1].events[0]
         assert ev["kind"] == "cross_rank"
@@ -408,7 +412,7 @@ class TestCrossRankDivergence:
         assert ev["first_divergent_leaf"] == {1: "params/0.weight"}
         assert ev["self_divergent"] is True
         assert ev["last_verified_global_step"] == 4    # fp at 2 and 4
-        assert ev["global_step"] == 6       # corruption at 5, fp at 6
+        assert ev["global_step"] == 6       # corruption and fp at 6
         # no repair ran: the corruption persists and so does the flag
         assert cbs[1].divergence_active is True
         assert finals[0] != finals[1]
@@ -429,7 +433,12 @@ class TestCrossRankDivergence:
         # after the step-5 corruption
         ev = cbs[1].events[0]
         assert ev["divergent_ranks"] == [1]
-        assert ev["first_divergent_leaf"] == {1: "params/0.weight"}
+        # the flip went into 0.weight at step 5; by the fingerprint at
+        # step 6 one update has carried it into the other leaves, and
+        # the sentinel names the first that differs in sorted order: a
+        # leaf of the corrupted layer, for the corrupted rank alone
+        leaf = ev["first_divergent_leaf"]
+        assert set(leaf) == {1} and leaf[1].startswith("params/0."), leaf
         assert _rollback_count("param_divergence") == before + 1
         # rewind-and-replay: steps 5 and 6 trained twice (8 + 2)
         assert len(losses[1]) == 10 and len(losses[0]) == 8
@@ -732,17 +741,58 @@ class TestExceptsLint:
 # ------------------------------------------------------ overhead smoke
 
 
+def _sentinel_costs(steps=10, fp_reps=5, replay_reps=3, hidden=1024,
+                    batch=128):
+    """``(train step, parameter-tree fingerprint, sampled step replay)``
+    in seconds, each a median, for a three-layer MLP of ``hidden``
+    (2.1 M parameters, 8 MB).  Host clock on the CPU."""
+    import time
+
+    from paddle_tpu.core.random import get_rng_state
+
+    def timed(fn, *args):
+        t0 = time.perf_counter()
+        fn(*args)
+        return time.perf_counter() - t0
+
+    paddle.seed(0)
+    model = paddle.Model(nn.Sequential(
+        nn.Linear(hidden, hidden), nn.ReLU(),
+        nn.Linear(hidden, hidden), nn.ReLU(), nn.Linear(hidden, 10)))
+    opt = paddle.optimizer.Momentum(learning_rate=0.01,
+                                    parameters=model.parameters())
+    model.prepare(opt, nn.CrossEntropyLoss())
+    rng = np.random.RandomState(0)
+    x = rng.randn(batch, hidden).astype(np.float32)
+    y = rng.randint(0, 10, (batch,)).astype(np.int64)
+
+    model.train_batch(x, y)                  # compile outside the clock
+    step_s = float(np.median([timed(model.train_batch, x, y)
+                              for _ in range(steps)]))
+    params, buffers = model.network.raw_state()
+    tree = {"params": dict(params)}
+    tree_fingerprint(tree)                   # warm the digest path
+    fp_s = float(np.median([timed(tree_fingerprint, tree)
+                            for _ in range(fp_reps)]))
+    snapshot = {"params": dict(params), "buffers": dict(buffers),
+                "opt_state": model._opt_state,
+                "rng": dict(get_rng_state()), "lr": float(opt.get_lr())}
+    model.replay_train_batch(snapshot, (x, y))
+    replay_s = float(np.median(
+        [timed(model.replay_train_batch, snapshot, (x, y))
+         for _ in range(replay_reps)]))
+    return step_s, fp_s, replay_s
+
+
 class TestSentinelOverheadSmoke:
     def test_amortized_overhead_under_bound(self):
         """Acceptance: fingerprint + replay cost, amortized over their
-        default sampling intervals, stays under the documented 3% of
-        step time at the bench config."""
-        spec = importlib.util.spec_from_file_location(
-            "bench_mod", os.path.join(REPO, "bench.py"))
-        bench = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(bench)
-        out = bench.bench_integrity(steps=10, fp_reps=5, replay_reps=3)
-        assert out["amortized_overhead_ratio"] < out["bound_ratio"], out
+        default sampling intervals (every 25 and every 100 steps), stays
+        under 3% of the step's time.  A ratio of host times on the CPU;
+        not measured on the chip."""
+        step_s, fp_s, replay_s = _sentinel_costs()
+        assert (fp_s / 25 + replay_s / 100) / step_s < 0.03, (
+            step_s, fp_s, replay_s)
         # fingerprints must stay cheap in absolute terms too: digesting
         # ~8MB of params is milliseconds, not a second
-        assert out["fingerprint_seconds_p50"] < 0.2, out
+        assert fp_s < 0.2, fp_s

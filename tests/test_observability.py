@@ -431,17 +431,6 @@ class TestServingMetricsThinClient:
         assert tuple(snap["step_phase_s"]) == STEP_PHASES
 
 
-# ------------------------------------------------------------------- bench
-class TestBenchTelemetry:
-    def test_section_telemetry_embeds_registry_snapshot(self):
-        import bench
-
-        default_registry().counter("bench_probe").inc(3)
-        out = bench._section_telemetry({"tokens_per_sec": 1.0})
-        assert out["metrics"]["bench_probe"]["value"] == 3
-        json.dumps(out)
-
-
 # ----------------------------------------------------------------- hapi
 class TestProfilerCallback:
     def test_fit_traces_batches_and_steps(self):

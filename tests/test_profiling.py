@@ -556,38 +556,72 @@ class TestSeriesContract:
 # -------------------------------------------------------- overhead smoke
 
 
+#: seconds between two walks: always on, escalated, during a capture
+SAMPLER_INTERVALS = {"default": 0.1, "escalated": 0.02, "capture": 0.01}
+
+
+def _stack_walk(n=60, reps=5, workers=4, depth=24):
+    """ONE stack-sampler walk over a realistic thread population —
+    ``workers`` threads parked ``depth`` frames deep (the recursion
+    gives the collapser real stacks to intern) plus the process's own
+    threads.  Each window reports its fastest walk (the minimum is the
+    intrinsic cost; slower walks measure preemption) and the result is
+    the median of ``reps`` window minima; beside it the share of wall
+    time the sampler takes at each of ``SAMPLER_INTERVALS``.  Host clock
+    on the CPU."""
+    import numpy as np
+
+    stop = threading.Event()
+    parked = []
+
+    def park(d):
+        if d:
+            return park(d - 1)
+        parked.append(None)
+        stop.wait()
+
+    threads = [threading.Thread(target=park, args=(depth,), daemon=True)
+               for _ in range(workers)]
+    for t in threads:
+        t.start()
+    while len(parked) < workers:     # wait until every stack is deep
+        time.sleep(0.001)
+
+    sampler = StackSampler()
+    try:
+
+        def fastest_walk():
+            best = float("inf")
+            for _ in range(n):
+                t0 = time.perf_counter()
+                sampler.sample_once()
+                best = min(best, time.perf_counter() - t0)
+            return best
+
+        fastest_walk()               # warm-up: intern the stack table
+        per_sample = float(np.median([fastest_walk() for _ in range(reps)]))
+    finally:
+        stop.set()
+        for t in threads:
+            t.join(timeout=2.0)
+    return {"per_sample_s": per_sample,
+            "overhead_ratio": {label: per_sample / interval for
+                               label, interval in SAMPLER_INTERVALS.items()}}
+
+
 class TestProfilingOverheadSmoke:
-    def test_sampler_walk_under_bound(self):
-        """Acceptance: one stack walk over a realistic thread
-        population keeps the always-on rate under the documented 1%
-        bound (50 ms request model).  Runs in a fresh subprocess: a
-        mid-suite interpreter carries daemon threads from earlier test
+    def test_sampler_walk_under_bound(self, in_fresh_process):
+        """Acceptance: at the always-on rate (one walk per 0.1 s) the
+        sampler takes under 1% of wall time.  A ratio of host times on
+        the CPU; not measured on the chip.  Runs in a fresh subprocess:
+        a mid-suite interpreter carries daemon threads from earlier test
         modules whose extra stacks inflate every walk — that measures
         the test session, not the sampler."""
-        import os
-        import subprocess
-        import sys
-
-        root = os.path.join(os.path.dirname(__file__), os.pardir)
-        code = (
-            "import importlib.util, json, sys\n"
-            "spec = importlib.util.spec_from_file_location("
-            "'bench_mod', sys.argv[1])\n"
-            "bench = importlib.util.module_from_spec(spec)\n"
-            "spec.loader.exec_module(bench)\n"
-            "print(json.dumps(bench.bench_profiling()))\n"
-        )
-        env = dict(os.environ, JAX_PLATFORMS="cpu")
-        proc = subprocess.run(
-            [sys.executable, "-c", code,
-             os.path.join(root, "bench.py")],
-            capture_output=True, text=True, timeout=300, cwd=root,
-            env=env)
-        assert proc.returncode == 0, proc.stderr[-2000:]
-        out = json.loads(proc.stdout.strip().splitlines()[-1])
-        assert out["implied_request_overhead_ratio"] < \
-            out["bound_ratio"], out
+        out = in_fresh_process(__file__, "_stack_walk")
+        assert out["overhead_ratio"]["default"] < 0.01, out
         # absolute sanity: sub-millisecond per walk
-        assert out["per_sample_us"] < 5000, out
-        # all three rates reported (escalated rows are informational)
-        assert set(out["rates"]) == {"default", "escalated", "capture"}
+        assert out["per_sample_s"] * 1e6 < 5000, out
+        # all three rates reported (escalation is bounded by the capture
+        # window, so those rows may pass 1% briefly and are not gated)
+        assert set(out["overhead_ratio"]) == {"default", "escalated",
+                                              "capture"}

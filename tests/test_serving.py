@@ -14,11 +14,11 @@ from paddle_tpu.kernels import dispatch
 from paddle_tpu.kernels.paged_attention import (_KEY_TILE, _SMALL_Q,
                                                 _ragged_attention_kernel,
                                                 _ragged_attention_ref,
-                                                paged_attention,
                                                 ragged_paged_attention,
                                                 ragged_work_items)
 from paddle_tpu.models.gpt import (GPT_CONFIGS, _layer_norm, gpt_forward,
                                    gpt_init, gpt_ragged_step)
+from paddle_tpu.models.ragged import RaggedBatch
 from paddle_tpu.serving import (Engine, PagedKVCache, RequestState,
                                 SamplingParams)
 
@@ -127,6 +127,15 @@ class TestPagedKVCache:
 
 
 # ----------------------------------------------------- paged attention
+
+
+def paged_attention(q, k_pages, v_pages, page_tables, seq_lens, scale=None,
+                    path=None):
+    """Decode attention, ``q [B, H, hd]``: one query slot a row (0 keys:
+    an idle row) through the one entry."""
+    return ragged_paged_attention(
+        q[:, None], k_pages, v_pages, page_tables,
+        (seq_lens > 0).astype(jnp.int32), seq_lens, scale, path)[:, 0]
 
 
 class TestPagedAttention:
@@ -306,7 +315,8 @@ class TestRaggedAttention:
                                       np.asarray(handed))
 
     def test_decode_entry_is_qlen1_degenerate_row(self):
-        """The legacy decode entry must equal a Q=1 ragged call."""
+        """A decode call through the public entry equals the kernel on
+        Q=1 rows."""
         q, kp, vp, tables, _, _ = self._case([1, 1, 1], [9, 4, 0], Q=1)
         lens = jnp.asarray([9, 4, 0], jnp.int32)
         scale = 1.0 / np.sqrt(q.shape[-1])
@@ -357,25 +367,25 @@ class TestRaggedAttention:
 # ------------------------------------------ the step against its oracle
 
 
-def _ragged_step_oracle(cfg, params, tokens, row_of_token, slot_of_token,
-                        query_lens, context_lens, k_pages, v_pages,
-                        page_tables, max_q, path):
-    """``gpt_ragged_step`` (dense branch) written the plain way: a Python
-    loop over layers, each writing its tokens into its own
-    [P, ps, H, hd] pool and attending on it through the public one-layer
-    API, the pools restacked at the end."""
+def _ragged_step_oracle(cfg, params, batch, k_pages, v_pages, max_q, path):
+    """``gpt_ragged_step`` (dense branch) written the plain way, the
+    batch's arithmetic included (nothing of ``models/ragged.py`` but the
+    tuple): a Python loop over layers, each writing its tokens into its
+    own [P, ps, H, hd] pool and attending on it through the public
+    one-layer API, the pools restacked at the end."""
+    tokens, rows, slots, query_lens, context_lens, page_tables = batch
     T, B = tokens.shape[0], query_lens.shape[0]
     H, hd, D = cfg.num_heads, cfg.head_dim, cfg.hidden
     P, page_size = k_pages.shape[1], k_pages.shape[2]
-    row_c = jnp.minimum(row_of_token, B - 1)
-    valid = (row_of_token < B) & (slot_of_token < query_lens[row_c])
-    pos = jnp.clip((context_lens - query_lens)[row_c] + slot_of_token, 0,
+    row_c = jnp.minimum(rows, B - 1)
+    valid = (rows < B) & (slots < query_lens[row_c])
+    pos = jnp.clip((context_lens - query_lens)[row_c] + slots, 0,
                    cfg.max_seq_len - 1)
     x = (params["wte"][tokens] + params["wpe"][pos]).astype(cfg.jdtype())
     page = jnp.where(valid, page_tables[row_c, pos // page_size], P)
     slot = pos % page_size
-    scat_row = jnp.where(valid, row_c, B)
-    scat_slot = jnp.minimum(slot_of_token, max_q - 1)
+    pad_row = jnp.where(valid, row_c, B)
+    pad_slot = jnp.minimum(slots, max_q - 1)
     k_out, v_out = [], []
     for l in range(cfg.num_layers):
         bp = jax.tree_util.tree_map(lambda a: a[l], params["blocks"])
@@ -388,10 +398,10 @@ def _ragged_step_oracle(cfg, params, tokens, row_of_token, slot_of_token,
         k_out.append(kp)
         v_out.append(vp)
         q_pad = jnp.zeros((B, max_q, H, hd), q.dtype) \
-            .at[scat_row, scat_slot].set(q, mode="drop")
+            .at[pad_row, pad_slot].set(q, mode="drop")
         attn = ragged_paged_attention(q_pad, kp, vp, page_tables, query_lens,
                                       context_lens, path=path)
-        attn = attn[row_c, scat_slot].reshape(T, D)
+        attn = attn[row_c, pad_slot].reshape(T, D)
         x = x + jnp.einsum("td,de->te", attn, bp["proj_w"]) + bp["proj_b"]
         h = _layer_norm(x, bp["ln2_g"], bp["ln2_b"])
         h = jnp.einsum("td,df->tf", h, bp["up_w"]) + bp["up_b"]
@@ -428,8 +438,8 @@ def test_ragged_step_equals_per_layer_oracle(tiny_model, path):
     ks = jax.random.split(jax.random.key(5), 2)
     k_pages = jax.random.normal(ks[0], pool, jnp.float32)
     v_pages = jax.random.normal(ks[1], pool, jnp.float32)
-    args = [jnp.asarray(a) for a in (tokens, rows, slots, qlens, ctxs)] \
-        + [k_pages, v_pages, jnp.asarray(tables)]
+    args = [RaggedBatch(*(jnp.asarray(a) for a in (
+        tokens, rows, slots, qlens, ctxs, tables))), k_pages, v_pages]
 
     got = jax.jit(lambda p, *a: gpt_ragged_step(
         cfg, p, *a, max_q=Q, attn_path=path))(params, *args)
@@ -459,7 +469,7 @@ class TestEngine:
                    for n in (5, 11, 3, 17)]
         refs = [naive_generate(cfg, params, p, 8) for p in prompts]
         eng = Engine(cfg, params, page_size=8, num_pages=64,
-                     max_batch_size=2, prefill_len=32)
+                     max_batch_size=2, chunk_len=32)
         outs = eng.generate(prompts, SamplingParams(max_new_tokens=8))
         assert outs == refs
         m = eng.metrics.snapshot()
@@ -478,7 +488,7 @@ class TestEngine:
         late = list(rng.randint(0, cfg.vocab_size, 4))
         sp = SamplingParams(max_new_tokens=10)
         eng = Engine(cfg, params, page_size=8, num_pages=64,
-                     max_batch_size=4, prefill_len=32)
+                     max_batch_size=4, chunk_len=32)
         reqs = [eng.add_request(p, sp) for p in early]
         for _ in range(3):
             eng.step()                        # decoding well underway
@@ -495,7 +505,7 @@ class TestEngine:
     def test_pool_exhaustion_rejects_gracefully(self, tiny_model):
         cfg, params = tiny_model
         eng = Engine(cfg, params, page_size=8, num_pages=4,
-                     max_batch_size=2, prefill_len=32)   # 32-token pool
+                     max_batch_size=2, chunk_len=32)   # 32-token pool
         r = eng.add_request(list(range(20)),
                             SamplingParams(max_new_tokens=20))
         assert r.state == RequestState.REJECTED
@@ -515,7 +525,7 @@ class TestEngine:
         p1 = list(rng.randint(0, cfg.vocab_size, 14))
         p2 = list(rng.randint(0, cfg.vocab_size, 14))
         eng = Engine(cfg, params, page_size=8, num_pages=6,
-                     max_batch_size=2, prefill_len=32)
+                     max_batch_size=2, chunk_len=32)
         sp = SamplingParams(max_new_tokens=20)
         outs = eng.generate([p1, p2], sp)
         assert eng.metrics.requests_preempted.value > 0
@@ -529,7 +539,7 @@ class TestEngine:
         sp = SamplingParams(max_new_tokens=10, temperature=0.8, top_k=40,
                             top_p=0.9, seed=1234)
         eng = Engine(cfg, params, page_size=8, num_pages=64,
-                     max_batch_size=2, prefill_len=32)
+                     max_batch_size=2, chunk_len=32)
         a = eng.generate(prompts, sp)
         b = eng.generate(prompts, sp)
         assert a == b
@@ -544,7 +554,7 @@ class TestEngine:
         prompt = list(range(4))
         first = naive_generate(cfg, params, prompt, 1)[0]
         eng = Engine(cfg, params, page_size=8, num_pages=64,
-                     max_batch_size=1, prefill_len=32)
+                     max_batch_size=1, chunk_len=32)
         req = eng.add_request(prompt, SamplingParams(
             max_new_tokens=10, stop_token_ids=(first,)))
         while eng.has_work():
@@ -558,7 +568,7 @@ class TestEngine:
 
         config = Config().enable_generation(
             cfg, params, page_size=8, num_pages=64, max_batch_size=2,
-            prefill_len=32)
+            chunk_len=32)
         pred = create_predictor(config)
         prompt = list(range(6))
         out = pred.generate([prompt], SamplingParams(max_new_tokens=5))
@@ -597,8 +607,8 @@ class TestChunkedPrefill:
         assert eng.cache.num_free_pages == eng.cache.num_pages
 
     def test_prompt_longer_than_chunk_admitted(self, tiny_model):
-        """The old prefill_len prompt-length rejection is gone: any
-        prompt that fits max_seq_len is admitted and chunked."""
+        """No prompt-length ceiling below max_seq_len: any prompt that
+        fits it is admitted and chunked."""
         cfg, params = tiny_model
         rng = np.random.RandomState(13)
         prompt = list(rng.randint(0, cfg.vocab_size, 100))
@@ -801,7 +811,7 @@ class TestDeadlineEviction:
         cfg, params = tiny_model
         clk = _ManualClock()
         eng = Engine(cfg, params, page_size=8, num_pages=64,
-                     max_batch_size=2, prefill_len=32, clock=clk)
+                     max_batch_size=2, chunk_len=32, clock=clk)
         req = eng.add_request(list(range(6)), SamplingParams(
             max_new_tokens=50, ttl_s=5.0))
         for _ in range(3):
@@ -825,7 +835,7 @@ class TestDeadlineEviction:
         clk = _ManualClock()
         # batch of 1: the second request waits in queue
         eng = Engine(cfg, params, page_size=8, num_pages=64,
-                     max_batch_size=1, prefill_len=32, clock=clk)
+                     max_batch_size=1, chunk_len=32, clock=clk)
         sp_long = SamplingParams(max_new_tokens=30)
         sp_ttl = SamplingParams(max_new_tokens=4, ttl_s=2.0)
         eng.add_request(list(range(5)), sp_long)
@@ -840,7 +850,7 @@ class TestDeadlineEviction:
         cfg, params = tiny_model
         clk = _ManualClock()
         eng = Engine(cfg, params, page_size=8, num_pages=64,
-                     max_batch_size=2, prefill_len=32, clock=clk,
+                     max_batch_size=2, chunk_len=32, clock=clk,
                      default_ttl_s=1.0)
         req = eng.add_request(list(range(4)),
                               SamplingParams(max_new_tokens=50))
@@ -854,7 +864,7 @@ class TestWatermarkShedding:
     def test_queue_depth_watermarks_with_hysteresis(self, tiny_model):
         cfg, params = tiny_model
         eng = Engine(cfg, params, page_size=8, num_pages=64,
-                     max_batch_size=1, prefill_len=32,
+                     max_batch_size=1, chunk_len=32,
                      shed_queue_high=3, shed_queue_low=1)
         sp = SamplingParams(max_new_tokens=3)
         reqs = [eng.add_request(list(range(4)), sp) for _ in range(6)]
@@ -881,7 +891,7 @@ class TestWatermarkShedding:
     def test_occupancy_watermark_sheds_until_pages_free(self, tiny_model):
         cfg, params = tiny_model
         eng = Engine(cfg, params, page_size=8, num_pages=4,
-                     max_batch_size=2, prefill_len=16,
+                     max_batch_size=2, chunk_len=16,
                      shed_occupancy_high=0.5, shed_occupancy_low=0.25)
         first = eng.add_request(list(range(10)),
                                 SamplingParams(max_new_tokens=4))
@@ -905,7 +915,7 @@ class TestWatermarkShedding:
         cfg, params = tiny_model
         clk = _ManualClock()
         eng = Engine(cfg, params, page_size=8, num_pages=64,
-                     max_batch_size=2, prefill_len=32, clock=clk,
+                     max_batch_size=2, chunk_len=32, clock=clk,
                      default_ttl_s=60.0, shed_queue_high=2,
                      shed_queue_low=0)
         sp = SamplingParams(max_new_tokens=4)
@@ -924,7 +934,7 @@ class TestWatermarkShedding:
     def test_shedding_disabled_by_default(self, tiny_model):
         cfg, params = tiny_model
         eng = Engine(cfg, params, page_size=8, num_pages=64,
-                     max_batch_size=1, prefill_len=32)
+                     max_batch_size=1, chunk_len=32)
         sp = SamplingParams(max_new_tokens=2)
         reqs = [eng.add_request(list(range(4)), sp) for _ in range(10)]
         assert all(r.state == RequestState.QUEUED for r in reqs)

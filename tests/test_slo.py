@@ -538,40 +538,100 @@ class TestAutoscalerSLOCoupling:
 # ------------------------------------------------------- overhead smoke
 
 
-class TestSLOOverheadSmoke:
-    def test_scrape_evaluate_cycle_under_bound(self):
-        """Acceptance: a full store-scrape + 3-objective evaluate
-        cycle over a serving-shaped registry stays under the 1%%
-        bound ``bench --section slo`` publishes (50 ms request
-        model).  Runs in a fresh subprocess: a mid-suite interpreter
-        carries daemon threads from earlier test modules whose GIL
-        share uniformly inflates every cycle ~2x — that measures the
-        test session, not the engine."""
-        import json
-        import os
-        import subprocess
-        import sys
+def _scrape_evaluate_cycle(n=80, reps=5):
+    """One full scrape+evaluate cycle — the TimeSeriesStore walking a
+    serving-sized metric population (the real ServingMetrics /
+    RouterMetrics / AutoscalerMetrics facades, three replicas' label
+    children, live TTFT histograms) and the SLOEngine re-computing burn
+    rates, budgets and alert state for availability + goodput + TTFT
+    latency, each with the page+ticket alert pair.  Each cycle is timed
+    alone and a window reports its fastest one (the minimum is the
+    intrinsic cost; slower cycles measure preemption by unrelated
+    threads); the result is the median of ``reps`` window minima.  Host
+    clock on the CPU."""
+    import time
 
-        root = os.path.join(os.path.dirname(__file__), os.pardir)
-        code = (
-            "import importlib.util, json, sys\n"
-            "spec = importlib.util.spec_from_file_location("
-            "'bench_mod', sys.argv[1])\n"
-            "bench = importlib.util.module_from_spec(spec)\n"
-            "spec.loader.exec_module(bench)\n"
-            "print(json.dumps(bench.bench_slo()))\n"
-        )
-        env = dict(os.environ, JAX_PLATFORMS="cpu")
-        proc = subprocess.run(
-            [sys.executable, "-c", code,
-             os.path.join(root, "bench.py")],
-            capture_output=True, text=True, timeout=300, cwd=root,
-            env=env)
-        assert proc.returncode == 0, proc.stderr[-2000:]
-        out = json.loads(proc.stdout.strip().splitlines()[-1])
-        assert out["implied_request_overhead_ratio"] < \
-            out["bound_ratio"], out
+    import numpy as np
+
+    from paddle_tpu.serving.metrics import (AutoscalerMetrics,
+                                            RouterMetrics, ServingMetrics)
+
+    reg = MetricsRegistry()
+    serving = ServingMetrics(registry=reg)
+    router = RouterMetrics(registry=reg)
+    AutoscalerMetrics(registry=reg)
+    rng = np.random.default_rng(7)
+
+    def traffic_beat(i):
+        # the population a real fleet scrape sees: per-replica label
+        # children plus live histograms
+        for rep in range(3):
+            router.dispatches.labels(replica=rep).inc()
+            if i % 7 == rep:
+                router.backpressure_retries.labels(replica=rep).inc()
+        router.finished.inc(3)
+        serving.requests_submitted.inc(3)
+        ttft = float(0.02 + 0.08 * rng.random())
+        serving.ttft.observe(ttft)
+        router.ttft.observe(ttft)
+
+    alerts = (BurnRateAlert("page", burn_rate_threshold=14.4,
+                            long_window_seconds=2.0,
+                            short_window_seconds=0.5),
+              BurnRateAlert("ticket", burn_rate_threshold=3.0,
+                            long_window_seconds=8.0,
+                            short_window_seconds=1.0))
+    slos = (
+        SLO("availability", target=0.999,
+            bad=("serving_requests_shed_total",
+                 "router_requests_lost_total"),
+            total=("serving_requests_submitted_total",),
+            alerts=alerts, budget_window_seconds=30.0),
+        SLO("goodput", target=0.95,
+            good=("router_requests_finished_total",),
+            total=("router_dispatches_total",),
+            alerts=alerts, budget_window_seconds=30.0),
+        SLO("ttft_fast", target=0.99,
+            histogram="serving_ttft_seconds", threshold_seconds=0.2,
+            alerts=alerts, budget_window_seconds=30.0),
+    )
+    store = TimeSeriesStore(reg, max_points=256)
+    engine = SLOEngine(store, slos, registry=reg)
+
+    def fastest_cycle():
+        best = float("inf")
+        for _ in range(n):
+            t0 = time.perf_counter()
+            store.scrape_once()
+            engine.evaluate()
+            best = min(best, time.perf_counter() - t0)
+        return best
+
+    for i in range(200):            # warm population + ring
+        traffic_beat(i)
+    fastest_cycle()                 # warm-up
+    windows = []
+    for w in range(reps):
+        for i in range(20):
+            traffic_beat(w * 20 + i)
+        windows.append(fastest_cycle())
+    return {"per_cycle_s": float(np.median(windows)),
+            "page_active": engine.page_active()}
+
+
+class TestSLOOverheadSmoke:
+    def test_scrape_evaluate_cycle_under_bound(self, in_fresh_process):
+        """Acceptance: a full store-scrape + 3-objective evaluate cycle
+        over a serving-shaped registry costs under 1% of 50 ms even if a
+        cycle ran per request (it runs per poll interval).  A ratio of
+        host times on the CPU against a model request of 50 ms; not
+        measured on the chip.  Runs in a fresh subprocess: a mid-suite
+        interpreter carries daemon threads from earlier test modules
+        whose GIL share uniformly inflates every cycle ~2x — that
+        measures the test session, not the engine."""
+        out = in_fresh_process(__file__, "_scrape_evaluate_cycle")
+        assert out["per_cycle_s"] / 0.05 < 0.01, out
         # absolute sanity: sub-millisecond per cycle
-        assert out["per_cycle_us"] < 5000, out
-        # the bench fleet is healthy: no page firing at the end
+        assert out["per_cycle_s"] * 1e6 < 5000, out
+        # the fleet is healthy: no page firing at the end
         assert out["page_active"] is False, out

@@ -1,5 +1,5 @@
-"""Compressed chaos soak — the tier-1 variant of ``bench.py --section
-soak``: a seeded diurnal/bursty trace through an autoscaled real-engine
+"""Compressed chaos soak (``serving.soak.run_soak`` at tier-1 size): a
+seeded diurnal/bursty trace through an autoscaled real-engine
 fleet while the chaos timeline fires a hard kill, admission and
 control-loop stalls, a spawn io_error (the fault sites
 ``autoscaler.poll`` / ``autoscaler.scale_up`` / ``serving.admit``),

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 
 from paddle_tpu.kernels import dispatch
 from paddle_tpu.models.gpt import GPT_CONFIGS, gpt_init
+from paddle_tpu.models.ragged import batch_shapes
 from paddle_tpu.observability.metrics import Histogram
 from paddle_tpu.observability.profiling import current_phase
 from paddle_tpu.profiler import Profiler, RecordEvent
@@ -269,8 +270,7 @@ def test_flash_kernel_lowering_carries_its_name(flash_lowering, name):
 
 
 def test_ragged_kernel_lowering_carries_its_name():
-    from paddle_tpu.kernels.paged_attention import (paged_attention,
-                                                    ragged_paged_attention)
+    from paddle_tpu.kernels.paged_attention import ragged_paged_attention
 
     bf16, i32 = jnp.bfloat16, jnp.int32
     pages = jax.ShapeDtypeStruct((32, 16, 4, 128), bf16)
@@ -281,9 +281,11 @@ def test_ragged_kernel_lowering_carries_its_name():
         jax.ShapeDtypeStruct((2, 8, 4, 128), bf16), pages, pages, tables,
         lens, lens)
     assert _kernel_names(ragged) == {"ragged_paged_attention": 1}
-    # the decode entry point goes through the same call
+    # a decode call (one query slot a row) is the same kernel
     decode = _tpu_lowering(
-        lambda *a: paged_attention(*a, path=dispatch.MOSAIC),
+        lambda q, kp, vp, tables, lens: ragged_paged_attention(
+            q[:, None], kp, vp, tables, (lens > 0).astype(i32), lens,
+            path=dispatch.MOSAIC)[:, 0],
         jax.ShapeDtypeStruct((2, 4, 128), bf16), pages, pages, tables, lens)
     assert _kernel_names(decode) == {"ragged_paged_attention": 1}
 
@@ -332,12 +334,9 @@ def _serve_step_text():
                               num_layers=2)
     eng = Engine(cfg, gpt_init(cfg, jax.random.key(0), dtype=jnp.float32),
                  page_size=4, num_pages=16, max_batch_size=2, chunk_len=4)
-    B, T = eng.max_batch_size, eng.token_budget
-    ints = lambda *shape: jnp.zeros(shape, jnp.int32)
     return eng._step_fn.lower(
-        eng.params, eng.cache.k_pages, eng.cache.v_pages, ints(T), ints(T),
-        ints(T), ints(B), ints(B),
-        ints(B, eng.cache.max_pages_per_seq)).compile().as_text()
+        eng.params, *eng.cache.state_arrays(),
+        batch_shapes(*eng.batch_dims)).compile().as_text()
 
 
 @pytest.mark.parametrize("step_text,scopes", [

@@ -48,7 +48,7 @@ def compile_kernels(device):
     serving cell's shapes (16 rows, 16 heads of 128, pages of 16, a table
     128 wide) — a 128-token chunk on one layer's pool with the work list
     built inside (``ragged_q128``: the wide and the narrow body), the
-    decode entry's one query slot (``ragged_q1``), the serving step's
+    one query slot of a decode-only call (``ragged_q1``), the serving step's
     call, a layer of a stacked pool with the list handed in
     (``ragged_stacked``), the 4 heads a chip holds under ``mp=4``
     (``ragged_h4``) — and flash forward+backward at head dims 64 and 128,
@@ -142,6 +142,7 @@ def lower_serve_step(devices, num_pages=1024, max_batch_size=8,
     one device or (``mp`` > 1) on a ``build_mesh(mp=mp)`` engine."""
     from paddle_tpu.distributed import mesh as mesh_mod
     from paddle_tpu.models.gpt import GPT_CONFIGS, gpt_init
+    from paddle_tpu.models.ragged import batch_shapes
     from paddle_tpu.serving import Engine
 
     cfg = GPT_CONFIGS["gpt3-1.3b"]
@@ -157,7 +158,6 @@ def lower_serve_step(devices, num_pages=1024, max_batch_size=8,
         eng = Engine(cfg, params, page_size=16, num_pages=1,
                      max_batch_size=max_batch_size, chunk_len=chunk_len,
                      mesh=mesh)
-        B, T = eng.max_batch_size, eng.token_budget
         pool = (cfg.num_layers, num_pages, 16, cfg.num_heads, cfg.head_dim)
         if mesh is None:
             rep = pages = SingleDeviceSharding(devices[0])
@@ -171,9 +171,7 @@ def lower_serve_step(devices, num_pages=1024, max_batch_size=8,
                                                    sharding=sh),
                 params, p_sh),
             *_on(pages, (pool, cfg.jdtype()), (pool, cfg.jdtype())),
-            *_on(rep, ((T,), jnp.int32), ((T,), jnp.int32),
-                 ((T,), jnp.int32), ((B,), jnp.int32), ((B,), jnp.int32),
-                 ((B, eng.cache.max_pages_per_seq), jnp.int32)))
+            batch_shapes(*eng.batch_dims, sharding=rep))
 
 
 def lower_hybrid_serve_step(devices, num_pages=8192, max_batch_size=16,
@@ -182,6 +180,7 @@ def lower_hybrid_serve_step(devices, num_pages=8192, max_batch_size=16,
     """The unified step of the sparse-plus-lightning decoder at the
     benchmark cell's knobs, on one device: its four state pools donated."""
     from paddle_tpu.models.hybrid import HYBRID_CONFIGS, hybrid_init
+    from paddle_tpu.models.ragged import batch_shapes
     from paddle_tpu.serving import Engine
 
     cfg = HYBRID_CONFIGS[config]
@@ -191,7 +190,6 @@ def lower_hybrid_serve_step(devices, num_pages=8192, max_batch_size=16,
         # 1 page and 1 row held here; the lowered shapes are the real ones
         eng = Engine(cfg, params, page_size=page_size, num_pages=1,
                      max_batch_size=max_batch_size, chunk_len=chunk_len)
-        B, T = eng.max_batch_size, eng.token_budget
         state = [(shape, dtype) for _, shape, dtype, _ in
                  eng.model.state_spec(num_pages=num_pages,
                                       page_size=page_size,
@@ -200,10 +198,7 @@ def lower_hybrid_serve_step(devices, num_pages=8192, max_batch_size=16,
             jax.tree_util.tree_map(
                 lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
                                                sharding=one), params),
-            *_on(one, *state),
-            *_on(one, ((T,), jnp.int32), ((T,), jnp.int32),
-                 ((T,), jnp.int32), ((B,), jnp.int32), ((B,), jnp.int32),
-                 ((B, eng.cache.max_pages_per_seq), jnp.int32)))
+            *_on(one, *state), batch_shapes(*eng.batch_dims, sharding=one))
 
 
 def _report(name, compile_fn):
