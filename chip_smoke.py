@@ -469,11 +469,7 @@ def check_requests(reqs, new_tokens):
 
 
 def lowered_serve_step(engine):
-    from paddle_tpu.models.ragged import batch_shapes
-
-    return engine._step_fn.lower(
-        engine.params, *engine.cache.state_arrays(),
-        batch_shapes(*engine.batch_dims))
+    return engine._step_fn.lower(*engine.step_args())
 
 
 def leg_serve(args):

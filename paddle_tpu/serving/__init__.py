@@ -169,7 +169,8 @@ there.  Semantics it guarantees:
   window empties and no suspects remain queued or on trial.
 - **innocents are never taxed** — a co-batched innocent rides the
   ordinary exactly-once failover: re-dispatch replays ``prompt +
-  harvested tokens`` and host-side greedy sampling is batch-
+  harvested tokens`` and sampling (on the device, keyed by seed and
+  position: ``serving/sampling.py``) is batch-
   composition-independent, so its output stays token-identical to a
   poison-free run no matter how many neighbours get quarantined.
 

@@ -78,9 +78,12 @@ of the same prompt (parity-tested).  Zero-ref cached pages are counted
 as free for watermark/occupancy purposes and LRU-evicted on demand, so
 a warm cache never sheds traffic it could serve.
 
-Sampling is host-side (greedy / temperature / top-k / top-p) with a
-per-request numpy Generator seeded at submit, so outputs are
-deterministic for a fixed seed regardless of batch composition.
+Sampling is on the device (``serving/sampling.py``): the jitted step
+ends by choosing each row's token (greedy / temperature / top-k / top-p)
+and the host reads ``[B]`` ids, never the ``[B, V]`` logits.  A draw is
+keyed by the request's seed and the sampled token's position, so outputs
+are deterministic for a fixed seed regardless of batch composition,
+preemption or re-dispatch.
 """
 from __future__ import annotations
 
@@ -93,7 +96,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..models.ragged import RaggedBatch, empty_batch
+from ..models.ragged import RaggedBatch, batch_shapes, empty_batch
 from ..observability.compile_watchdog import watch
 from ..observability.profiling import pop_phase, push_phase
 from ..observability.tracing import Tracer, default_tracer
@@ -102,6 +105,7 @@ from ..resilience.faults import fault_point
 from .kv_cache import PagedKVCache
 from .metrics import STEP_PHASES, ServingMetrics
 from .model import as_served
+from .sampling import GREEDY, sample_tokens, slot_entry
 
 __all__ = ["SamplingParams", "Request", "RequestState", "Engine"]
 
@@ -123,10 +127,16 @@ class RequestState:
 @dataclasses.dataclass
 class SamplingParams:
     """temperature == 0 is greedy (argmax); top_k/top_p only apply when
-    sampling.  stop_token_ids end generation (the stop token is kept in
-    the output, reason "stop"); max_new_tokens caps it (reason "length").
-    ttl_s bounds submit→finish wall time: past it the request is evicted
-    (reason "deadline") even mid-decode."""
+    sampling.  The token is chosen on the device, inside the serving step
+    (``serving/sampling.py``).  ``seed`` names a stream of draws keyed by
+    (seed, position of the sampled token): the same seed gives the same
+    tokens whoever shares the batch, through a preemption, and when the
+    request is moved to another engine with its output so far as prompt;
+    any Python int, of which 64 bits are used.  stop_token_ids end
+    generation (the stop token is kept in the output, reason "stop");
+    max_new_tokens caps it (reason "length").  ttl_s bounds submit→finish
+    wall time: past it the request is evicted (reason "deadline") even
+    mid-decode."""
     max_new_tokens: int = 16
     temperature: float = 0.0
     top_k: int = 0
@@ -152,7 +162,6 @@ class Request:
     retry_after_s: float = None  # drain-estimate hint on RETRY_AFTER
     prompt_pos: int = 0        # prompt tokens already written to pages
     _chunks_done: int = 0      # prefill chunks completed (span index)
-    _rng: object = None
     _span: object = None       # root trace span (one per request)
     _phase: object = None      # current lifecycle child span
 
@@ -162,14 +171,13 @@ class Request:
 
     def _reset_for_recompute(self):
         """Preemption rewinds to the prompt — including mid-prefill
-        chunk progress; the reseeded rng replays the exact same draws,
-        so a preempted request's final output is identical to its
-        uninterrupted one."""
+        chunk progress; a draw is keyed by (seed, position), so the
+        recomputation replays the exact same draws and a preempted
+        request's final output is identical to its uninterrupted one."""
         self.tokens = list(self.prompt)
         self.prompt_pos = 0
         self._chunks_done = 0
         self.state = RequestState.QUEUED
-        self._rng = np.random.default_rng(self.sampling.seed)
 
 
 class _StepPhases:
@@ -343,12 +351,24 @@ class Engine:
             if jax.default_backend() != "cpu" else ()
         model_step = model.make_step(max_q=self.chunk_len, mesh=mesh)
 
+        # the rows' sampling parameters by batch slot (sampling.py): the
+        # host's copy, and the device's, sent again only after admission
+        # put a tenant with another entry into a slot
+        self._slot_sampling = [GREEDY] * max_batch_size
+        self._sampling_table = None
+        #: the last step's ``logits [B, V]`` as the program left them on
+        #: the device: for a test or a debugging caller; ``step()`` never
+        #: reads them
+        self.step_logits = None
+
         def _step(params, *args):
-            # (params, every state pool, the ragged batch): the pools
-            # flat, so that they are donated one by one
-            *state, batch = args
+            # (params, every state pool, the ragged batch, the sampling
+            # table): the pools flat, so that they are donated one by one
+            *state, batch, sampling = args
             logits, state = model_step(params, tuple(state), batch)
-            return (logits, *state)
+            ids = sample_tokens(logits, sampling, batch.query_lens,
+                                batch.context_lens)
+            return (ids, logits, *state)
 
         # GSPMD serving (prepare(mesh=...) analogue): the model shards its
         # params and names the page pools' spec (the dense family: the
@@ -356,11 +376,12 @@ class Engine:
         # HEAD axis along "mp") — each model-parallel shard owns its head
         # group's pages, so page writes are local and the only
         # cross-shard traffic is the per-layer psum GSPMD inserts at the
-        # residual write plus ONE logits gather per step (out_shardings
-        # pins logits replicated; pages stay sharded end-to-end, never
+        # residual write plus ONE logits gather per step (the rows are
+        # sampled from the gathered logits; out_shardings pins the ids and
+        # the logits replicated; pages stay sharded end-to-end, never
         # gathered).
         self.mesh = mesh
-        self._page_sharding = None
+        self._page_sharding = self._replicated = None
         jit_kw = {"donate_argnums": donate}
         if mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as P
@@ -373,10 +394,10 @@ class Engine:
             for name, a in self.cache.arrays.items():
                 self.cache.arrays[name] = jax.device_put(a, psh)
             self._page_sharding = psh
-            rep = NamedSharding(mesh, P())
+            self._replicated = rep = NamedSharding(mesh, P())
             jit_kw.update(
-                in_shardings=(p_sh,) + (psh,) * n_state + (rep,),
-                out_shardings=(rep,) + (psh,) * n_state)
+                in_shardings=(p_sh,) + (psh,) * n_state + (rep, rep),
+                out_shardings=(rep, rep) + (psh,) * n_state)
         # watchdog-wrapped: the ONE statically-shaped program — prompt
         # chunks and decode rows share it — must compile exactly once;
         # any recompile here is a serving bug the watchdog flags with
@@ -404,7 +425,6 @@ class Engine:
                       sampling=sampling, t_submit=self._clock())
         self._next_id += 1
         req.tokens = list(req.prompt)
-        req._rng = np.random.default_rng(sampling.seed)
         ttl = sampling.ttl_s if sampling.ttl_s is not None \
             else self.default_ttl_s
         if ttl is not None:
@@ -623,6 +643,10 @@ class Engine:
             req._admit_seq = self._admit_seq
             self._admit_seq += 1
             self._slots[slot] = req
+            entry = slot_entry(req.sampling, len(req.prompt))
+            if entry != self._slot_sampling[slot]:
+                self._slot_sampling[slot] = entry
+                self._sampling_table = None      # sent with the next step
             self.metrics.requests_admitted.inc()
             self.metrics.queue_wait.observe(now - req.t_submit)
             self._end_phase(req, end_s=now)      # queued → admitted
@@ -711,11 +735,25 @@ class Engine:
             if stable:
                 return plan
 
+    def step_args(self, params=None, state=None, *, sharding=None):
+        """The jitted step's arguments, for ``_step_fn.lower(*...)``:
+        the parameters and state pools (the engine's own, or the given
+        abstract ones), then the ragged batch and the sampling table as
+        ``ShapeDtypeStruct``s on ``sharding``.  The one place that knows
+        the step's signature besides ``__init__`` and the dispatch."""
+        return (self.params if params is None else params,
+                *(self.cache.state_arrays() if state is None else state),
+                batch_shapes(*self.batch_dims, sharding=sharding),
+                jax.ShapeDtypeStruct((self.max_batch_size, len(GREEDY)),
+                                     jnp.uint32, sharding=sharding))
+
     def _unified_step_once(self, plan, phases):
-        """Run the one jitted program over the planned ragged batch and
-        sample every row that is owed a token: the phases pack, dispatch,
-        device_wait, fetch and sample of the call's ``_StepPhases``.
-        Returns ``_commit``'s arguments, or None when nothing ran."""
+        """Run the one jitted program over the planned ragged batch — it
+        ends by choosing a token for every row — and hand every row that
+        is owed one its id: the phases pack, dispatch, device_wait, fetch
+        (the ``[B]`` ids) and sample (the per-row hook) of the call's
+        ``_StepPhases``.  Returns ``_commit``'s arguments, or None when
+        nothing ran."""
         if not plan:
             return None
         with phases.phase("pack"):
@@ -731,17 +769,27 @@ class Engine:
         t0 = self._clock()
         with RecordEvent("serving::unified_step"):
             with phases.phase("dispatch", step_phase):
-                logits, *state = self._step_fn(
+                if self._sampling_table is None:
+                    table = np.array(self._slot_sampling, np.uint32)
+                    self._sampling_table = (
+                        jnp.asarray(table) if self._replicated is None
+                        else jax.device_put(table, self._replicated))
+                ids, self.step_logits, *state = self._step_fn(
                     self.params, *self.cache.state_arrays(),
-                    RaggedBatch(*(jnp.asarray(a) for a in batch)))
+                    RaggedBatch(*(jnp.asarray(a) for a in batch)),
+                    self._sampling_table)
+                # the 64 bytes follow the program to the host on their
+                # own: a read begun only after the wait costs one more
+                # round trip to the device (0.45 ms on a v5e, PERF.md)
+                ids.copy_to_host_async()
             with phases.phase("device_wait", step_phase):
-                logits.block_until_ready()
+                ids.block_until_ready()
             with phases.phase("fetch", step_phase):
-                logits = np.asarray(logits)
+                ids = np.asarray(ids).tolist()
         self.cache.set_state(state)
         t1 = self._clock()
         with phases.phase("sample"):
-            sampled = self._sample_rows(logits, sched)
+            sampled = self._sample_rows(ids, sched)
         return sched, sampled, t0, t1
 
     def _pack(self, plan):
@@ -782,19 +830,26 @@ class Engine:
             return None
         return batch, sched
 
-    def _sample_rows(self, logits, sched):
+    def _sample_rows(self, ids, sched):
         """{batch slot: next token} for every row whose context now
         covers its prompt (a decode row, or the chunk that completed a
-        prompt).  A row whose sampling raises is retired FAILED here and
-        has no entry, like any other row-attributable failure."""
+        prompt), from the ids the device chose.  A row whose hook raises
+        is retired FAILED here and has no entry, like any other
+        row-attributable failure.  Counts the step under the path the
+        program took: the ``cond``'s predicate, from what the plan holds
+        (``sampling.any_stochastic``)."""
         sampled = {}
+        stochastic = False
         for i, req, _, ctx in sched:
             if ctx < len(req.prompt):
                 continue                     # more chunks to go
+            stochastic = stochastic or req.sampling.temperature > 0.0
             try:
-                sampled[i] = self._sample_token(logits[i], req)
+                sampled[i] = self._sample_token(ids[i], req)
             except Exception as e:
                 self._fail(req, e)
+        (self.metrics.sample_steps_stochastic if stochastic
+         else self.metrics.sample_steps_greedy).inc()
         return sampled
 
     def _commit(self, sched, sampled, t0, t1):
@@ -882,27 +937,11 @@ class Engine:
                 else a * inst + (1 - a) * self._decode_rate_ewma)
 
     # ------------------------------------------------------------ sampling
-    def _sample_token(self, logits_row, req):
-        sp = req.sampling
-        logits = np.asarray(logits_row, np.float64)
-        if sp.temperature <= 0.0:
-            return int(np.argmax(logits))
-        logits = logits / sp.temperature
-        if sp.top_k and sp.top_k < logits.size:
-            kth = np.partition(logits, -sp.top_k)[-sp.top_k]
-            logits = np.where(logits < kth, -np.inf, logits)
-        probs = np.exp(logits - np.max(logits))
-        probs = probs / probs.sum()
-        if sp.top_p < 1.0:
-            order = np.argsort(-probs)
-            cum = np.cumsum(probs[order])
-            # smallest prefix reaching top_p (always keep the first)
-            cut = int(np.searchsorted(cum, sp.top_p)) + 1
-            mask = np.zeros_like(probs)
-            mask[order[:cut]] = 1.0
-            probs = probs * mask
-            probs = probs / probs.sum()
-        return int(req._rng.choice(probs.size, p=probs))
+    def _sample_token(self, token_id, req):
+        """The per-row seam of the ``sample`` phase: receives the id the
+        device chose for ``req``'s row and returns the token to commit.
+        Raising here retires that row FAILED and no other."""
+        return token_id
 
     # ------------------------------------------------------------- finish
     def _maybe_finish(self, req):

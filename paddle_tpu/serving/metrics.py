@@ -16,6 +16,9 @@ process-wide registry); this module keeps the serving-shaped facade:
   prefix_cache_* — radix prefix-cache hits / hit tokens / LRU
                  evictions (counters) + cached pages (gauge): every
                  hit token is prefill FLOPs the pool skipped
+  sample_steps — ``serving_sample_steps_total{path}``: steps whose
+                 on-device sampling ran the argmax alone (greedy) or
+                 the sort as well (stochastic)
   step_phase   — ``serving_step_phase_seconds{phase}``: every
                  ``Engine.step()`` call cut into ``STEP_PHASES``, one
                  observation per phase per call (0 for a phase the call
@@ -142,6 +145,16 @@ class ServingMetrics:
             kind="context")
         self.attention_selected = self.attention_positions.labels(
             kind="selected")
+        self.sample_steps = add(Counter(
+            "serving_sample_steps_total", labelnames=("path",),
+            help="steps by the path their on-device sampling took: "
+                 "greedy (an argmax and nothing else) or stochastic (some "
+                 "row owed a token asked for temperature > 0: the sort "
+                 "ran); the predicate of the program's cond, counted on "
+                 "the host from the plan"))
+        self.sample_steps_greedy = self.sample_steps.labels(path="greedy")
+        self.sample_steps_stochastic = self.sample_steps.labels(
+            path="stochastic")
         self.state_resets = add(Counter(
             "serving_state_resets_total",
             help="rows whose recurrent state the step zeroed: an "

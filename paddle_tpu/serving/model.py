@@ -13,7 +13,10 @@ the state that step carries.
 - ``make_step(max_q=, mesh=)``: ``step(params, state, batch) -> (logits
   [B, V], state)`` with ``state`` the tuple of pools and ``batch`` the
   scheduler's ``RaggedBatch`` (``models/ragged.py`` owns the format);
-  jitted by the engine with every pool donated.
+  jitted by the engine with every pool donated.  A model returns logits
+  and never samples: the engine's own jitted wrapper chooses each row's
+  token from them in the same program (``serving/sampling.py``), once for
+  every served model, and hands the host ``[B]`` ids.
 - ``recurrent``: the model keeps per-row state that is a function of
   every token the row has seen.  Pages of a cached prefix say nothing of
   that state, so the engine serves such a model cold (no prefix reuse).

@@ -50,14 +50,18 @@ def tiny():
 
 
 def serve(cfg, params, prompts, new_tokens, **engine):
-    """Drive the engine to the end; per request the logits row it sampled
-    each token from, and the engine."""
+    """Drive the engine to the end; per request the logits row each of
+    its tokens was sampled from, and the engine.  The tokens are chosen
+    on the device: the rows are read from the logits the step left there
+    (``Engine.step_logits``, which ``step()`` itself never reads), at the
+    moment the per-row hook is handed the row's id."""
     eng = Engine(cfg, params, **engine)
     seen, sound = {}, eng._sample_token
 
-    def spy(row, req):
+    def spy(token, req):
+        row = eng.step_logits[eng._slots.index(req)]
         seen.setdefault(req.id, []).append(np.asarray(row, np.float32))
-        return sound(row, req)
+        return sound(token, req)
 
     eng._sample_token = spy
     reqs = [eng.add_request(p, SamplingParams(max_new_tokens=n))

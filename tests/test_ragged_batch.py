@@ -188,12 +188,13 @@ def test_batch_shapes_are_what_pack_produces(engine):
                              empty_batch(*dims)):
         assert (s.shape, s.dtype) == (a.shape, a.dtype) == (e.shape, e.dtype)
         assert a.dtype == np.int32, name
-    # the jitted step takes exactly these six operands, in this order
-    lowered = eng._step_fn.lower(eng.params, *eng.cache.state_arrays(),
-                                 shapes)
+    # the jitted step takes exactly these six operands, in this order,
+    # and after them the sampling table by batch slot
+    lowered = eng._step_fn.lower(*eng.step_args())
     flat = jax.tree_util.tree_leaves(lowered.in_avals)
-    assert [(a.shape, a.dtype) for a in flat[-6:]] == [
+    assert [(a.shape, a.dtype) for a in flat[-7:-1]] == [
         (s.shape, s.dtype) for s in shapes]
+    assert (flat[-1].shape, flat[-1].dtype) == ((dims[0], 6), np.uint32)
 
 
 def test_empty_batch_is_all_idle_rows_and_padding_slots():

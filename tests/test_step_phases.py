@@ -23,7 +23,6 @@ import jax.numpy as jnp
 
 from paddle_tpu.kernels import dispatch
 from paddle_tpu.models.gpt import GPT_CONFIGS, gpt_init
-from paddle_tpu.models.ragged import batch_shapes
 from paddle_tpu.observability.metrics import Histogram
 from paddle_tpu.observability.profiling import current_phase
 from paddle_tpu.profiler import Profiler, RecordEvent
@@ -334,9 +333,7 @@ def _serve_step_text():
                               num_layers=2)
     eng = Engine(cfg, gpt_init(cfg, jax.random.key(0), dtype=jnp.float32),
                  page_size=4, num_pages=16, max_batch_size=2, chunk_len=4)
-    return eng._step_fn.lower(
-        eng.params, *eng.cache.state_arrays(),
-        batch_shapes(*eng.batch_dims)).compile().as_text()
+    return eng._step_fn.lower(*eng.step_args()).compile().as_text()
 
 
 @pytest.mark.parametrize("step_text,scopes", [

@@ -92,23 +92,29 @@ def test_gpt_serve_step_keeps_its_temporaries_behind_the_model_interface(
         devices):
     """The dense family is served through the engine's model interface
     (``serving/model.py``) and its compiled step holds what it held: at
-    the benchmark's 1024 pages and 16 rows the same 5,852,876,800 B of
-    arguments and one Mosaic call — the equal-heads kernel, which the
-    grouped / selected mode must not reach.  XLA plans 1,128,960 B of
-    temporaries in HBM: the 1,032,192 B of PR 27's program and 96,768 B
-    that the kernel's work list brought (PR 29; by the compiler's memory
-    report the block of small arrays grew by three 16 KiB slots, 496.5 to
-    544.5 KiB — the list's rows, tiles and count, built once a step — and
-    the loops hold 36 more scalar slots).  The padded queries and the
-    kernel's output are in the chip's fast memory, 26.85 MiB as before,
-    which this figure does not count."""
+    the benchmark's 1024 pages and 16 rows 5,852,880,896 B of arguments
+    (the 5,852,876,800 B of weights, pools and batch, and one 4 KiB tile
+    for the ``[16, 6]`` sampling table) and one Mosaic call — the
+    equal-heads kernel, which the grouped / selected mode must not reach.
+    XLA plans 1,580,544 B of temporaries in HBM: the 1,032,192 B of PR
+    27's program, 96,768 B that the kernel's work list brought (PR 29; by
+    the compiler's memory report the block of small arrays grew by three
+    16 KiB slots, 496.5 to 544.5 KiB — the list's rows, tiles and count,
+    built once a step — and the loops hold 36 more scalar slots) and
+    451,584 B for sampling on the device (PR 31: the rows' ids, the
+    ``cond``'s operands and what the drawing branch keeps in HBM).  The
+    padded queries and the kernel's output are in the chip's fast memory,
+    26.85 MiB as before, which this figure does not count.  The draw is
+    compiled once, in one branch of one ``conditional``, and nothing in
+    the step sorts (a sort of 50,304 entries takes this compiler 22 s,
+    the whole step 3 s)."""
     compiled = tpu_aot.lower_serve_step(
         devices, num_pages=1024, max_batch_size=16, chunk_len=128).compile()
-    mem = compiled.memory_analysis()
-    assert mem.temp_size_in_bytes == 1128960
-    assert mem.argument_size_in_bytes == 5852876800
-    assert compiled.as_text().count(
-        'custom_call_target="tpu_custom_call"') == 1
+    mem, text = compiled.memory_analysis(), compiled.as_text()
+    assert mem.temp_size_in_bytes == 1580544
+    assert mem.argument_size_in_bytes == 5852880896
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert (text.count(" conditional("), text.count(" sort(")) == (1, 0)
 
 
 def test_hybrid_serve_step_compiles_for_v5e_and_moves_no_pool(devices):
@@ -116,7 +122,10 @@ def test_hybrid_serve_step_compiles_for_v5e_and_moves_no_pool(devices):
     (published widths, 8 layers, 16 rows, chunks of 512, 8192 pages of
     64): Mosaic accepts both new kernels, every state pool is donated and
     aliased, nothing re-lays or copies a key/value pool, and the plan
-    fits the chip."""
+    fits the chip: 6,950,199,296 B of arguments and 840,354,816 B of
+    temporaries (6,950,195,200 B and 839,838,720 B before the step
+    sampled its ``[16, 73472]`` logits itself, PR 31: 4,096 B for the
+    table, 516,096 B for the ids and the drawing branch)."""
     from paddle_tpu.models.hybrid import HYBRID_CONFIGS
 
     compiled = tpu_aot.lower_hybrid_serve_step(devices).compile()
@@ -129,6 +138,9 @@ def test_hybrid_serve_step_compiles_for_v5e_and_moves_no_pool(devices):
         + 6 * 16 * 32 * 128 * 128 * 4
     assert mem.alias_size_in_bytes >= pools
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12e9
+    assert (mem.argument_size_in_bytes, mem.temp_size_in_bytes) == \
+        (6950199296, 840354816)
+    assert text.count(" conditional(") == 1
     pool = "bf16[2,8192,2,64,128]"
     movers = [line.strip()[:160] for line in text.splitlines()
               if re.match(rf"\s+(?:ROOT )?%\S+ = {re.escape(pool)}\S* "

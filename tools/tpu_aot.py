@@ -142,7 +142,6 @@ def lower_serve_step(devices, num_pages=1024, max_batch_size=8,
     one device or (``mp`` > 1) on a ``build_mesh(mp=mp)`` engine."""
     from paddle_tpu.distributed import mesh as mesh_mod
     from paddle_tpu.models.gpt import GPT_CONFIGS, gpt_init
-    from paddle_tpu.models.ragged import batch_shapes
     from paddle_tpu.serving import Engine
 
     cfg = GPT_CONFIGS["gpt3-1.3b"]
@@ -165,13 +164,13 @@ def lower_serve_step(devices, num_pages=1024, max_batch_size=8,
         else:
             rep, pages = NamedSharding(mesh, P()), eng._page_sharding
             p_sh = mesh_mod.sharding_tree(params, mesh)
-        return eng._step_fn.lower(
+        return eng._step_fn.lower(*eng.step_args(
             jax.tree_util.tree_map(
                 lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype,
                                                    sharding=sh),
                 params, p_sh),
-            *_on(pages, (pool, cfg.jdtype()), (pool, cfg.jdtype())),
-            batch_shapes(*eng.batch_dims, sharding=rep))
+            _on(pages, (pool, cfg.jdtype()), (pool, cfg.jdtype())),
+            sharding=rep))
 
 
 def lower_hybrid_serve_step(devices, num_pages=8192, max_batch_size=16,
@@ -180,7 +179,6 @@ def lower_hybrid_serve_step(devices, num_pages=8192, max_batch_size=16,
     """The unified step of the sparse-plus-lightning decoder at the
     benchmark cell's knobs, on one device: its four state pools donated."""
     from paddle_tpu.models.hybrid import HYBRID_CONFIGS, hybrid_init
-    from paddle_tpu.models.ragged import batch_shapes
     from paddle_tpu.serving import Engine
 
     cfg = HYBRID_CONFIGS[config]
@@ -194,11 +192,11 @@ def lower_hybrid_serve_step(devices, num_pages=8192, max_batch_size=16,
                  eng.model.state_spec(num_pages=num_pages,
                                       page_size=page_size,
                                       max_batch_size=max_batch_size)]
-        return eng._step_fn.lower(
+        return eng._step_fn.lower(*eng.step_args(
             jax.tree_util.tree_map(
                 lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
                                                sharding=one), params),
-            *_on(one, *state), batch_shapes(*eng.batch_dims, sharding=one))
+            _on(one, *state), sharding=one))
 
 
 def _report(name, compile_fn):
