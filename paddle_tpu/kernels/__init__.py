@@ -6,9 +6,11 @@ jit'ed CPU math (operators/math/jit).  On TPU, XLA already fuses elementwise
 chains into matmuls, so only genuinely structured kernels live here:
 flash attention (+ring variant for sequence parallelism), the ragged
 paged-attention kernel behind the serving engine's KV cache (equal heads
-over every page, or grouped heads over selected pages) and the lightning
-(decayed linear) attention kernel over a per-row recurrent state.
+over every page, or grouped heads over selected pages), the lightning
+(decayed linear) attention kernel over a per-row recurrent state and the
+selective state-space scan (input-dependent decay) over the same.
 """
 from .flash_attention import flash_attention, flash_attention_available  # noqa: F401
 from .lightning_attention import lightning_attention  # noqa: F401
 from .paged_attention import ragged_paged_attention  # noqa: F401
+from .ssd_scan import ssd_scan  # noqa: F401

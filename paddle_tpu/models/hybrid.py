@@ -209,11 +209,18 @@ def _rope(x, pos, theta):
                            axis=-1).astype(x.dtype)
 
 
+def _gated_mlp(h, gate_w, up_w, down_w, gate_scale=None):
+    """``W_down(silu(gate_scale * W_gate h) * (W_up h))`` on ``h [T, D]``."""
+    a = jnp.einsum("td,df->tf", h, gate_w)
+    if gate_scale is not None:
+        a = a * gate_scale
+    b = jnp.einsum("td,df->tf", h, up_w)
+    return jnp.einsum("tf,fd->td", jax.nn.silu(a) * b, down_w)
+
+
 def _mlp(cfg, p, i, x):
-    h = _rms(x, p["ln2"][i], cfg.rms_eps)
-    a = jnp.einsum("td,df->tf", h, p["mlp_gate_w"][i])
-    b = jnp.einsum("td,df->tf", h, p["mlp_up_w"][i])
-    return jnp.einsum("tf,fd->td", jax.nn.silu(a) * b, p["mlp_down_w"][i])
+    return _gated_mlp(_rms(x, p["ln2"][i], cfg.rms_eps), p["mlp_gate_w"][i],
+                      p["mlp_up_w"][i], p["mlp_down_w"][i])
 
 
 def _write_compressed(cfg, kc, kp, layer, tables, query_lens, context_lens,

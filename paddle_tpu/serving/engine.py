@@ -859,7 +859,7 @@ class Engine:
         occ = round(self.cache.occupancy(), 4)
         n_rows = len(sched)
         committed = 0
-        in_context = read = resets = 0
+        in_context = read = resets = chunk_rows = decode_rows = 0
         for i, req, q, ctx in sched:
             # what the step's attention layers had to read for this row,
             # from the lengths alone (no device read); a row that began
@@ -867,6 +867,12 @@ class Engine:
             a, b = self.model.attention_positions(ctx, q)
             in_context, read = in_context + a, read + b
             resets += self.model.recurrent and ctx == q
+            if self.model.recurrent:
+                # the step advanced this row's state by a chunk or a token
+                if req.prompt_pos < len(req.prompt):
+                    chunk_rows += 1
+                else:
+                    decode_rows += 1
             if req.state != RequestState.RUNNING:
                 continue                     # failed while sampling
             # per-row commit isolation: anything this row's
@@ -928,6 +934,10 @@ class Engine:
         self.metrics.attention_selected.inc(read)
         if resets:
             self.metrics.state_resets.inc(resets)
+        if chunk_rows:
+            self.metrics.state_row_steps_chunk.inc(chunk_rows)
+        if decode_rows:
+            self.metrics.state_row_steps_decode.inc(decode_rows)
         if dt > 0 and committed:
             # EWMA decode throughput feeds the drain/retry-after hint
             inst = committed / dt

@@ -159,6 +159,17 @@ class ServingMetrics:
             "serving_state_resets_total",
             help="rows whose recurrent state the step zeroed: an "
                  "admitted or recomputed request's first chunk"))
+        self.state_row_steps = add(Counter(
+            "serving_state_row_steps_total", labelnames=("kind",),
+            help="rows whose recurrent state a step advanced, by what the "
+                 "row ran (kind=chunk: a prompt chunk; kind=decode: one "
+                 "token): each is one read and one write of the row's "
+                 "state in every recurrent layer; from the plan, on the "
+                 "host, for a model that keeps such state"))
+        self.state_row_steps_chunk = self.state_row_steps.labels(
+            kind="chunk")
+        self.state_row_steps_decode = self.state_row_steps.labels(
+            kind="decode")
         self.recurrent_state_bytes = add(Gauge(
             "serving_recurrent_state_bytes",
             help="bytes of per-row recurrent state the cache manager "

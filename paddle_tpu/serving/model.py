@@ -33,7 +33,7 @@ from __future__ import annotations
 import jax
 import numpy as np
 
-__all__ = ["GPTServed", "HybridServed", "as_served"]
+__all__ = ["GPTServed", "HybridServed", "SSMServed", "as_served"]
 
 
 class GPTServed:
@@ -140,6 +140,51 @@ class HybridServed:
         return context, int(np.where(n <= cfg.dense_len, n, sparse).sum())
 
 
+class SSMServed:
+    """The parallel-mixer decoder (``models/ssm.py``): a state-space mixer
+    and grouped-query attention in every block, so every layer holds
+    pages, a convolution window and a scan state."""
+
+    recurrent = True
+
+    def __init__(self, cfg):
+        self.cfg = cfg
+
+    def init_params(self):
+        from ..models.ssm import ssm_init
+
+        return ssm_init(self.cfg)
+
+    def state_spec(self, **sizes):
+        from ..models.ssm import ssm_state_spec
+
+        return ssm_state_spec(self.cfg, **sizes)
+
+    def make_step(self, *, max_q, mesh=None):
+        from ..models.ssm import ssm_ragged_step
+
+        if mesh is not None:
+            raise NotImplementedError(
+                "Engine(mesh=...) with a state-space mixer beside "
+                "attention: the pages shard by key/value head, the scan "
+                "state by head and the convolution window by channel, and "
+                "none has a rule table or a shard_map around its kernel "
+                "yet (PERF.md, open questions)")
+        cfg = self.cfg
+
+        def step(params, state, batch):
+            logits, *state = ssm_ragged_step(cfg, params, batch, *state,
+                                             max_q=max_q)
+            return logits, tuple(state)
+
+        return jax.jit(step)       # as GPTServed.make_step
+
+    def attention_positions(self, ctx, q):
+        # dense: token at position p attends over p + 1 positions
+        n = q * ctx - q * (q - 1) // 2
+        return n, n
+
+
 def as_served(model):
     """``model`` if it already has the interface, else the served form of
     a known config object."""
@@ -152,5 +197,9 @@ def as_served(model):
         return GPTServed(model)
     if isinstance(model, HybridConfig):
         return HybridServed(model)
+    from ..models.ssm import SSMConfig
+
+    if isinstance(model, SSMConfig):
+        return SSMServed(model)
     raise TypeError(f"Engine cannot serve a {type(model).__name__}: pass a "
                     f"served-model object (paddle_tpu.serving.model)")

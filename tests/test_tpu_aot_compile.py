@@ -146,3 +146,40 @@ def test_hybrid_serve_step_compiles_for_v5e_and_moves_no_pool(devices):
               if re.match(rf"\s+(?:ROOT )?%\S+ = {re.escape(pool)}\S* "
                           rf"(copy|transpose|dynamic-update-slice)\(", line)]
     assert not movers, "key/value pool movers:\n" + "\n".join(movers)
+
+
+def test_ssm_serve_step_compiles_for_v5e_and_fits_the_chip(devices):
+    """The parallel-mixer step (a state-space mixer beside grouped-query
+    attention in every block) at the benchmark cell's shapes — published
+    widths, 6 layers, the whole 261,120-id vocabulary, 64 rows, chunks of
+    128, 131,072 cached positions in 256 pages of 512: Mosaic accepts the selective-scan
+    kernel and the grouped-heads attention kernel at a group of 5 (one
+    call each: the layers are one ``lax.scan``), all four state pools are
+    donated and aliased, and the plan fits the chip before any chip time
+    is spent: 13,742,309,376 B of arguments (10.51 GB of weights, 1.61 GB
+    of pages, 1.62 GB of window and scan state) and 128,466,432 B of
+    temporaries, with the ``[64, 261120]`` float32 logits 66,846,720 B
+    more: 13.94 GB of the chip's 17.18."""
+    compiled = tpu_aot.lower_ssm_serve_step(devices).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    pools = 2 * 6 * 256 * 4 * 512 * 128 * 2 + 6 * 64 * 3 * 5120 * 2 \
+        + 6 * 64 * 32 * 128 * 256 * 4
+    assert mem.alias_size_in_bytes == pools == 3233021952
+    assert (mem.argument_size_in_bytes, mem.temp_size_in_bytes) == \
+        (13742309376, 128466432)
+    assert mem.output_size_in_bytes - mem.alias_size_in_bytes < 68e6
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes) < 2 ** 34
+    assert text.count(" conditional(") == 1
+    pool = "bf16[6,256,4,512,128]"
+    movers = [line.strip()[:160] for line in text.splitlines()
+              if re.match(rf"\s+(?:ROOT )?%\S+ = {re.escape(pool)}\S* "
+                          rf"(copy|transpose|dynamic-update-slice)\(", line)]
+    assert not movers, "key/value pool movers:\n" + "\n".join(movers)
+    state = "f32[6,64,32,128,256]"
+    movers = [line.strip()[:160] for line in text.splitlines()
+              if re.match(rf"\s+(?:ROOT )?%\S+ = {re.escape(state)}\S* "
+                          rf"(copy|transpose|dynamic-update-slice|fusion)\(",
+                          line)]
+    assert not movers, "scan state movers:\n" + "\n".join(movers)

@@ -173,19 +173,17 @@ def lower_serve_step(devices, num_pages=1024, max_batch_size=8,
             sharding=rep))
 
 
-def lower_hybrid_serve_step(devices, num_pages=8192, max_batch_size=16,
-                            chunk_len=512, page_size=64,
-                            config="minicpm-sala-8l"):
-    """The unified step of the sparse-plus-lightning decoder at the
-    benchmark cell's knobs, on one device: its four state pools donated."""
-    from paddle_tpu.models.hybrid import HYBRID_CONFIGS, hybrid_init
+def _lower_recurrent_serve_step(devices, cfg, init, num_pages,
+                                max_batch_size, chunk_len, page_size):
+    """The unified step of a served model with per-row state, on one
+    device, every state pool at its real shape and donated."""
     from paddle_tpu.serving import Engine
 
-    cfg = HYBRID_CONFIGS[config]
-    params = jax.eval_shape(lambda: hybrid_init(cfg))
+    params = jax.eval_shape(lambda: init(cfg))
     one = SingleDeviceSharding(devices[0])
     with as_if_on_tpu():
-        # 1 page and 1 row held here; the lowered shapes are the real ones
+        # 1 page held here (and the rows' state, on the host); the lowered
+        # shapes are the real ones
         eng = Engine(cfg, params, page_size=page_size, num_pages=1,
                      max_batch_size=max_batch_size, chunk_len=chunk_len)
         state = [(shape, dtype) for _, shape, dtype, _ in
@@ -197,6 +195,31 @@ def lower_hybrid_serve_step(devices, num_pages=8192, max_batch_size=16,
                 lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
                                                sharding=one), params),
             _on(one, *state), sharding=one))
+
+
+def lower_hybrid_serve_step(devices, num_pages=8192, max_batch_size=16,
+                            chunk_len=512, page_size=64,
+                            config="minicpm-sala-8l"):
+    """The unified step of the sparse-plus-lightning decoder at the
+    benchmark cell's knobs, on one device: its four state pools donated."""
+    from paddle_tpu.models.hybrid import HYBRID_CONFIGS, hybrid_init
+
+    return _lower_recurrent_serve_step(
+        devices, HYBRID_CONFIGS[config], hybrid_init, num_pages,
+        max_batch_size, chunk_len, page_size)
+
+
+def lower_ssm_serve_step(devices, num_pages=256, max_batch_size=64,
+                         chunk_len=128, page_size=512,
+                         config="falcon-h1-34b-6l"):
+    """The unified step of the parallel-mixer decoder (a state-space mixer
+    beside grouped-query attention in every block) at the benchmark cell's
+    knobs, on one device: its four state pools donated."""
+    from paddle_tpu.models.ssm import SSM_CONFIGS, ssm_init
+
+    return _lower_recurrent_serve_step(
+        devices, SSM_CONFIGS[config], ssm_init, num_pages, max_batch_size,
+        chunk_len, page_size)
 
 
 def _report(name, compile_fn):
@@ -227,6 +250,8 @@ def main(argv):
                                            max_batch_size=16).compile())
     ok &= _report("serve hybrid 8l B16 chunk512 8192 pages of 64",
                   lambda: lower_hybrid_serve_step(devices).compile())
+    ok &= _report("serve ssm 6l B64 chunk128 256 pages of 512",
+                  lambda: lower_ssm_serve_step(devices).compile())
     if "--four" in argv:
         ok &= _report("train pp=2 x mp=2",
                       lambda: lower_train_step(devices, pp=2, mp=2).compile())
