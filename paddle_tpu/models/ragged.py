@@ -9,8 +9,11 @@ step a :class:`RaggedBatch`; the step (``gpt_ragged_step``,
 ``hybrid_ragged_step``) reads it through a :class:`RaggedView`.  What the
 six arrays mean, how padding is marked and how a packed token finds its
 row, its position and its place in the page pool is written here and
-nowhere else: a change of the format (one packed transfer, sampled ids
-coming back) is an edit to this file and to ``Engine._pack``.
+nowhere else: a change of the format (one packed transfer) is an edit to
+this file and to ``Engine._pack``.  The one entry a model's step never sees
+as the scheduler wrote it is a token the host does not hold yet
+(:func:`pending_token`): the engine's jitted wrapper puts the id in its
+place (:func:`resolve_pending`) before it calls the step.
 
 Nothing here imports ``paddle_tpu.serving``: this is the lowest layer the
 model steps and, through ``serving/model.py``, the engine both import.
@@ -23,7 +26,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["RaggedBatch", "RaggedView", "empty_batch", "batch_shapes"]
+__all__ = ["RaggedBatch", "RaggedView", "empty_batch", "batch_shapes",
+           "pending_token", "resolve_pending"]
 
 
 class RaggedBatch(NamedTuple):
@@ -43,6 +47,15 @@ class RaggedBatch(NamedTuple):
     [B, max_pages] maps a row's logical page (position // page size) to
     its physical page in the pools; entries past the row's context are
     never read.
+
+    A token that is still on the device.  The scheduler dispatches a step
+    while the one before it runs, so a decode row's newest token may be
+    one the host has not read: in its place ``tokens`` holds
+    ``pending_token(b) = -(b + 1)``, "the id the step before this one
+    chose for batch slot ``b``".  Ids are never negative, so a negative
+    entry is always that marker.  ``resolve_pending`` replaces it from the
+    previous step's ``ids [B]`` inside the engine's jitted program, before
+    the model's step runs: a model's step sees ids only.
     """
     tokens: object
     rows: object
@@ -50,6 +63,22 @@ class RaggedBatch(NamedTuple):
     query_lens: object
     context_lens: object
     page_tables: object
+
+
+def pending_token(slot):
+    """What ``tokens`` holds for a token the previous step chose for batch
+    slot ``slot`` and the host has not read."""
+    return -(slot + 1)
+
+
+def resolve_pending(batch: RaggedBatch, prev_ids):
+    """``batch`` with every :func:`pending_token` replaced by the id it
+    names in ``prev_ids [B]``, the ids the previous step chose by batch
+    slot.  Traced, inside the engine's jitted step."""
+    tokens = batch.tokens
+    slot = jnp.clip(-tokens - 1, 0, prev_ids.shape[0] - 1)
+    return batch._replace(
+        tokens=jnp.where(tokens < 0, jnp.take(prev_ids, slot), tokens))
 
 
 def _shapes(B, T, max_pages):

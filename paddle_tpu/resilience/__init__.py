@@ -48,6 +48,7 @@ from .faults import (  # noqa: F401
     FaultSpec,
     SimulatedCrash,
     current_injector,
+    fault_armed,
     fault_point,
     injected_faults,
     install,
@@ -66,6 +67,7 @@ __all__ = [
     "CheckpointManager", "CheckpointAuditError", "verify_checkpoint",
     "IntegrityCallback", "tree_fingerprint", "compare_digests",
     "FaultInjector", "FaultSpec", "SimulatedCrash", "fault_point",
+    "fault_armed",
     "install", "uninstall", "current_injector", "injected_faults",
     "install_from_env",
     "Deadline", "RetryError", "backoff_delays", "retry",

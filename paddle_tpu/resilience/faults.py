@@ -85,7 +85,7 @@ import os
 import time
 
 __all__ = ["SimulatedCrash", "PoisonRequestError", "FAULT_KINDS",
-           "FaultSpec", "FaultInjector", "fault_point",
+           "FaultSpec", "FaultInjector", "fault_point", "fault_armed",
            "install", "uninstall", "current_injector", "injected_faults",
            "install_from_env"]
 
@@ -354,6 +354,14 @@ def fault_point(site, path=None, tree=None, span=None, tokens=None):
     if _injector is not None:
         _injector.on_fault_point(site, path=path, tree=tree, span=span,
                                  tokens=tokens)
+
+
+def fault_armed(site):
+    """Is an injector installed that holds a spec for ``site``?  What a
+    caller asks before a fault site that has to see settled state (the
+    serving engine commits its step in flight before ``serving.step``)."""
+    return _injector is not None and any(
+        spec.site == site for spec in _injector.specs)
 
 
 def install_from_env(var="PADDLE_TPU_FAULTS"):

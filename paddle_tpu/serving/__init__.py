@@ -27,6 +27,14 @@ is sampled by the step in which the LAST chunk completes — that is the
 TTFT event (``serving_ttft_seconds``), and each chunk increments
 ``serving_prefill_chunks_total``.
 
+One step is kept in flight: ``Engine.step()`` dispatches a program while
+the one before it runs and only then reads and commits that one, so the
+host's scheduling hides behind the device.  A token is visible one
+``step()`` call after the call that dispatched its program, a finish is
+seen one call late (a stop token costs its row one dropped step more; a
+length finish none), and ``has_work()`` is true until the last step is
+committed (:mod:`engine`, "One step in flight").
+
 Admission semantics: any prompt with prompt + max_new_tokens ≤
 cfg.max_seq_len (and a page count the pool could ever hold) is
 admissible — there is no prompt-length ceiling below that.  Pages are

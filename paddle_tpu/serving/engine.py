@@ -22,8 +22,32 @@ Per ``step()``:
      admitted short prompt is not starved behind a long one).  Chunk
      K/V is written into the paged pool incrementally; the row whose
      chunk completes its prompt samples the first token (TTFT), decode
-     rows sample their next token.
-  4. gauges — page-pool occupancy into the metrics registry.
+     rows sample their next token.  The program is dispatched and left
+     running.
+  4. settle the step before — wait for the program the *previous* call
+     dispatched, read its ids and commit them (tokens, spans, finishes).
+  5. gauges — page-pool occupancy into the metrics registry.
+
+One step in flight.  ``step()`` call k plans, packs and dispatches
+program k while program k-1 runs on the device, and only then waits for
+k-1: the host's phases hide behind the chip.  The scheduler therefore
+plans from what it has *scheduled* (``Request.prompt_pos`` and
+``Request._pending`` advance at dispatch), not from what is committed, and
+a decode row whose newest token is still on the device is packed with a
+marker the program resolves from the previous step's ids
+(``models/ragged.py``).  A finish the host can foresee (``max_new_tokens``,
+``max_seq_len``) costs nothing: the row is not scheduled past its last
+token.  One it cannot (a stop token, seen at commit when the row already
+rides in the next program) is over-run by exactly one step, whose result
+for that row is dropped; the position it wrote lies in pages freed with
+the request, and the device runs programs in order, so a later tenant's
+writes land after it.  A token is visible one ``step()`` call after the
+call that dispatched its program, and ``has_work()`` stays true until the
+last one is committed.  What has to see committed state settles the step
+in flight first (``_drain``): a preemption for memory, ``evacuate()``, an
+armed ``serving.step`` fault site.  With nothing in flight (the first
+step, the step after a drain) the same code runs in the plain order; depth
+is one and there is no switch.
 
 Admission control: requests that can NEVER fit (prompt + max_new_tokens
 over the model's max_seq_len, or more pages than the whole pool) are
@@ -96,12 +120,18 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..models.ragged import RaggedBatch, batch_shapes, empty_batch
+from ..models.ragged import (
+    RaggedBatch,
+    batch_shapes,
+    empty_batch,
+    pending_token,
+    resolve_pending,
+)
 from ..observability.compile_watchdog import watch
 from ..observability.profiling import pop_phase, push_phase
 from ..observability.tracing import Tracer, default_tracer
 from ..profiler.profiler import RecordEvent
-from ..resilience.faults import fault_point
+from ..resilience.faults import fault_armed, fault_point
 from .kv_cache import PagedKVCache
 from .metrics import STEP_PHASES, ServingMetrics
 from .model import as_served
@@ -160,7 +190,10 @@ class Request:
     t_finished: float = None
     deadline: float = None     # absolute engine-clock time, None = no TTL
     retry_after_s: float = None  # drain-estimate hint on RETRY_AFTER
-    prompt_pos: int = 0        # prompt tokens already written to pages
+    prompt_pos: int = 0        # prompt tokens scheduled into pages (by a
+    #                            dispatched step, committed or in flight)
+    _pending: int = 0          # tokens a dispatched step chooses for this
+    #                            request that are not in ``tokens`` yet
     _chunks_done: int = 0      # prefill chunks completed (span index)
     _span: object = None       # root trace span (one per request)
     _phase: object = None      # current lifecycle child span
@@ -176,8 +209,19 @@ class Request:
         request's final output is identical to its uninterrupted one."""
         self.tokens = list(self.prompt)
         self.prompt_pos = 0
+        self._pending = 0
         self._chunks_done = 0
         self.state = RequestState.QUEUED
+
+
+@dataclasses.dataclass
+class _InFlight:
+    """A dispatched step program whose ids the host has not read."""
+    sched: list                # (slot, req, q, new ctx) of every packed row
+    ids: object                # [B] int32 on the device, on its way to the host
+    logits: object             # [B, V] on the device
+    t0: float                  # engine clock at dispatch
+    kind: str                  # "prefill_chunk" | "decode": the sampler's tag
 
 
 class _StepPhases:
@@ -191,9 +235,12 @@ class _StepPhases:
     — with ``tag`` — the ``StackSampler``'s phase marker.  Time is
     ``RecordEvent``'s (``time.perf_counter_ns``), never the engine's
     injectable clock.  Phases follow one another and never nest, so the
-    object is its own context manager.  ``close()`` observes every
-    phase exactly once, 0 for one the call did not reach, so the n-th
-    sample of every phase is the n-th call's.
+    object is its own context manager; a phase entered twice in a call
+    (a drain before the call's own wait) adds up.  ``close()`` observes
+    every phase exactly once, 0 for one the call did not reach, so the
+    n-th sample of every phase is the n-th call's.  ``pack`` and
+    ``dispatch`` are of the program the call sends, ``device_wait``,
+    ``fetch``, ``sample`` and ``commit`` of the one sent before it.
     """
 
     _NAMES = {p: f"serving::step/{p}" for p in STEP_PHASES}
@@ -299,6 +346,7 @@ class Engine:
         self.tracer = tracer
         self._decode_rate_ewma = None     # tok/s, None until first decode
         self._ewma_alpha = 0.25
+        self._t_settled = float("-inf")   # clock when a step's ids were last read
         self.default_ttl_s = default_ttl_s
         self.drain_floor_s = (self.DRAIN_FLOOR_S if drain_floor_s is None
                               else float(drain_floor_s))
@@ -342,6 +390,7 @@ class Engine:
         self._queue = deque()
         self._slots = [None] * max_batch_size
         self._just_finished = []
+        self._inflight = None               # the dispatched, unread step
         self._admit_seq = 0                 # admission order, for preemption
         self._next_id = 0
         # donation chains every state pool through steps; XLA:CPU can't
@@ -356,15 +405,20 @@ class Engine:
         # put a tenant with another entry into a slot
         self._slot_sampling = [GREEDY] * max_batch_size
         self._sampling_table = None
-        #: the last step's ``logits [B, V]`` as the program left them on
-        #: the device: for a test or a debugging caller; ``step()`` never
-        #: reads them
+        #: the ``logits [B, V]`` of the step whose ids were read last, as
+        #: the program left them on the device: while ``_sample_token``
+        #: runs for a row, the logits that row's id was chosen from.  For
+        #: a test or a debugging caller; ``step()`` never reads them
         self.step_logits = None
 
         def _step(params, *args):
             # (params, every state pool, the ragged batch, the sampling
-            # table): the pools flat, so that they are donated one by one
-            *state, batch, sampling = args
+            # table, the ids the step before chose): the pools flat, so
+            # that they are donated one by one.  A token the host has not
+            # seen is named in the batch and filled in here: the model's
+            # step sees ids only
+            *state, batch, sampling, prev_ids = args
+            batch = resolve_pending(batch, prev_ids)
             logits, state = model_step(params, tuple(state), batch)
             ids = sample_tokens(logits, sampling, batch.query_lens,
                                 batch.context_lens)
@@ -378,8 +432,9 @@ class Engine:
         # cross-shard traffic is the per-layer psum GSPMD inserts at the
         # residual write plus ONE logits gather per step (the rows are
         # sampled from the gathered logits; out_shardings pins the ids and
-        # the logits replicated; pages stay sharded end-to-end, never
-        # gathered).
+        # the logits replicated, and the ids come back in as the next
+        # step's ``prev_ids`` the same way; pages stay sharded
+        # end-to-end, never gathered).
         self.mesh = mesh
         self._page_sharding = self._replicated = None
         jit_kw = {"donate_argnums": donate}
@@ -396,7 +451,7 @@ class Engine:
             self._page_sharding = psh
             self._replicated = rep = NamedSharding(mesh, P())
             jit_kw.update(
-                in_shardings=(p_sh,) + (psh,) * n_state + (rep, rep),
+                in_shardings=(p_sh,) + (psh,) * n_state + (rep,) * 3,
                 out_shardings=(rep, rep) + (psh,) * n_state)
         # watchdog-wrapped: the ONE statically-shaped program — prompt
         # chunks and decode rows share it — must compile exactly once;
@@ -404,6 +459,11 @@ class Engine:
         # the offending shape diff
         self._step_fn = watch(jax.jit(_step, **jit_kw),
                               name="serving::unified_step")
+        # the ids the newest dispatched step chose, still on the device:
+        # the next step's last operand
+        ids = np.zeros((max_batch_size,), np.int32)
+        self._prev_ids = (jnp.asarray(ids) if self._replicated is None
+                          else jax.device_put(ids, self._replicated))
 
     # ------------------------------------------------------------- submit
     def add_request(self, prompt, sampling: SamplingParams = None, *,
@@ -677,9 +737,20 @@ class Engine:
                 req._span.attributes.get("preemptions", 0) + 1
             req._phase = self.tracer.start_span("queued", req._span)
 
+    def _last_token_pending(self, req):
+        """Is the token in flight for ``req`` the one it ends on, by a
+        count the host holds (``max_new_tokens``, ``max_seq_len``)?  Such
+        a row is not scheduled again: the finish costs no step.  A stop
+        token cannot be foreseen and is over-run by one step."""
+        return req._pending > 0 and (
+            len(req.output) + req._pending >= req.sampling.max_new_tokens
+            or len(req.tokens) + req._pending >= self.cfg.max_seq_len)
+
     def _plan_rows(self):
-        """{batch slot: query tokens this step} under token_budget.
-        Decode rows always get their one token; mid-prefill rows then
+        """{batch slot: query tokens this step} under token_budget, from
+        what has been scheduled so far (committed or in flight).
+        Decode rows always get their one token, but for a row whose last
+        token is already in flight; mid-prefill rows then
         split the remaining budget fairly (ceil-share, admission order)
         so a short prompt admitted behind a long one still makes
         progress toward its TTFT instead of starving."""
@@ -689,11 +760,11 @@ class Engine:
         for i, req in enumerate(self._slots):
             if req is None:
                 continue
-            if req.prompt_pos >= len(req.prompt):
+            if req.prompt_pos < len(req.prompt):
+                chunkers.append(i)
+            elif not self._last_token_pending(req):
                 plan[i] = 1
                 budget -= 1
-            else:
-                chunkers.append(i)
         chunkers.sort(key=lambda i: self._slots[i]._admit_seq)
         for n, i in enumerate(chunkers):
             if budget <= 0:
@@ -710,8 +781,12 @@ class Engine:
     def _ensure_capacity(self):
         """Pages for every planned row's post-step context — the chunk a
         mid-prefill row is about to write, or the token decode is about
-        to append; preempt youngest-first (mid-prefill rows included)
-        when the pool runs dry.  Returns the final, feasible plan."""
+        to append (the one in flight counted in); preempt youngest-first
+        (mid-prefill rows included) when the pool runs dry.  Returns the
+        final, feasible plan — or None, having preempted nothing, when
+        the pool runs dry while a step is in flight: the caller settles
+        that step first (what it finishes frees pages, and a preemption
+        rewinds committed state) and asks again."""
         while True:
             plan = self._plan_rows()
             stable = True
@@ -723,9 +798,11 @@ class Engine:
                 if req.prompt_pos < len(req.prompt):
                     target = req.prompt_pos + plan[i]
                 else:
-                    target = len(req.tokens)
+                    target = len(req.tokens) + req._pending
                 while req in self._slots and \
                         not self.cache.extend(req.id, target):
+                    if self._inflight is not None:
+                        return None
                     victim = max(self._running(),
                                  key=lambda r: r._admit_seq)
                     self._preempt(victim)
@@ -738,65 +815,101 @@ class Engine:
     def step_args(self, params=None, state=None, *, sharding=None):
         """The jitted step's arguments, for ``_step_fn.lower(*...)``:
         the parameters and state pools (the engine's own, or the given
-        abstract ones), then the ragged batch and the sampling table as
-        ``ShapeDtypeStruct``s on ``sharding``.  The one place that knows
-        the step's signature besides ``__init__`` and the dispatch."""
+        abstract ones), then the ragged batch, the sampling table and the
+        previous step's ids as ``ShapeDtypeStruct``s on ``sharding``.
+        The one place that knows the step's signature besides
+        ``__init__`` and the dispatch."""
+        B = self.max_batch_size
         return (self.params if params is None else params,
                 *(self.cache.state_arrays() if state is None else state),
                 batch_shapes(*self.batch_dims, sharding=sharding),
-                jax.ShapeDtypeStruct((self.max_batch_size, len(GREEDY)),
-                                     jnp.uint32, sharding=sharding))
+                jax.ShapeDtypeStruct((B, len(GREEDY)), jnp.uint32,
+                                     sharding=sharding),
+                jax.ShapeDtypeStruct((B,), jnp.int32, sharding=sharding))
 
-    def _unified_step_once(self, plan, phases):
-        """Run the one jitted program over the planned ragged batch — it
-        ends by choosing a token for every row — and hand every row that
-        is owed one its id: the phases pack, dispatch, device_wait, fetch
-        (the ``[B]`` ids) and sample (the per-row hook) of the call's
-        ``_StepPhases``.  Returns ``_commit``'s arguments, or None when
-        nothing ran."""
-        if not plan:
-            return None
-        with phases.phase("pack"):
-            packed = self._pack(plan)
-        if packed is None:
-            return None
-        batch, sched = packed
+    def _dispatch(self, batch, sched, phases):
+        """Send the packed batch to the one jitted program — it ends by
+        choosing a token for every row — and leave it running: the
+        ``dispatch`` phase.  What the scheduler plans from advances here,
+        not at commit: the pools (futures, chained through programs by
+        donation), the ids the next program resolves its pending tokens
+        from, every row's ``prompt_pos`` and ``_pending``, and a completed
+        prompt's full pages in the radix tree (any program that reads
+        them is ordered after this one).  Returns the ``_InFlight``."""
         # phase attribution for the sampling profiler: a step with any
         # mid-prefill row is a prefill chunk, else pure decode
-        step_phase = "prefill_chunk" if any(
-            req.prompt_pos < len(req.prompt)
-            for _, req, _, _ in sched) else "decode"
+        kind = "prefill_chunk" if any(
+            ctx - q < len(req.prompt) for _, req, q, ctx in sched) \
+            else "decode"
+        ahead = self._inflight is not None
         t0 = self._clock()
-        with RecordEvent("serving::unified_step"):
-            with phases.phase("dispatch", step_phase):
-                if self._sampling_table is None:
-                    table = np.array(self._slot_sampling, np.uint32)
-                    self._sampling_table = (
-                        jnp.asarray(table) if self._replicated is None
-                        else jax.device_put(table, self._replicated))
-                ids, self.step_logits, *state = self._step_fn(
-                    self.params, *self.cache.state_arrays(),
-                    RaggedBatch(*(jnp.asarray(a) for a in batch)),
-                    self._sampling_table)
-                # the 64 bytes follow the program to the host on their
-                # own: a read begun only after the wait costs one more
-                # round trip to the device (0.45 ms on a v5e, PERF.md)
-                ids.copy_to_host_async()
-            with phases.phase("device_wait", step_phase):
-                ids.block_until_ready()
-            with phases.phase("fetch", step_phase):
-                ids = np.asarray(ids).tolist()
+        with phases.phase("dispatch", kind):
+            if self._sampling_table is None:
+                table = np.array(self._slot_sampling, np.uint32)
+                self._sampling_table = (
+                    jnp.asarray(table) if self._replicated is None
+                    else jax.device_put(table, self._replicated))
+            ids, logits, *state = self._step_fn(
+                self.params, *self.cache.state_arrays(),
+                RaggedBatch(*(jnp.asarray(a) for a in batch)),
+                self._sampling_table, self._prev_ids)
+            # the 64 bytes follow the program to the host on their
+            # own: a read begun only after the wait costs one more
+            # round trip to the device (0.45 ms on a v5e, PERF.md)
+            ids.copy_to_host_async()
         self.cache.set_state(state)
-        t1 = self._clock()
+        self._prev_ids = ids
+        (self.metrics.steps_ahead if ahead
+         else self.metrics.steps_not_ahead).inc()
+        for _, req, q, ctx in sched:
+            n = len(req.prompt)
+            if ctx - q < n:
+                req.prompt_pos = ctx
+                # prompt complete: its FULL pages are reusable K/V once
+                # this program has run — register them in the radix tree
+                # so the next request sharing this prefix skips the
+                # prefill FLOPs (the partial final page keeps taking
+                # decode writes and is never shared)
+                if ctx >= n and self.prefix_cache:
+                    self.cache.insert_prefix(req.id, req.prompt)
+            if ctx >= n:
+                req._pending += 1            # the program owes it a token
+        return _InFlight(sched, ids, logits, t0, kind)
+
+    def _collect(self, step, phases):
+        """Wait for a dispatched program and read its ``[B]`` ids: the
+        ``device_wait`` and ``fetch`` phases.  Returns (ids, the engine
+        clock once they are read)."""
+        with phases.phase("device_wait", step.kind):
+            step.ids.block_until_ready()
+        with phases.phase("fetch", step.kind):
+            ids = np.asarray(step.ids).tolist()
+        return ids, self._clock()
+
+    def _drain(self, reason, phases=None):
+        """Settle the step in flight now — wait, read, sample, commit —
+        because what comes next has to see committed state.  A no-op
+        with nothing in flight.  Outside a ``step()`` call the phases'
+        events are recorded and no sample of the series is."""
+        step, self._inflight = self._inflight, None
+        if step is None:
+            return
+        if phases is None:
+            phases = _StepPhases(self.metrics.step_phases)   # never closed
+        self.metrics.pipeline_drains.labels(reason=reason).inc()
+        ids, t1 = self._collect(step, phases)
         with phases.phase("sample"):
-            sampled = self._sample_rows(ids, sched)
-        return sched, sampled, t0, t1
+            sampled = self._sample_rows(ids, step)
+        with phases.phase("commit"):
+            self._commit(step, sampled, t1)
 
     def _pack(self, plan):
         """The planned rows packed row-major into the step's host
         ``RaggedBatch`` (``models/ragged.py`` has the contract):
         ``(batch, sched)`` with ``sched`` the ``(slot, req, q, new ctx)``
-        of every packed row, or None when no row is left to run."""
+        of every packed row, or None when no row is left to run.  A
+        decode row sends its newest token, or, while that token is still
+        on the device, the marker that names it."""
         batch = empty_batch(*self.batch_dims)
         tokens, rows, slots, qlens, ctxs, tables = batch
         sched = []                               # (slot, req, q, new ctx)
@@ -811,8 +924,9 @@ class Engine:
                     chunk = req.prompt[req.prompt_pos:req.prompt_pos + q]
                     ctx = req.prompt_pos + q
                 else:
-                    chunk = req.tokens[-1:]
-                    ctx = len(req.tokens)
+                    chunk = (pending_token(i) if req._pending
+                             else req.tokens[-1])
+                    ctx = len(req.tokens) + req._pending
                 table = self.cache.page_table(req.id)
             except Exception as e:
                 # row-attributable plan failure: THIS row dies, the
@@ -830,17 +944,25 @@ class Engine:
             return None
         return batch, sched
 
-    def _sample_rows(self, ids, sched):
-        """{batch slot: next token} for every row whose context now
-        covers its prompt (a decode row, or the chunk that completed a
-        prompt), from the ids the device chose.  A row whose hook raises
-        is retired FAILED here and has no entry, like any other
+    def _sample_rows(self, ids, step):
+        """{batch slot: next token} for every row of ``step`` whose
+        context now covers its prompt (a decode row, or the chunk that
+        completed a prompt), from the ids the device chose.  A row whose
+        request is no longer running (it ended while this step was in
+        flight: a stop token, a deadline, a failure) has no entry: the
+        step over-ran it, and the result is dropped.  A row whose hook
+        raises is retired FAILED here and has no entry, like any other
         row-attributable failure.  Counts the step under the path the
         program took: the ``cond``'s predicate, from what the plan holds
         (``sampling.any_stochastic``)."""
+        self.step_logits = step.logits
         sampled = {}
         stochastic = False
-        for i, req, _, ctx in sched:
+        overrun = 0
+        for i, req, _, ctx in step.sched:
+            if req.state != RequestState.RUNNING:
+                overrun += 1
+                continue
             if ctx < len(req.prompt):
                 continue                     # more chunks to go
             stochastic = stochastic or req.sampling.temperature > 0.0
@@ -848,14 +970,23 @@ class Engine:
                 sampled[i] = self._sample_token(ids[i], req)
             except Exception as e:
                 self._fail(req, e)
+        if overrun:
+            self.metrics.overrun_rows.inc(overrun)
         (self.metrics.sample_steps_stochastic if stochastic
          else self.metrics.sample_steps_greedy).inc()
         return sampled
 
-    def _commit(self, sched, sampled, t0, t1):
+    def _commit(self, step, sampled, t1):
         """Fold one step's results into each request's lifecycle:
-        counters, flight-recorder spans, the sampled token, finish."""
-        dt = t1 - t0
+        counters, flight-recorder spans, the sampled token, finish.
+        ``t1`` is when the step's ids were read; the spans run from the
+        program's own dispatch to it, and the decode rate is tokens over
+        the time since the step before was read (or since this one's
+        dispatch, when nothing was in flight before it): with a step
+        always in flight, dispatch to read spans two programs."""
+        sched, t0 = step.sched, step.t0
+        dt = t1 - max(t0, self._t_settled)
+        self._t_settled = t1
         occ = round(self.cache.occupancy(), 4)
         n_rows = len(sched)
         committed = 0
@@ -867,21 +998,20 @@ class Engine:
             a, b = self.model.attention_positions(ctx, q)
             in_context, read = in_context + a, read + b
             resets += self.model.recurrent and ctx == q
+            mid_prefill = ctx - q < len(req.prompt)
             if self.model.recurrent:
                 # the step advanced this row's state by a chunk or a token
-                if req.prompt_pos < len(req.prompt):
+                if mid_prefill:
                     chunk_rows += 1
                 else:
                     decode_rows += 1
             if req.state != RequestState.RUNNING:
-                continue                     # failed while sampling
+                continue             # over-run, or failed while sampling
             # per-row commit isolation: anything this row's
             # bookkeeping raises is ITS failure — the row retires
             # FAILED, every other row in the batch commits normally
             try:
-                mid_prefill = req.prompt_pos < len(req.prompt)
                 if mid_prefill:
-                    req.prompt_pos = ctx
                     self.metrics.prefill_tokens.inc(q)
                     self.metrics.prefill_chunks.inc()
                     if req._span is not None:
@@ -895,15 +1025,9 @@ class Engine:
                     req._chunks_done += 1
                     if ctx < len(req.prompt):
                         continue             # more chunks to go
-                    # prompt complete: its FULL pages are now reusable
-                    # K/V — register them in the radix tree so the next
-                    # request sharing this prefix skips the prefill
-                    # FLOPs (the partial final page keeps taking decode
-                    # writes and is never shared)
-                    if self.prefix_cache:
-                        self.cache.insert_prefix(req.id, req.prompt)
                     # the chunk that completed the prompt falls through
                     # and commits the request's first token — TTFT
+                req._pending -= 1
                 req.tokens.append(sampled[i])
                 committed += 1
                 self.metrics.tokens_generated.inc()
@@ -949,8 +1073,15 @@ class Engine:
     # ------------------------------------------------------------ sampling
     def _sample_token(self, token_id, req):
         """The per-row seam of the ``sample`` phase: receives the id the
-        device chose for ``req``'s row and returns the token to commit.
-        Raising here retires that row FAILED and no other."""
+        device chose for ``req``'s row and returns the token that is
+        committed to ``req.tokens``; ``self.step_logits`` is then the
+        logits that id was chosen from.  Raising here retires that row
+        FAILED and no other (its result in the step already in flight is
+        dropped).  The hook runs one step behind the device: the row's
+        next step was dispatched before this call and continues from the
+        id the device chose, whatever is returned here.  What a request
+        continues from is the sampling table's business, on the device
+        (``serving/sampling.py``); this hook sees and records."""
         return token_id
 
     # ------------------------------------------------------------- finish
@@ -977,17 +1108,32 @@ class Engine:
 
     # --------------------------------------------------------------- drive
     def has_work(self):
-        return bool(self._queue) or any(r is not None for r in self._slots)
+        """Anything queued, running, or dispatched and not yet committed:
+        ``while eng.has_work(): eng.step()`` ends with every token
+        committed."""
+        return (bool(self._queue) or self._inflight is not None
+                or any(r is not None for r in self._slots))
 
     def step(self):
         """One scheduler iteration: evict past-deadline requests, admit,
-        run the unified ragged step (prompt chunks + decode rows in one
-        batch), update gauges.  Returns requests that finished (or were
-        evicted) this step.  The call is cut into ``STEP_PHASES``
-        (``_StepPhases``): one ``serving::step`` event around one
-        ``serving::step/<phase>`` event per phase, and one observation
-        of ``serving_step_phase_seconds`` per phase, also when it
-        raises."""
+        plan and dispatch the unified ragged step (prompt chunks + decode
+        rows in one batch) — and then, while that program runs, wait for
+        the one the call before dispatched, read its ids and commit them;
+        update gauges.  Returns the requests whose finish (or eviction)
+        this call committed.
+
+        A program's tokens are therefore visible one call after the call
+        that dispatched it (a call with nothing left to plan only commits
+        the step in flight), and a request's finish is seen one call
+        late: by then a row that ended on a stop token has ridden one
+        more program, whose result for it is dropped
+        (``serving_overrun_rows_total``); a row that ends by a count the
+        host holds is never scheduled past its last token.
+
+        The call is cut into ``STEP_PHASES`` (``_StepPhases``): one
+        ``serving::step`` event around one ``serving::step/<phase>`` event
+        per phase reached, and one observation of
+        ``serving_step_phase_seconds`` per phase, also when it raises."""
         phases = _StepPhases(self.metrics.step_phases)
         with RecordEvent("serving::step"):
             try:
@@ -996,6 +1142,12 @@ class Engine:
                 phases.close()
 
     def _step(self, phases):
+        if self._inflight is not None and fault_armed("serving.step"):
+            # the fault site below stands for the whole replica failing
+            # "before any request state mutates", reads every request's
+            # committed stream and may rewrite the pools: it sees settled
+            # state, as it did when the loop was synchronous
+            self._drain("fault_injection", phases)
         with phases.phase("admit", "admission"):
             # fault site: an io_error here is the whole step failing the
             # way a crashed replica's RPC would — before any request
@@ -1014,7 +1166,30 @@ class Engine:
             self._try_admit()
         with phases.phase("plan", "admission"):
             plan = self._ensure_capacity()
-        ran = self._unified_step_once(plan, phases)
+        if plan is None:
+            # the pool cannot cover the plan while a step is in flight:
+            # settle it, then plan (and, if it must be, preempt) on
+            # committed state
+            self._drain("memory", phases)
+            with phases.phase("plan", "admission"):
+                plan = self._ensure_capacity()
+        packed = None
+        if plan:
+            with phases.phase("pack"):
+                packed = self._pack(plan)
+        before, ran = self._inflight, None
+        if packed is not None or before is not None:
+            with RecordEvent("serving::unified_step"):
+                # program k goes out while k-1 runs; only then is k-1
+                # waited for.  With nothing planned the call just settles
+                # the step in flight
+                self._inflight = (self._dispatch(*packed, phases)
+                                  if packed is not None else None)
+                if before is not None:
+                    ids, t1 = self._collect(before, phases)
+            if before is not None:
+                with phases.phase("sample"):
+                    ran = before, self._sample_rows(ids, before), t1
         with phases.phase("commit"):
             if ran is not None:
                 self._commit(*ran)
@@ -1060,7 +1235,16 @@ class Engine:
         (prompt + already-sampled tokens), so this engine's paged KV
         state is never trusted again.  Each request leaves in state
         ``EVACUATED`` with its trace closed; partial output is
-        preserved — nothing is re-sampled here, nothing is lost."""
+        preserved — nothing is re-sampled here, nothing is lost.  A
+        step in flight is settled first, so its tokens leave with their
+        requests (a request it finishes is finished, not evacuated); if
+        the device cannot be waited for any more, that step is dropped
+        and its tokens are drawn again wherever the requests land — a
+        draw is keyed by (seed, position)."""
+        try:
+            self._drain("evacuate")
+        except Exception:
+            pass    # silent-ok: a dead device; what was committed leaves
         now = self._clock()
         running = sorted(self._running(), key=lambda r: r._admit_seq)
         for req in running:
@@ -1080,7 +1264,9 @@ class Engine:
     def health(self):
         """Live scheduler health — the ``/healthz`` payload: shedding
         flag, queue depth, in-flight batch, pool occupancy, and the
-        drain estimate a cooperating front-end should back off by."""
+        drain estimate a cooperating front-end should back off by.
+        Host state only, and never a wait: the tokens of a step in
+        flight count as still owed."""
         return {"healthy": not self._shedding,
                 "queue_depth": len(self._queue),
                 "running": len(self._running()),

@@ -23,7 +23,13 @@ process-wide registry); this module keeps the serving-shaped facade:
                  ``Engine.step()`` call cut into ``STEP_PHASES``, one
                  observation per phase per call (0 for a phase the call
                  did not reach), so the n-th sample of every phase
-                 belongs to the n-th call
+                 belongs to the n-th call.  The engine keeps one step in
+                 flight: pack and dispatch are of the program the call
+                 sends, device_wait to commit of the one before it
+  steps_dispatched / pipeline_drains / overrun_rows — how often the
+                 step in flight hid the host's phases, how often it had
+                 to be settled first and why, and the rows computed for
+                 a request that had already ended
 
 Every metric is registered (serving_-prefixed) into the default
 MetricsRegistry with replace semantics, so rebuilding ``ServingMetrics``
@@ -155,6 +161,26 @@ class ServingMetrics:
         self.sample_steps_greedy = self.sample_steps.labels(path="greedy")
         self.sample_steps_stochastic = self.sample_steps.labels(
             path="stochastic")
+        self.steps_dispatched = add(Counter(
+            "serving_steps_dispatched_total", labelnames=("ahead",),
+            help="step programs dispatched, by whether the step before "
+                 "was still in flight (ahead=yes: the host's phases of "
+                 "this step ran behind the device) or not (ahead=no: the "
+                 "first step, and the one after a drain)"))
+        self.steps_ahead = self.steps_dispatched.labels(ahead="yes")
+        self.steps_not_ahead = self.steps_dispatched.labels(ahead="no")
+        self.pipeline_drains = add(Counter(
+            "serving_pipeline_drains_total", labelnames=("reason",),
+            help="times the step in flight was waited for and committed "
+                 "before the engine went on, because what came next had "
+                 "to see committed state: memory (a planned row's pages "
+                 "could not be had), evacuate, fault_injection"))
+        self.overrun_rows = add(Counter(
+            "serving_overrun_rows_total",
+            help="rows a step computed for a request that was no longer "
+                 "running when the step's ids were read (a stop token, a "
+                 "deadline or a failure seen one step late); the result "
+                 "is dropped"))
         self.state_resets = add(Counter(
             "serving_state_resets_total",
             help="rows whose recurrent state the step zeroed: an "

@@ -305,9 +305,11 @@ def test_greedy_rows_beside_a_stochastic_row_are_the_argmax(tiny_model,
     assert outs[1] != oracle(b, 10)
     m = eng.metrics
     assert m.sample_steps_stochastic.value > 0
-    # every step is counted once, under one path
+    # every step program is counted once, under one path (and there is
+    # one call more than programs: the last only commits)
+    programs = m.steps_ahead.value + m.steps_not_ahead.value
     assert (m.sample_steps_greedy.value + m.sample_steps_stochastic.value
-            == m.step_phases["sample"].total)
+            == programs == m.step_phases["sample"].total - 1)
 
 
 def test_step_reads_ids_and_never_the_logits(tiny_model, oracle):

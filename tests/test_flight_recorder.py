@@ -133,9 +133,15 @@ class TestEngineRequestTracing:
             assert s["parent_id"] == root["span_id"]
             assert root["start_s"] <= s["start_s"]
             assert s["end_s"] <= root["end_s"]
-        # lifecycle order: queued → chunk[i] → decode[i]
+        # lifecycle order: queued → chunk[i] → decode[i].  A span runs
+        # from its program's dispatch to the read of its ids, and the
+        # next program is dispatched while this one runs: consecutive
+        # spans follow one another by their starts and by their ends
         assert spans["queued"]["end_s"] <= spans["chunk[0]"]["start_s"]
-        assert spans["chunk[0]"]["end_s"] <= spans["decode[1]"]["start_s"]
+        for a, b in (("chunk[0]", "decode[1]"), ("decode[1]", "decode[2]")):
+            assert spans[a]["start_s"] < spans[b]["start_s"]
+            assert spans[b]["start_s"] < spans[a]["end_s"] \
+                < spans[b]["end_s"]
         # occupancy rides on the decode spans
         assert spans["decode[1]"]["attributes"]["page_occupancy"] > 0
 
@@ -212,7 +218,9 @@ class TestRetryAfterHint:
                            max_batch_size=1)
         for _ in range(3):
             eng.add_request([1, 2], SamplingParams(max_new_tokens=4))
-        eng.step()                       # prefill + decode → EWMA rate
+        eng.step()                       # prefill, dispatched
+        assert eng.decode_rate() is None     # nothing read yet
+        eng.step()                       # its token committed → EWMA rate
         assert eng.decode_rate() is not None and eng.decode_rate() > 0
         shed = eng.add_request([3, 4], SamplingParams(max_new_tokens=4))
         assert shed.state == RequestState.RETRY_AFTER

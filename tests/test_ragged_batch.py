@@ -11,6 +11,7 @@ import jax.numpy as jnp
 
 from paddle_tpu.models.gpt import GPT_CONFIGS, gpt_init
 from paddle_tpu.models.ragged import (RaggedBatch, RaggedView, batch_shapes,
+                                      pending_token, resolve_pending,
                                       empty_batch)
 from paddle_tpu.serving import Engine, SamplingParams
 
@@ -159,8 +160,12 @@ def test_pack_yields_the_documented_contract(engine):
     # rows in ascending slot order, each row's tokens contiguous and in order
     assert batch.rows.tolist() == [0, 1] + [2] * 6 + [Bm] * (Tm - n)
     assert batch.slots.tolist() == [0, 0, 0, 1, 2, 3, 4, 5] + [0] * (Tm - n)
+    # the two decode rows' newest tokens are still on the device (the one
+    # step so far is in flight): each sends the marker that names its slot
+    assert [r._pending for r in reqs] == [1, 1, 0]
     assert batch.tokens[:n].tolist() == (
-        [reqs[0].tokens[-1], reqs[1].tokens[-1]] + reqs[2].prompt[4:10])
+        [pending_token(0), pending_token(1)] + reqs[2].prompt[4:10])
+    assert [pending_token(0), pending_token(1)] == [-1, -2]
     assert (batch.tokens[n:] == 0).all()
     assert batch.query_lens.tolist() == [1, 1, 6, 0]
     # context_lens counts this step's tokens in: a decode row's whole
@@ -189,12 +194,37 @@ def test_batch_shapes_are_what_pack_produces(engine):
         assert (s.shape, s.dtype) == (a.shape, a.dtype) == (e.shape, e.dtype)
         assert a.dtype == np.int32, name
     # the jitted step takes exactly these six operands, in this order,
-    # and after them the sampling table by batch slot
+    # and after them the sampling table by batch slot and the ids the
+    # step before chose
     lowered = eng._step_fn.lower(*eng.step_args())
     flat = jax.tree_util.tree_leaves(lowered.in_avals)
-    assert [(a.shape, a.dtype) for a in flat[-7:-1]] == [
+    assert [(a.shape, a.dtype) for a in flat[-8:-2]] == [
         (s.shape, s.dtype) for s in shapes]
-    assert (flat[-1].shape, flat[-1].dtype) == ((dims[0], 6), np.uint32)
+    assert (flat[-2].shape, flat[-2].dtype) == ((dims[0], 6), np.uint32)
+    assert (flat[-1].shape, flat[-1].dtype) == ((dims[0],), np.int32)
+
+
+def test_a_pending_token_is_resolved_from_the_previous_ids(engine):
+    """What the engine's jitted wrapper does before the model's step: a
+    negative entry names a batch slot of the previous step's ids; every
+    other entry, padding included, passes through."""
+    eng, reqs = engine
+    batch, _ = eng._pack(eng._plan_rows())
+    prev = np.array([900, 901, 902, 903], np.int32)
+    out = resolve_pending(RaggedBatch(*map(jnp.asarray, batch)),
+                          jnp.asarray(prev))
+    assert out.tokens.tolist() == [900, 901] + batch.tokens[2:].tolist()
+    for name in RaggedBatch._fields[1:]:
+        assert (np.asarray(getattr(out, name))
+                == getattr(batch, name)).all(), name
+    # once the step in flight is committed the host holds the tokens, and
+    # the same rows send the tokens themselves
+    eng._drain("test")
+    assert [r._pending for r in reqs] == [0, 0, 0]
+    again, _ = eng._pack(eng._plan_rows())
+    assert again.tokens[:2].tolist() == [reqs[0].tokens[-1],
+                                         reqs[1].tokens[-1]]
+    assert (again.context_lens == batch.context_lens).all()
 
 
 def test_empty_batch_is_all_idle_rows_and_padding_slots():

@@ -490,9 +490,10 @@ class TestEngine:
         eng = Engine(cfg, params, page_size=8, num_pages=64,
                      max_batch_size=4, chunk_len=32)
         reqs = [eng.add_request(p, sp) for p in early]
-        for _ in range(3):
+        for _ in range(4):
             eng.step()                        # decoding well underway
-        assert all(len(r.output) >= 3 for r in reqs)
+        # a step's tokens are visible one call later: 4 dispatched, 3 read
+        assert all(len(r.output) == 3 and r._pending == 1 for r in reqs)
         late_req = eng.add_request(late, sp)
         while eng.has_work():
             eng.step()
@@ -680,6 +681,9 @@ class TestChunkedPrefill:
         assert req.prompt_pos == 8 and req.t_first_token is None
         eng.step()                            # completing chunk samples
         assert req.prompt_pos == 12
+        # ... on the device: the host reads the token one call later
+        assert req.t_first_token is None and req._pending == 1
+        eng.step()
         assert req.t_first_token is not None
         assert len(req.output) == 1
         assert eng.metrics.ttft.summary()["count"] == 1
@@ -814,18 +818,23 @@ class TestDeadlineEviction:
                      max_batch_size=2, chunk_len=32, clock=clk)
         req = eng.add_request(list(range(6)), SamplingParams(
             max_new_tokens=50, ttl_s=5.0))
-        for _ in range(3):
+        for _ in range(4):
             clk.advance(1.0)
             eng.step()
         assert req.state == RequestState.RUNNING
         produced = len(req.output)
-        assert produced >= 3
+        assert produced == 3 and req._pending == 1
         clk.advance(10.0)                  # now past the deadline
         done = eng.step()
         assert req in done
         assert req.state == RequestState.EVICTED
         assert req.finish_reason == "deadline"
-        assert len(req.output) == produced   # partial output preserved
+        # partial output preserved; the token that was in flight for it
+        # when the deadline passed is dropped, not appended to a request
+        # that has ended
+        assert len(req.output) == produced
+        assert eng.metrics.overrun_rows.value == 1
+        assert not eng.has_work()
         # every page came back to the pool
         assert eng.cache.num_free_pages == eng.cache.num_pages
         assert eng.metrics.deadline_evictions.value == 1
@@ -1034,12 +1043,16 @@ class TestEvacuate:
                      max_batch_size=2, chunk_len=8, tracer=Tracer())
         sp = SamplingParams(max_new_tokens=8)
         r1, r2, r3 = (eng.add_request(p, sp) for p in (p1, p2, p3))
-        for _ in range(2):
+        for _ in range(3):
             eng.step()
-        assert r1.output                     # decoding
+        assert len(r1.output) == 1 and r1._pending == 1  # decoding
         assert 0 < r2.prompt_pos             # chunking
         assert r3.state == RequestState.QUEUED
         got = eng.evacuate()
+        # the step in flight was settled first: its token left with r1
+        assert len(r1.output) == 2 and r1._pending == 0
+        assert eng.metrics.pipeline_drains.labels(
+            reason="evacuate").value == 1
         assert [r.id for r in got] == [r1.id, r2.id, r3.id]
         assert all(r.state == RequestState.EVACUATED for r in got)
         assert all(r.finish_reason == "evacuated" for r in got)

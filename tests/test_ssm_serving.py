@@ -314,11 +314,11 @@ def test_state_row_steps_count_what_the_plan_says(make):
     planned = {"chunk": 0, "decode": 0}
     sound = eng._commit
 
-    def spy(sched, *rest):
-        for _, req, q, _ in sched:
-            planned["chunk" if q > 1 or req.prompt_pos < len(req.prompt)
+    def spy(step, *rest):
+        for _, req, q, ctx in step.sched:
+            planned["chunk" if ctx - q < len(req.prompt)
                     else "decode"] += 1
-        return sound(sched, *rest)
+        return sound(step, *rest)
 
     eng._commit = spy
     rng = np.random.default_rng(5)

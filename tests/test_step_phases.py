@@ -74,8 +74,17 @@ def test_every_phase_has_one_sample_per_step(tiny_model):
     got = _phase_samples(eng)
     assert {p: len(v) for p, v in got.items()} == \
         dict.fromkeys(STEP_PHASES, steps)
-    # a step that ran the program spent time in every phase
-    assert all(got[p][-1] > 0 for p in STEP_PHASES)
+    # one step is in flight: the first call dispatches and has nothing to
+    # wait for, the last has nothing left to plan and commits what the
+    # call before it dispatched, and every call between spent time in
+    # every phase
+    sent, read = ("pack", "dispatch"), ("device_wait", "fetch", "sample")
+    assert all(got[p][1] > 0 for p in sent)
+    assert all(got[p][1] == 0.0 for p in read)
+    assert all(got[p][-1] == 0.0 for p in sent)
+    assert all(got[p][-1] > 0 for p in read)
+    assert all(got[p][i] > 0 for p in STEP_PHASES
+               for i in range(2, steps - 1))
     family = eng.metrics.registry.get("serving_step_phase_seconds")
     assert family.labels(phase="fetch").samples() == got["fetch"]
 
@@ -94,7 +103,14 @@ def test_phase_counts_stay_aligned_when_step_raises(tiny_model):
     got = _phase_samples(eng)
     assert {p: len(v) for p, v in got.items()} == \
         dict.fromkeys(STEP_PHASES, 3)
-    assert got["device_wait"][1] == 0.0 and got["device_wait"][2] > 0
+    # the armed fault site sees committed state: the call that raised had
+    # settled the step in flight first and dispatched nothing, and the
+    # call after it found nothing in flight to wait for
+    assert got["device_wait"][0] == 0.0 and got["dispatch"][0] > 0
+    assert got["device_wait"][1] > 0 and got["dispatch"][1] == 0.0
+    assert got["device_wait"][2] == 0.0 and got["dispatch"][2] > 0
+    assert eng.metrics.pipeline_drains.labels(
+        reason="fault_injection").value == 1
 
 
 def test_histogram_samples_returns_newest_reservoir_in_order():
@@ -131,10 +147,15 @@ def test_ring_phase_events_contiguous_in_order_and_cover_the_step(
     whole = [ev for ev in spans if ev[1] == "serving::step"]
     assert len(whole) == calls >= 3
     names = [f"serving::step/{p}" for p in STEP_PHASES]
-    for _, _, start, end, _ in whole:
+    for n, (_, _, start, end, _) in enumerate(whole):
         inside = [ev for ev in spans if ev[1] in names
                   and start <= ev[2] and ev[3] <= end]
-        assert [ev[1] for ev in inside] == names
+        # the last call has nothing left to plan: it only settles the
+        # step the call before it left in flight
+        last = n == len(whole) - 1
+        assert [ev[1] for ev in inside] == [
+            name for name in names if not (last and name.endswith(
+                ("/pack", "/dispatch")))]
         # in order, none overlapping the next
         for a, b in zip(inside, inside[1:]):
             assert a[3] <= b[2]
@@ -146,10 +167,13 @@ def test_ring_phase_events_contiguous_in_order_and_cover_the_step(
                    and start <= ev[2] and ev[3] <= end]
         assert len(unified) == 1
         by = {ev[1]: ev for ev in inside}
-        assert unified[0][2] <= by["serving::step/dispatch"][2]
+        # it spans the call's time with the device: one program sent,
+        # the one before it waited for and read
         assert by["serving::step/fetch"][3] <= unified[0][3]
-        assert unified[0][2] >= by["serving::step/pack"][3]
         assert unified[0][3] <= by["serving::step/sample"][2]
+        if not last:
+            assert unified[0][2] <= by["serving::step/dispatch"][2]
+            assert unified[0][2] >= by["serving::step/pack"][3]
 
 
 def test_phase_helper_sets_the_samplers_tag(tiny_model):
@@ -227,9 +251,12 @@ def test_step_phases_land_in_a_jax_trace_without_a_profiler_session(
         jax.profiler.stop_trace()
     counts = collections.Counter(
         n for n, _, _ in _host_events(str(tmp_path), "serving::"))
+    # every call settles the step before it; all but the last send one
     assert counts == {"serving::step": calls,
                       "serving::unified_step": calls,
-                      **{f"serving::step/{p}": calls for p in STEP_PHASES}}
+                      **{f"serving::step/{p}": calls - (p in ("pack",
+                                                              "dispatch"))
+                         for p in STEP_PHASES}}
 
 
 # ------------------------------------------------------------ kernel names

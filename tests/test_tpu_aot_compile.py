@@ -92,17 +92,21 @@ def test_gpt_serve_step_keeps_its_temporaries_behind_the_model_interface(
         devices):
     """The dense family is served through the engine's model interface
     (``serving/model.py``) and its compiled step holds what it held: at
-    the benchmark's 1024 pages and 16 rows 5,852,880,896 B of arguments
-    (the 5,852,876,800 B of weights, pools and batch, and one 4 KiB tile
-    for the ``[16, 6]`` sampling table) and one Mosaic call — the
+    the benchmark's 1024 pages and 16 rows 5,852,881,408 B of arguments
+    (the 5,852,876,800 B of weights, pools and batch, one 4 KiB tile
+    for the ``[16, 6]`` sampling table and 512 B for the ``[16]`` ids of
+    the step before, PR 34) and one Mosaic call — the
     equal-heads kernel, which the grouped / selected mode must not reach.
-    XLA plans 1,580,544 B of temporaries in HBM: the 1,032,192 B of PR
+    XLA plans 1,677,312 B of temporaries in HBM: the 1,032,192 B of PR
     27's program, 96,768 B that the kernel's work list brought (PR 29; by
     the compiler's memory report the block of small arrays grew by three
     16 KiB slots, 496.5 to 544.5 KiB — the list's rows, tiles and count,
     built once a step — and the loops hold 36 more scalar slots) and
     451,584 B for sampling on the device (PR 31: the rows' ids, the
-    ``cond``'s operands and what the drawing branch keeps in HBM).  The
+    ``cond``'s operands and what the drawing branch keeps in HBM) and
+    96,768 B where the packed tokens are no longer an operand the
+    embedding reads in place but the result of resolving the pending ones
+    (PR 34: a gather and a select over ``[143]`` ids).  The
     padded queries and the kernel's output are in the chip's fast memory,
     26.85 MiB as before, which this figure does not count.  The draw is
     compiled once, in one branch of one ``conditional``, and nothing in
@@ -111,8 +115,8 @@ def test_gpt_serve_step_keeps_its_temporaries_behind_the_model_interface(
     compiled = tpu_aot.lower_serve_step(
         devices, num_pages=1024, max_batch_size=16, chunk_len=128).compile()
     mem, text = compiled.memory_analysis(), compiled.as_text()
-    assert mem.temp_size_in_bytes == 1580544
-    assert mem.argument_size_in_bytes == 5852880896
+    assert mem.temp_size_in_bytes == 1677312
+    assert mem.argument_size_in_bytes == 5852881408
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert (text.count(" conditional("), text.count(" sort(")) == (1, 0)
 
@@ -122,10 +126,12 @@ def test_hybrid_serve_step_compiles_for_v5e_and_moves_no_pool(devices):
     (published widths, 8 layers, 16 rows, chunks of 512, 8192 pages of
     64): Mosaic accepts both new kernels, every state pool is donated and
     aliased, nothing re-lays or copies a key/value pool, and the plan
-    fits the chip: 6,950,199,296 B of arguments and 840,354,816 B of
+    fits the chip: 6,950,199,808 B of arguments and 840,322,560 B of
     temporaries (6,950,195,200 B and 839,838,720 B before the step
     sampled its ``[16, 73472]`` logits itself, PR 31: 4,096 B for the
-    table, 516,096 B for the ids and the drawing branch)."""
+    table, 516,096 B for the ids and the drawing branch; PR 34's
+    ``[16]`` ids of the step before are 512 B more of arguments, and the
+    plan with the resolved tokens packs 32,256 B tighter)."""
     from paddle_tpu.models.hybrid import HYBRID_CONFIGS
 
     compiled = tpu_aot.lower_hybrid_serve_step(devices).compile()
@@ -139,7 +145,7 @@ def test_hybrid_serve_step_compiles_for_v5e_and_moves_no_pool(devices):
     assert mem.alias_size_in_bytes >= pools
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12e9
     assert (mem.argument_size_in_bytes, mem.temp_size_in_bytes) == \
-        (6950199296, 840354816)
+        (6950199808, 840322560)
     assert text.count(" conditional(") == 1
     pool = "bf16[2,8192,2,64,128]"
     movers = [line.strip()[:160] for line in text.splitlines()
@@ -156,9 +162,10 @@ def test_ssm_serve_step_compiles_for_v5e_and_fits_the_chip(devices):
     kernel and the grouped-heads attention kernel at a group of 5 (one
     call each: the layers are one ``lax.scan``), all four state pools are
     donated and aliased, and the plan fits the chip before any chip time
-    is spent: 13,742,309,376 B of arguments (10.51 GB of weights, 1.61 GB
-    of pages, 1.62 GB of window and scan state) and 128,466,432 B of
-    temporaries, with the ``[64, 261120]`` float32 logits 66,846,720 B
+    is spent: 13,742,309,888 B of arguments (10.51 GB of weights, 1.61 GB
+    of pages, 1.62 GB of window and scan state, and since PR 34 the 512 B
+    of the ``[64]`` ids of the step before) and 128,563,200 B of
+    temporaries (96,768 B of them for the resolved tokens, PR 34), with the ``[64, 261120]`` float32 logits 66,846,720 B
     more: 13.94 GB of the chip's 17.18."""
     compiled = tpu_aot.lower_ssm_serve_step(devices).compile()
     text, mem = compiled.as_text(), compiled.memory_analysis()
@@ -167,7 +174,7 @@ def test_ssm_serve_step_compiles_for_v5e_and_fits_the_chip(devices):
         + 6 * 64 * 32 * 128 * 256 * 4
     assert mem.alias_size_in_bytes == pools == 3233021952
     assert (mem.argument_size_in_bytes, mem.temp_size_in_bytes) == \
-        (13742309376, 128466432)
+        (13742309888, 128563200)
     assert mem.output_size_in_bytes - mem.alias_size_in_bytes < 68e6
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes - mem.alias_size_in_bytes) < 2 ** 34
