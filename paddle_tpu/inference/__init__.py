@@ -87,7 +87,7 @@ class Config:
     # ---- autoregressive generation (serving engine) ----
     def enable_generation(self, model_config, params=None, *, page_size=16,
                           num_pages=256, max_batch_size=4, chunk_len=None,
-                          prefix_cache=None):
+                          prefix_cache=None, num_window_pages=None):
         """Switch create_predictor to a GenerationPredictor: a
         continuous-batching, paged-cache generation engine
         (paddle_tpu.serving) over the given model — a served-model
@@ -102,13 +102,17 @@ class Config:
         cached prefix skips that prefill entirely, token-identically.  It
         defaults to
         on, except for a model with recurrent layers, which is served
-        cold and for which ``True`` is refused."""
+        cold and for which ``True`` is refused.  num_window_pages sizes
+        the pools of a model's sliding-window layers (default: what
+        max_batch_size rows can hold at most)."""
         self.generation = {
             "config": model_config, "params": params,
             "knobs": {"page_size": page_size, "num_pages": num_pages,
                       "max_batch_size": max_batch_size,
                       "chunk_len": chunk_len, "prefix_cache": prefix_cache},
         }
+        if num_window_pages is not None:
+            self.generation["knobs"]["num_window_pages"] = num_window_pages
         return self
 
     # ---- model source for rebuild-precision paths ----

@@ -90,6 +90,28 @@ costs its K pages and not the width of the page table.  This path is
 chosen statically by ``selected is not None``; it takes one page an item
 through a ``BlockSpec``, and shares no code with the equal-heads kernel
 above.
+
+A lower edge (``window=``, static, with the grouped mode).  A sliding-window
+layer's token at position ``p`` reads positions ``p - window < j <= p``.
+The work list then holds, for a tile of query tokens, only the pages its
+tokens' windows reach (the pages behind them are never fetched: their
+entries in the page table may be stale, the cache manager has given the
+pages back), and the kernel masks the edge per token.  ``H / Hkv`` need
+not be a power of two (9 query heads a group is fine: the group is a
+leading axis of the tiles).
+
+Queries packed in tiles (``q_tiles=``, with the grouped mode and no lists).
+``[B, Q, H, hd]`` pads every row to the widest chunk: at 64 rows and chunks
+of 1024 that is 65,536 query slots for a step's 1,088 tokens, and writing,
+turning and reading them took more of a call than its pages did (2.5 ms of
+a window layer's call against 0.04 ms of keys and values: chip run, PR 35).
+With ``q_tiles = (tile_rows [NT], tile_index [NT])`` the queries come as
+``[NT, tile, H, hd]``: tile ``n`` holds the query slots ``tile_index[n] *
+tile ...`` of batch row ``tile_rows[n]`` (``B`` marks an unused tile), one
+tile a decode row and one more for every ``tile`` tokens of a chunk
+(``models/ragged.py`` ``RaggedView.pad_tiles``), and the result comes back
+in the same layout.  The items are (tile, group, page); the body is the
+grouped mode's without the lists.
 """
 from __future__ import annotations
 
@@ -370,7 +392,8 @@ def _selected_onehot(sel_blocks, W):
 
 
 def _listed_attention_ref(q, k_pages, v_pages, page_tables, query_lens,
-                          context_lens, scale, layer, sel_blocks, dense_len):
+                          context_lens, scale, layer, sel_blocks, dense_len,
+                          window=None):
     """Gather-then-mask oracle of the grouped / selected mode."""
     B, Q, H, hd = q.shape
     Hkv, page_size = k_pages.shape[2], k_pages.shape[3]
@@ -384,6 +407,8 @@ def _listed_attention_ref(q, k_pages, v_pages, page_tables, query_lens,
     pos, valid = _token_positions(Q, query_lens, context_lens)
     t = jnp.arange(T)
     ok = (t[None, None, :] <= pos[:, :, None]) & valid[:, :, None]
+    if window is not None:
+        ok = ok & (t[None, None, :] > pos[:, :, None] - window)
     ok = jnp.broadcast_to(ok[:, None], (B, Hkv, Q, T))
     if sel_blocks is not None:
         allowed = (_selected_onehot(sel_blocks, W)
@@ -417,7 +442,7 @@ def _work_items(visit, n_max):
 def _listed_kernel(seg_ref, lp_ref, n_ref, tbl_ref, qlen_ref, ctx_ref,
                    layer_ref, q_ref, sel_ref, kp_ref, vp_ref, o_ref, acc_ref,
                    m_ref, l_ref, *, scale, page_size, groups, tiles, tile,
-                   small, dense_len, n_max):
+                   small, dense_len, n_max, window):
     del tbl_ref, layer_ref            # only the page index_maps read them
     i = pl.program_id(0)
     seg = seg_ref[i]
@@ -457,6 +482,8 @@ def _listed_kernel(seg_ref, lp_ref, n_ref, tbl_ref, qlen_ref, ctx_ref,
                          keepdims=True)[None]        # [1, R, 1]
         ok = ((kv <= at) & (tq < q_len)
               & ((at + 1 <= dense_len) | member))
+        if window is not None:
+            ok = ok & (kv > at - window)
         s = jnp.where(ok, s, _NEG_INF)
         m_prev = m_ref[:, :R]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -486,7 +513,8 @@ def _listed_kernel(seg_ref, lp_ref, n_ref, tbl_ref, qlen_ref, ctx_ref,
 
 def _listed_attention_kernel(q, k_pages, v_pages, page_tables, query_lens,
                              context_lens, scale, layer, sel_blocks,
-                             dense_len, total_q, interpret, sel_mask=None):
+                             dense_len, total_q, interpret, sel_mask=None,
+                             window=None):
     B, Q, H, hd = q.shape
     Hkv, page_size = k_pages.shape[2], k_pages.shape[3]
     G, W = H // Hkv, page_tables.shape[1]
@@ -505,14 +533,19 @@ def _listed_attention_kernel(q, k_pages, v_pages, page_tables, query_lens,
     # into W flags took 5.6 ms a call at 16 x 2 x 512 x 64 into 544)
     listed = sel_mask if sel_mask is not None \
         else _selected_onehot(sel_blocks, W)
-    reads = (listed
-             | (dense_tok[:, :, None]
-                & (w[None, None, :] <= pos[:, :, None] // page_size)
-                )[:, None])
+    reach = w[None, None, :] <= pos[:, :, None] // page_size
+    if window is not None:
+        # the first position the token's window holds, and its page
+        reach = reach & (w[None, None, :] >= jnp.maximum(
+            pos - window + 1, 0)[:, :, None] // page_size)
+    reads = listed | (dense_tok[:, :, None] & reach)[:, None]
     reads = reads & valid[:, None, :, None]
     visit = reads.reshape(B, Hkv, tiles, tile, W).any(3)
     # tiles that hold a token: one a row and one more per `tile` tokens
-    n_max = Hkv * (B + -(-(total_q or B * Q) // tile)) * W
+    # pages one tile can reach: all of them, or its tokens' windows
+    per_tile = W if window is None else min(
+        W, (window + tile - 2) // page_size + 2)
+    n_max = Hkv * (B + -(-(total_q or B * Q) // tile)) * per_tile
     n_max = min(n_max, B * Hkv * tiles * W)
     seg, lpage, n = _work_items(visit.reshape(B * Hkv * tiles, W), n_max)
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
@@ -552,16 +585,199 @@ def _listed_attention_kernel(q, k_pages, v_pages, page_tables, query_lens,
     kernel = functools.partial(
         _listed_kernel, scale=scale, page_size=page_size, groups=Hkv,
         tiles=tiles, tile=tile, small=small, dense_len=dense_len,
-        n_max=n_max)
+        n_max=n_max, window=window)
     q5 = q.reshape(B, Q, Hkv, G, hd).transpose(0, 2, 3, 1, 4)
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q5.shape, q.dtype),
         interpret=interpret,
-        name="ragged_paged_attention",
+        # a window layer's calls under a name of their own: a trace then
+        # tells them from the full layers' (either name holds the other's)
+        name="ragged_paged_attention" + ("" if window is None
+                                         else "_window"),
     )(seg, lpage, n.reshape(1).astype(jnp.int32), page_tables, query_lens,
       context_lens, layer, q5, sel_blocks, k_pages, v_pages)
     out = out.transpose(0, 3, 1, 2, 4).reshape(B, Q, H, hd)
+    # tiles that no item visited were never written
+    return jnp.where(valid[:, :, None, None], out, jnp.zeros_like(out))
+
+
+
+# ---------------------------------- grouped heads, queries packed in tiles
+
+
+def _tile_positions(tile, tile_rows, tile_index, query_lens, context_lens):
+    """For tile-packed queries: ``(row [NT] clamped into range, pos [NT,
+    tile] absolute position of each slot, valid)``; tile ``n`` holds the
+    query slots ``tile_index[n] * tile ...`` of batch row ``tile_rows[n]``
+    (``B``: an unused tile)."""
+    B = query_lens.shape[0]
+    row = jnp.minimum(tile_rows, B - 1)
+    q_len, ctx = query_lens[row], context_lens[row]
+    tq = tile_index[:, None] * tile + jnp.arange(tile)[None, :]
+    pos = (ctx - q_len)[:, None] + tq
+    valid = (tile_rows < B)[:, None] & (tq < q_len[:, None])
+    return row, pos, valid
+
+
+def _tiled_attention_ref(q, k_pages, v_pages, page_tables, query_lens,
+                         context_lens, scale, layer, tile_rows, tile_index,
+                         window):
+    """Gather-then-mask oracle of the tile-packed mode: every tile against
+    its row's whole table."""
+    NT, tile, H, hd = q.shape
+    Hkv, page_size = k_pages.shape[2], k_pages.shape[3]
+    G, W = H // Hkv, page_tables.shape[1]
+    T = W * page_size
+    row, pos, valid = _tile_positions(tile, tile_rows, tile_index,
+                                      query_lens, context_lens)
+    gather = lambda pool: pool[layer, page_tables[row]].transpose(
+        0, 2, 1, 3, 4).reshape(NT, Hkv, T, hd).astype(jnp.float32)
+    k, v = gather(k_pages), gather(v_pages)
+    qg = q.astype(jnp.float32).reshape(NT, tile, Hkv, G, hd)
+    s = jnp.einsum("nqhgd,nhtd->nhgqt", qg, k) * scale
+    t = jnp.arange(T)
+    ok = (t[None, None, :] <= pos[:, :, None]) & valid[:, :, None]
+    if window is not None:
+        ok = ok & (t[None, None, :] > pos[:, :, None] - window)
+    s = jnp.where(ok[:, None, None], s, _NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    out = jnp.einsum("nhgqt,nhtd->nqhgd", p, v).reshape(NT, tile, H, hd)
+    return jnp.where(valid[:, :, None, None], out, 0.0).astype(q.dtype)
+
+
+def _tiled_kernel(seg_ref, lp_ref, n_ref, trow_ref, tidx_ref, tbl_ref,
+                  qlen_ref, ctx_ref, layer_ref, q_ref, kp_ref, vp_ref, o_ref,
+                  acc_ref, m_ref, l_ref, *, scale, page_size, groups, tile,
+                  small, n_max, window):
+    """One (query tile, key/value head, page) item: ``_listed_kernel``
+    without the per-token lists, the tile's row and place in it read from
+    scalar prefetch."""
+    del tbl_ref, layer_ref            # only the page index_maps read them
+    i = pl.program_id(0)
+    seg = seg_ref[i]
+    first = (i == 0) | (seg_ref[jnp.maximum(i - 1, 0)] != seg)
+    last = (i == n_ref[0] - 1) | (seg_ref[jnp.minimum(i + 1, n_max - 1)]
+                                  != seg)
+    tid = seg // groups
+    b, qt = trow_ref[tid], tidx_ref[tid]
+    lpage = lp_ref[i]
+    q_len, ctx = qlen_ref[b], ctx_ref[b]
+    start = lpage * page_size
+    in_tile = q_len - qt * tile       # query tokens of the row in this tile
+
+    @pl.when(first)
+    def _init():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+
+    def update(R):
+        """The page against the tile's first ``R`` query slots (static):
+        a decode row pays for ``small`` slots, not for the tile."""
+        G = q_ref.shape[2]
+        hd = q_ref.shape[4]
+        q = q_ref[0, 0, :, :R, :].reshape(G * R, hd)
+        k = kp_ref[0, 0, 0]                          # [ps, hd]
+        v = vp_ref[0, 0, 0]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        s = s.reshape(G, R, page_size)
+        tq = qt * tile + jax.lax.broadcasted_iota(jnp.int32, (1, R, 1), 1)
+        kv = start + jax.lax.broadcasted_iota(jnp.int32, (1, 1, page_size),
+                                              2)
+        at = ctx - q_len + tq                        # absolute position
+        ok = (kv <= at) & (tq < q_len)
+        if window is not None:
+            ok = ok & (kv > at - window)
+        s = jnp.where(ok, s, _NEG_INF)
+        m_prev = m_ref[:, :R]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        # a slot with nothing allowed yet keeps m at -inf: exp(s - m)
+        # would be 1 there, so mask the probabilities too
+        p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[:, :R] = l_ref[:, :R] * corr + jnp.sum(p, axis=-1,
+                                                     keepdims=True)
+        pv = jnp.dot(p.reshape(G * R, page_size).astype(v.dtype), v,
+                     preferred_element_type=jnp.float32)
+        acc_ref[:, :R] = acc_ref[:, :R] * corr + pv.reshape(G, R, hd)
+        m_ref[:, :R] = m_new
+
+    if small < tile:
+        pl.when(in_tile <= small)(lambda: update(small))
+        pl.when(in_tile > small)(lambda: update(tile))
+    else:
+        update(tile)
+
+    @pl.when(last)
+    def _final():
+        l = l_ref[:]
+        o = acc_ref[:] / jnp.where(l == 0.0, 1.0, l)
+        o_ref[0, 0] = o.astype(o_ref.dtype)
+
+
+def _tiled_attention_kernel(q, k_pages, v_pages, page_tables, query_lens,
+                            context_lens, scale, layer, tile_rows,
+                            tile_index, interpret, window):
+    NT, tile, H, hd = q.shape
+    Hkv, page_size = k_pages.shape[2], k_pages.shape[3]
+    G, W = H // Hkv, page_tables.shape[1]
+    small = min(16, tile)
+    row, pos, valid = _tile_positions(tile, tile_rows, tile_index,
+                                      query_lens, context_lens)
+    w = jnp.arange(W)
+    reach = w[None, None, :] <= pos[:, :, None] // page_size
+    if window is not None:
+        reach = reach & (w[None, None, :] >= jnp.maximum(
+            pos - window + 1, 0)[:, :, None] // page_size)
+    visit = (reach & valid[:, :, None]).any(1)                   # [NT, W]
+    visit = jnp.broadcast_to(visit[:, None], (NT, Hkv, W))
+    # pages one tile can reach: all of them, or its tokens' windows
+    per_tile = W if window is None else min(
+        W, (window + tile - 2) // page_size + 2)
+    n_max = NT * Hkv * per_tile
+    seg, lpage, n = _work_items(visit.reshape(NT * Hkv, W), n_max)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
+
+    def q_block(i, seg, lp, n, trow, tidx, tbl, ql, cl, lyr):
+        return (seg[i] // Hkv, seg[i] % Hkv, 0, 0, 0)
+
+    def page_block(i, seg, lp, n, trow, tidx, tbl, ql, cl, lyr):
+        return (lyr[0], tbl[trow[seg[i] // Hkv], lp[i]], seg[i] % Hkv, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=9,
+        grid=(n,),
+        in_specs=[
+            pl.BlockSpec((1, 1, G, tile, hd), q_block),
+            pl.BlockSpec((1, 1, 1, page_size, hd), page_block),
+            pl.BlockSpec((1, 1, 1, page_size, hd), page_block),
+        ],
+        out_specs=pl.BlockSpec((1, 1, G, tile, hd), q_block),
+        scratch_shapes=[
+            pltpu.VMEM((G, tile, hd), jnp.float32),
+            pltpu.VMEM((G, tile, 1), jnp.float32),
+            pltpu.VMEM((G, tile, 1), jnp.float32),
+        ],
+    )
+    kernel = functools.partial(
+        _tiled_kernel, scale=scale, page_size=page_size, groups=Hkv,
+        tile=tile, small=small, n_max=n_max, window=window)
+    q5 = q.reshape(NT, tile, Hkv, G, hd).transpose(0, 2, 3, 1, 4)
+    out = pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(q5.shape, q.dtype),
+        interpret=interpret,
+        # a window layer's calls under a name of their own: a trace then
+        # tells them from the full layers' (either name holds the other's)
+        name="ragged_paged_attention" + ("" if window is None
+                                         else "_window"),
+    )(seg, lpage, n.reshape(1).astype(jnp.int32), row.astype(jnp.int32),
+      tile_index.astype(jnp.int32), page_tables, query_lens, context_lens,
+      layer, q5, k_pages, v_pages)
+    out = out.transpose(0, 3, 1, 2, 4).reshape(NT, tile, H, hd)
     # tiles that no item visited were never written
     return jnp.where(valid[:, :, None, None], out, jnp.zeros_like(out))
 
@@ -571,7 +787,8 @@ def _listed_attention_kernel(q, k_pages, v_pages, page_tables, query_lens,
 
 def ragged_paged_attention(q, k_pages, v_pages, page_tables, query_lens,
                            context_lens, scale=None, path=None, layer=None,
-                           selected=None, total_q=None, items=None):
+                           selected=None, total_q=None, items=None,
+                           window=None, q_tiles=None):
     """Fused prefill+decode attention over a paged KV cache (see module
     docstring for layouts).  ``layer`` (a traced int32 scalar) comes with
     a stacked ``[L, P, page_size, H, hd]`` pool and names the layer whose
@@ -584,7 +801,10 @@ def ragged_paged_attention(q, k_pages, v_pages, page_tables, query_lens,
     dense_len)`` takes the grouped-heads / selected-pages mode over a
     head-major stacked pool ``[L, P, Hkv, page_size, hd]``; ``total_q``
     (static) then bounds the query tokens of all rows together, which
-    bounds its work list."""
+    bounds its work list; ``window`` (static, with ``selected``) is a
+    sliding-window layer's lower edge; ``q_tiles = (tile_rows,
+    tile_index)`` (with ``selected=(None, ...)``) takes ``q`` packed in
+    tiles, ``[NT, tile, H, hd]``, and returns that layout."""
     if (k_pages.ndim == 5) != (layer is not None):
         raise ValueError("a stacked [L, P, page_size, H, hd] pool comes "
                          "with its `layer`, a one-layer pool without")
@@ -594,6 +814,22 @@ def ragged_paged_attention(q, k_pages, v_pages, page_tables, query_lens,
     page_tables = page_tables.astype(jnp.int32)
     query_lens = query_lens.astype(jnp.int32)
     context_lens = context_lens.astype(jnp.int32)
+    if window is not None and selected is None:
+        raise ValueError("`window` comes with the grouped-heads mode "
+                         "(`selected=`)")
+    if q_tiles is not None:
+        if selected is None or selected[0] is not None:
+            raise ValueError("`q_tiles` comes with the grouped-heads mode "
+                             "and no lists (`selected=(None, ...)`)")
+        if layer is None:
+            k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
+        tile_rows, tile_index = q_tiles
+        call = _tiled_attention_ref if path == dispatch.REFERENCE \
+            else functools.partial(_tiled_attention_kernel,
+                                   interpret=(path == dispatch.INTERPRET))
+        return call(q, k_pages, v_pages, page_tables, query_lens,
+                    context_lens, scale, layer, tile_rows, tile_index,
+                    window=window)
     if selected is not None:
         sel_blocks, dense_len, *sel_mask = selected
         if layer is None:
@@ -601,12 +837,12 @@ def ragged_paged_attention(q, k_pages, v_pages, page_tables, query_lens,
         if path == dispatch.REFERENCE:
             return _listed_attention_ref(
                 q, k_pages, v_pages, page_tables, query_lens, context_lens,
-                scale, layer, sel_blocks, dense_len)
+                scale, layer, sel_blocks, dense_len, window)
         return _listed_attention_kernel(
             q, k_pages, v_pages, page_tables, query_lens, context_lens,
             scale, layer, sel_blocks, dense_len, total_q,
             interpret=(path == dispatch.INTERPRET),
-            sel_mask=sel_mask[0] if sel_mask else None)
+            sel_mask=sel_mask[0] if sel_mask else None, window=window)
     if path == dispatch.REFERENCE:
         return _ragged_attention_ref(q, k_pages, v_pages, page_tables,
                                      query_lens, context_lens, scale, layer)

@@ -102,6 +102,21 @@ of the same prompt (parity-tested).  Zero-ref cached pages are counted
 as free for watermark/occupancy purposes and LRU-evicted on demand, so
 a warm cache never sheds traffic it could serve.
 
+Window layers (a model whose ``window`` is set: sliding-window attention
+layers beside full ones).  Their pools are a second group with a page table
+and a budget of their own (``serving/kv_cache.py``, kind
+``"window_pages"``).  Before a row's pages are extended for a step, the
+window pages whose every position lies more than ``window`` behind the
+first token the step runs for that row are given back, so a row holds at
+most ``ceil((window + chunk_len) / page_size) + 1`` of them
+(``kv_cache.window_pages_per_row``) however long it grows; admission and
+the per-step extension need both pools to cover the row (a row that either
+cannot cover waits, or preempts, as before).  A page
+given back while the step before is still in flight is safe to hand to
+another row: the device runs the programs in order, and the step that
+writes it is the later one.  Such a model is served cold (``prefix_cache``
+off): a cached prefix's window pages are gone once its sequence moved on.
+
 Sampling is on the device (``serving/sampling.py``): the jitted step
 ends by choosing each row's token (greedy / temperature / top-k / top-p)
 and the host reads ``[B]`` ids, never the ``[B, V]`` logits.  A draw is
@@ -121,7 +136,6 @@ import jax
 import jax.numpy as jnp
 
 from ..models.ragged import (
-    RaggedBatch,
     batch_shapes,
     empty_batch,
     pending_token,
@@ -132,7 +146,7 @@ from ..observability.profiling import pop_phase, push_phase
 from ..observability.tracing import Tracer, default_tracer
 from ..profiler.profiler import RecordEvent
 from ..resilience.faults import fault_armed, fault_point
-from .kv_cache import PagedKVCache
+from .kv_cache import PagedKVCache, window_pages_per_row
 from .metrics import STEP_PHASES, ServingMetrics
 from .model import as_served
 from .sampling import GREEDY, sample_tokens, slot_entry
@@ -327,9 +341,18 @@ class Engine:
                  shed_occupancy_high=None, shed_occupancy_low=None,
                  shed_queue_high=None, shed_queue_low=None,
                  drain_floor_s=None, prefix_cache=None, clock=None,
-                 tracer=None, mesh=None):
+                 tracer=None, mesh=None, num_window_pages=None):
         self.model = model = as_served(model)
         self.cfg = cfg = model.cfg
+        #: positions a sliding-window layer reads (None: no such layers)
+        self.window = getattr(model, "window", None)
+        if prefix_cache and self.window:
+            raise ValueError(
+                "prefix_cache=True with a model that has window layers: a "
+                "cached prefix's window pages were given back as its "
+                "sequence moved past them, so it cannot be resumed.  Such "
+                "a model is served cold (prefix_cache=False, the default "
+                "for it)")
         if prefix_cache and model.recurrent:
             raise ValueError(
                 "prefix_cache=True with a model that has recurrent layers: "
@@ -338,7 +361,7 @@ class Engine:
                 "Such a model is served cold (prefix_cache=False, the "
                 "default for it) until a prefix can carry a state snapshot")
         if prefix_cache is None:
-            prefix_cache = not model.recurrent
+            prefix_cache = not model.recurrent and not self.window
         self._clock = clock or time.perf_counter
         if tracer is None:
             tracer = (default_tracer() if clock is None
@@ -369,15 +392,29 @@ class Engine:
         self.token_budget = max(
             token_budget or (self.chunk_len + max_batch_size - 1),
             max_batch_size)
+        sizes = {"num_pages": num_pages, "page_size": page_size,
+                 "max_batch_size": max_batch_size}
+        if self.window:
+            # the most one row holds, for every row: no row ever waits
+            # for a window page unless the caller sized the pool below it
+            per_row = min(window_pages_per_row(self.window, page_size,
+                                               self.chunk_len),
+                          -(-cfg.max_seq_len // page_size))
+            sizes["num_window_pages"] = (num_window_pages
+                                         or max_batch_size * per_row)
+            if sizes["num_window_pages"] < per_row:
+                raise ValueError(
+                    f"num_window_pages {num_window_pages}: one row's "
+                    f"window and chunk take {per_row} pages")
         self.cache = PagedKVCache(
             num_pages=num_pages, page_size=page_size,
-            max_seq_len=cfg.max_seq_len,
-            state=model.state_spec(num_pages=num_pages, page_size=page_size,
-                                   max_batch_size=max_batch_size))
+            max_seq_len=cfg.max_seq_len, state=model.state_spec(**sizes))
         # the static sizes of the step's ragged batch: rows, packed query
         # tokens, width of a row's page table
         self.batch_dims = (max_batch_size, self.token_budget,
                            self.cache.max_pages_per_seq)
+        self._window_tables = self.cache.num_window_pages > 0
+        self._window_released_seen = 0
         # prefix/radix reuse: admission walks the radix tree so a shared
         # system prompt is a refcount bump instead of prefill FLOPs;
         # completed prompts are inserted back.  Off = always-cold
@@ -399,6 +436,10 @@ class Engine:
         donate = tuple(range(1, 1 + n_state)) \
             if jax.default_backend() != "cpu" else ()
         model_step = model.make_step(max_q=self.chunk_len, mesh=mesh)
+        # numbers the model's step counts on the device (int32 [n], after
+        # its logits and state): they ride to the host behind the ids, in
+        # the one array the host already reads
+        n_stats = len(getattr(model, "step_stats", ()))
 
         # the rows' sampling parameters by batch slot (sampling.py): the
         # host's copy, and the device's, sent again only after admission
@@ -418,10 +459,14 @@ class Engine:
             # seen is named in the batch and filled in here: the model's
             # step sees ids only
             *state, batch, sampling, prev_ids = args
+            if n_stats:
+                prev_ids = prev_ids[:max_batch_size]
             batch = resolve_pending(batch, prev_ids)
-            logits, state = model_step(params, tuple(state), batch)
+            logits, state, *stats = model_step(params, tuple(state), batch)
             ids = sample_tokens(logits, sampling, batch.query_lens,
                                 batch.context_lens)
+            if n_stats:
+                ids = jnp.concatenate([ids, stats[0].astype(ids.dtype)])
             return (ids, logits, *state)
 
         # GSPMD serving (prepare(mesh=...) analogue): the model shards its
@@ -461,7 +506,7 @@ class Engine:
                               name="serving::unified_step")
         # the ids the newest dispatched step chose, still on the device:
         # the next step's last operand
-        ids = np.zeros((max_batch_size,), np.int32)
+        ids = np.zeros((max_batch_size + n_stats,), np.int32)
         self._prev_ids = (jnp.asarray(ids) if self._replicated is None
                           else jax.device_put(ids, self._replicated))
 
@@ -799,6 +844,11 @@ class Engine:
                     target = req.prompt_pos + plan[i]
                 else:
                     target = len(req.tokens) + req._pending
+                if self.window:
+                    # the step's first token for this row sits at
+                    # target - plan[i] and reads `window` positions back
+                    self.cache.release_window(
+                        req.id, target - plan[i] - self.window + 1)
                 while req in self._slots and \
                         not self.cache.extend(req.id, target):
                     if self._inflight is not None:
@@ -822,10 +872,12 @@ class Engine:
         B = self.max_batch_size
         return (self.params if params is None else params,
                 *(self.cache.state_arrays() if state is None else state),
-                batch_shapes(*self.batch_dims, sharding=sharding),
+                batch_shapes(*self.batch_dims, sharding=sharding,
+                             window_tables=self._window_tables),
                 jax.ShapeDtypeStruct((B, len(GREEDY)), jnp.uint32,
                                      sharding=sharding),
-                jax.ShapeDtypeStruct((B,), jnp.int32, sharding=sharding))
+                jax.ShapeDtypeStruct(self._prev_ids.shape, jnp.int32,
+                                     sharding=sharding))
 
     def _dispatch(self, batch, sched, phases):
         """Send the packed batch to the one jitted program — it ends by
@@ -851,7 +903,7 @@ class Engine:
                     else jax.device_put(table, self._replicated))
             ids, logits, *state = self._step_fn(
                 self.params, *self.cache.state_arrays(),
-                RaggedBatch(*(jnp.asarray(a) for a in batch)),
+                type(batch)(*(jnp.asarray(a) for a in batch)),
                 self._sampling_table, self._prev_ids)
             # the 64 bytes follow the program to the host on their
             # own: a read begun only after the wait costs one more
@@ -884,6 +936,10 @@ class Engine:
             step.ids.block_until_ready()
         with phases.phase("fetch", step.kind):
             ids = np.asarray(step.ids).tolist()
+        if len(ids) > self.max_batch_size:
+            # what the model's step counted, behind the rows' ids
+            self.model.record_stats(self.metrics,
+                                    ids[self.max_batch_size:])
         return ids, self._clock()
 
     def _drain(self, reason, phases=None):
@@ -910,8 +966,9 @@ class Engine:
         of every packed row, or None when no row is left to run.  A
         decode row sends its newest token, or, while that token is still
         on the device, the marker that names it."""
-        batch = empty_batch(*self.batch_dims)
-        tokens, rows, slots, qlens, ctxs, tables = batch
+        batch = empty_batch(*self.batch_dims,
+                            window_tables=self._window_tables)
+        tokens, rows, slots, qlens, ctxs, tables, *window_tables = batch
         sched = []                               # (slot, req, q, new ctx)
         off = 0
         for i in range(self.max_batch_size):     # packing is row-major
@@ -928,6 +985,9 @@ class Engine:
                              else req.tokens[-1])
                     ctx = len(req.tokens) + req._pending
                 table = self.cache.page_table(req.id)
+                if window_tables:
+                    window_tables[0][i] = self.cache.window_page_table(
+                        req.id)
             except Exception as e:
                 # row-attributable plan failure: THIS row dies, the
                 # batch (arrays untouched for it) runs without it
@@ -1195,6 +1255,8 @@ class Engine:
                 self._commit(*ran)
             self._update_shedding()
             self.metrics.page_occupancy.set(self.cache.occupancy())
+            if self._window_tables:
+                self._sync_window_metrics()
             self.metrics.queue_depth.set(len(self._queue))
             self.metrics.estimated_drain_s.set(self.estimated_drain_s())
             self._sync_prefix_metrics()
@@ -1215,6 +1277,18 @@ class Engine:
                 counter.inc(delta)
                 self._prefix_seen[key] = stats[key]
         m.prefix_cache_pages.set(stats["cached_pages"])
+
+    def _sync_window_metrics(self):
+        """The two pools' pages in use and the window pages given back
+        since the last call (the cache counts, the registry wants a
+        monotonic counter)."""
+        m, cache = self.metrics, self.cache
+        m.pages_in_use_full.set(cache.num_used_pages)
+        m.pages_in_use_window.set(cache.num_used_window_pages)
+        delta = cache.window_pages_released - self._window_released_seen
+        if delta:
+            m.window_pages_released.inc(delta)
+            self._window_released_seen = cache.window_pages_released
 
     def prefix_summary(self, max_entries=32):
         """Bounded radix-tree summary for cache-aware routing — the

@@ -21,6 +21,16 @@ recurrent layer's state).  A sequence is bound to its row slot when it is
 allocated and released with its pages.  Without ``state=`` the manager
 holds the two pools of a model with ``num_heads`` equal heads.
 
+Window pools (kind ``"window_pages"``: the pages of sliding-window
+attention layers) have a page axis of their own size, their own free list
+and their own table per sequence.  They are allocated and extended with
+the sequence's other pages, position for position, but a page whose every
+position lies behind the window is given back (``release_window``), so a
+sequence holds a number of them that does not grow with its context.  A
+released page's entry in the table stays where it was and is never read.
+They take no part in the prefix cache, copy-on-write or ``defrag``: a
+model that has them is served cold.
+
 Allocation is chunk-granular: the engine's chunked-prefill scheduler
 ``allocate``s only a prompt's first chunk at admission and ``extend``s
 the table as later chunks (and decode tokens) land, so a long prompt
@@ -68,7 +78,7 @@ import threading
 
 import jax.numpy as jnp
 
-__all__ = ["PagedKVCache", "prefix_hashes"]
+__all__ = ["PagedKVCache", "prefix_hashes", "window_pages_per_row"]
 
 #: chain hash of the empty prefix (the radix root)
 _ROOT_HASH = "radix-root"
@@ -102,6 +112,13 @@ def prefix_hashes(token_ids, page_size, max_pages=64):
         h = _chunk_hash(h, key)
         out.append(h)
     return out
+
+
+def window_pages_per_row(window, page_size, chunk_len):
+    """Pages of a window pool one row can hold: the window behind its
+    chunk's first token and the chunk, and one more for where they start
+    in a page.  It does not grow with the context."""
+    return -(-(window + chunk_len) // page_size) + 1
 
 
 class _PrefixNode:
@@ -153,7 +170,7 @@ class PagedKVCache:
         self.arrays = {}
         self._kinds = {}
         for name, shape, dt, kind in state:
-            if kind not in ("pages", "slots"):
+            if kind not in ("pages", "slots", "window_pages"):
                 raise ValueError(f"state {name!r}: kind {kind!r}")
             if kind == "pages" and shape[1] != self.num_pages:
                 raise ValueError(f"state {name!r}: axis 1 of {shape} is "
@@ -165,6 +182,15 @@ class PagedKVCache:
             raise ValueError(f"slot states disagree on the rows: {slots}")
         self.num_slots = slots.pop() if slots else None
         self._slot_of = {}         # seq_id -> batch row, where bound
+        window = {self.arrays[n].shape[1]
+                  for n in self._of_kind("window_pages")}
+        if len(window) > 1:
+            raise ValueError(f"window pools disagree on the pages: {window}")
+        self.num_window_pages = window.pop() if window else 0
+        self._window_free = list(range(self.num_window_pages - 1, -1, -1))
+        self._window_tables = {}   # seq_id -> [physical window page ids]
+        self._window_first = {}    # seq_id -> logical pages given back
+        self.window_pages_released = 0      # monotonic, for the counter
         # LIFO free list: recently-freed (still-warm) pages are reused first
         self._free = list(range(num_pages - 1, -1, -1))
         self._tables = {}          # seq_id -> [physical page ids]
@@ -264,6 +290,51 @@ class PagedKVCache:
     def can_allocate(self, num_tokens):
         return self.pages_for(num_tokens) <= self.num_free_pages
 
+    @property
+    def num_used_window_pages(self):
+        return self.num_window_pages - len(self._window_free)
+
+    def _take_window_locked(self, seq_id, num_tokens):
+        """Window pages for ``seq_id``'s table to cover ``num_tokens``
+        (all or nothing): the ids, or None when the window pools cannot
+        cover them.  [] for a model without window pools."""
+        if not self.num_window_pages:
+            return []
+        need = self.pages_for(num_tokens) - len(
+            self._window_tables.get(seq_id, ()))
+        if need > len(self._window_free):
+            return None
+        return [self._window_free.pop() for _ in range(max(need, 0))]
+
+    def _take_both_locked(self, seq_id, num_tokens, need):
+        """``need`` pages and the window pages for ``seq_id`` to cover
+        ``num_tokens``, from both groups or from neither: ``(pages, window
+        pages)`` or None."""
+        window = self._take_window_locked(seq_id, num_tokens)
+        pages = None if window is None else self._take_pages_locked(need)
+        if pages is None:
+            self._window_free.extend(window or ())
+            return None
+        return pages, window
+
+    def release_window(self, seq_id, first_position):
+        """Give back ``seq_id``'s window pages whose every position is
+        below ``first_position`` (the first one its window layers still
+        read).  Their table entries stay and are never read again.
+        Returns the number of pages released."""
+        table = self._window_tables.get(seq_id)
+        if table is None:
+            return 0
+        first = self._window_first[seq_id]
+        upto = min(max(first_position, 0) // self.page_size, len(table))
+        if upto <= first:
+            return 0
+        with self._lock:
+            self._window_free.extend(table[first:upto])
+            self._window_first[seq_id] = upto
+            self.window_pages_released += upto - first
+        return upto - first
+
     def seq_ids(self):
         return list(self._tables)
 
@@ -283,12 +354,16 @@ class PagedKVCache:
                 f"seq {seq_id!r}: {num_tokens} tokens need {need} pages > "
                 f"max_pages_per_seq {self.max_pages_per_seq}")
         with self._lock:
-            pages = self._take_pages_locked(need)
-            if pages is None:
+            taken = self._take_both_locked(seq_id, num_tokens, need)
+            if taken is None:
                 return False
+            pages, window = taken
             if slot is not None:
                 self._slot_of[seq_id] = slot
             self._tables[seq_id] = pages
+            if self.num_window_pages:
+                self._window_tables[seq_id] = window
+                self._window_first[seq_id] = 0
         return True
 
     def extend(self, seq_id, num_tokens):
@@ -305,10 +380,13 @@ class PagedKVCache:
                 f"seq {seq_id!r}: extend to {num_tokens} tokens exceeds "
                 f"max_pages_per_seq {self.max_pages_per_seq}")
         with self._lock:
-            pages = self._take_pages_locked(need)
-            if pages is None:
+            taken = self._take_both_locked(seq_id, num_tokens, need)
+            if taken is None:
                 return False
+            pages, window = taken
             table.extend(pages)
+            if self.num_window_pages:
+                self._window_tables[seq_id].extend(window)
         return True
 
     def free(self, seq_id):
@@ -321,6 +399,9 @@ class PagedKVCache:
             for p in self._tables.pop(seq_id):
                 self._release_page_locked(p)
             self._slot_of.pop(seq_id, None)
+            first = self._window_first.pop(seq_id, 0)
+            self._window_free.extend(
+                self._window_tables.pop(seq_id, ())[first:])
 
     def reset(self):
         """Free everything — tables, prefix cache, refcounts — and zero
@@ -335,6 +416,10 @@ class PagedKVCache:
             self._evictable = 0
             self._evict_heap = []
             self._slot_of.clear()
+            self._window_free = list(range(self.num_window_pages - 1, -1,
+                                           -1))
+            self._window_tables.clear()
+            self._window_first.clear()
             for name, a in self.arrays.items():
                 self.arrays[name] = jnp.zeros_like(a)
 
@@ -476,6 +561,11 @@ class PagedKVCache:
         zero-ref cached page."""
         if seq_id in self._tables:
             raise ValueError(f"seq {seq_id!r} already allocated")
+        if self.num_window_pages:
+            raise ValueError(
+                "prefix reuse with window pools: a cached prefix's window "
+                "pages were given back as its sequence moved on, so it "
+                "cannot be resumed; such a model is admitted cold")
         self._check_slot(seq_id, slot)
         n = len(token_ids)
         with self._lock:
@@ -657,6 +747,24 @@ class PagedKVCache:
                 assert set(self._slot_of) == set(self._tables), \
                     (f"sequences without a row of state: "
                      f"{set(self._tables) - set(self._slot_of)}")
+            held = [p for sid, t in self._window_tables.items()
+                    for p in t[self._window_first[sid]:]]
+            assert len(held) == len(set(held)), \
+                "a window page is in two tables"
+            assert len(self._window_free) == len(set(self._window_free)), \
+                "window free list holds duplicates (double free)"
+            assert not (set(self._window_free) & set(held)), \
+                "window page both free and held"
+            assert len(self._window_free) + len(held) \
+                == self.num_window_pages, \
+                "window pages leaked: free + held != pool"
+            assert set(self._window_tables) == (
+                set(self._tables) if self.num_window_pages else set()), \
+                "a sequence's window table outlived it (or never was)"
+            for name in self._of_kind("window_pages"):
+                assert self.arrays[name].shape[1] \
+                    == self.num_window_pages, \
+                    f"{name}: {self.arrays[name].shape} lost its pages"
             for page, node in self._tree_pages.items():
                 assert node.page == page, \
                     f"tree-page map drift: {page} -> node.page {node.page}"
@@ -680,6 +788,17 @@ class PagedKVCache:
         width = width or self.max_pages_per_seq
         table = self._tables[seq_id]
         return table + [0] * (width - len(table))
+
+    def window_page_table(self, seq_id, width=None):
+        """seq_id's window table padded with 0 to ``width``; the entries
+        of pages given back are stale, and like the padding never read."""
+        width = width or self.max_pages_per_seq
+        table = self._window_tables[seq_id]
+        return table + [0] * (width - len(table))
+
+    def window_pages_held(self, seq_id):
+        return len(self._window_tables[seq_id]) \
+            - self._window_first[seq_id]
 
     # -------------------------------------------------------------- defrag
     def defrag(self):

@@ -26,6 +26,11 @@ process-wide registry); this module keeps the serving-shaped facade:
                  belongs to the n-th call.  The engine keeps one step in
                  flight: pack and dispatch are of the program the call
                  sends, device_wait to commit of the one before it
+  pages_in_use / window_pages_released — a model with sliding-window
+                 layers: pages held by pool, and window pages given back
+  expert_pairs / expert_weight_reads / expert_rows_max — a sparse-expert
+                 model: pairs computed on the held experts, experts whose
+                 weights a step read, and how uneven the routing was
   steps_dispatched / pipeline_drains / overrun_rows — how often the
                  step in flight hid the host's phases, how often it had
                  to be settled first and why, and the rows computed for
@@ -201,6 +206,33 @@ class ServingMetrics:
             help="bytes of per-row recurrent state the cache manager "
                  "holds (0 for a model that keeps none)"))
         self.page_occupancy = add(Gauge("serving_page_occupancy"))
+        self.pages_in_use = add(Gauge(
+            "serving_pages_in_use", labelnames=("pool",),
+            help="pages some sequence holds, by pool, for a model with "
+                 "sliding-window layers: pool=full follows a row's whole "
+                 "context, pool=window holds what the window still "
+                 "reaches (the pages behind it are given back)"))
+        self.pages_in_use_full = self.pages_in_use.labels(pool="full")
+        self.pages_in_use_window = self.pages_in_use.labels(pool="window")
+        self.window_pages_released = add(Counter(
+            "serving_window_pages_released_total",
+            help="window-layer pages given back to their pool because "
+                 "every position in them lay behind the row's window"))
+        self.expert_pairs = add(Counter(
+            "serving_expert_pairs_total",
+            help="(token, expert) pairs the experts held here computed, "
+                 "over all sparse layers; counted on the device, read "
+                 "with the step's ids"))
+        self.expert_weight_reads = add(Counter(
+            "serving_expert_weight_reads_total",
+            help="(layer, held expert)s that got at least one pair in a "
+                 "step: each is one read of that expert's three matrices; "
+                 "counted on the device, read with the step's ids"))
+        self.expert_rows_max = add(Gauge(
+            "serving_expert_rows_max",
+            help="the fullest held expert's pairs in one layer of the "
+                 "last step, over the mean a held expert got in a layer "
+                 "of that step (1 = even routing)"))
         self.queue_depth = add(Gauge(
             "serving_queue_depth",
             help="requests waiting in the admission queue"))

@@ -9,7 +9,11 @@ the state that step carries.
   cache manager builds, in the order the step takes and returns them, as
   ``(name, shape, dtype, kind)``; kind ``"pages"`` has the physical page
   on axis 1 (allocated, shared, copied and compacted page by page), kind
-  ``"slots"`` the batch row (one fixed state per in-flight request).
+  ``"slots"`` the batch row (one fixed state per in-flight request),
+  kind ``"window_pages"`` a page axis of its own size
+  (``num_window_pages=``, passed to a model whose ``window`` is set): the
+  pools of sliding-window layers, with a page table of their own whose
+  pages behind the window are given back.
 - ``make_step(max_q=, mesh=)``: ``step(params, state, batch) -> (logits
   [B, V], state)`` with ``state`` the tuple of pools and ``batch`` the
   scheduler's ``RaggedBatch`` (``models/ragged.py`` owns the format);
@@ -27,13 +31,19 @@ the state that step carries.
   ``serving_attention_positions_total`` counter.
 - ``shard(params, mesh)``: GSPMD serving, where the model has it
   (``make_step(mesh=...)`` raises ``NotImplementedError`` where not).
+- optional: ``window`` (positions a sliding-window layer reads; the
+  engine then keeps the second page table and budget), and ``step_stats``
+  (names of int32 numbers the step counts on the device and returns after
+  its state; they reach the host behind the ids and are handed to
+  ``record_stats(metrics, values)``).
 """
 from __future__ import annotations
 
 import jax
 import numpy as np
 
-__all__ = ["GPTServed", "HybridServed", "SSMServed", "as_served"]
+__all__ = ["GPTServed", "HybridServed", "SSMServed", "MoEWindowServed",
+           "as_served"]
 
 
 class GPTServed:
@@ -185,6 +195,65 @@ class SSMServed:
         return n, n
 
 
+class MoEWindowServed:
+    """The sparse-expert decoder with sliding-window layers beside full
+    ones (``models/moe_window.py``): two groups of page pools, and an
+    expert layer that holds a share of the experts.  Served cold: a cached
+    prefix whose window pages were given back cannot be resumed."""
+
+    recurrent = False
+    step_stats = ("expert_pairs", "expert_rows_fullest", "experts_read")
+
+    def __init__(self, cfg):
+        self.cfg = cfg
+        self.window = cfg.window
+
+    def init_params(self):
+        from ..models.moe_window import moe_window_init
+
+        return moe_window_init(self.cfg)
+
+    def state_spec(self, **sizes):
+        from ..models.moe_window import moe_window_state_spec
+
+        return moe_window_state_spec(self.cfg, **sizes)
+
+    def make_step(self, *, max_q, mesh=None):
+        from ..models.moe_window import moe_window_ragged_step
+
+        if mesh is not None:
+            raise NotImplementedError(
+                "Engine(mesh=...) with sparse experts and window layers: "
+                "the experts shard by expert (an `ep` axis the mesh does "
+                "not have, and an exchange of tokens between holders), "
+                "the two groups of pools by key/value head, and neither "
+                "has a rule table or a shard_map around its kernel yet "
+                "(PERF.md, open questions)")
+        cfg = self.cfg
+
+        def step(params, state, batch):
+            logits, *state, stats = moe_window_ragged_step(
+                cfg, params, batch, *state, max_q=max_q)
+            return logits, tuple(state), stats
+
+        return jax.jit(step)       # as GPTServed.make_step
+
+    def attention_positions(self, ctx, q):
+        # per layer: a full layer's token at position p reads p + 1
+        # positions, a window layer's the last `window` of them
+        n = np.arange(ctx - q + 1, ctx + 1, dtype=np.int64)
+        return int(n.sum()), int(np.minimum(n, self.window).sum())
+
+    def record_stats(self, metrics, values):
+        from ..models.moe_window import SPARSE
+
+        pairs, fullest, read = values
+        metrics.expert_pairs.inc(pairs)
+        metrics.expert_weight_reads.inc(read)
+        slots = self.cfg.mlp_types.count(SPARSE) * self.cfg.experts_held[1]
+        metrics.expert_rows_max.set(fullest * slots / pairs if pairs else 0)
+
+
 def as_served(model):
     """``model`` if it already has the interface, else the served form of
     a known config object."""
@@ -201,5 +270,9 @@ def as_served(model):
 
     if isinstance(model, SSMConfig):
         return SSMServed(model)
+    from ..models.moe_window import MoEWindowConfig
+
+    if isinstance(model, MoEWindowConfig):
+        return MoEWindowServed(model)
     raise TypeError(f"Engine cannot serve a {type(model).__name__}: pass a "
                     f"served-model object (paddle_tpu.serving.model)")

@@ -358,8 +358,16 @@ class TestTelemetryServerE2E:
         # private tracer: the process-wide one carries traces from other
         # tests, and this test counts exactly its own two requests
         eng = _tiny_engine(tracer=Tracer())
-        eng.metrics = ServingMetrics()          # fresh global series
-        with start_telemetry_server(port=0, engine=eng) as srv:
+        # and a private registry: /healthz folds process-wide gauges
+        # (training_healthy, slo_page_active, hang_watchdog_active,
+        # integrity_divergence_active) on top of the engine's health, and
+        # one that another test file left raised in this worker's default
+        # registry turned this probe to 503 (the one failure of the
+        # driver's whole run at PR 34: order, not timing)
+        reg = MetricsRegistry()
+        eng.metrics = ServingMetrics(registry=reg)
+        with start_telemetry_server(port=0, engine=eng,
+                                    registry=reg) as srv:
             assert srv.port > 0
             eng.generate([[1, 2, 3], [4, 5]],
                          SamplingParams(max_new_tokens=3))

@@ -234,3 +234,69 @@ def test_empty_batch_is_all_idle_rows_and_padding_slots():
                  "page_tables"):
         assert not getattr(batch, name).any(), name
     assert not bool(_view(batch).valid.any())
+
+
+# ------------------------------------------------- tiles and window tables
+
+
+@pytest.mark.parametrize("n", [2, 4, 8])
+def test_tiles_hold_one_rows_query_slots_each_and_unpad_inverts(n):
+    """Row 0's chunk of 5 in tiles of ``n`` slots, one tile for each decode
+    row, none for the idle row; ``B + T / n`` tiles in all, the rest
+    unused (row ``B``)."""
+    batch = _mixed()
+    v = _view(batch)
+    rows, index = (np.asarray(a) for a in v.tiles(n))
+    per_row = -(-batch.query_lens // n)
+    assert len(rows) == B + -(-T // n)
+    want_rows = np.repeat(np.arange(B), per_row)
+    assert rows[: per_row.sum()].tolist() == want_rows.tolist()
+    assert (rows[per_row.sum():] == B).all()
+    assert index[: per_row.sum()].tolist() == [
+        t for k in per_row for t in range(k)]
+    a = jnp.asarray(np.arange(1, T + 1) * 10, jnp.float32)
+    tiles = v.pad_tiles(a, n, fill=-1.0)
+    assert tiles.shape == (len(rows), n)
+    np.testing.assert_array_equal(np.asarray(v.unpad_tiles(tiles))[:N_VALID],
+                                  np.asarray(a)[:N_VALID])
+    # slot s of a row's chunk sits in the row's tile s // n, lane s % n
+    first = np.cumsum(per_row) - per_row
+    for t in range(N_VALID):
+        r, s = int(batch.rows[t]), int(batch.slots[t])
+        assert float(tiles[first[r] + s // n, s % n]) == float(a[t])
+    assert int((np.asarray(tiles) != -1.0).sum()) == N_VALID
+
+
+def test_a_window_batch_carries_a_second_table_and_a_second_target():
+    from paddle_tpu.models.ragged import WindowRaggedBatch
+
+    plain = empty_batch(B, T, MAX_PAGES)
+    assert type(plain) is RaggedBatch and len(plain) == 6
+    batch = empty_batch(B, T, MAX_PAGES, window_tables=True)
+    assert type(batch) is WindowRaggedBatch
+    assert batch._fields == RaggedBatch._fields + ("window_page_tables",)
+    assert batch.window_page_tables.shape == (B, MAX_PAGES)
+    shapes = batch_shapes(B, T, MAX_PAGES, window_tables=True)
+    assert [s.shape for s in shapes] == [a.shape for a in batch]
+    mixed = _mixed()
+    other = (np.arange(B * MAX_PAGES, dtype=np.int32) % 7).reshape(
+        B, MAX_PAGES)
+    v = RaggedView(WindowRaggedBatch(*map(jnp.asarray, mixed),
+                                     jnp.asarray(other)),
+                   max_q=Q, max_seq_len=MAX_SEQ, num_pages=NUM_PAGES,
+                   page_size=PS, num_window_pages=7)
+    pos = np.asarray(v.pos)[:N_VALID]
+    rows = np.asarray(v.row)[:N_VALID]
+    assert np.asarray(v.window_page)[:N_VALID].tolist() == \
+        other[rows, pos // PS].tolist()
+    assert np.asarray(v.page)[:N_VALID].tolist() == \
+        mixed.page_tables[rows, pos // PS].tolist()
+    # a masked token's target is out of either pool's range
+    assert (np.asarray(v.window_page)[N_VALID:] == 7).all()
+    assert (np.asarray(v.page)[N_VALID:] == NUM_PAGES).all()
+    assert not hasattr(_view(mixed), "window_page")
+    # a pending token is resolved in either kind of batch
+    out = resolve_pending(v.batch._replace(
+        tokens=v.batch.tokens.at[5].set(pending_token(1))),
+        jnp.asarray([7, 8, 9, 10], jnp.int32))
+    assert type(out) is WindowRaggedBatch and int(out.tokens[5]) == 8

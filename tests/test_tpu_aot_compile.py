@@ -190,3 +190,38 @@ def test_ssm_serve_step_compiles_for_v5e_and_fits_the_chip(devices):
                           rf"(copy|transpose|dynamic-update-slice|fusion)\(",
                           line)]
     assert not movers, "scan state movers:\n" + "\n".join(movers)
+
+
+def test_moe_window_serve_step_compiles_for_v5e_and_fits_the_chip(devices):
+    """The sparse-expert step with sliding-window layers at the benchmark
+    cell's shapes — published widths, 8 layers, 64 of 256 experts, 25,088
+    ids, 64 rows, chunks of 1024 (1,088 packed tokens), 2,240 full-layer and
+    256 window-layer pages of 512: Mosaic accepts the grouped expert product
+    (two calls in each of the 7 sparse layers) and the grouped-heads
+    attention kernel at groups of 6 and of 9 with the window's lower edge
+    (8 calls), all four page pools are donated and aliased, no layer's
+    experts are sliced out of their stack (384 MB a matrix: what the first
+    compile of this step planned), and the plan fits the chip before any
+    chip time is spent: 9.37 GB of weights, 2.35 GB of full-layer pages and
+    0.81 GB of window-layer pages among 12.52 GB of arguments."""
+    compiled = tpu_aot.lower_moe_window_serve_step(devices).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert text.count('custom_call_target="tpu_custom_call"') == 8 + 2 * 7
+    pools = 2 * 2 * 2240 * 2 * 512 * 128 * 2 + 2 * 6 * 256 * 2 * 512 * 128 * 2
+    assert mem.alias_size_in_bytes == pools == 3154116608
+    weights = 4_683_660_288 * 2
+    assert weights + pools < mem.argument_size_in_bytes < weights + pools \
+        + 2 ** 20
+    assert mem.temp_size_in_bytes < 1.2e9
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes) < 2 ** 34
+    movers = [line.strip()[:160] for line in text.splitlines()
+              if re.match(r"\s+(?:ROOT )?%\S+ = bf16\[(2,2240|6,256),2,512,"
+                          r"128\]\S* (copy|transpose|dynamic-update-slice)\(",
+                          line)]
+    assert not movers, "key/value pool movers:\n" + "\n".join(movers)
+    sliced = [line.strip()[:160] for line in text.splitlines()
+              if re.match(r"\s+(?:ROOT )?%\S+ = bf16\[64,(3072,1024|1024,"
+                          r"3072)\]", line)]
+    assert not sliced, "a layer's experts out of their stack:\n" \
+        + "\n".join(sliced)

@@ -174,9 +174,13 @@ def lower_serve_step(devices, num_pages=1024, max_batch_size=8,
 
 
 def _lower_recurrent_serve_step(devices, cfg, init, num_pages,
-                                max_batch_size, chunk_len, page_size):
-    """The unified step of a served model with per-row state, on one
-    device, every state pool at its real shape and donated."""
+                                max_batch_size, chunk_len, page_size,
+                                num_window_pages=None,
+                                held_window_pages=None):
+    """The unified step of a served model with state of its own kinds
+    (per-row state, or window pools: ``num_window_pages`` lowered,
+    ``held_window_pages`` held here), on one device, every state pool at
+    its real shape and donated."""
     from paddle_tpu.serving import Engine
 
     params = jax.eval_shape(lambda: init(cfg))
@@ -184,12 +188,16 @@ def _lower_recurrent_serve_step(devices, cfg, init, num_pages,
     with as_if_on_tpu():
         # 1 page held here (and the rows' state, on the host); the lowered
         # shapes are the real ones
+        window = {} if num_window_pages is None \
+            else {"num_window_pages": num_window_pages}
         eng = Engine(cfg, params, page_size=page_size, num_pages=1,
-                     max_batch_size=max_batch_size, chunk_len=chunk_len)
+                     max_batch_size=max_batch_size, chunk_len=chunk_len,
+                     num_window_pages=held_window_pages)
         state = [(shape, dtype) for _, shape, dtype, _ in
                  eng.model.state_spec(num_pages=num_pages,
                                       page_size=page_size,
-                                      max_batch_size=max_batch_size)]
+                                      max_batch_size=max_batch_size,
+                                      **window)]
         return eng._step_fn.lower(*eng.step_args(
             jax.tree_util.tree_map(
                 lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
@@ -222,6 +230,26 @@ def lower_ssm_serve_step(devices, num_pages=256, max_batch_size=64,
         chunk_len, page_size)
 
 
+def lower_moe_window_serve_step(devices, num_pages=2240,
+                                num_window_pages=256, max_batch_size=64,
+                                chunk_len=1024, page_size=512,
+                                config="laguna-s-2.1-8l"):
+    """The unified step of the sparse-expert decoder with sliding-window
+    layers at the benchmark cell's knobs, on one device: its two groups of
+    page pools donated, both page tables in the batch."""
+    from paddle_tpu.models.moe_window import (MOE_WINDOW_CONFIGS,
+                                              moe_window_init)
+    from paddle_tpu.serving.kv_cache import window_pages_per_row
+
+    cfg = MOE_WINDOW_CONFIGS[config]
+    # one row's window pages held here, on the host
+    return _lower_recurrent_serve_step(
+        devices, cfg, moe_window_init, num_pages, max_batch_size, chunk_len,
+        page_size, num_window_pages=num_window_pages,
+        held_window_pages=window_pages_per_row(cfg.window, page_size,
+                                               chunk_len))
+
+
 def _report(name, compile_fn):
     t0 = time.perf_counter()
     compiled = compile_fn()
@@ -252,6 +280,8 @@ def main(argv):
                   lambda: lower_hybrid_serve_step(devices).compile())
     ok &= _report("serve ssm 6l B64 chunk128 256 pages of 512",
                   lambda: lower_ssm_serve_step(devices).compile())
+    ok &= _report("serve moe-window 8l B64 chunk1024 2240 + 256 pages of 512",
+                  lambda: lower_moe_window_serve_step(devices).compile())
     if "--four" in argv:
         ok &= _report("train pp=2 x mp=2",
                       lambda: lower_train_step(devices, pp=2, mp=2).compile())
