@@ -82,14 +82,27 @@ which pages are fetched, the lists decide what a token reads); without it
 the flags are scattered from the lists.
 So one call serves dense rows, sparse decode rows and sparse prefill
 chunks, each chunk token with its own blocks.  The kernel walks a flat
-list of (row, group, 128-token query tile, page) items built from the
-lists with plain ``jnp``: a page that no token of a tile selected is
-never fetched, the rest are masked per token inside the kernel, and the
-grid is as long as the list (a dynamic grid bound), so a decode row
-costs its K pages and not the width of the page table.  This path is
-chosen statically by ``selected is not None``; it takes one page an item
-through a ``BlockSpec``, and shares no code with the equal-heads kernel
-above.
+list of (row, group, 128-token query tile, up to ``pages`` listed pages)
+items built from the lists with plain ``jnp`` (``listed_work_items``): a
+page that no token of a tile selected is never fetched, the rest are masked
+per token inside the kernel, and the grid is as long as the list (a dynamic
+grid bound), so a decode row costs its K pages and not the width of the
+page table.  ``pages`` follows from the page size alone: whole pages
+covering ``_LISTED_KEY_TILE`` = 512 key positions, 8 pages of 64, one page
+of 512 (settled on the chip, PERF.md §6 PR 36).  A segment's pages go into
+its items in ascending order, so only its last item may be partly filled.
+The pools stay in HBM and an item's pages are copied ``[layer, table[row,
+lpage], group] -> VMEM`` into one of two buffers, the next item's copies in
+flight while this one is multiplied — ``_fetch_pages``, the equal-heads
+kernel's own fetch — and a slot of an item that holds no page is neither
+fetched nor waited for, is masked out of the scores and meets zeros or old
+pool values in the value buffer.  Both products run once an item over its
+``pages * page_size`` positions, and the running maximum, sum and the
+accumulator's rescale once an item; the per-token rules (causal edge,
+``dense_len``, the token's own list, ``window``) are applied page by page
+of the item.  This path is chosen statically by ``selected is not None``.
+A step that wants to count the list builds it itself and hands it in
+(``items=``).
 
 A lower edge (``window=``, static, with the grouped mode).  A sliding-window
 layer's token at position ``p`` reads positions ``p - window < j <= p``.
@@ -126,7 +139,8 @@ from jax.experimental.pallas import tpu as pltpu
 from ..ops.linalg import mxu_precision
 from . import dispatch
 
-__all__ = ["ragged_paged_attention", "ragged_work_items"]
+__all__ = ["ragged_paged_attention", "ragged_work_items",
+           "listed_work_items"]
 
 _NEG_INF = -1e30
 
@@ -135,6 +149,13 @@ _NEG_INF = -1e30
 # pages: at least this many), and the query slots its narrow body computes
 _KEY_TILE = 128
 _SMALL_Q = 16
+# key positions one work item of the grouped-heads kernel covers (whole
+# pages: 8 of 64, one of 512).  The wide body's float32 score tile is then
+# [2048, 512] at 16 heads a group, inside the default VMEM limit; 1,024
+# positions need it raised (17 MB) and took 13% less on the chip for the
+# whole call at the long-context cell's shapes, 256 40% more (PERF.md §6
+# PR 36)
+_LISTED_KEY_TILE = 512
 
 
 def _stacked(k_pages, v_pages, layer):
@@ -184,10 +205,28 @@ def _ragged_attention_ref(q, k_pages, v_pages, page_tables, query_lens,
 # ------------------------------------------------------------------- kernel
 
 
-def _pages_per_item(page_size, max_pages):
-    """Pages in one work item: whole pages covering ``_KEY_TILE`` key
+def _pages_per_item(page_size, max_pages, key_tile=_KEY_TILE):
+    """Pages in one work item: whole pages covering ``key_tile`` key
     positions, and never more than a row's page table holds."""
-    return max(1, min(max_pages, -(-_KEY_TILE // page_size)))
+    return max(1, min(max_pages, -(-key_tile // page_size)))
+
+
+def _fetch_pages(pools, bufs, sem, slot, page_of, live, pages, page_size,
+                 wait):
+    """Start (or wait for) the copies of one item's first ``live`` pages,
+    keys and values, out of HBM into buffer ``slot``, page ``j`` at
+    positions ``j * page_size ...``; ``page_of(j)`` is the index of the
+    item's ``j``-th page in a pool.  Pages past ``live`` are neither
+    fetched nor waited for."""
+    for j in range(pages):
+        @pl.when(j < live)
+        def _():
+            src = page_of(j)
+            at = pl.ds(j * page_size, page_size)
+            for p, (hbm, buf) in enumerate(zip(pools, bufs)):
+                copy = pltpu.make_async_copy(
+                    hbm.at[src], buf.at[slot, at], sem.at[p, slot])
+                copy.wait() if wait else copy.start()
 
 
 def ragged_work_items(query_lens, context_lens, page_size, max_pages):
@@ -230,23 +269,16 @@ def _ragged_kernel(row_ref, tile_ref, n_ref, tbl_ref, qlen_ref, ctx_ref,
     slot = i % 2
 
     def fetch(item, slot, wait):
-        """Start (or wait for) the copies of ``item``'s pages, keys and
-        values, out of HBM into buffer ``slot``: the pages its row's
+        """``item``'s pages into buffer ``slot``: the pages its row's
         context reaches and no others."""
         r = row_ref[item]
         page0 = tile_ref[item] * pages
         live = (ctx_ref[r] + page_size - 1) // page_size - page0
-        for j in range(pages):
-            @pl.when(j < live)
-            def _():
-                page = tbl_ref[r, jnp.minimum(page0 + j, max_pages - 1)]
-                at = pl.ds(j * page_size, page_size)
-                for p, (hbm, buf) in enumerate(((kp_hbm, k_buf),
-                                                (vp_hbm, v_buf))):
-                    copy = pltpu.make_async_copy(
-                        hbm.at[layer, page], buf.at[slot, at],
-                        sem.at[p, slot])
-                    copy.wait() if wait else copy.start()
+        _fetch_pages(
+            (kp_hbm, vp_hbm), (k_buf, v_buf), sem, slot,
+            lambda j: (layer,
+                       tbl_ref[r, jnp.minimum(page0 + j, max_pages - 1)]),
+            live, pages, page_size, wait)
 
     @pl.when(i == 0)
     def _prime():
@@ -439,22 +471,125 @@ def _work_items(visit, n_max):
     return seg, w, ends[-1]
 
 
-def _listed_kernel(seg_ref, lp_ref, n_ref, tbl_ref, qlen_ref, ctx_ref,
-                   layer_ref, q_ref, sel_ref, kp_ref, vp_ref, o_ref, acc_ref,
-                   m_ref, l_ref, *, scale, page_size, groups, tiles, tile,
-                   small, dense_len, n_max, window):
-    del tbl_ref, layer_ref            # only the page index_maps read them
+def _paged_work_items(visit, n_max, pages):
+    """``_work_items`` with up to ``pages`` entries of one segment an item:
+    segment by segment, a segment's entries in ascending order,
+    ``ceil(count / pages)`` items a segment, so only its last one may be
+    partly filled.  Returns ``(seg [n_max], w [n_max * pages], count
+    [n_max], n)`` int32 with the first ``n`` items in use: item ``i`` is
+    ``count[i]`` entries of segment ``seg[i]``, ``w[i * pages : i * pages +
+    count[i]]`` (a flat array: it rides in scalar memory).  (The tiled
+    mode keeps the list of single entries above until its body takes items
+    of several pages too.)"""
+    S, W = visit.shape
+    counts = visit.sum(-1).astype(jnp.int32)
+    per_seg = -(-counts // pages)
+    ends = jnp.cumsum(per_seg)
+    i = jnp.arange(n_max, dtype=jnp.int32)
+    seg = jnp.minimum(jnp.sum(i[:, None] >= ends[None, :], axis=1),
+                      S - 1).astype(jnp.int32)
+    rank = (i - (ends - per_seg)[seg]) * pages    # of the item's first entry
+    order = jnp.argsort(~visit, axis=-1, stable=True).astype(jnp.int32)
+    at = rank[:, None] + jnp.arange(pages, dtype=jnp.int32)[None, :]
+    w = order[seg[:, None], jnp.clip(at, 0, W - 1)]
+    count = jnp.clip(counts[seg] - rank, 0, pages)
+    return seg, w.reshape(-1), count, ends[-1]
+
+
+def listed_work_items(query_lens, context_lens, page_size, max_pages, max_q,
+                      groups, selected=(None, 2 ** 30), total_q=None,
+                      window=None):
+    """The grouped-heads kernel's grid, as a list: one item for every (row,
+    key/value group, 128-token query tile) and every ``pages`` logical
+    pages, in ascending order, that some token of the tile reads (its list,
+    or within ``dense_len`` everything up to its own position, from the
+    ``window``'s edge on).  ``selected``, ``total_q`` and ``window`` are
+    ``ragged_paged_attention``'s, ``max_q`` its padded query width ``Q``,
+    ``groups`` the pool's ``Hkv``.  Returns ``(seg, lpages, count, n)``:
+    per item its segment ``(row * groups + group) * tiles + tile``, its
+    logical pages ``lpages[i * pages : i * pages + count[i]]`` and how
+    many they are, with the first ``n [1]`` items in use; ``pages`` follows
+    from the page size (``_LISTED_KEY_TILE`` key positions an item).  A
+    step hands it to the layer's call (``items=``) and may count it:
+    ``n`` items holding ``count.sum()`` pages."""
+    B, Q, Hkv, W = query_lens.shape[0], max_q, groups, max_pages
+    sel_blocks, dense_len, *sel_mask = selected
+    tile = min(128, Q)
+    if Q % tile:
+        raise ValueError(f"chunk width {Q} is not a multiple of {tile}")
+    tiles = Q // tile
+    pages = _pages_per_item(page_size, W, _LISTED_KEY_TILE)
+    pos, valid = _token_positions(Q, query_lens, context_lens)
+    # the pages a token reads: its list, or (a dense token) all up to its own
+    w = jnp.arange(W)
+    reach = w[None, None, :] <= pos[:, :, None] // page_size
+    if window is not None:
+        # the first position the token's window holds, and its page
+        reach = reach & (w[None, None, :] >= jnp.maximum(
+            pos - window + 1, 0)[:, :, None] // page_size)
+    reads = reach[:, None]
+    if sel_blocks is not None:
+        # (the caller's mask where it has one: scattering K indices a token
+        # into W flags took 5.6 ms a call at 16 x 2 x 512 x 64 into 544)
+        listed = sel_mask[0] if sel_mask else _selected_onehot(sel_blocks, W)
+        reads = listed | (reads & (pos + 1 <= dense_len)[:, None, :, None])
+    reads = jnp.broadcast_to(reads & valid[:, None, :, None],
+                             (B, Hkv, Q, W))
+    visit = reads.reshape(B, Hkv, tiles, tile, W).any(3)
+    # pages one tile can reach: all of them, or its tokens' windows
+    per_tile = W if window is None else min(
+        W, (window + tile - 2) // page_size + 2)
+    # tiles that hold a token: one a row and one more per `tile` tokens
+    n_max = Hkv * min(B + -(-(total_q or B * Q) // tile),
+                      B * tiles) * -(-per_tile // pages)
+    seg, lpages, count, n = _paged_work_items(
+        visit.reshape(B * Hkv * tiles, W), n_max, pages)
+    return seg, lpages, count, n.reshape(1).astype(jnp.int32)
+
+
+def _listed_kernel(seg_ref, lp_ref, cnt_ref, n_ref, tbl_ref, qlen_ref,
+                   ctx_ref, layer_ref, q_ref, sel_ref, kp_hbm, vp_hbm, o_ref,
+                   k_buf, v_buf, sem, acc_ref, m_ref, l_ref, *, scale,
+                   page_size, pages, groups, tiles, tile, small, dense_len,
+                   window):
     i = pl.program_id(0)
+    n = n_ref[0]
+    layer = layer_ref[0]
+    n_max = seg_ref.shape[0]
     seg = seg_ref[i]
     first = (i == 0) | (seg_ref[jnp.maximum(i - 1, 0)] != seg)
-    last = (i == n_ref[0] - 1) | (seg_ref[jnp.minimum(i + 1, n_max - 1)]
-                                  != seg)
+    last = (i == n - 1) | (seg_ref[jnp.minimum(i + 1, n_max - 1)] != seg)
     b = seg // (groups * tiles)
     qt = seg % tiles
-    lpage = lp_ref[i]
     q_len, ctx = qlen_ref[b], ctx_ref[b]
-    start = lpage * page_size
     in_tile = q_len - qt * tile       # query tokens of the row in this tile
+    count = cnt_ref[i]
+    slot = i % 2
+    T = pages * page_size
+
+    def fetch(item, slot, wait):
+        """``item``'s listed pages of its row and group into buffer
+        ``slot``."""
+        s = seg_ref[item]
+        r, g = s // (groups * tiles), (s // tiles) % groups
+        _fetch_pages(
+            (kp_hbm, vp_hbm), (k_buf, v_buf), sem, slot,
+            lambda j: (layer, tbl_ref[r, lp_ref[item * pages + j]], g),
+            cnt_ref[item], pages, page_size, wait)
+
+    @pl.when(i == 0)
+    def _prime():
+        # a slot of an item that holds no page is masked out of the scores,
+        # but 0 x (whatever the buffer held) has to be 0 in the second
+        # product: after this the buffers only ever hold pool values
+        v_buf[:] = jnp.zeros_like(v_buf)
+        fetch(0, 0, wait=False)
+
+    @pl.when(i + 1 < n)
+    def _next():
+        fetch(jnp.minimum(i + 1, n_max - 1), 1 - slot, wait=False)
+
+    fetch(i, slot, wait=True)
 
     @pl.when(first)
     def _init():
@@ -463,23 +598,34 @@ def _listed_kernel(seg_ref, lp_ref, n_ref, tbl_ref, qlen_ref, ctx_ref,
         l_ref[:] = jnp.zeros_like(l_ref)
 
     def update(R):
-        """The page against the tile's first ``R`` query slots (static):
-        a decode row pays for ``small`` slots, not for the tile."""
+        """The item's pages against the tile's first ``R`` query slots
+        (static): a decode row pays for ``small`` slots, not for the
+        tile."""
         G = q_ref.shape[2]
         hd = q_ref.shape[4]
         q = q_ref[0, 0, :, :R, :].reshape(G * R, hd)
-        k = kp_ref[0, 0, 0]                          # [ps, hd]
-        v = vp_ref[0, 0, 0]
+        k = k_buf[slot]                              # [T, hd]
+        v = v_buf[slot]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
-        s = s.reshape(G, R, page_size)
+        s = s.reshape(G, R, T)
         tq = qt * tile + jax.lax.broadcasted_iota(jnp.int32, (1, R, 1), 1)
-        kv = start + jax.lax.broadcasted_iota(jnp.int32, (1, 1, page_size),
-                                              2)
         at = ctx - q_len + tq                        # absolute position
-        member = jnp.any(sel_ref[0, 0, :R, :] == lpage, axis=-1,
-                         keepdims=True)[None]        # [1, R, 1]
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, 1, T), 2)
+        sel = sel_ref[0, 0, :R, :]
+        # page by page of the item: the positions its columns hold (a
+        # slot with no page lies behind no token) and whether the token
+        # listed that page
+        kv = jnp.full((1, 1, T), 2 ** 30, jnp.int32)
+        member = jnp.zeros((1, R, T), jnp.bool_)
+        for j in range(pages):
+            lpage = lp_ref[i * pages + j]
+            here = ((col >= j * page_size) & (col < (j + 1) * page_size)
+                    & (j < count))
+            kv = jnp.where(here, (lpage - j) * page_size + col, kv)
+            listed = jnp.any(sel == lpage, axis=-1, keepdims=True)[None]
+            member = member | (listed & here)
         ok = ((kv <= at) & (tq < q_len)
               & ((at + 1 <= dense_len) | member))
         if window is not None:
@@ -493,7 +639,7 @@ def _listed_kernel(seg_ref, lp_ref, n_ref, tbl_ref, qlen_ref, ctx_ref,
         corr = jnp.exp(m_prev - m_new)
         l_ref[:, :R] = l_ref[:, :R] * corr + jnp.sum(p, axis=-1,
                                                      keepdims=True)
-        pv = jnp.dot(p.reshape(G * R, page_size).astype(v.dtype), v,
+        pv = jnp.dot(p.reshape(G * R, T).astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
         acc_ref[:, :R] = acc_ref[:, :R] * corr + pv.reshape(G, R, hd)
         m_ref[:, :R] = m_new
@@ -513,79 +659,57 @@ def _listed_kernel(seg_ref, lp_ref, n_ref, tbl_ref, qlen_ref, ctx_ref,
 
 def _listed_attention_kernel(q, k_pages, v_pages, page_tables, query_lens,
                              context_lens, scale, layer, sel_blocks,
-                             dense_len, total_q, interpret, sel_mask=None,
-                             window=None):
+                             dense_len, items, interpret, window=None):
+    """``items`` is ``listed_work_items`` of the same lengths, lists and
+    ``window``."""
     B, Q, H, hd = q.shape
     Hkv, page_size = k_pages.shape[2], k_pages.shape[3]
     G, W = H // Hkv, page_tables.shape[1]
     tile = min(128, Q)
-    if Q % tile:
-        raise ValueError(f"chunk width {Q} is not a multiple of {tile}")
     tiles, small = Q // tile, min(16, tile)
-    pos, valid = _token_positions(Q, query_lens, context_lens)
+    pages = _pages_per_item(page_size, W, _LISTED_KEY_TILE)
+    seg, lpages, count, n = items
     if sel_blocks is None:
         sel_blocks = jnp.full((B, Hkv, Q, 1), -1, jnp.int32)
         dense_len = 2 ** 30
-    # the pages a token reads: its list, or (a dense token) all up to its own
-    w = jnp.arange(W)
-    dense_tok = pos + 1 <= dense_len
-    # (the caller's mask where it has one: scattering K indices a token
-    # into W flags took 5.6 ms a call at 16 x 2 x 512 x 64 into 544)
-    listed = sel_mask if sel_mask is not None \
-        else _selected_onehot(sel_blocks, W)
-    reach = w[None, None, :] <= pos[:, :, None] // page_size
-    if window is not None:
-        # the first position the token's window holds, and its page
-        reach = reach & (w[None, None, :] >= jnp.maximum(
-            pos - window + 1, 0)[:, :, None] // page_size)
-    reads = listed | (dense_tok[:, :, None] & reach)[:, None]
-    reads = reads & valid[:, None, :, None]
-    visit = reads.reshape(B, Hkv, tiles, tile, W).any(3)
-    # tiles that hold a token: one a row and one more per `tile` tokens
-    # pages one tile can reach: all of them, or its tokens' windows
-    per_tile = W if window is None else min(
-        W, (window + tile - 2) // page_size + 2)
-    n_max = Hkv * (B + -(-(total_q or B * Q) // tile)) * per_tile
-    n_max = min(n_max, B * Hkv * tiles * W)
-    seg, lpage, n = _work_items(visit.reshape(B * Hkv * tiles, W), n_max)
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
 
     def row_of(s):
         return s // (Hkv * tiles), (s // tiles) % Hkv, s % tiles
 
-    def q_block(i, seg, lp, n, tbl, ql, cl, lyr):
+    def q_block(i, seg, *_):
         b, g, qt = row_of(seg[i])
         return (b, g, 0, qt, 0)
 
-    def sel_block(i, seg, lp, n, tbl, ql, cl, lyr):
+    def sel_block(i, seg, *_):
         b, g, qt = row_of(seg[i])
         return (b, g, qt, 0)
 
-    def page_block(i, seg, lp, n, tbl, ql, cl, lyr):
-        b, g, _ = row_of(seg[i])
-        return (lyr[0], tbl[b, lp[i]], g, 0, 0)
-
     K = sel_blocks.shape[-1]
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    buf = (2, pages * page_size, hd)             # two buffers of one item
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=7,
-        grid=(n,),
+        num_scalar_prefetch=8,
+        grid=(n[0],),
         in_specs=[
             pl.BlockSpec((1, 1, G, tile, hd), q_block),
             pl.BlockSpec((1, 1, tile, K), sel_block),
-            pl.BlockSpec((1, 1, 1, page_size, hd), page_block),
-            pl.BlockSpec((1, 1, 1, page_size, hd), page_block),
+            in_hbm, in_hbm,
         ],
         out_specs=pl.BlockSpec((1, 1, G, tile, hd), q_block),
         scratch_shapes=[
+            pltpu.VMEM(buf, k_pages.dtype),
+            pltpu.VMEM(buf, v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.VMEM((G, tile, hd), jnp.float32),
             pltpu.VMEM((G, tile, 1), jnp.float32),
             pltpu.VMEM((G, tile, 1), jnp.float32),
         ],
     )
     kernel = functools.partial(
-        _listed_kernel, scale=scale, page_size=page_size, groups=Hkv,
-        tiles=tiles, tile=tile, small=small, dense_len=dense_len,
-        n_max=n_max, window=window)
+        _listed_kernel, scale=scale, page_size=page_size, pages=pages,
+        groups=Hkv, tiles=tiles, tile=tile, small=small,
+        dense_len=dense_len, window=window)
     q5 = q.reshape(B, Q, Hkv, G, hd).transpose(0, 2, 3, 1, 4)
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
@@ -595,12 +719,12 @@ def _listed_attention_kernel(q, k_pages, v_pages, page_tables, query_lens,
         # tells them from the full layers' (either name holds the other's)
         name="ragged_paged_attention" + ("" if window is None
                                          else "_window"),
-    )(seg, lpage, n.reshape(1).astype(jnp.int32), page_tables, query_lens,
-      context_lens, layer, q5, sel_blocks, k_pages, v_pages)
+    )(seg, lpages, count, n, page_tables, query_lens, context_lens, layer,
+      q5, sel_blocks, k_pages, v_pages)
     out = out.transpose(0, 3, 1, 2, 4).reshape(B, Q, H, hd)
     # tiles that no item visited were never written
+    valid = _token_positions(Q, query_lens, context_lens)[1]
     return jnp.where(valid[:, :, None, None], out, jnp.zeros_like(out))
-
 
 
 # ---------------------------------- grouped heads, queries packed in tiles
@@ -797,7 +921,9 @@ def ragged_paged_attention(q, k_pages, v_pages, page_tables, query_lens,
     TPU and the jnp gather reference elsewhere (identical contract, fp32
     softmax in both).  ``items`` is ``ragged_work_items`` of the same
     lengths, for a caller that makes several calls on them (a step's
-    layers); left out, the kernel builds it.  ``selected=(sel_blocks,
+    layers), or in the grouped-heads mode ``listed_work_items`` of the
+    same lengths and lists, for a caller that counts it; left out, the
+    kernel builds it.  ``selected=(sel_blocks,
     dense_len)`` takes the grouped-heads / selected-pages mode over a
     head-major stacked pool ``[L, P, Hkv, page_size, hd]``; ``total_q``
     (static) then bounds the query tokens of all rows together, which
@@ -831,18 +957,22 @@ def ragged_paged_attention(q, k_pages, v_pages, page_tables, query_lens,
                     context_lens, scale, layer, tile_rows, tile_index,
                     window=window)
     if selected is not None:
-        sel_blocks, dense_len, *sel_mask = selected
+        sel_blocks, dense_len = selected[:2]
         if layer is None:
             k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
         if path == dispatch.REFERENCE:
             return _listed_attention_ref(
                 q, k_pages, v_pages, page_tables, query_lens, context_lens,
                 scale, layer, sel_blocks, dense_len, window)
+        if items is None:
+            items = listed_work_items(
+                query_lens, context_lens, k_pages.shape[3],
+                page_tables.shape[1], q.shape[1], k_pages.shape[2],
+                selected, total_q, window)
         return _listed_attention_kernel(
             q, k_pages, v_pages, page_tables, query_lens, context_lens,
-            scale, layer, sel_blocks, dense_len, total_q,
-            interpret=(path == dispatch.INTERPRET),
-            sel_mask=sel_mask[0] if sel_mask else None, window=window)
+            scale, layer, sel_blocks, dense_len, items,
+            interpret=(path == dispatch.INTERPRET), window=window)
     if path == dispatch.REFERENCE:
         return _ragged_attention_ref(q, k_pages, v_pages, page_tables,
                                      query_lens, context_lens, scale, layer)

@@ -327,10 +327,15 @@ def hybrid_ragged_step(cfg: HybridConfig, params, batch: RaggedBatch,
     ``dense_only`` (static) leaves block selection out: every query
     attends over its whole context (a control for the benchmark).
 
-    Returns ``(logits [B, V], k_pages, v_pages, kc_pages, lin_state)``."""
+    Returns ``(logits [B, V], k_pages, v_pages, kc_pages, lin_state, stats
+    [2] int32)``: ``stats`` is the work items of the sparse layers'
+    attention calls, over all of them, and the pages those items hold (an
+    item is up to ``pages`` listed pages of one row, group and query tile:
+    their ratio is how full the items were)."""
     from ..kernels.lightning_attention import (lightning_attention,
                                                lightning_slopes)
-    from ..kernels.paged_attention import ragged_paged_attention
+    from ..kernels.paged_attention import (listed_work_items,
+                                           ragged_paged_attention)
 
     tokens, query_lens, context_lens, page_tables = (
         batch.tokens, batch.query_lens, batch.context_lens,
@@ -384,16 +389,23 @@ def hybrid_ragged_step(cfg: HybridConfig, params, batch: RaggedBatch,
                         view.pad(sel_tok, -1).transpose(0, 2, 1, 3),
                         cfg.dense_len,
                         view.pad(flag_tok, False).transpose(0, 2, 1, 3))
+            # the kernel's work list, built here so that the step can
+            # count it
+            items = listed_work_items(
+                query_lens, context_lens, kp.shape[3], page_tables.shape[1],
+                view.Q, Hkv, selected, total_q=T)
             attn = ragged_paged_attention(
                 view.pad(q), kp, vp, page_tables, query_lens, context_lens,
                 path=attn_path, layer=jnp.int32(i),
-                selected=selected, total_q=T)
+                selected=selected, total_q=T, items=items)
             attn = view.unpad(attn).reshape(T, H * hd).astype(x.dtype)
             gate = jax.nn.sigmoid(jnp.einsum("td,de->te", h, p["gate_w"][i]))
             x = x + c * jnp.einsum("te,ed->td", gate * attn, p["o_w"][i])
         with jax.named_scope("mlp"):
             x = x + c * _mlp(cfg, p, i, x)
-        return x.astype(cfg.jdtype()), kp, vp, kc
+        _, _, count, n = items
+        return (x.astype(cfg.jdtype()), kp, vp, kc,
+                jnp.stack([n[0], jnp.sum(count)]))
 
     def lightning_layer(x, state, i):
         p = params[LIGHTNING]
@@ -423,12 +435,14 @@ def hybrid_ragged_step(cfg: HybridConfig, params, batch: RaggedBatch,
         return x.astype(cfg.jdtype()), state
 
     seen = {SPARSE: 0, LIGHTNING: 0}
+    stats = jnp.zeros((2,), jnp.int32)
     for kind in cfg.mixer_types:
         i = seen[kind]
         seen[kind] += 1
         if kind == SPARSE:
-            x, k_pages, v_pages, kc_pages = sparse_layer(
+            x, k_pages, v_pages, kc_pages, counted = sparse_layer(
                 x, k_pages, v_pages, kc_pages, i)
+            stats = stats + counted
         else:
             x, lin_state = lightning_layer(x, lin_state, i)
 
@@ -436,4 +450,4 @@ def hybrid_ragged_step(cfg: HybridConfig, params, batch: RaggedBatch,
         x = _rms(x, params["norm_f"], cfg.rms_eps)
         logits = jnp.einsum("bd,dv->bv", view.last(x),
                             params["lm_head"]) / cfg.logit_divisor
-    return logits, k_pages, v_pages, kc_pages, lin_state
+    return logits, k_pages, v_pages, kc_pages, lin_state, stats

@@ -26,6 +26,9 @@ process-wide registry); this module keeps the serving-shaped facade:
                  belongs to the n-th call.  The engine keeps one step in
                  flight: pack and dispatch are of the program the call
                  sends, device_wait to commit of the one before it
+  attention_items / attention_item_pages — a model whose attention takes
+                 listed pages: the kernel's work items over the sparse
+                 layers and the pages they hold
   pages_in_use / window_pages_released — a model with sliding-window
                  layers: pages held by pool, and window pages given back
   expert_pairs / expert_weight_reads / expert_rows_max — a sparse-expert
@@ -156,6 +159,18 @@ class ServingMetrics:
             kind="context")
         self.attention_selected = self.attention_positions.labels(
             kind="selected")
+        self.attention_items = add(Counter(
+            "serving_attention_items_total",
+            help="work items of the grouped-heads paged-attention kernel "
+                 "over a step's sparse layers: one grid step each, up to "
+                 "`pages` listed pages of one (row, key/value group, query "
+                 "tile); counted on the device, read with the step's ids"))
+        self.attention_item_pages = add(Counter(
+            "serving_attention_item_pages_total",
+            help="the listed pages those items hold: over "
+                 "serving_attention_items_total, how full an item was (1 "
+                 "= a page an item); counted on the device, read with the "
+                 "step's ids"))
         self.sample_steps = add(Counter(
             "serving_sample_steps_total", labelnames=("path",),
             help="steps by the path their on-device sampling took: "

@@ -103,6 +103,7 @@ class HybridServed:
     """The sparse-plus-lightning decoder (``models/hybrid.py``)."""
 
     recurrent = True
+    step_stats = ("attention_items", "attention_item_pages")
 
     def __init__(self, cfg, *, dense_only=False):
         self.cfg = cfg
@@ -131,10 +132,10 @@ class HybridServed:
         cfg, dense_only = self.cfg, self.dense_only
 
         def step(params, state, batch):
-            logits, *state = hybrid_ragged_step(
+            logits, *state, stats = hybrid_ragged_step(
                 cfg, params, batch, *state, max_q=max_q,
                 dense_only=dense_only)
-            return logits, tuple(state)
+            return logits, tuple(state), stats
 
         return jax.jit(step)       # as GPTServed.make_step
 
@@ -148,6 +149,11 @@ class HybridServed:
         bs = cfg.block_size
         sparse = np.minimum(n, (cfg.topk - 1) * bs + (n - 1) % bs + 1)
         return context, int(np.where(n <= cfg.dense_len, n, sparse).sum())
+
+    def record_stats(self, metrics, values):
+        items, pages = values
+        metrics.attention_items.inc(items)
+        metrics.attention_item_pages.inc(pages)
 
 
 class SSMServed:

@@ -133,6 +133,79 @@ def test_selection_and_decay_are_not_idle(tiny):
         assert worst_gap(model, params, reqs, seen) > 100 * TOL
 
 
+def test_the_step_counts_its_attention_items_and_their_pages(tiny):
+    """``serving_attention_items_total`` and ``..._item_pages_total`` come
+    from the device with the step's ids.  At the tiny size an item holds up
+    to the whole table (64 pages of 4), so a live row is one item a sparse
+    layer and key/value group.  Its pages follow from the lengths within
+    ``dense_len`` (every page up to the chunk's last position); past it a
+    token lists ``topk`` pages, and the kernel visits those and the few
+    that tie with the last one taken (two neighbouring blocks score the
+    span they share), so a row holds between ``topk`` and all it can
+    reach."""
+    cfg, params, model = tiny
+    rng = np.random.default_rng(4)
+    prompts = [rng.integers(0, 1000, n).tolist() for n in (50, 9, 27)]
+    eng = Engine(cfg, params, page_size=4, num_pages=128, max_batch_size=3,
+                 chunk_len=16)
+    served, seen, steps = eng.model, {}, []
+    record, positions, sound = (served.record_stats,
+                                served.attention_positions,
+                                eng._sample_token)
+
+    def spy_record(metrics, values):
+        steps.append((tuple(values), []))
+        return record(metrics, values)
+
+    def spy_positions(ctx, q):
+        steps[-1][1].append((ctx, q))
+        return positions(ctx, q)
+
+    def spy_token(token, req):
+        row = eng.step_logits[eng._slots.index(req)]
+        seen.setdefault(req.id, []).append(np.asarray(row, np.float32))
+        return sound(token, req)
+
+    served.record_stats, served.attention_positions = spy_record, \
+        spy_positions
+    eng._sample_token = spy_token
+    reqs = [eng.add_request(p, SamplingParams(max_new_tokens=n))
+            for p, n in zip(prompts, (12, 40, 20))]
+    while eng.has_work():
+        eng.step()
+    # counting changed no token: the parity of the first test holds
+    assert worst_gap(model, params, reqs, seen) < TOL
+
+    calls = 2 * cfg.num_kv_heads            # sparse layers x groups
+    ps, exact, listed, reachable = 4, 0, 0, 0
+    for (items, pages), rows in steps:
+        assert items == calls * len(rows)
+        lo = hi = 0
+        for ctx, q in rows:
+            reach = least = (ctx - 1) // ps + 1
+            if ctx > cfg.dense_len:
+                # one sparse token's list, or its dense tokens' reach
+                dense = cfg.dense_len // ps if ctx - q < cfg.dense_len \
+                    else 0
+                least = max(cfg.topk, dense)
+            lo, hi = lo + calls * least, hi + calls * reach
+        assert lo <= pages <= hi, (pages, rows)
+        assert items <= pages <= 64 * items       # fill between 1 and pages
+        if lo == hi:
+            exact += 1
+        else:
+            listed, reachable = listed + pages, reachable + hi
+    # steps of dense rows alone, and steps where the lists chose: there a
+    # row's items hold well under what it could reach
+    assert exact > 5 and len(steps) - exact > 30
+    assert listed < 0.8 * reachable
+    m = eng.metrics
+    assert m.attention_items.value == sum(s[0][0] for s in steps) > 0
+    assert m.attention_item_pages.value == sum(s[0][1] for s in steps)
+    # the dense family's step counts nothing: its program is unchanged
+    assert not hasattr(GPTServed, "step_stats")
+
+
 def test_prefix_reuse_and_mesh_are_refused_for_a_recurrent_model(tiny):
     cfg, params, _ = tiny
     with pytest.raises(ValueError, match="recurrent state never saw"):

@@ -1,13 +1,25 @@
 """``ragged_paged_attention`` with grouped heads and selected pages: the
 Pallas kernel (under the interpreter) against the gather-and-mask oracle,
-and both against a plain per-token softmax over the listed blocks."""
+and both against a plain per-token softmax over the listed blocks.
+
+A work item of the kernel is up to ``pages`` listed pages of one (row,
+group, query tile), ``pages`` following from the page size
+(``_LISTED_KEY_TILE`` key positions an item, never more than the table
+holds).  At the test's pages of 4 and table of 16 that is the whole table:
+every segment is one partly filled item.  The kernel cases also run with
+the constant set to 8 (2 pages an item: segments of several items, the
+last partly filled) and to 4 (one page an item: the algorithm as it was
+before items held several pages)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from paddle_tpu.kernels import dispatch
-from paddle_tpu.kernels.paged_attention import (_work_items,
+from paddle_tpu.kernels import paged_attention as pa
+from paddle_tpu.kernels.paged_attention import (_paged_work_items,
+                                                _pages_per_item, _work_items,
+                                                listed_work_items,
                                                 ragged_paged_attention)
 
 B, Q, HKV, G, HD, PS, P, W, K, L = 4, 32, 2, 2, 8, 4, 64, 16, 3, 2
@@ -37,11 +49,31 @@ def inputs(seed=0):
     return q, kp, vp, tables, jnp.asarray(sel), pos
 
 
+# (path, key positions an item): the oracle, and the kernel at 16, 2 and 1
+# pages an item
+CASES = [(dispatch.REFERENCE, 512), (dispatch.INTERPRET, 512),
+         (dispatch.INTERPRET, 8), (dispatch.INTERPRET, 4)]
+KERNEL_CASES = [key_tile for path, key_tile in CASES
+                if path == dispatch.INTERPRET]
+
+
 def attend(path, q, kp, vp, tables, selected, layer=1):
+    # a new jit each call: the trace reads the module's constant anew
     return jax.jit(lambda *a: ragged_paged_attention(
         *a, path=path, layer=jnp.int32(layer), selected=selected,
         total_q=40))(q, kp, vp, tables, jnp.asarray(QUERY_LENS),
                      jnp.asarray(CONTEXT_LENS))
+
+
+def items_of(selected):
+    """The kernel's work list for the module's lengths, as numpy:
+    ``(segment, pages [n, pages an item], count)`` of the items in use."""
+    seg, lp, count, n = listed_work_items(
+        jnp.asarray(QUERY_LENS), jnp.asarray(CONTEXT_LENS), PS, W, Q, HKV,
+        selected, total_q=40)
+    n = int(n[0])
+    return (np.asarray(seg)[:n], np.asarray(lp).reshape(len(seg), -1)[:n],
+            np.asarray(count)[:n])
 
 
 def by_hand(q, kp, vp, tables, sel, pos, layer=1):
@@ -69,8 +101,12 @@ def by_hand(q, kp, vp, tables, sel, pos, layer=1):
     return out
 
 
-@pytest.mark.parametrize("path", [dispatch.REFERENCE, dispatch.INTERPRET])
-def test_selected_pages_with_a_mask_per_query_token(path):
+@pytest.mark.parametrize("path,key_tile", CASES)
+def test_selected_pages_with_a_mask_per_query_token(path, key_tile,
+                                                    monkeypatch):
+    """A dense row, a sparse decode row, an idle row and a chunk across
+    the ``dense_len`` edge in one call."""
+    monkeypatch.setattr(pa, "_LISTED_KEY_TILE", key_tile)
     q, kp, vp, tables, sel, pos = inputs()
     got = attend(path, q, kp, vp, tables, (sel, DENSE_LEN))
     assert got.shape == q.shape
@@ -82,8 +118,10 @@ def test_selected_pages_with_a_mask_per_query_token(path):
     assert float(jnp.abs(got[0, 5:]).max()) == 0.0
 
 
-@pytest.mark.parametrize("path", [dispatch.REFERENCE, dispatch.INTERPRET])
-def test_grouped_heads_without_a_list_attend_over_everything(path):
+@pytest.mark.parametrize("path,key_tile", CASES)
+def test_grouped_heads_without_a_list_attend_over_everything(path, key_tile,
+                                                             monkeypatch):
+    monkeypatch.setattr(pa, "_LISTED_KEY_TILE", key_tile)
     q, kp, vp, tables, _, pos = inputs(1)
     got = attend(path, q, kp, vp, tables, (None, 0))
     np.testing.assert_allclose(
@@ -117,7 +155,33 @@ def test_grouped_equals_the_equal_heads_kernel_on_repeated_heads():
                                atol=2e-5)
 
 
-def test_bfloat16_kernel_stays_near_the_float32_oracle():
+@pytest.mark.parametrize("key_tile", KERNEL_CASES)
+def test_two_tokens_of_one_tile_with_disjoint_lists_share_an_item(
+        key_tile, monkeypatch):
+    """Tokens 16 and 20 of the chunk (positions 44 and 48, past
+    ``dense_len``) list pages {0, 2, 11} and {1, 3, 12}: at 16 and at 2
+    pages an item, pages of both lists sit in one item ({0, 1}), and each
+    token reads its own and none of the other's."""
+    monkeypatch.setattr(pa, "_LISTED_KEY_TILE", key_tile)
+    q, kp, vp, tables, sel, pos = inputs(5)
+    sel = np.array(sel)
+    assert pos[3, 16] == 44 and pos[3, 20] == 48
+    sel[3, :, 16], sel[3, :, 20] = [0, 2, 11], [1, 3, 12]
+    pages = _pages_per_item(PS, W, key_tile)
+    seg, lp, count = items_of((jnp.asarray(sel), DENSE_LEN))
+    if pages > 1:
+        first = lp[seg == 3 * HKV][0]           # row 3, group 0
+        assert {0, 1} <= set(first[:2].tolist())
+    got = attend(dispatch.INTERPRET, q, kp, vp, tables,
+                 (jnp.asarray(sel), DENSE_LEN))
+    np.testing.assert_allclose(
+        np.asarray(got), by_hand(q, kp, vp, tables, sel, pos), atol=2e-5)
+
+
+@pytest.mark.parametrize("key_tile", KERNEL_CASES)
+def test_bfloat16_kernel_stays_near_the_float32_oracle(key_tile,
+                                                       monkeypatch):
+    monkeypatch.setattr(pa, "_LISTED_KEY_TILE", key_tile)
     q, kp, vp, tables, sel, _ = inputs(4)
     bf = lambda a: a.astype(jnp.bfloat16)
     got = attend(dispatch.INTERPRET, bf(q), bf(kp), bf(vp), tables,
@@ -129,9 +193,83 @@ def test_bfloat16_kernel_stays_near_the_float32_oracle():
                                np.asarray(want, np.float32), atol=0.05)
 
 
+def test_pages_an_item_follow_from_the_page_size():
+    """Whole pages covering 512 key positions: 8 of the long-context
+    cell's 64, one of the chat cell's 512, and never more than the table
+    holds."""
+    assert _pages_per_item(64, 544, pa._LISTED_KEY_TILE) == 8
+    assert _pages_per_item(512, 4, pa._LISTED_KEY_TILE) == 1
+    assert _pages_per_item(1024, 4, pa._LISTED_KEY_TILE) == 1
+    assert _pages_per_item(PS, W, pa._LISTED_KEY_TILE) == W
+
+
 def test_work_items_list_each_segments_pages_in_order():
     visit = jnp.array([[0, 1, 0, 1], [0, 0, 0, 0], [1, 1, 1, 0]], bool)
     seg, w, n = _work_items(visit, 8)
     assert int(n) == 5
     assert list(np.asarray(seg[:5])) == [0, 0, 2, 2, 2]
     assert list(np.asarray(w[:5])) == [1, 3, 0, 1, 2]
+    # the same list as items of one entry
+    seg1, w1, count, n1 = _paged_work_items(visit, 8, 1)
+    assert int(n1) == 5 and list(np.asarray(count)) == [1] * 5 + [0] * 3
+    assert (np.asarray(seg1[:5]) == np.asarray(seg[:5])).all()
+    assert (np.asarray(w1[:5]) == np.asarray(w[:5])).all()
+    # two entries an item: the last item of a segment partly filled
+    seg, w, count, n = _paged_work_items(visit, 4, 2)
+    assert int(n) == 3
+    assert list(np.asarray(seg[:3])) == [0, 2, 2]
+    assert list(np.asarray(count)) == [2, 2, 1, 0]
+    w = np.asarray(w).reshape(4, 2)
+    assert w[0].tolist() == [1, 3] and w[1].tolist() == [0, 1]
+    assert w[2, 0] == 2
+
+
+@pytest.mark.parametrize("pages", [1, 2, 3, 5, 16])
+def test_every_visited_page_is_in_exactly_one_item(pages):
+    """Segments with 0 to 16 visited pages: every (segment, page) once, a
+    segment's pages ascending across its items, ``ceil(visited / pages)``
+    items a segment and only its last one partly filled."""
+    rng = np.random.default_rng(pages)
+    visit = rng.random((12, 16)) < rng.random((12, 1))
+    visit[0], visit[1], visit[2, :] = False, True, np.arange(16) == 7
+    per_seg = -(-visit.sum(1) // pages)
+    n_max = int(per_seg.sum()) + 3
+    seg, w, count, n = map(np.asarray, _paged_work_items(
+        jnp.asarray(visit), n_max, pages))
+    n, w = int(n), w.reshape(n_max, pages)
+    assert n == per_seg.sum() and not count[n:].any()
+    assert np.bincount(seg[:n], minlength=12).tolist() == per_seg.tolist()
+    listed = [(s, p) for s, row, c in zip(seg[:n], w, count)
+              for p in row[:c]]
+    assert listed == [tuple(x) for x in np.argwhere(visit)]
+    for s in range(12):
+        assert (count[:n][seg[:n] == s][:-1] == pages).all()
+
+
+@pytest.mark.parametrize("key_tile", KERNEL_CASES)
+def test_the_cells_rows_fill_items_as_their_lengths_and_lists_say(
+        key_tile, monkeypatch):
+    """The module's rows: the dense row reads 3 pages a group, the decode
+    row its list, the idle row nothing, the chunk's one tile the union of
+    its dense tokens' reach and its sparse tokens' lists."""
+    monkeypatch.setattr(pa, "_LISTED_KEY_TILE", key_tile)
+    _, _, _, _, sel, pos = inputs()
+    pages = _pages_per_item(PS, W, key_tile)
+    seg, lp, count = items_of((sel, DENSE_LEN))
+    sel = np.asarray(sel)
+    for b, g in np.ndindex(B, HKV):
+        want = set()
+        for t in range(int(QUERY_LENS[b])):
+            p = int(pos[b, t])
+            want |= set(range(p // PS + 1)) if p + 1 <= DENSE_LEN \
+                else set(sel[b, g, t].tolist()) - {-1}
+        mine = seg == b * HKV + g
+        got = [p for row, c in zip(lp[mine], count[mine]) for p in row[:c]]
+        assert got == sorted(want)
+        assert mine.sum() == -(-len(want) // pages)
+    if pages == 2:
+        # full items and partly filled last ones: what the kernel's cases
+        # above run
+        assert {1, 2} <= set(count.tolist())
+    if pages == W:
+        assert 0 < count.min() and count.max() < W

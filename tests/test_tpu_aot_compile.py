@@ -126,8 +126,12 @@ def test_hybrid_serve_step_compiles_for_v5e_and_moves_no_pool(devices):
     (published widths, 8 layers, 16 rows, chunks of 512, 8192 pages of
     64): Mosaic accepts both new kernels, every state pool is donated and
     aliased, nothing re-lays or copies a key/value pool, and the plan
-    fits the chip: 6,950,199,808 B of arguments and 840,322,560 B of
-    temporaries (6,950,195,200 B and 839,838,720 B before the step
+    fits the chip: 6,950,199,808 B of arguments and 841,193,472 B of
+    temporaries (840,322,560 B before the attention kernel's items held 8
+    listed pages each, PR 36: the work list is 2,856 items with their 8
+    page slots and a count each where it was 22,848 items of one page,
+    built by the step, which sums it into two int32 behind the ids;
+    6,950,195,200 B and 839,838,720 B before the step
     sampled its ``[16, 73472]`` logits itself, PR 31: 4,096 B for the
     table, 516,096 B for the ids and the drawing branch; PR 34's
     ``[16]`` ids of the step before are 512 B more of arguments, and the
@@ -145,7 +149,7 @@ def test_hybrid_serve_step_compiles_for_v5e_and_moves_no_pool(devices):
     assert mem.alias_size_in_bytes >= pools
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12e9
     assert (mem.argument_size_in_bytes, mem.temp_size_in_bytes) == \
-        (6950199808, 840322560)
+        (6950199808, 841193472)
     assert text.count(" conditional(") == 1
     pool = "bf16[2,8192,2,64,128]"
     movers = [line.strip()[:160] for line in text.splitlines()
@@ -164,8 +168,11 @@ def test_ssm_serve_step_compiles_for_v5e_and_fits_the_chip(devices):
     donated and aliased, and the plan fits the chip before any chip time
     is spent: 13,742,309,888 B of arguments (10.51 GB of weights, 1.61 GB
     of pages, 1.62 GB of window and scan state, and since PR 34 the 512 B
-    of the ``[64]`` ids of the step before) and 128,563,200 B of
-    temporaries (96,768 B of them for the resolved tokens, PR 34), with the ``[64, 261120]`` float32 logits 66,846,720 B
+    of the ``[64]`` ids of the step before) and 128,745,984 B of
+    temporaries (96,768 B of them for the resolved tokens, PR 34; 182,784 B
+    for the attention kernel's work list with a count an item, PR 36: at
+    pages of 512 an item is one page as before, fetched by the kernel's
+    own copies where a ``BlockSpec`` brought it), with the ``[64, 261120]`` float32 logits 66,846,720 B
     more: 13.94 GB of the chip's 17.18."""
     compiled = tpu_aot.lower_ssm_serve_step(devices).compile()
     text, mem = compiled.as_text(), compiled.memory_analysis()
@@ -174,7 +181,7 @@ def test_ssm_serve_step_compiles_for_v5e_and_fits_the_chip(devices):
         + 6 * 64 * 32 * 128 * 256 * 4
     assert mem.alias_size_in_bytes == pools == 3233021952
     assert (mem.argument_size_in_bytes, mem.temp_size_in_bytes) == \
-        (13742309888, 128563200)
+        (13742309888, 128745984)
     assert mem.output_size_in_bytes - mem.alias_size_in_bytes < 68e6
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes - mem.alias_size_in_bytes) < 2 ** 34

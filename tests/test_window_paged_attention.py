@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 
 from paddle_tpu.kernels import dispatch
+from paddle_tpu.kernels import paged_attention as pa
 from paddle_tpu.kernels.paged_attention import ragged_paged_attention
 
 B, Q, HKV, HD, PS, P, W, L = 5, 32, 2, 8, 4, 96, 16, 2
@@ -59,9 +60,16 @@ def by_hand(q, kp, vp, tables, pos, groups, window, layer=1):
     return out
 
 
+# (path, key positions an item of the padded-rows kernel): at 512 an item is
+# the whole table of 16 pages, at 8 two pages; either way the window's
+# lower edge (10 positions back) falls inside an item
 @pytest.mark.parametrize("groups", [2, 3])
-@pytest.mark.parametrize("path", [dispatch.REFERENCE, dispatch.INTERPRET])
-def test_a_token_reads_the_last_window_positions_and_no_more(path, groups):
+@pytest.mark.parametrize("path,key_tile", [(dispatch.REFERENCE, 512),
+                                           (dispatch.INTERPRET, 512),
+                                           (dispatch.INTERPRET, 8)])
+def test_a_token_reads_the_last_window_positions_and_no_more(
+        path, key_tile, groups, monkeypatch):
+    monkeypatch.setattr(pa, "_LISTED_KEY_TILE", key_tile)
     q, kp, vp, tables, pos = inputs(groups)
     got = attend(path, q, kp, vp, tables, WINDOW)
     assert got.shape == q.shape
