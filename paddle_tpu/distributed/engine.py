@@ -1142,8 +1142,9 @@ class HybridEngine:
                 with jax.named_scope("forward_backward"):
                     l, g = grad_fn(params, xs[0], xs[1], k)
                     gc = to_chunks(g)
-                return (loss_sum + l,
-                        tuple(a + c for a, c in zip(gsum, gc))), None
+                with jax.named_scope("grad_accumulate"):
+                    return (loss_sum + l,
+                            tuple(a + c for a, c in zip(gsum, gc))), None
 
             def chunk_zero(p, z3):
                 n = int(np.prod(p.shape))
@@ -1157,8 +1158,9 @@ class HybridEngine:
             (loss_sum, g_chunks), _ = jax.lax.scan(
                 acc_body, (jnp.zeros((), jnp.float32), g0),
                 (tok, lab, jnp.arange(accum)))
-            loss = loss_sum / accum
-            g_chunks = [g / accum for g in g_chunks]
+            with jax.named_scope("grad_accumulate"):
+                loss = loss_sum / accum
+                g_chunks = [g / accum for g in g_chunks]
 
         with jax.named_scope("optimizer"):
             new_params, new_opt = self._apply_grads(
@@ -1343,6 +1345,11 @@ class HybridEngine:
         fn = self.build_step()
         lr = jnp.asarray(lr if lr is not None else self.ec.lr, jnp.float32)
         seed = jnp.asarray(dropout_seed, jnp.uint32)
+        if fn.abstract_args is None:
+            # batch and sequence are known only now: the first call, which
+            # compiles anyway, describes the program by its operands'
+            # shapes and shardings (``instruction_table``); no lowering
+            fn.describe(params, opt_state, tokens, labels, lr, seed)
         # the host's part of a step: the dispatch (enqueue), not the wait
         with RecordEvent("hybrid_engine::step"):
             return fn(params, opt_state, tokens, labels, lr, seed)

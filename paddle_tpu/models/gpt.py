@@ -380,15 +380,17 @@ def gpt_ragged_step(cfg: GPTConfig, params, batch: RaggedBatch, k_pages,
     view = RaggedView(batch, max_q=max_q, max_seq_len=cfg.max_seq_len,
                       num_pages=k_pages.shape[1], page_size=page_size)
 
-    x = jnp.take(params["wte"], tokens, axis=0) + \
-        jnp.take(params["wpe"], view.pos, axis=0)
-    x = x.astype(cfg.jdtype())                                     # [T, D]
+    with jax.named_scope("embed"):
+        x = jnp.take(params["wte"], tokens, axis=0) + \
+            jnp.take(params["wpe"], view.pos, axis=0)
+        x = x.astype(cfg.jdtype())                                 # [T, D]
 
     from ..kernels.paged_attention import (ragged_paged_attention,
                                            ragged_work_items)
 
-    items = ragged_work_items(query_lens, context_lens, page_size,
-                              page_tables.shape[1])
+    with jax.named_scope("work_list"):
+        items = ragged_work_items(query_lens, context_lens, page_size,
+                                  page_tables.shape[1])
 
     def attend(q, kp, vp, tables, q_lens, ctx_lens, layer, items):
         return ragged_paged_attention(q, kp, vp, tables, q_lens, ctx_lens,

@@ -351,14 +351,16 @@ def hybrid_ragged_step(cfg: HybridConfig, params, batch: RaggedBatch,
         else jnp.zeros((Hl,), jnp.float32)
 
     # `select` scores tokens in tiles of one row each
-    nt, tile, lane = _select_tiles(view)
-    tile_row = jnp.zeros((nt,), jnp.int32).at[tile].set(view.row,
-                                                        mode="drop")
-    tile_pos = jnp.full((nt, _SELECT_TILE), -1, jnp.int32).at[
-        tile, lane].set(pos, mode="drop")
+    with jax.named_scope("select_tiles"):
+        nt, tile, lane = _select_tiles(view)
+        tile_row = jnp.zeros((nt,), jnp.int32).at[tile].set(view.row,
+                                                            mode="drop")
+        tile_pos = jnp.full((nt, _SELECT_TILE), -1, jnp.int32).at[
+            tile, lane].set(pos, mode="drop")
 
-    x = (jnp.take(params["wte"], tokens, axis=0).astype(jnp.float32)
-         * cfg.scale_emb).astype(cfg.jdtype())                     # [T, D]
+    with jax.named_scope("embed"):
+        x = (jnp.take(params["wte"], tokens, axis=0).astype(jnp.float32)
+             * cfg.scale_emb).astype(cfg.jdtype())                 # [T, D]
 
     def sparse_layer(x, kp, vp, kc, i):
         p = params[SPARSE]
@@ -391,9 +393,10 @@ def hybrid_ragged_step(cfg: HybridConfig, params, batch: RaggedBatch,
                         view.pad(flag_tok, False).transpose(0, 2, 1, 3))
             # the kernel's work list, built here so that the step can
             # count it
-            items = listed_work_items(
-                query_lens, context_lens, kp.shape[3], page_tables.shape[1],
-                view.Q, Hkv, selected, total_q=T)
+            with jax.named_scope("work_list"):
+                items = listed_work_items(
+                    query_lens, context_lens, kp.shape[3],
+                    page_tables.shape[1], view.Q, Hkv, selected, total_q=T)
             attn = ragged_paged_attention(
                 view.pad(q), kp, vp, page_tables, query_lens, context_lens,
                 path=attn_path, layer=jnp.int32(i),

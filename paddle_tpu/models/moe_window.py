@@ -319,6 +319,7 @@ def moe_window_ragged_step(cfg: MoEWindowConfig, params, batch: RaggedBatch,
                              p["o_w"][i], preferred_element_type=f32)
         return (x.astype(f32) + out).astype(dtype), kp, vp
 
+    @jax.named_scope("sparse_mlp")
     def sparse_mlp(x, i):
         p = params[SPARSE]
         u = _rms(x, p["ln2"][i], cfg.rms_eps)
@@ -350,7 +351,8 @@ def moe_window_ragged_step(cfg: MoEWindowConfig, params, batch: RaggedBatch,
                            p["up_w"][i], p["down_w"][i])
         return (x.astype(f32) + h.astype(f32)).astype(dtype)
 
-    x = jnp.take(params["wte"], tokens, axis=0).astype(dtype)      # [T, D]
+    with jax.named_scope("embed"):
+        x = jnp.take(params["wte"], tokens, axis=0).astype(dtype)  # [T, D]
     seen = dict.fromkeys((FULL, SLIDING, DENSE, SPARSE), 0)
     pairs = jnp.zeros((), jnp.int32)
     fullest = jnp.zeros((), jnp.int32)

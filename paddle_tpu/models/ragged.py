@@ -144,6 +144,7 @@ class RaggedView:
     :meth:`pad_tiles` and :meth:`unpad_tiles`.
     """
 
+    @jax.named_scope("batch_view")
     def __init__(self, batch: RaggedBatch, *, max_q, max_seq_len, num_pages,
                  page_size, num_window_pages=None):
         self.batch = batch

@@ -279,7 +279,8 @@ def ssm_ragged_step(cfg: SSMConfig, params, batch: RaggedBatch, k_pages,
     def block(carry, xs):
         x, kp, vp, conv, ssm = carry
         p, layer = xs
-        u = _rms(x, p["ln1"], cfg.rms_eps)
+        with jax.named_scope("norm"):
+            u = _rms(x, p["ln1"], cfg.rms_eps)
         with jax.named_scope("attn"):
             a = u * cfg.attention_in_multiplier \
                 if cfg.attention_in_multiplier != 1 else u
@@ -329,15 +330,17 @@ def ssm_ragged_step(cfg: SSMConfig, params, batch: RaggedBatch, k_pages,
             y = view.unpad(y, q_axis=2).reshape(T, Hs * P)
             y = _gated_group_norm(cfg, y, z, p["ssm_norm"])
             out = out_proj(y, p["out_w"], cfg.ssm_out_multiplier)
-        x = (x.astype(f32) + att + out).astype(dtype)
+        with jax.named_scope("residual"):
+            x = (x.astype(f32) + att + out).astype(dtype)
         with jax.named_scope("mlp"):
             h = _gated_mlp(_rms(x, p["ln2"], cfg.rms_eps), p["mlp_gate_w"],
                            p["mlp_up_w"], p["mlp_down_w"], mlp_gate)
             x = (x.astype(f32) + h.astype(f32) * mlp_down).astype(dtype)
         return (x, kp, vp, conv, ssm), None
 
-    x = (jnp.take(params["wte"], tokens, axis=0).astype(f32)
-         * cfg.embedding_multiplier).astype(dtype)                 # [T, D]
+    with jax.named_scope("embed"):
+        x = (jnp.take(params["wte"], tokens, axis=0).astype(f32)
+             * cfg.embedding_multiplier).astype(dtype)             # [T, D]
     (x, k_pages, v_pages, conv_state, ssm_state), _ = jax.lax.scan(
         block, (x, k_pages, v_pages, conv_state, ssm_state),
         (params["blocks"], jnp.arange(cfg.num_layers, dtype=jnp.int32)))

@@ -12,7 +12,10 @@ platform/monitor.h grown into a production observability stack):
   hybrid-engine step, inference predictors, jit.to_static): counts
   compilations, records compile wall-time + HLO cost analysis, and
   WARNs with the argument shape/dtype diff on post-warmup recompiles —
-  the ragged-shape regression detector.
+  the ragged-shape regression detector.  It also keeps each engine
+  step's jitted function and abstract arguments, and
+  ``instruction_table(name)`` maps every instruction of the compiled
+  step to the scopes that made it (a profile's operation names).
 - :mod:`.tracing` — the flight recorder: a thread-safe
   :class:`Span`/:class:`Tracer` model with a bounded ring of completed
   traces.  The serving engine records every request's lifecycle
@@ -111,9 +114,16 @@ from .aggregate import (  # noqa: F401
 )
 from .compile_watchdog import (  # noqa: F401
     CompileWatchdog,
+    abstract_like,
     default_watchdog,
     disable_compile_watchdog,
     enable_compile_watchdog,
+    innermost_scope,
+    instruction_table,
+    leaf_primitive,
+    named_scopes,
+    parse_instruction_table,
+    pass_of,
     watch,
     watchdog_enabled,
 )
@@ -177,6 +187,8 @@ __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "default_registry",
     "CompileWatchdog", "default_watchdog", "watch",
     "enable_compile_watchdog", "disable_compile_watchdog",
+    "instruction_table", "parse_instruction_table", "abstract_like",
+    "named_scopes", "innermost_scope", "pass_of", "leaf_primitive",
     "watchdog_enabled",
     "Span", "Tracer", "default_tracer",
     "ResourceSampler", "TelemetryServer", "start_telemetry_server",

@@ -27,18 +27,51 @@ pytree of shapes/dtypes + static values) — exactly jax.jit's executable
 cache key, so the count matches XLA's behavior without reaching into
 jax internals.  Compile wall-time is the first call's wall time (trace +
 compile + run; on real programs run time is noise next to compile time).
+
+**What a watched program is made of** (works with the watchdog disabled):
+an engine that builds a step also hands :func:`watch` the step's
+*abstract* arguments (``ShapeDtypeStruct``s with the real arrays'
+shardings; :func:`abstract_like` makes them from a call's operands), and
+the watchdog keeps, per watched name and for the life of the process,
+only the jitted function and those shapes — no array, no engine; the
+newest registration of a name wins.  Registering compiles and lowers
+nothing.  :func:`instruction_table` then lowers with the shapes and
+compiles — in the process that ran the step jax's own lowering cache
+hands back the executable that ran (0.06 to 0.6 s on a v5e, no compile
+logged); elsewhere a compile, or a read of the persistent compile cache —
+and maps every instruction of ``compiled.as_text()`` that runs as a device
+operation to the ``op_name`` path jax recorded for it — the
+``jax.named_scope``s, transformations and primitive that made it::
+
+    # after any jax.profiler trace of a serving or a training process
+    table = instruction_table("serving::unified_step")   # or
+    table = instruction_table("hybrid_engine::step")
+    table["add_add_fusion.14"]
+    # 'jit(_step)/jit(step)/while/body/closed_call/mlp/tf,fd->td/dot_general'
+
+The trace's operation names are these instruction names, so the table
+turns a profile into device time by scope: :func:`named_scopes` /
+:func:`innermost_scope` give the scopes on a path, :func:`pass_of` says
+whether it is the ``forward``, the ``recompute`` (a ``jax.checkpoint``
+replay) or the ``backward`` pass of a differentiated program, and
+:func:`leaf_primitive` the primitive (``dot_general``, ``scatter``,
+``pallas_call``).  ``benchmark/scope_share.py`` reads it for the per-layer
+metrics; it replaces the hand-made operation dumps of PRs 27 to 35.
 """
 from __future__ import annotations
 
 import contextlib
 import logging
 import os
+import re
 import threading
 import time
 
 __all__ = ["CompileWatchdog", "watch", "default_watchdog",
            "enable_compile_watchdog", "disable_compile_watchdog",
-           "watchdog_enabled"]
+           "watchdog_enabled", "abstract_like", "instruction_table",
+           "parse_instruction_table", "named_scopes", "innermost_scope",
+           "pass_of", "leaf_primitive"]
 
 logger = logging.getLogger("paddle_tpu.observability")
 
@@ -118,6 +151,168 @@ def _cost_analysis(fn, args, kwargs, allow_compile=False):
     return None
 
 
+# ---- what a compiled program is made of ---------------------------------
+
+def abstract_like(tree):
+    """``tree`` with every array leaf as a ``ShapeDtypeStruct``: its shape,
+    dtype and weak type, and its sharding where the array is committed to
+    one (an uncommitted array lowers as an unspecified sharding does) —
+    what ``jit(...).lower`` needs to lower the program a call with these
+    operands compiles, and nothing that holds a buffer."""
+    import jax
+
+    def leaf(x):
+        aval = jax.typeof(x)
+        sharding = getattr(x, "sharding", None)
+        if isinstance(x, jax.Array) and not x.committed:
+            sharding = None
+        return jax.ShapeDtypeStruct(aval.shape, aval.dtype,
+                                    sharding=sharding,
+                                    weak_type=aval.weak_type)
+
+    return jax.tree_util.tree_map(leaf, tree)
+
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%(\S+) \(.*\{$")
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%(\S+) = ")
+_CALLEE = re.compile(r"\b(calls|to_apply)=%([^\s,)}]+)")
+_OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
+
+
+def parse_instruction_table(text):
+    """``{instruction name: op_name path}`` from ``compiled.as_text()``,
+    for every instruction that runs as a device operation: those of the
+    entry computation, of loop bodies and conditions, of branches and of
+    called computations — not the insides of a fusion or of a reducer
+    (``calls=`` of a ``fusion``, ``to_apply=`` of anything but a
+    ``call``), which never appear in a trace.  Instruction names are
+    unique in a module.  A fusion that XLA left without metadata (the
+    in-place page scatters of a large step) takes the path of its fused
+    computation's root, else of the first instruction inside that has
+    one; any other instruction without metadata maps to ``""``."""
+    computations, roots, inside, fused = {}, {}, set(), {}
+    current = None                      # the computation being read
+    for line in text.splitlines():
+        head = _COMPUTATION.match(line)
+        if head:
+            current = head.group(1)
+            computations[current] = {}
+            continue
+        ins = _INSTRUCTION.match(line)
+        if ins is None or current is None:
+            continue
+        name = ins.group(1)
+        at = line.rfind(" metadata={")
+        found = _OP_NAME.search(line, at) if at >= 0 else None
+        # XLA joins the paths of instructions it merged with ";"
+        path = found.group(1).split(";")[0] if found else ""
+        computations[current][name] = path
+        if line.lstrip().startswith("ROOT "):
+            roots[current] = path
+        for attr, callee in _CALLEE.findall(line):
+            if attr == "calls" and " fusion(" in line:
+                inside.add(callee)
+                if not path:
+                    fused[name] = callee
+            elif attr == "to_apply" and " call(" not in line:
+                inside.add(callee)
+    table = {}
+    for computation, instructions in computations.items():
+        if computation not in inside:
+            table.update(instructions)
+    for name, callee in fused.items():
+        if name in table:
+            table[name] = roots.get(callee) or next(
+                (p for p in computations.get(callee, {}).values() if p), "")
+    return table
+
+
+# path components that jax's transformations and control flow put on an
+# ``op_name`` path: everything else before the primitive is a scope
+_STRUCTURAL = frozenset((
+    "while", "body", "cond", "closed_call", "checkpoint",
+    "rematted_computation", "shard_map"))
+_BRANCH = re.compile(r"^branch_\d+_fun$")
+_IDENTIFIER = re.compile(r"^[A-Za-z_][\w.\-]*$")
+
+
+def _components(path):
+    """The path split at the slashes that are outside parentheses."""
+    out, depth, start = [], 0, 0
+    for i, ch in enumerate(path):
+        if ch == "(":
+            depth += 1
+        elif ch == ")":
+            depth = max(0, depth - 1)
+        elif ch == "/" and depth == 0:
+            out.append(path[start:i])
+            start = i + 1
+    out.append(path[start:])
+    return [c for c in out if c]
+
+
+def leaf_primitive(path):
+    """The primitive that made the instruction: the path's last component
+    (``dot_general``, ``scatter``, ``pallas_call``); ``""`` for ``""``."""
+    parts = _components(path)
+    return parts[-1] if parts else ""
+
+
+_WRAPPED = re.compile(r"^([A-Za-z_]\w*)\((.*)\)$")
+
+
+def _scope_of(component):
+    """The named scope one path component carries, or ``None``: the
+    component itself, or what a transformation wraps (a scope opened
+    directly under ``grad`` reads ``jvp(ce_head)`` and
+    ``transpose(jvp(ce_head))``); a ``jit(f)`` carries a function's name,
+    not a scope."""
+    wrapped = _WRAPPED.match(component)
+    while wrapped:
+        if wrapped.group(1) in ("jit", "pjit"):
+            return None
+        component = wrapped.group(2)
+        wrapped = _WRAPPED.match(component)
+    if (_IDENTIFIER.match(component) and component not in _STRUCTURAL
+            and not _BRANCH.match(component)):
+        return component
+    return None
+
+
+def named_scopes(path):
+    """The ``jax.named_scope``s on an ``op_name`` path, outermost first:
+    its components before the primitive, but for transformations
+    (``jit(f)``, ``jvp()``, ``vmap()``), control flow's own (``while``,
+    ``body``, ``closed_call``, ``branch_1_fun``, ``checkpoint``, ...) and
+    an einsum's spec."""
+    found = (_scope_of(c) for c in _components(path)[:-1])
+    return tuple(c for c in found if c)
+
+
+def innermost_scope(path, names):
+    """The innermost of the scopes ``names`` on ``path``, or ``None``."""
+    for c in reversed(named_scopes(path)):
+        if c in names:
+            return c
+    return None
+
+
+def pass_of(path):
+    """Which pass of a differentiated program the instruction belongs to:
+    ``"recompute"`` (a ``jax.checkpoint`` replay: under
+    ``rematted_computation``), ``"backward"`` (under ``transpose(jvp``),
+    ``"forward"`` (under ``jvp(`` alone), or ``None`` (not differentiated:
+    an optimizer, a serving step)."""
+    parts = _components(path)
+    if "rematted_computation" in parts:
+        return "recompute"
+    if any(c.startswith("transpose(") and "jvp(" in c for c in parts):
+        return "backward"
+    if any(c.startswith("jvp(") for c in parts):
+        return "forward"
+    return None
+
+
 class _FnStats:
     __slots__ = ("name", "calls", "compiles", "recompiles",
                  "compile_time_s", "signatures", "last_signature",
@@ -152,6 +347,20 @@ class WatchedFunction:
         self.__wrapped__ = fn
         self._name = name
         self._watchdog = watchdog
+        #: ``(args, kwargs)`` of ``ShapeDtypeStruct``s once the program is
+        #: described (``watch(..., abstract_args=)`` or :meth:`describe`)
+        self.abstract_args = None
+        self._table = None      # its instruction table, once asked for
+
+    def describe(self, *args, **kwargs):
+        """Register the program under its watched name with the abstract
+        form of these operands (arrays or ``ShapeDtypeStruct``s), for
+        :meth:`CompileWatchdog.instruction_table`.  No lowering, no
+        compile, no device call; keeps shapes and shardings only."""
+        self.abstract_args = abstract_like((args, kwargs))
+        self._table = None
+        with self._watchdog._lock:
+            self._watchdog._programs[self._name] = self
 
     def __call__(self, *args, **kwargs):
         wd = self._watchdog
@@ -177,6 +386,9 @@ class CompileWatchdog:
         self.cost_analysis = cost_analysis
         self._registry = registry
         self._stats = {}        # guarded-by: self._lock
+        # name -> the newest described WatchedFunction (a jitted function
+        # and shapes): outlives the engine that built it, holds no array
+        self._programs = {}     # guarded-by: self._lock
         self._lock = threading.Lock()
 
     # ---- lifecycle ------------------------------------------------------
@@ -200,12 +412,43 @@ class CompileWatchdog:
         return self._registry
 
     # ---- wrapping -------------------------------------------------------
-    def watch(self, fn, name=None):
-        """Wrap a jitted callable; returns a transparent proxy."""
+    def watch(self, fn, name=None, abstract_args=None):
+        """Wrap a jitted callable; returns a transparent proxy.
+        ``abstract_args``: the positional arguments of a call as
+        ``ShapeDtypeStruct``s (arrays are reduced to theirs), which
+        registers the program for :meth:`instruction_table`."""
         if isinstance(fn, WatchedFunction):
             return fn
         name = name or getattr(fn, "__name__", repr(fn))
-        return WatchedFunction(fn, name, self)
+        watched = WatchedFunction(fn, name, self)
+        if abstract_args is not None:
+            watched.describe(*abstract_args)
+        return watched
+
+    def instruction_table(self, name):
+        """``{instruction name: op_name path}`` of the newest program
+        registered as ``name`` (:func:`parse_instruction_table`), or
+        ``None`` for a name nobody described.  The first request lowers
+        with the kept shapes and compiles (in the process that ran the
+        step: the executable it ran, from jax's lowering cache) and the
+        table is kept.  Never raises: a program that no longer lowers
+        logs why."""
+        with self._lock:
+            watched = self._programs.get(name)
+        if watched is None:
+            return None
+        if watched._table is None:
+            args, kwargs = watched.abstract_args
+            try:
+                text = watched.__wrapped__.lower(
+                    *args, **kwargs).compile().as_text()
+            except Exception:
+                logger.warning("instruction_table(%r): the program does "
+                               "not lower from its abstract arguments",
+                               name, exc_info=True)
+                return None
+            watched._table = parse_instruction_table(text)
+        return watched._table
 
     def _record_call(self, watched, args, kwargs):
         sig = _signature(args, kwargs)
@@ -265,9 +508,14 @@ def default_watchdog() -> CompileWatchdog:
     return _default
 
 
-def watch(fn, name=None):
+def watch(fn, name=None, abstract_args=None):
     """Wrap ``fn`` under the default watchdog (dormant until enabled)."""
-    return _default.watch(fn, name)
+    return _default.watch(fn, name, abstract_args)
+
+
+def instruction_table(name):
+    """The default watchdog's :meth:`CompileWatchdog.instruction_table`."""
+    return _default.instruction_table(name)
 
 
 def enable_compile_watchdog():

@@ -459,14 +459,16 @@ class Engine:
             # seen is named in the batch and filled in here: the model's
             # step sees ids only
             *state, batch, sampling, prev_ids = args
-            if n_stats:
-                prev_ids = prev_ids[:max_batch_size]
-            batch = resolve_pending(batch, prev_ids)
+            with jax.named_scope("pending"):
+                if n_stats:
+                    prev_ids = prev_ids[:max_batch_size]
+                batch = resolve_pending(batch, prev_ids)
             logits, state, *stats = model_step(params, tuple(state), batch)
-            ids = sample_tokens(logits, sampling, batch.query_lens,
-                                batch.context_lens)
-            if n_stats:
-                ids = jnp.concatenate([ids, stats[0].astype(ids.dtype)])
+            with jax.named_scope("sample"):
+                ids = sample_tokens(logits, sampling, batch.query_lens,
+                                    batch.context_lens)
+                if n_stats:
+                    ids = jnp.concatenate([ids, stats[0].astype(ids.dtype)])
             return (ids, logits, *state)
 
         # GSPMD serving (prepare(mesh=...) analogue): the model shards its
@@ -498,17 +500,21 @@ class Engine:
             jit_kw.update(
                 in_shardings=(p_sh,) + (psh,) * n_state + (rep,) * 3,
                 out_shardings=(rep, rep) + (psh,) * n_state)
-        # watchdog-wrapped: the ONE statically-shaped program — prompt
-        # chunks and decode rows share it — must compile exactly once;
-        # any recompile here is a serving bug the watchdog flags with
-        # the offending shape diff
-        self._step_fn = watch(jax.jit(_step, **jit_kw),
-                              name="serving::unified_step")
         # the ids the newest dispatched step chose, still on the device:
         # the next step's last operand
         ids = np.zeros((max_batch_size + n_stats,), np.int32)
         self._prev_ids = (jnp.asarray(ids) if self._replicated is None
                           else jax.device_put(ids, self._replicated))
+        # watchdog-wrapped: the ONE statically-shaped program — prompt
+        # chunks and decode rows share it — must compile exactly once;
+        # any recompile here is a serving bug the watchdog flags with
+        # the offending shape diff.  Described by its shapes alone (no
+        # lowering, no compile here), so that the watchdog can say what
+        # the compiled step is made of after this engine is gone
+        # (``instruction_table``)
+        self._step_fn = watch(
+            jax.jit(_step, **jit_kw), name="serving::unified_step",
+            abstract_args=self.step_args(sharding=self._replicated))
 
     # ------------------------------------------------------------- submit
     def add_request(self, prompt, sampling: SamplingParams = None, *,

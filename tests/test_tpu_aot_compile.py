@@ -23,6 +23,23 @@ def devices():
     return tpu_aot.topology_devices()
 
 
+@pytest.fixture(scope="module")
+def serve_small(devices):
+    """The GPT serving step at a pool of 64 pages, compiled once."""
+    return tpu_aot.lower_serve_step(
+        devices, num_pages=64, max_batch_size=16, chunk_len=128).compile()
+
+
+@pytest.fixture(scope="module")
+def hybrid_compiled(devices):
+    return tpu_aot.lower_hybrid_serve_step(devices).compile()
+
+
+@pytest.fixture(scope="module")
+def moe_compiled(devices):
+    return tpu_aot.lower_moe_window_serve_step(devices).compile()
+
+
 def test_kernels_compile_for_v5e(devices):
     device = devices[0]
     assert (device.platform, device.device_kind) == ("tpu", "TPU v5 lite")
@@ -34,16 +51,14 @@ def test_kernels_compile_for_v5e(devices):
             f"{name}: no Mosaic kernel in the compiled program"
 
 
-def test_serve_step_moves_no_page_pool(devices):
+def test_serve_step_moves_no_page_pool(serve_small):
     """The compiled serving step touches its two donated page pools with
     one in-place scatter each and the kernel, and nothing else: no copy,
     slice, update-slice or fusion produces a pool, or one layer's pages,
     alone or inside a tuple-shaped result (as the layer scan's xs/ys did:
     4 x 64 MiB a layer and two 1.5 GiB copies a step at the benchmark's
     1024 pages)."""
-    pages = 64
-    compiled = tpu_aot.lower_serve_step(
-        devices, num_pages=pages, max_batch_size=16, chunk_len=128).compile()
+    pages, compiled = 64, serve_small
     text = compiled.as_text()
     from paddle_tpu.models.gpt import GPT_CONFIGS
 
@@ -121,7 +136,8 @@ def test_gpt_serve_step_keeps_its_temporaries_behind_the_model_interface(
     assert (text.count(" conditional("), text.count(" sort(")) == (1, 0)
 
 
-def test_hybrid_serve_step_compiles_for_v5e_and_moves_no_pool(devices):
+def test_hybrid_serve_step_compiles_for_v5e_and_moves_no_pool(
+        hybrid_compiled):
     """The sparse-plus-lightning step at the benchmark cell's shapes
     (published widths, 8 layers, 16 rows, chunks of 512, 8192 pages of
     64): Mosaic accepts both new kernels, every state pool is donated and
@@ -138,7 +154,7 @@ def test_hybrid_serve_step_compiles_for_v5e_and_moves_no_pool(devices):
     plan with the resolved tokens packs 32,256 B tighter)."""
     from paddle_tpu.models.hybrid import HYBRID_CONFIGS
 
-    compiled = tpu_aot.lower_hybrid_serve_step(devices).compile()
+    compiled = hybrid_compiled
     text, mem = compiled.as_text(), compiled.memory_analysis()
     cfg = HYBRID_CONFIGS["minicpm-sala-8l"]
     # one Mosaic call a layer: 2 sparse, 6 lightning
@@ -199,7 +215,8 @@ def test_ssm_serve_step_compiles_for_v5e_and_fits_the_chip(devices):
     assert not movers, "scan state movers:\n" + "\n".join(movers)
 
 
-def test_moe_window_serve_step_compiles_for_v5e_and_fits_the_chip(devices):
+def test_moe_window_serve_step_compiles_for_v5e_and_fits_the_chip(
+        moe_compiled):
     """The sparse-expert step with sliding-window layers at the benchmark
     cell's shapes — published widths, 8 layers, 64 of 256 experts, 25,088
     ids, 64 rows, chunks of 1024 (1,088 packed tokens), 2,240 full-layer and
@@ -211,7 +228,7 @@ def test_moe_window_serve_step_compiles_for_v5e_and_fits_the_chip(devices):
     compile of this step planned), and the plan fits the chip before any
     chip time is spent: 9.37 GB of weights, 2.35 GB of full-layer pages and
     0.81 GB of window-layer pages among 12.52 GB of arguments."""
-    compiled = tpu_aot.lower_moe_window_serve_step(devices).compile()
+    compiled = moe_compiled
     text, mem = compiled.as_text(), compiled.memory_analysis()
     assert text.count('custom_call_target="tpu_custom_call"') == 8 + 2 * 7
     pools = 2 * 2 * 2240 * 2 * 512 * 128 * 2 + 2 * 6 * 256 * 2 * 512 * 128 * 2
@@ -232,3 +249,96 @@ def test_moe_window_serve_step_compiles_for_v5e_and_fits_the_chip(devices):
                           r"3072)\]", line)]
     assert not sliced, "a layer's experts out of their stack:\n" \
         + "\n".join(sliced)
+
+
+# ------------------------------------------------ the instruction table
+# (paddle_tpu/observability/compile_watchdog.py) on the TPU compiler's text
+
+
+def _table(compiled):
+    from paddle_tpu.observability import compile_watchdog as cw
+
+    table = cw.parse_instruction_table(compiled.as_text())
+    # XLA names a Mosaic call after the kernel's own scope; the
+    # ``get-tuple-element``s that hand on the results of a kernel with
+    # several carry its path under other names
+    calls = {n: p for n, p in table.items()
+             if cw.leaf_primitive(p) == "pallas_call"
+             and n.startswith(cw.named_scopes(p)[-1])}
+    return cw, table, calls
+
+
+def _scoped_share(cw, table):
+    fusions = [p for n, p in table.items() if "fusion" in n]
+    return sum(bool(cw.named_scopes(p)) for p in fusions) / len(fusions)
+
+
+def test_serve_step_table_names_kernel_scatters_and_most_fusions(
+        serve_small):
+    """The names a trace of the GPT serving cell shows (``fusion.196``,
+    ``ragged_paged_attention.3``) are this table's keys: the one Mosaic
+    call under ``attn``, both page scatters under ``kv_write``, and at
+    least 80% of the top-level fusions under some named scope (the rest:
+    the layer scan's own slicing of the stacked biases, and what XLA made
+    without metadata)."""
+    cw, table, calls = _table(serve_small)
+    assert len(calls) == 1
+    (name, path), = calls.items()
+    assert name.startswith("ragged_paged_attention")
+    assert cw.named_scopes(path)[-2:] == ("attn", "ragged_paged_attention")
+    scatters = [n for n, p in table.items()
+                if "kv_write" in cw.named_scopes(p)
+                and cw.leaf_primitive(p) == "scatter"]
+    assert len(scatters) == 2 and all("fusion" in n for n in scatters)
+    assert _scoped_share(cw, table) >= 0.8
+    scopes = {s for p in table.values() for s in cw.named_scopes(p)}
+    assert {"attn", "kv_write", "mlp", "lm_head", "embed", "work_list",
+            "batch_view", "pending", "sample"} <= scopes
+    assert {cw.pass_of(p) for p in table.values()} == {None}
+
+
+def test_train_step_table_separates_the_replayed_forward(devices):
+    """Under full recomputation the flash forward kernel is in the
+    compiled train step twice: once as the ``forward`` pass and once as
+    the ``recompute`` the backward pass asks for; the two backward
+    kernels are ``backward``, the optimizer is no pass at all."""
+    cw, table, calls = _table(tpu_aot.lower_train_step(devices[:1]).compile())
+    passes = {}
+    for name, path in calls.items():
+        kernel = cw.named_scopes(path)[-1]
+        assert name.startswith(kernel)
+        passes.setdefault(kernel, []).append(cw.pass_of(path))
+    assert sorted(passes.pop("flash_fwd")) == ["forward", "recompute"]
+    assert passes == {"flash_bwd_dkdv": ["backward"],
+                      "flash_bwd_dq": ["backward"]}
+    assert _scoped_share(cw, table) >= 0.9
+    optimizer = [p for p in table.values()
+                 if "optimizer" in cw.named_scopes(p)]
+    assert len(optimizer) > 20
+    assert {cw.pass_of(p) for p in optimizer} == {None}
+    replayed = {s for p in table.values() if cw.pass_of(p) == "recompute"
+                for s in cw.named_scopes(p)}
+    assert {"forward_backward", "attn", "mlp"} <= replayed
+
+
+def test_hybrid_and_moe_step_tables_name_selection_and_experts(
+        hybrid_compiled, moe_compiled):
+    cw, table, calls = _table(hybrid_compiled)
+    under = lambda scope: {n: p for n, p in table.items()
+                           if scope in cw.named_scopes(p)}
+    assert any(n.startswith("sort") for n in under("select"))
+    assert any(cw.leaf_primitive(p) == "dot_general"
+               for p in under("select").values())
+    assert under("work_list") and not set(under("work_list")) & set(
+        under("select"))
+    assert sorted(cw.named_scopes(p)[-2] for p in calls.values()) == \
+        ["sparse_attn"] * 2 + ["state_write"] * 6
+    assert _scoped_share(cw, table) >= 0.8
+
+    cw, table, calls = _table(moe_compiled)
+    experts = [p for p in calls.values() if "experts" in cw.named_scopes(p)]
+    assert len(experts) == 2 * 7 and len(calls) == 8 + 2 * 7
+    outside = [n for n, p in table.items() if n not in calls
+               and "experts" in cw.named_scopes(p) and "fusion" in n]
+    assert len(outside) > 7          # the rank, the scatter, the gathers
+    assert _scoped_share(cw, table) >= 0.8
