@@ -6,15 +6,20 @@ every token against all ``num_experts`` experts of the model and takes its
 (``experts_held``) and computes, for each token, the weighted outputs of
 those of its choices that it holds.  The rest of the sum lives on other
 holders; what a holder returns is its part, and a caller with every holder's
-part adds them up (``tests/test_dropless_experts.py`` does, at a small size).
+part adds them up (``tests/test_expert_matmul.py`` does, at a small size).
 Nothing here stands in for the other holders or for their exchange.
 
 **No token is dropped and no capacity exists.**  The (token, expert) pairs
-on held experts are sorted by expert into a buffer of static size
-(``kernels/expert_matmul.buffer_rows``: at most ``T * min(top_k, count)``
-pairs, so nothing can overflow) and multiplied group by group by the ragged
-kernel ``expert_matmul`` — gate and up with the SwiGLU between, then down —
-so a token's result does not depend on what its batch-mates chose.
+on held experts are ranked by expert into the rows of a buffer of static
+size (``kernels/expert_matmul.buffer_rows``: at most ``T * min(top_k,
+count)`` pairs, so nothing can overflow) — but the buffer holds no token
+rows: what is built at that size is each row's token id and routing weight
+(two small scatters).  The kernels move the rows themselves:
+``expert_matmul`` fetches a tile's real rows from ``u`` by id and computes
+gate and up with the SwiGLU between; ``expert_matmul_add`` multiplies by
+the down matrix and adds each float32 result, times its weight, into its
+token's row of the output.  Only the pairs a step really has are moved, and
+a token's result does not depend on what its batch-mates chose.
 ``distributed/moe.py`` is the other kind (GShard: one-hot dispatch einsums,
 tokens over capacity dropped); it is untouched and not used for serving.
 
@@ -28,13 +33,13 @@ import jax.numpy as jnp
 
 from ..kernels import dispatch
 from ..kernels.expert_matmul import (buffer_rows, expert_layout,
-                                     expert_matmul)
+                                     expert_matmul, expert_matmul_add)
 
 __all__ = ["route_top_k", "dropless_experts", "expert_tile"]
 
 
 def expert_tile(tokens, top_k, num_experts, dtype):
-    """Rows of one tile of the sorted buffer: about the pairs an expert
+    """Rows of one tile of the buffer's layout: about the pairs an expert
     expects (``tokens * top_k / num_experts``), a power of two between the
     dtype's sublane tile and 128: a tile far wider than a group is rows of
     padding multiplied for nothing."""
@@ -97,16 +102,14 @@ def dropless_experts(u, weights, experts, w_gate, w_up, w_down, *,
     N = buffer_rows(T * min(k, count), count, tile)
     dest = jnp.where(flat_e < count,
                      starts[jnp.minimum(flat_e, count - 1)] + rank, N)
-    # the token of each buffer row: an int32 scatter, then one row gather
+    # the token and the routing weight of each buffer row: two scatters
+    # of T * k scalars; the kernels fetch and add the rows of D themselves
     src = jnp.zeros((N,), jnp.int32).at[dest].set(
         jnp.arange(T * k, dtype=jnp.int32) // k, mode="drop")
-    x = jnp.take(u, src, axis=0)                                   # [N, D]
-    h = expert_matmul(x, group_sizes, w_gate, w_up, tile=tile, layer=layer,
-                      path=path)
-    out = expert_matmul(h, group_sizes, w_down, tile=tile, layer=layer,
-                        path=path)
-    # tiles past the last group were never written: read held pairs only
-    got = jnp.take(out, jnp.minimum(dest, N - 1), axis=0).reshape(T, k, D)
-    got = jnp.where(held[..., None], got.astype(jnp.float32), 0.0)
-    y = jnp.sum(got * weights[..., None], axis=1)
+    scale = jnp.zeros((N,), jnp.float32).at[dest].set(
+        weights.reshape(T * k), mode="drop")
+    h = expert_matmul(u, src, group_sizes, w_gate, w_up, tile=tile,
+                      layer=layer, path=path)
+    y = expert_matmul_add(h, src, scale, group_sizes, w_down, tokens=T,
+                          tile=tile, layer=layer, path=path)
     return y, group_sizes

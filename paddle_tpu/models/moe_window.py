@@ -37,7 +37,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .experts import dropless_experts, route_top_k
+from ..kernels.expert_matmul import visited_rows
+from .experts import dropless_experts, expert_tile, route_top_k
 from .hybrid import _gated_mlp, _rms
 from .ragged import RaggedBatch, RaggedView
 
@@ -266,10 +267,11 @@ def moe_window_ragged_step(cfg: MoEWindowConfig, params, batch: RaggedBatch,
     (default: ``max_q``, at most 128).
 
     Returns ``(logits [B, V] float32, k_pages, v_pages, window_k_pages,
-    window_v_pages, stats [3] int32)``: ``stats`` is the (token, expert)
+    window_v_pages, stats [4] int32)``: ``stats`` is the (token, expert)
     pairs the held experts computed over all sparse layers, the most any
-    one held expert got in one layer, and the (layer, held expert)s that
-    got a pair at all: whose weights the step read."""
+    one held expert got in one layer, the (layer, held expert)s that got a
+    pair at all: whose weights the step read, and the rows of the tiles
+    the expert kernels visited (the pairs and their tiles' padding)."""
     from ..kernels.paged_attention import ragged_paged_attention
 
     tokens, query_lens, context_lens = (batch.tokens, batch.query_lens,
@@ -278,6 +280,7 @@ def moe_window_ragged_step(cfg: MoEWindowConfig, params, batch: RaggedBatch,
     Hkv, hd = cfg.num_kv_heads, cfg.head_dim
     f32, dtype = jnp.float32, cfg.jdtype()
     query_tile = query_tile or min(_QUERY_TILE, max_q or T)
+    pair_tile = expert_tile(T, cfg.top_k, cfg.num_experts, dtype)
     view = RaggedView(batch, max_q=max_q, max_seq_len=cfg.max_seq_len,
                       num_pages=k_pages.shape[1], page_size=k_pages.shape[3],
                       num_window_pages=window_k_pages.shape[1])
@@ -340,7 +343,7 @@ def moe_window_ragged_step(cfg: MoEWindowConfig, params, batch: RaggedBatch,
                 u, weights, experts, p["gate_w"], p["up_w"], p["down_w"],
                 layer=i, experts_held=cfg.experts_held,
                 num_experts=cfg.num_experts, valid=view.valid,
-                path=attn_path)
+                tile=pair_tile, path=attn_path)
         y = x.astype(f32) + shared.astype(f32) + routed
         return y.astype(dtype), sizes
 
@@ -357,6 +360,7 @@ def moe_window_ragged_step(cfg: MoEWindowConfig, params, batch: RaggedBatch,
     pairs = jnp.zeros((), jnp.int32)
     fullest = jnp.zeros((), jnp.int32)
     active = jnp.zeros((), jnp.int32)
+    tile_rows = jnp.zeros((), jnp.int32)
     for kind, mlp in zip(cfg.layer_types, cfg.mlp_types):
         i, j = seen[kind], seen[mlp]
         seen[kind] += 1
@@ -371,6 +375,7 @@ def moe_window_ragged_step(cfg: MoEWindowConfig, params, batch: RaggedBatch,
             pairs = pairs + jnp.sum(sizes)
             fullest = jnp.maximum(fullest, jnp.max(sizes))
             active = active + jnp.sum((sizes > 0).astype(jnp.int32))
+            tile_rows = tile_rows + visited_rows(sizes, pair_tile)
         else:
             x = dense_mlp(x, j)
 
@@ -379,4 +384,4 @@ def moe_window_ragged_step(cfg: MoEWindowConfig, params, batch: RaggedBatch,
         logits = jnp.einsum("bd,dv->bv", view.last(x), params["lm_head"],
                             preferred_element_type=f32)
     return (logits, k_pages, v_pages, window_k_pages, window_v_pages,
-            jnp.stack([pairs, fullest, active]))
+            jnp.stack([pairs, fullest, active, tile_rows]))

@@ -34,6 +34,9 @@ process-wide registry); this module keeps the serving-shaped facade:
   expert_pairs / expert_weight_reads / expert_rows_max — a sparse-expert
                  model: pairs computed on the held experts, experts whose
                  weights a step read, and how uneven the routing was
+  expert_tile_rows — the same model: rows of the tiles its expert kernels
+                 visited, the pairs and their tiles' padding (pairs over
+                 tile rows is the fill of a tile)
   steps_dispatched / pipeline_drains / overrun_rows — how often the
                  step in flight hid the host's phases, how often it had
                  to be settled first and why, and the rows computed for
@@ -238,6 +241,13 @@ class ServingMetrics:
             help="(token, expert) pairs the experts held here computed, "
                  "over all sparse layers; counted on the device, read "
                  "with the step's ids"))
+        self.expert_tile_rows = add(Counter(
+            "serving_expert_tile_rows_total",
+            help="rows of the tiles the expert kernels visited, over all "
+                 "sparse layers: each held expert's pairs rounded up to "
+                 "whole tiles, what the kernels fetch room for, multiply "
+                 "and add; counted on the device, read with the step's "
+                 "ids"))
         self.expert_weight_reads = add(Counter(
             "serving_expert_weight_reads_total",
             help="(layer, held expert)s that got at least one pair in a "

@@ -208,7 +208,8 @@ class MoEWindowServed:
     prefix whose window pages were given back cannot be resumed."""
 
     recurrent = False
-    step_stats = ("expert_pairs", "expert_rows_fullest", "experts_read")
+    step_stats = ("expert_pairs", "expert_rows_fullest", "experts_read",
+                  "expert_tile_rows")
 
     def __init__(self, cfg):
         self.cfg = cfg
@@ -253,8 +254,9 @@ class MoEWindowServed:
     def record_stats(self, metrics, values):
         from ..models.moe_window import SPARSE
 
-        pairs, fullest, read = values
+        pairs, fullest, read, tile_rows = values
         metrics.expert_pairs.inc(pairs)
+        metrics.expert_tile_rows.inc(tile_rows)
         metrics.expert_weight_reads.inc(read)
         slots = self.cfg.mlp_types.count(SPARSE) * self.cfg.experts_held[1]
         metrics.expert_rows_max.set(fullest * slots / pairs if pairs else 0)

@@ -1,10 +1,16 @@
-"""The grouped matrix product of the dropless expert layer
-(``kernels/expert_matmul.py``): the Pallas kernel under the interpreter
-against the ``jnp`` path and against a plain loop over the groups, at
-routings that leave experts empty, fill one expert with everything and need
-several tiles an expert; and the expert layer around it
+"""The two grouped products of the dropless expert layer
+(``kernels/expert_matmul.py``), which move their own rows: the Pallas
+kernels under the interpreter and the ``jnp`` paths against plain loops —
+the fetch-by-id call against the product over ``take(u, rows)``, the
+weighted add against gathering every pair's result back, masking and
+summing over a token's choices — at routings that leave experts empty, fill
+one expert with everything (several tiles adding into the same tokens),
+put two choices of a token in one tile, hold every choice, hold none, and
+carry padding tokens; and the expert layer around them
 (``models/experts.py``): every choice held, none held, the shares of four
 holders adding up to the whole layer."""
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,33 +18,90 @@ import pytest
 
 from paddle_tpu.kernels import dispatch
 from paddle_tpu.kernels.expert_matmul import (_items, buffer_rows,
-                                              expert_layout, expert_matmul)
+                                              expert_layout, expert_matmul,
+                                              expert_matmul_add,
+                                              visited_rows)
 from paddle_tpu.models.experts import (dropless_experts, expert_tile,
                                        route_top_k)
 
 E, K, N_OUT, TILE, LAYERS = 5, 16, 256, 8, 2
+TOKENS = 6
+ELSEWHERE = E                    # a choice this holder does not hold
+JUNK = 10 ** 6                   # the token id of a padding row: never read
+PATHS = [dispatch.REFERENCE, dispatch.INTERPRET]
 
 
-def case(sizes, seed=0):
-    """A buffer in the layout of ``sizes``, its padding rows filled with
-    junk that must not show."""
-    sizes = np.asarray(sizes, np.int32)
-    ks = jax.random.split(jax.random.key(seed), 4)
-    n = buffer_rows(int(sizes.sum()), E, TILE)
-    x = jax.random.normal(ks[0], (n, K))
-    w = jax.random.normal(ks[1], (LAYERS, E, K, N_OUT)) / 4
-    up = jax.random.normal(ks[2], (LAYERS, E, K, N_OUT)) / 4
-    return x, jnp.asarray(sizes), w, up
+def routing_of(sizes):
+    """``experts [TOKENS, k]`` that give the held experts ``sizes`` pairs:
+    the pairs dealt to the tokens in turn, so a token has several choices,
+    on one expert where a group is larger than the tokens."""
+    flat = np.repeat(np.arange(E), sizes)
+    k = max(1, -(-len(flat) // TOKENS))
+    flat = np.concatenate([flat, np.full(TOKENS * k - len(flat), ELSEWHERE)])
+    return flat.reshape(k, TOKENS).T.copy()
 
 
-def by_hand(x, sizes, w, up, layer):
-    """Group by group; rows outside every group stay ``nan`` (the kernel
+SIZES = [[3, 0, 9, 1, 8],        # an empty expert, a tile and a bit
+         [0, 0, 21, 0, 0],       # everything on one expert: three tiles
+         [0, 0, 0, 0, 0],        # no pair at all
+         [8, 8, 8, 8, 8]]        # whole tiles
+ALL_VALID = np.ones(TOKENS, bool)
+# name -> (experts [TOKENS, k], valid [TOKENS])
+ROUTINGS = {
+    **{f"sizes{i}": (routing_of(s), ALL_VALID) for i, s in enumerate(SIZES)},
+    "every_choice_held": (
+        (np.arange(TOKENS)[:, None] + np.arange(4)[None, :]) % E, ALL_VALID),
+    "two_choices_of_a_token_in_one_tile": (
+        np.array([[1, 1, 4], [1, ELSEWHERE, 1], [3, 3, 3]] * 2), ALL_VALID),
+    "padding_tokens_touch_nothing": (
+        (np.arange(TOKENS)[:, None] + np.arange(3)[None, :]) % E,
+        np.arange(TOKENS) % 3 != 1),
+}
+
+
+def case(name, seed=0):
+    """A routing laid out by hand: ``sizes [E]``, and per buffer row its
+    token id and routing weight (junk in the padding rows, which must not
+    show and must not be read), per (token, choice) its buffer row (-1:
+    not held), the routing weights, and both calls' matrices."""
+    experts, valid = ROUTINGS[name]
+    T, k = experts.shape
+    rng = np.random.default_rng(seed)
+    weights = rng.uniform(0.1, 1.0, (T, k)).astype(np.float32)
+    held = (experts < E) & valid[:, None]
+    sizes = np.bincount(experts[held], minlength=E).astype(np.int32)
+    tiles = -(-sizes // TILE)
+    starts = (np.cumsum(tiles) - tiles) * TILE
+    n = buffer_rows(T * min(k, E), E, TILE)
+    rows = np.full(n, JUNK, np.int32)
+    scale = np.full(n, np.nan, np.float32)
+    dest = np.full((T, k), -1)
+    fill = np.zeros(E, int)
+    for t in range(T):
+        for j in range(k):
+            if held[t, j]:
+                e = experts[t, j]
+                dest[t, j] = r = starts[e] + fill[e]
+                fill[e] += 1
+                rows[r], scale[r] = t, weights[t, j]
+    ks = jax.random.split(jax.random.key(seed), 5)
+    return dict(
+        sizes=jnp.asarray(sizes), rows=jnp.asarray(rows),
+        scale=jnp.asarray(scale), dest=dest, weights=weights, starts=starts,
+        u=jax.random.normal(ks[0], (T, K)),
+        w=jax.random.normal(ks[1], (LAYERS, E, K, N_OUT)) / 4,
+        up=jax.random.normal(ks[2], (LAYERS, E, K, N_OUT)) / 4,
+        h=jax.random.normal(ks[3], (n, N_OUT)),
+        down=jax.random.normal(ks[4], (LAYERS, E, N_OUT, K)) / 4)
+
+
+def by_hand(x, c, w, up, layer):
+    """Group by group; rows outside every group stay ``nan`` (a call
     writes zeros in a visited tile's padding and nothing past the last
-    group: see ``rows_written``)."""
-    starts, _ = expert_layout(sizes, TILE)
-    out = np.full((x.shape[0], N_OUT), np.nan, np.float32)
+    group)."""
+    out = np.full((x.shape[0], w.shape[-1]), np.nan, np.float32)
     x = np.asarray(x, np.float64)
-    for e, (s, n) in enumerate(zip(np.asarray(starts), np.asarray(sizes))):
+    for e, (s, n) in enumerate(zip(c["starts"], np.asarray(c["sizes"]))):
         rows = x[s:s + n]
         y = rows @ np.asarray(w[layer, e], np.float64)
         if up is not None:
@@ -48,59 +111,147 @@ def by_hand(x, sizes, w, up, layer):
     return out
 
 
-SIZES = [[3, 0, 9, 1, 8],        # an empty expert, a tile and a bit
-         [0, 0, 21, 0, 0],       # everything on one expert: three tiles
-         [0, 0, 0, 0, 0],        # no pair at all
-         [8, 8, 8, 8, 8]]        # whole tiles
-
-
 @pytest.mark.parametrize("swiglu", [False, True])
-@pytest.mark.parametrize("sizes", SIZES)
-@pytest.mark.parametrize("path", [dispatch.REFERENCE, dispatch.INTERPRET])
-def test_each_group_times_its_own_experts_matrix(path, sizes, swiglu):
-    x, sizes, w, up = case(sizes)
-    up = up if swiglu else None
+@pytest.mark.parametrize("name", ROUTINGS)
+@pytest.mark.parametrize("path", PATHS)
+def test_rows_fetched_by_id_times_their_groups_matrix(path, name, swiglu):
+    """``expert_matmul(u, rows, ...)`` is the product over ``take(u,
+    rows)``: nobody builds that buffer, and the padding rows' ids are not
+    read."""
+    c = case(name)
+    up = c["up"] if swiglu else None
     got = np.asarray(jax.jit(lambda *a: expert_matmul(
-        *a, tile=TILE, layer=jnp.int32(1), path=path))(x, sizes, w, up))
-    want = by_hand(x, sizes, w, up, 1)
+        *a, tile=TILE, layer=jnp.int32(1), path=path))(
+        c["u"], c["rows"], c["sizes"], c["w"], up))
+    x = np.asarray(c["u"])[np.minimum(np.asarray(c["rows"]), TOKENS - 1)]
+    want = by_hand(x, c, c["w"], up, 1)
     real = ~np.isnan(want[:, 0])
+    assert real.sum() == int(c["sizes"].sum())
     np.testing.assert_allclose(got[real], want[real], atol=2e-4)
     # a visited tile's padding rows are zeros, whatever the buffer held
-    starts, tiles = (np.asarray(a) for a in expert_layout(sizes, TILE))
-    for s, t, n in zip(starts, tiles, np.asarray(sizes)):
+    _, tiles = (np.asarray(a) for a in expert_layout(c["sizes"], TILE))
+    for s, t, n in zip(c["starts"], tiles, np.asarray(c["sizes"])):
         assert not got[s + n:s + t * TILE].any()
 
 
+@pytest.mark.parametrize("name", ROUTINGS)
+@pytest.mark.parametrize("path", PATHS)
+def test_weighted_results_are_added_into_their_tokens_rows(path, name):
+    """``expert_matmul_add`` against the formulation it replaced: every
+    pair's result gathered back from the buffer, the choices held
+    elsewhere masked, the weighted sum over a token's choices.  A token
+    without a pair, and every token when nobody has one, gets zeros; junk
+    in the padding rows (``nan`` weights, ids out of range) does not show."""
+    c = case(name, seed=1)
+    got = np.asarray(jax.jit(lambda *a: expert_matmul_add(
+        *a, tokens=TOKENS, tile=TILE, layer=jnp.int32(1), path=path))(
+        c["h"], c["rows"], c["scale"], c["sizes"], c["down"]))
+    out = by_hand(c["h"], c, c["down"], None, 1)
+    held = c["dest"] >= 0
+    gathered = np.where(held[..., None], out[np.maximum(c["dest"], 0)], 0.0)
+    want = (gathered * c["weights"][..., None]).sum(axis=1)
+    assert got.shape == (TOKENS, K) and got.dtype == np.float32
+    np.testing.assert_allclose(got, want, atol=3e-4)
+    assert not got[~held.any(axis=1)].any()
+
+
+@pytest.mark.parametrize("call", ["fetch", "add"])
+def test_two_column_blocks_give_one_blocks_result(call, monkeypatch):
+    """The list walks a column block at a time; with the matrices in two
+    blocks every column is what it is in one (the weighted add zeroes and
+    fills each block of its accumulator in turn)."""
+    # (the package's attribute of that name is the function)
+    module = importlib.import_module("paddle_tpu.kernels.expert_matmul")
+    c = case("sizes0", seed=2)
+    wide = jax.random.normal(jax.random.key(9), (E, N_OUT, 256)) / 4
+
+    def run():
+        if call == "fetch":
+            return jax.jit(lambda: expert_matmul(
+                c["u"], c["rows"], c["sizes"], c["w"][0], c["up"][0],
+                tile=TILE, path=dispatch.INTERPRET))()
+        return jax.jit(lambda: expert_matmul_add(
+            c["h"], c["rows"], c["scale"], c["sizes"], wide, tokens=TOKENS,
+            tile=TILE, path=dispatch.INTERPRET))()
+
+    # the accumulator's own limit narrows the block as the tokens grow
+    assert module._column_block(1024, 3072, 2, acc_rows=64) == 3072
+    assert module._column_block(1024, 3072, 2, acc_rows=1088) == 1536
+    one = np.asarray(run())
+    depth = K if call == "fetch" else N_OUT
+    monkeypatch.setattr(module, "_BLOCK_BYTES", depth * 128 * 4)
+    assert module._column_block(depth, 256, 4) == 128
+    two = np.asarray(run())
+    real = ~np.isnan(by_hand(c["h"], c, c["down"], None, 0)[:, 0])
+    rows = real if call == "fetch" else slice(None)
+    np.testing.assert_allclose(one[rows], two[rows], atol=1e-4)
+
+
 def test_one_layers_matrices_without_a_layer():
-    x, sizes, w, up = case(SIZES[0], seed=1)
-    for path in (dispatch.REFERENCE, dispatch.INTERPRET):
-        a = expert_matmul(x, sizes, w[0], up[0], tile=TILE, path=path)
-        b = expert_matmul(x, sizes, w, up, tile=TILE, layer=0, path=path)
+    c = case("sizes0", seed=1)
+    u, rows, scale, sizes, h = (c[n] for n in ("u", "rows", "scale", "sizes",
+                                               "h"))
+    for path in PATHS:
+        a = expert_matmul(u, rows, sizes, c["w"][0], c["up"][0], tile=TILE,
+                          path=path)
+        b = expert_matmul(u, rows, sizes, c["w"], c["up"], tile=TILE,
+                          layer=0, path=path)
+        real = np.asarray(rows) != JUNK
+        np.testing.assert_array_equal(np.asarray(a)[real],
+                                      np.asarray(b)[real])
+        a = expert_matmul_add(h, rows, scale, sizes, c["down"][1],
+                              tokens=TOKENS, tile=TILE, path=path)
+        b = expert_matmul_add(h, rows, scale, sizes, c["down"],
+                              tokens=TOKENS, tile=TILE, layer=1, path=path)
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     with pytest.raises(ValueError, match="layer"):
-        expert_matmul(x, sizes, w, tile=TILE)
+        expert_matmul(u, rows, sizes, c["w"], tile=TILE)
+    with pytest.raises(ValueError, match="layer"):
+        expert_matmul_add(h, rows, scale, sizes, c["down"], tokens=TOKENS,
+                          tile=TILE)
     with pytest.raises(ValueError, match="whole tiles"):
-        expert_matmul(x[:-1], sizes, w[0], tile=TILE)
+        expert_matmul(u, rows[:-1], sizes, c["w"][0], tile=TILE)
+    with pytest.raises(ValueError, match="pieces"):
+        expert_matmul(u[:, :-1], rows, sizes, c["w"][0, :, :-1], tile=TILE,
+                      path=dispatch.INTERPRET)
 
 
 @pytest.mark.parametrize("sizes", SIZES)
 def test_an_experts_weights_are_fetched_once_and_only_if_it_has_a_pair(
         sizes):
-    """The work list orders items expert, column block, row tile: the
-    weight block's index changes once per (expert with a pair, block), so
-    Pallas fetches every such block once and no other."""
+    """The work list orders items column block, then the visited tiles in
+    buffer order, which is expert order: the weight block's index changes
+    once per (block, expert with a pair), so Pallas fetches every such
+    block once and no other; a token's results arrive in ascending expert
+    order; with no pair at all one item a block still writes the output."""
     sizes = jnp.asarray(sizes, jnp.int32)
     blocks, n_tiles = 3, buffer_rows(int(sizes.sum()), E, TILE) // TILE
-    e, blk, tile, n, _ = (np.asarray(a) for a in _items(
+    e, blk, tile, n, live = (np.asarray(a) for a in _items(
         sizes, TILE, blocks, n_tiles))
     n = int(n[0])
-    _, tiles = (np.asarray(a) for a in expert_layout(sizes, TILE))
-    assert n == blocks * tiles.sum()
-    keys = list(zip(e[:n], blk[:n]))
-    fetches = 1 + sum(a != b for a, b in zip(keys, keys[1:])) if n else 0
-    assert fetches == blocks * (np.asarray(sizes) > 0).sum()
-    # and every (tile, block) of the output is written exactly once
+    starts, tiles = (np.asarray(a) for a in expert_layout(sizes, TILE))
+    visited = int(tiles.sum())
+    assert n == blocks * max(visited, 1)
+    assert int(visited_rows(sizes, TILE)) == visited * TILE
+    keys = list(zip(blk[:n], e[:n]))
+    fetches = 1 + sum(a != b for a, b in zip(keys, keys[1:]))
+    assert fetches == blocks * max(int((np.asarray(sizes) > 0).sum()), 1)
+    # every (tile, block) of the output is written exactly once, the tiles
+    # of a block in buffer order and so in expert order
     assert len({(t, b) for t, b in zip(tile[:n], blk[:n])}) == n
+    per = max(visited, 1)
+    assert tile[:n].tolist() == list(range(per)) * blocks
+    assert blk[:n].tolist() == sorted(blk[:n].tolist())
+    assert all(np.diff(e[b * per:(b + 1) * per]).min(initial=0) >= 0
+               for b in range(blocks))
+    # the rows of each buffer tile that are pairs
+    want = np.zeros(n_tiles, int)
+    for s, t, size in zip(starts, tiles, np.asarray(sizes)):
+        for j in range(t):
+            want[s // TILE + j] = min(TILE, size - j * TILE)
+    assert live.tolist() == want.tolist()
+    assert live[:visited].sum() == int(sizes.sum()) and (
+        visited or not live.any())
 
 
 def test_the_buffer_holds_any_routing():

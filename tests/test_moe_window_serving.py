@@ -253,6 +253,38 @@ def test_the_router_is_neither_a_tie_nor_one_expert(tiny):
         m.steps_ahead.value + m.steps_not_ahead.value)
 
 
+def test_tile_rows_are_each_layers_groups_rounded_up_to_whole_tiles(
+        tiny, monkeypatch):
+    """``serving_expert_tile_rows_total`` is, over the sparse layers of
+    every step, each held expert's pairs rounded up to whole tiles of the
+    step's tile: what the expert kernels visit.  Never under the pairs."""
+    import paddle_tpu.models.moe_window as mw
+
+    cfg, params, _ = tiny
+    seen, sound = [], mw.dropless_experts
+
+    def spy(*args, tile, **kw):
+        y, sizes = sound(*args, tile=tile, **kw)
+        jax.debug.callback(
+            lambda s: seen.append((tile, np.asarray(s))), sizes)
+        return y, sizes
+
+    monkeypatch.setattr(mw, "dropless_experts", spy)
+    rng = np.random.default_rng(6)
+    prompts = [rng.integers(0, 512, n).tolist() for n in (40, 7)]
+    _, _, eng = serve(cfg, params, prompts, (3, 5), page_size=4,
+                      num_pages=64, max_batch_size=2, chunk_len=16)
+    jax.effects_barrier()
+    m = eng.metrics
+    tile = mw.expert_tile(17, cfg.top_k, cfg.num_experts, jnp.float32)
+    assert seen and {t for t, _ in seen} == {tile}
+    assert m.expert_pairs.value == sum(int(s.sum()) for _, s in seen) > 0
+    assert m.expert_tile_rows.value == sum(
+        int((-(-s // tile)).sum()) * tile for _, s in seen)
+    assert m.expert_tile_rows.value >= m.expert_pairs.value
+    assert MoEWindowServed.step_stats[-1] == "expert_tile_rows"
+
+
 def test_the_pallas_kernels_under_the_interpreter_give_the_jnp_paths_step():
     """One step of the model with both kernels (the window's lower edge,
     the grouped expert product) under the Pallas interpreter against the

@@ -249,6 +249,14 @@ def test_moe_window_serve_step_compiles_for_v5e_and_fits_the_chip(
                           r"3072)\]", line)]
     assert not sliced, "a layer's experts out of their stack:\n" \
         + "\n".join(sliced)
+    # the expert kernels move their own rows: no array of the sorted
+    # buffer's 14,976 rows of 3072, and no [tokens, choices, 3072] of
+    # results gathered back, is built around them
+    moved = [line.strip()[:160] for line in text.splitlines()
+             if re.match(r"\s+(?:ROOT )?%\S+ = \w+\[(14976,3072|"
+                         r"108[78],10,3072)\]", line)]
+    assert not moved, "token rows moved outside the expert kernels:\n" \
+        + "\n".join(moved)
 
 
 # ------------------------------------------------ the instruction table
@@ -340,5 +348,5 @@ def test_hybrid_and_moe_step_tables_name_selection_and_experts(
     assert len(experts) == 2 * 7 and len(calls) == 8 + 2 * 7
     outside = [n for n, p in table.items() if n not in calls
                and "experts" in cw.named_scopes(p) and "fusion" in n]
-    assert len(outside) > 7          # the rank, the scatter, the gathers
+    assert len(outside) > 7          # the rank, the layout, two scatters
     assert _scoped_share(cw, table) >= 0.8

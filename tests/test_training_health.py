@@ -22,6 +22,18 @@ from paddle_tpu.observability.compile_watchdog import (default_watchdog,
 from paddle_tpu.observability.goodput import device_peak_flops, mfu
 
 
+@pytest.fixture(autouse=True, scope="module")
+def _leave_no_health_gauge_behind():
+    """Some monitors here live in the process's default registry; a
+    ``training_healthy`` of 0 left there turns every later ``/healthz`` of
+    this worker into a 503 (``tests/test_fleet.py``, when the scheduler
+    gives it the worker after this file)."""
+    yield
+    from paddle_tpu.observability import default_registry
+
+    default_registry().unregister("training_healthy")
+
+
 class Toy(Dataset):
     def __init__(self, n=16, bad_at=None):
         rng = np.random.RandomState(0)
