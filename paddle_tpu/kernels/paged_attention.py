@@ -120,11 +120,19 @@ turning and reading them took more of a call than its pages did (2.5 ms of
 a window layer's call against 0.04 ms of keys and values: chip run, PR 35).
 With ``q_tiles = (tile_rows [NT], tile_index [NT])`` the queries come as
 ``[NT, tile, H, hd]``: tile ``n`` holds the query slots ``tile_index[n] *
-tile ...`` of batch row ``tile_rows[n]`` (``B`` marks an unused tile), one
-tile a decode row and one more for every ``tile`` tokens of a chunk
-(``models/ragged.py`` ``RaggedView.pad_tiles``), and the result comes back
-in the same layout.  The items are (tile, group, page); the body is the
-grouped mode's without the lists.
+tile ...`` of batch row ``tile_rows[n]`` (``B`` marks an unused tile), a
+row's tiles consecutive and in order, one tile a decode row and one more
+for every ``tile`` tokens of a chunk (``models/ragged.py``
+``RaggedView.pad_tiles``; the model steps take tiles of ``QUERY_TILE`` =
+16 slots), and the result comes back in the same layout.  The kernel cuts
+a row's tiles into runs of up to ``_RUN_TILES`` = 8 and walks (run, group,
+page) items: a run of one tile (every decode row) goes through a body 16
+slots wide, a longer one through a body that reads 8 tiles in one block and
+multiplies their flattened ``[8 * G * 16, hd]`` rows in one product each
+way, so a chunk is still read 128 query slots at a time against a page.
+Results leave by the kernel's own copies, one a tile of the run, the next
+run's going while this one is multiplied, so no write reaches another
+row's tiles.  The body is the grouped mode's without the lists.
 """
 from __future__ import annotations
 
@@ -140,7 +148,7 @@ from ..ops.linalg import mxu_precision
 from . import dispatch
 
 __all__ = ["ragged_paged_attention", "ragged_work_items",
-           "listed_work_items"]
+           "listed_work_items", "query_tile_runs", "QUERY_TILE"]
 
 _NEG_INF = -1e30
 
@@ -156,6 +164,10 @@ _SMALL_Q = 16
 # whole call at the long-context cell's shapes, 256 40% more (PERF.md §6
 # PR 36)
 _LISTED_KEY_TILE = 512
+# query slots of one tile of the tile-packed mode (``q_tiles=``), and the
+# tiles of one row that one of its work items reads at most
+QUERY_TILE = 16
+_RUN_TILES = 8
 
 
 def _stacked(k_pages, v_pages, layer):
@@ -770,76 +782,155 @@ def _tiled_attention_ref(q, k_pages, v_pages, page_tables, query_lens,
     return jnp.where(valid[:, :, None, None], out, 0.0).astype(q.dtype)
 
 
-def _tiled_kernel(seg_ref, lp_ref, n_ref, trow_ref, tidx_ref, tbl_ref,
-                  qlen_ref, ctx_ref, layer_ref, q_ref, kp_ref, vp_ref, o_ref,
-                  acc_ref, m_ref, l_ref, *, scale, page_size, groups, tile,
-                  small, n_max, window):
-    """One (query tile, key/value head, page) item: ``_listed_kernel``
-    without the per-token lists, the tile's row and place in it read from
-    scalar prefetch."""
+def query_tile_runs(q_len, tile=QUERY_TILE, run=_RUN_TILES):
+    """How the tile-packed kernel reads a row of ``q_len`` query tokens in
+    tiles of ``tile`` slots: ``(single, runs)``, its runs of one tile (the
+    narrow body: every decode row) and its runs of 2 to ``run`` tiles (the
+    wide body).  Host arithmetic, for a counter."""
+    full, rest = divmod(-(-q_len // tile), run)
+    return int(rest == 1), full + int(rest > 1)
+
+
+def _tile_runs(tile, tile_rows, tile_index, query_lens, run):
+    """The runs of tile-packed queries: a row's tiles cut into runs of up
+    to ``run`` from its first.  Returns ``(start, count, row, index)``,
+    each ``[NR]`` int32: a run's first tile, its tiles, its batch row and
+    its first tile's index within the row (``count`` 0 and ``row`` ``B``
+    past the last run), with ``NR`` a static bound on the runs."""
+    NT, B = tile_rows.shape[0], query_lens.shape[0]
+    # a row of k tiles has ceil(k / run) runs, at most 1 + (k - 1) / run:
+    # over at most B rows and NT tiles, B + (NT - B) / run
+    NR = min(NT, B + max(NT - B, 0) // run)
+    opens = (tile_rows < B) & (tile_index % run == 0)
+    opened = jnp.cumsum(opens.astype(jnp.int32))
+    r = jnp.arange(NR, dtype=jnp.int32)
+    # run r opens at the first tile by which r + 1 runs have opened
+    start = jnp.sum(opened[None, :] <= r[:, None], axis=1).astype(jnp.int32)
+    live = r < opened[-1]
+    at = jnp.minimum(start, NT - 1)
+    row = jnp.where(live, tile_rows[at], B)
+    index = tile_index[at]
+    tiles = -(-query_lens[jnp.minimum(row, B - 1)] // tile)
+    count = jnp.where(live, jnp.clip(tiles - index, 0, run), 0)
+    return (start, count.astype(jnp.int32), row.astype(jnp.int32),
+            index.astype(jnp.int32))
+
+
+def _tiled_kernel(seg_ref, lp_ref, n_ref, start_ref, count_ref, row_ref,
+                  index_ref, tbl_ref, qlen_ref, ctx_ref, layer_ref, q_ref,
+                  kp_ref, vp_ref, o_hbm, acc_ref, m_ref, l_ref, o_buf, sem,
+                  sent_ref, *, scale, page_size, groups, n_max, n_tiles,
+                  window):
+    """One (run, key/value head, page) item: ``_listed_kernel`` without the
+    per-token lists, the run's row and place in it read from scalar
+    prefetch.  The queries come as the ``RB`` tiles from the run's first
+    (or, near the end of the layout, the last ``RB`` tiles: the run then
+    starts ``shift`` tiles in); results leave by the kernel's own copies,
+    one a tile of the run, so no write reaches a tile of another run."""
     del tbl_ref, layer_ref            # only the page index_maps read them
     i = pl.program_id(0)
+    n = n_ref[0]
     seg = seg_ref[i]
     first = (i == 0) | (seg_ref[jnp.maximum(i - 1, 0)] != seg)
-    last = (i == n_ref[0] - 1) | (seg_ref[jnp.minimum(i + 1, n_max - 1)]
-                                  != seg)
-    tid = seg // groups
-    b, qt = trow_ref[tid], tidx_ref[tid]
-    lpage = lp_ref[i]
+    last = (i == n - 1) | (seg_ref[jnp.minimum(i + 1, n_max - 1)] != seg)
+    r, g = seg // groups, seg % groups
+    start, count = start_ref[r], count_ref[r]
+    b, index = row_ref[r], index_ref[r]
+    RB, _, G, tile, hd = q_ref.shape
+    shift = start - jnp.minimum(start, n_tiles - RB)
     q_len, ctx = qlen_ref[b], ctx_ref[b]
-    start = lpage * page_size
-    in_tile = q_len - qt * tile       # query tokens of the row in this tile
+    kv = lp_ref[i] * page_size + jax.lax.broadcasted_iota(
+        jnp.int32, (1, 1, 1, page_size), 3)
 
-    @pl.when(first)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+    @pl.when(i == 0)
+    def _start():
+        # copies in flight from each half of ``o_buf``, and runs finished
+        sent_ref[0], sent_ref[1], sent_ref[2] = 0, 0, 0
 
-    def update(R):
-        """The page against the tile's first ``R`` query slots (static):
-        a decode row pays for ``small`` slots, not for the tile."""
-        G = q_ref.shape[2]
-        hd = q_ref.shape[4]
-        q = q_ref[0, 0, :, :R, :].reshape(G * R, hd)
+    def drain(slot):
+        """Wait for the copies in flight from half ``slot`` of ``o_buf``."""
+        def wait(j, carry):
+            pltpu.make_async_copy(o_buf.at[slot, 0], o_hbm.at[0, 0],
+                                  sem.at[slot]).wait()
+            return carry
+        jax.lax.fori_loop(0, sent_ref[slot], wait, 0)
+        sent_ref[slot] = 0
+
+    def item(R):
+        """The page against ``R`` tiles (static): the run's one tile, or
+        the whole block; the scratch rows ``:R`` hold them."""
+        base = shift if R == 1 else 0          # the block's first tile read
+
+        @pl.when(first)
+        def _init():
+            acc_ref[:R] = jnp.zeros_like(acc_ref[:R])
+            m_ref[:R] = jnp.full_like(m_ref[:R], _NEG_INF)
+            l_ref[:R] = jnp.zeros_like(l_ref[:R])
+
+        # the tiles' query rows flattened into one product each way: the
+        # order of rows does not matter to a product
+        q = q_ref[pl.ds(base, R), 0].reshape(R * G * tile, hd)
         k = kp_ref[0, 0, 0]                          # [ps, hd]
         v = vp_ref[0, 0, 0]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
-        s = s.reshape(G, R, page_size)
-        tq = qt * tile + jax.lax.broadcasted_iota(jnp.int32, (1, R, 1), 1)
-        kv = start + jax.lax.broadcasted_iota(jnp.int32, (1, 1, page_size),
-                                              2)
+        s = s.reshape(R, G, tile, page_size)
+        t = base + jax.lax.broadcasted_iota(jnp.int32, (R, 1, tile, 1), 0)
+        tq = ((index + t - shift) * tile
+              + jax.lax.broadcasted_iota(jnp.int32, (R, 1, tile, 1), 2))
         at = ctx - q_len + tq                        # absolute position
-        ok = (kv <= at) & (tq < q_len)
+        ok = ((kv <= at) & (tq < q_len) & (t >= shift)
+              & (t < shift + count))
         if window is not None:
             ok = ok & (kv > at - window)
         s = jnp.where(ok, s, _NEG_INF)
-        m_prev = m_ref[:, :R]
+        m_prev = m_ref[:R]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         # a slot with nothing allowed yet keeps m at -inf: exp(s - m)
         # would be 1 there, so mask the probabilities too
         p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
         corr = jnp.exp(m_prev - m_new)
-        l_ref[:, :R] = l_ref[:, :R] * corr + jnp.sum(p, axis=-1,
-                                                     keepdims=True)
-        pv = jnp.dot(p.reshape(G * R, page_size).astype(v.dtype), v,
+        l_ref[:R] = l_ref[:R] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        pv = jnp.dot(p.reshape(R * G * tile, page_size).astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
-        acc_ref[:, :R] = acc_ref[:, :R] * corr + pv.reshape(G, R, hd)
-        m_ref[:, :R] = m_new
+        acc_ref[:R] = acc_ref[:R] * corr + pv.reshape(R, G, tile, hd)
+        m_ref[:R] = m_new
 
-    if small < tile:
-        pl.when(in_tile <= small)(lambda: update(small))
-        pl.when(in_tile > small)(lambda: update(tile))
+        @pl.when(last)
+        def _final():
+            l = l_ref[:R]
+            o = acc_ref[:R] / jnp.where(l == 0.0, 1.0, l)
+            o_buf[sent_ref[2] % 2, :R] = o.astype(o_buf.dtype)
+
+    # the half of ``o_buf`` this run's result goes to, free once the
+    # copies of the run before the last are done
+    pl.when(last)(lambda: drain(sent_ref[2] % 2))
+    if RB > 1:
+        pl.when(count == 1)(lambda: item(1))
+        pl.when(count > 1)(lambda: item(RB))
     else:
-        update(tile)
+        item(1)
 
     @pl.when(last)
-    def _final():
-        l = l_ref[:]
-        o = acc_ref[:] / jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = o.astype(o_ref.dtype)
+    def _send():
+        slot = sent_ref[2] % 2
+        # the run's first row in the scratch: the narrow body's is row 0
+        src = jnp.where(count == 1, 0, shift)
+
+        def send(j, carry):
+            pltpu.make_async_copy(o_buf.at[slot, src + j],
+                                  o_hbm.at[start + j, g],
+                                  sem.at[slot]).start()
+            return carry
+        jax.lax.fori_loop(0, count, send, 0)
+        sent_ref[slot] = count
+        sent_ref[2] += 1
+
+    @pl.when(i == n - 1)
+    def _flush():
+        drain(0)
+        drain(1)
 
 
 def _tiled_attention_kernel(q, k_pages, v_pages, page_tables, query_lens,
@@ -848,47 +939,62 @@ def _tiled_attention_kernel(q, k_pages, v_pages, page_tables, query_lens,
     NT, tile, H, hd = q.shape
     Hkv, page_size = k_pages.shape[2], k_pages.shape[3]
     G, W = H // Hkv, page_tables.shape[1]
-    small = min(16, tile)
-    row, pos, valid = _tile_positions(tile, tile_rows, tile_index,
-                                      query_lens, context_lens)
-    w = jnp.arange(W)
-    reach = w[None, None, :] <= pos[:, :, None] // page_size
+    RB = min(_RUN_TILES, NT)          # tiles of a run, at most
+    start, count, row, index = _tile_runs(tile, tile_rows, tile_index,
+                                          query_lens, RB)
+    NR = start.shape[0]
+    # the positions a run's tokens hold: its first tile's first slot to
+    # its last token
+    r = jnp.minimum(row, query_lens.shape[0] - 1)
+    q_len, ctx = query_lens[r], context_lens[r]
+    p0 = ctx - q_len + index * tile
+    p1 = ctx - q_len + jnp.minimum(q_len, (index + count) * tile) - 1
+    w = jnp.arange(W)[None, :]
+    visit = (w <= (p1 // page_size)[:, None]) & (count > 0)[:, None]
     if window is not None:
-        reach = reach & (w[None, None, :] >= jnp.maximum(
-            pos - window + 1, 0)[:, :, None] // page_size)
-    visit = (reach & valid[:, :, None]).any(1)                   # [NT, W]
-    visit = jnp.broadcast_to(visit[:, None], (NT, Hkv, W))
-    # pages one tile can reach: all of them, or its tokens' windows
-    per_tile = W if window is None else min(
-        W, (window + tile - 2) // page_size + 2)
-    n_max = NT * Hkv * per_tile
-    seg, lpage, n = _work_items(visit.reshape(NT * Hkv, W), n_max)
+        # the first position the first token's window holds, and its page
+        visit = visit & (w >= (jnp.maximum(p0 - window + 1, 0)
+                               // page_size)[:, None])
+    visit = jnp.broadcast_to(visit[:, None], (NR, Hkv, W))
+    # pages one run can reach: all of them, or its tokens' windows
+    per_run = W if window is None else min(
+        W, (window + RB * tile - 2) // page_size + 2)
+    n_max = NR * Hkv * per_run
+    seg, lpage, n = _work_items(visit.reshape(NR * Hkv, W), n_max)
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
 
-    def q_block(i, seg, lp, n, trow, tidx, tbl, ql, cl, lyr):
-        return (seg[i] // Hkv, seg[i] % Hkv, 0, 0, 0)
+    def q_block(i, seg, lp, n, start, *_):
+        # element offset of the block's first tile; the group's block
+        return (jnp.minimum(start[seg[i] // Hkv], NT - RB), seg[i] % Hkv,
+                0, 0, 0)
 
-    def page_block(i, seg, lp, n, trow, tidx, tbl, ql, cl, lyr):
-        return (lyr[0], tbl[trow[seg[i] // Hkv], lp[i]], seg[i] % Hkv, 0, 0)
+    def page_block(i, seg, lp, n, start, count, row, index, tbl, ql, cl,
+                   lyr):
+        return (lyr[0], tbl[row[seg[i] // Hkv], lp[i]], seg[i] % Hkv, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=9,
+        num_scalar_prefetch=11,
         grid=(n,),
         in_specs=[
-            pl.BlockSpec((1, 1, G, tile, hd), q_block),
+            # every dimension by element offset (Mosaic takes all or none)
+            pl.BlockSpec(tuple(map(pl.Element, (RB, 1, G, tile, hd))),
+                         q_block),
             pl.BlockSpec((1, 1, 1, page_size, hd), page_block),
             pl.BlockSpec((1, 1, 1, page_size, hd), page_block),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, tile, hd), q_block),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[
-            pltpu.VMEM((G, tile, hd), jnp.float32),
-            pltpu.VMEM((G, tile, 1), jnp.float32),
-            pltpu.VMEM((G, tile, 1), jnp.float32),
+            pltpu.VMEM((RB, G, tile, hd), jnp.float32),
+            pltpu.VMEM((RB, G, tile, 1), jnp.float32),
+            pltpu.VMEM((RB, G, tile, 1), jnp.float32),
+            pltpu.VMEM((2, RB, G, tile, hd), q.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((3,), jnp.int32),
         ],
     )
     kernel = functools.partial(
         _tiled_kernel, scale=scale, page_size=page_size, groups=Hkv,
-        tile=tile, small=small, n_max=n_max, window=window)
+        n_max=n_max, n_tiles=NT, window=window)
     q5 = q.reshape(NT, tile, Hkv, G, hd).transpose(0, 2, 3, 1, 4)
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
@@ -898,11 +1004,12 @@ def _tiled_attention_kernel(q, k_pages, v_pages, page_tables, query_lens,
         # tells them from the full layers' (either name holds the other's)
         name="ragged_paged_attention" + ("" if window is None
                                          else "_window"),
-    )(seg, lpage, n.reshape(1).astype(jnp.int32), row.astype(jnp.int32),
-      tile_index.astype(jnp.int32), page_tables, query_lens, context_lens,
-      layer, q5, k_pages, v_pages)
+    )(seg, lpage, n.reshape(1).astype(jnp.int32), start, count, row, index,
+      page_tables, query_lens, context_lens, layer, q5, k_pages, v_pages)
     out = out.transpose(0, 3, 1, 2, 4).reshape(NT, tile, H, hd)
-    # tiles that no item visited were never written
+    # tiles that no run wrote hold whatever the buffer held
+    valid = _tile_positions(tile, tile_rows, tile_index, query_lens,
+                            context_lens)[2]
     return jnp.where(valid[:, :, None, None], out, jnp.zeros_like(out))
 
 

@@ -48,8 +48,6 @@ __all__ = ["MoEWindowConfig", "moe_window_init", "moe_window_ragged_step",
 
 FULL, SLIDING = "full_attention", "sliding_attention"
 DENSE, SPARSE = "dense", "sparse"
-# query slots of one tile handed to the attention kernel, at most
-_QUERY_TILE = 128
 
 
 @dataclasses.dataclass(unsafe_hash=True)
@@ -260,11 +258,11 @@ def _rope(x, pos, table):
 
 def moe_window_ragged_step(cfg: MoEWindowConfig, params, batch: RaggedBatch,
                            k_pages, v_pages, window_k_pages, window_v_pages,
-                           *, max_q=None, attn_path=None, query_tile=None):
+                           *, max_q=None, attn_path=None):
     """Unified ragged step over the two groups of page pools; ``batch``
     carries both page tables (``models/ragged.py``).  The attention kernel
-    takes the queries in tiles of ``query_tile`` slots of one row each
-    (default: ``max_q``, at most 128).
+    takes the queries in tiles of ``QUERY_TILE`` (16) slots of one row
+    each, and reads up to 8 tiles of a row at once.
 
     Returns ``(logits [B, V] float32, k_pages, v_pages, window_k_pages,
     window_v_pages, stats [4] int32)``: ``stats`` is the (token, expert)
@@ -272,14 +270,13 @@ def moe_window_ragged_step(cfg: MoEWindowConfig, params, batch: RaggedBatch,
     one held expert got in one layer, the (layer, held expert)s that got a
     pair at all: whose weights the step read, and the rows of the tiles
     the expert kernels visited (the pairs and their tiles' padding)."""
-    from ..kernels.paged_attention import ragged_paged_attention
+    from ..kernels.paged_attention import QUERY_TILE, ragged_paged_attention
 
     tokens, query_lens, context_lens = (batch.tokens, batch.query_lens,
                                         batch.context_lens)
     T = tokens.shape[0]
     Hkv, hd = cfg.num_kv_heads, cfg.head_dim
     f32, dtype = jnp.float32, cfg.jdtype()
-    query_tile = query_tile or min(_QUERY_TILE, max_q or T)
     pair_tile = expert_tile(T, cfg.top_k, cfg.num_experts, dtype)
     view = RaggedView(batch, max_q=max_q, max_seq_len=cfg.max_seq_len,
                       num_pages=k_pages.shape[1], page_size=k_pages.shape[3],
@@ -306,14 +303,14 @@ def moe_window_ragged_step(cfg: MoEWindowConfig, params, batch: RaggedBatch,
                 kp = kp.at[at].set(k.astype(kp.dtype), mode="drop")
                 vp = vp.at[at].set(v.astype(vp.dtype), mode="drop")
             # queries in tiles of one row each: 64 decode rows beside a
-            # chunk of 1024 are 73 tiles, not 64 rows of 1024 slots
+            # chunk of 1024 are 128 tiles of 16 slots, not 64 rows of 1024
             heads = ragged_paged_attention(
-                view.pad_tiles(q, query_tile), kp, vp,
+                view.pad_tiles(q, QUERY_TILE), kp, vp,
                 batch.window_page_tables if sliding else batch.page_tables,
                 query_lens, context_lens, path=attn_path,
                 layer=jnp.int32(i), selected=(None, 2 ** 30),
                 window=cfg.window if sliding else None,
-                q_tiles=view.tiles(query_tile))
+                q_tiles=view.tiles(QUERY_TILE))
             heads = view.unpad_tiles(heads)                     # [T, H, hd]
             gate = jax.nn.sigmoid(jnp.einsum(
                 "td,dh->th", u, p["g_w"][i], preferred_element_type=f32))

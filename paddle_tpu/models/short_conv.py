@@ -49,8 +49,6 @@ __all__ = ["ShortConvMoEConfig", "short_conv_init", "short_conv_ragged_step",
 
 CONV, ATTN = "conv", "full_attention"
 DENSE, SPARSE = "dense", "sparse"
-# query slots of one tile handed to the attention kernel, at most
-_QUERY_TILE = 128
 # a pool row's lanes: a head narrower than this is stored zero-padded to it
 _LANES = 128
 
@@ -202,8 +200,8 @@ def short_conv_ragged_step(cfg: ShortConvMoEConfig, params,
     """Unified ragged step over the attention layers' page pools and the
     convolution layers' windows.  A row whose chunk starts at position 0
     (``view.fresh``) starts its windows from zero, inside the step.  The
-    attention kernel takes the queries in tiles of ``max_q`` slots (at
-    most 128) of one row each.
+    attention kernel takes the queries in tiles of ``QUERY_TILE`` (16)
+    slots of one row each.
 
     Returns ``(logits [B, V] float32, k_pages, v_pages, conv_state, stats
     [4] int32)``, ``stats`` as ``moe_window_ragged_step``'s: the (token,
@@ -211,7 +209,7 @@ def short_conv_ragged_step(cfg: ShortConvMoEConfig, params,
     one layer, the (layer, expert)s that got a pair at all (whose weights
     the step read), and the rows of the tiles the expert kernels
     visited."""
-    from ..kernels.paged_attention import ragged_paged_attention
+    from ..kernels.paged_attention import QUERY_TILE, ragged_paged_attention
 
     tokens, query_lens, context_lens = (batch.tokens, batch.query_lens,
                                         batch.context_lens)
@@ -223,7 +221,6 @@ def short_conv_ragged_step(cfg: ShortConvMoEConfig, params,
     widen = lambda a: jnp.pad(a, ((0, 0), (0, 0),
                                   (0, cfg.pool_head_dim - hd)))
     f32, dtype = jnp.float32, cfg.jdtype()
-    query_tile = min(_QUERY_TILE, max_q or T)
     pair_tile = expert_tile(T, cfg.top_k, E, dtype)
     view = RaggedView(batch, max_q=max_q, max_seq_len=cfg.max_seq_len,
                       num_pages=k_pages.shape[1], page_size=k_pages.shape[3])
@@ -273,10 +270,10 @@ def short_conv_ragged_step(cfg: ShortConvMoEConfig, params,
                 vp = vp.at[at].set(v.astype(vp.dtype), mode="drop")
             # queries in tiles of one row each: a decode row is one tile
             heads = ragged_paged_attention(
-                view.pad_tiles(q, query_tile), kp, vp, batch.page_tables,
+                view.pad_tiles(q, QUERY_TILE), kp, vp, batch.page_tables,
                 query_lens, context_lens, scale=1 / math.sqrt(hd),
                 path=attn_path, layer=jnp.int32(i), selected=(None, 2 ** 30),
-                q_tiles=view.tiles(query_tile))
+                q_tiles=view.tiles(QUERY_TILE))
             heads = view.unpad_tiles(heads)[..., :hd].reshape(T, H * hd)
             out = jnp.einsum("te,ed->td", heads, p["o_w"][i],
                              preferred_element_type=f32)

@@ -1057,6 +1057,8 @@ class Engine:
         n_rows = len(sched)
         committed = 0
         in_context = read = resets = chunk_rows = decode_rows = 0
+        tile_runs = getattr(self.model, "query_tile_runs", None)
+        single_tiles = tile_runs_wide = 0
         for i, req, q, ctx in sched:
             # what the step's attention layers had to read for this row,
             # from the lengths alone (no device read); a row that began
@@ -1064,6 +1066,11 @@ class Engine:
             a, b = self.model.attention_positions(ctx, q)
             in_context, read = in_context + a, read + b
             resets += self.model.recurrent and ctx == q
+            if tile_runs is not None:
+                # the runs of query tiles its attention kernel reads
+                one, several = tile_runs(q)
+                single_tiles += one
+                tile_runs_wide += several
             mid_prefill = ctx - q < len(req.prompt)
             if self.model.recurrent:
                 # the step advanced this row's state by a chunk or a token
@@ -1128,6 +1135,10 @@ class Engine:
             self.metrics.state_row_steps_chunk.inc(chunk_rows)
         if decode_rows:
             self.metrics.state_row_steps_decode.inc(decode_rows)
+        if single_tiles:
+            self.metrics.attention_tiles_single.inc(single_tiles)
+        if tile_runs_wide:
+            self.metrics.attention_tiles_run.inc(tile_runs_wide)
         if dt > 0 and committed:
             # EWMA decode throughput feeds the drain/retry-after hint
             inst = committed / dt

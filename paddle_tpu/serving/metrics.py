@@ -37,6 +37,10 @@ process-wide registry); this module keeps the serving-shaped facade:
   expert_tile_rows — the same model: rows of the tiles its expert kernels
                  visited, the pairs and their tiles' padding (pairs over
                  tile rows is the fill of a tile)
+  attention_query_tiles — a model whose attention takes its queries in
+                 tiles of 16 slots: how often the kernel's narrow body (a
+                 run of one tile) and its wide body (a run of several
+                 tiles of one row) engage
   steps_dispatched / pipeline_drains / overrun_rows — how often the
                  step in flight hid the host's phases, how often it had
                  to be settled first and why, and the rows computed for
@@ -248,6 +252,18 @@ class ServingMetrics:
                  "whole tiles, what the kernels fetch room for, multiply "
                  "and add; counted on the device, read with the step's "
                  "ids"))
+        self.attention_query_tiles = add(Counter(
+            "serving_attention_query_tiles_total", labelnames=("kind",),
+            help="a model whose attention takes its queries packed in "
+                 "tiles of 16 slots: the runs its kernel reads a step's "
+                 "live rows in, per step (not per layer): kind=single a "
+                 "run of one tile (the narrow body: every decode row), "
+                 "kind=run a run of 2 to 8 tiles of one row (the wide "
+                 "body); from the plan, on the host"))
+        self.attention_tiles_single = self.attention_query_tiles.labels(
+            kind="single")
+        self.attention_tiles_run = self.attention_query_tiles.labels(
+            kind="run")
         self.expert_weight_reads = add(Counter(
             "serving_expert_weight_reads_total",
             help="(layer, held expert)s that got at least one pair in a "

@@ -32,10 +32,13 @@ the state that step carries.
 - ``shard(params, mesh)``: GSPMD serving, where the model has it
   (``make_step(mesh=...)`` raises ``NotImplementedError`` where not).
 - optional: ``window`` (positions a sliding-window layer reads; the
-  engine then keeps the second page table and budget), and ``step_stats``
+  engine then keeps the second page table and budget), ``step_stats``
   (names of int32 numbers the step counts on the device and returns after
   its state; they reach the host behind the ids and are handed to
-  ``record_stats(metrics, values)``).
+  ``record_stats(metrics, values)``), and ``query_tile_runs(q)`` (a model
+  whose attention takes its queries packed in tiles: how the kernel reads
+  a row of ``q`` tokens, ``(runs of one tile, runs of several)``, host
+  arithmetic for the ``serving_attention_query_tiles_total`` counter).
 """
 from __future__ import annotations
 
@@ -218,7 +221,18 @@ class _ExpertStats:
             fullest * self.expert_slots / pairs if pairs else 0)
 
 
-class MoEWindowServed(_ExpertStats):
+class _TiledQueries:
+    """A model whose attention layers hand the kernel their queries packed
+    in tiles (``kernels/paged_attention.py``, ``q_tiles=``)."""
+
+    @staticmethod
+    def query_tile_runs(q):
+        from ..kernels.paged_attention import query_tile_runs
+
+        return query_tile_runs(q)
+
+
+class MoEWindowServed(_ExpertStats, _TiledQueries):
     """The sparse-expert decoder with sliding-window layers beside full
     ones (``models/moe_window.py``): two groups of page pools, and an
     expert layer that holds a share of the experts.  Served cold: a cached
@@ -270,7 +284,7 @@ class MoEWindowServed(_ExpertStats):
         return int(n.sum()), int(np.minimum(n, self.window).sum())
 
 
-class ShortConvServed(_ExpertStats):
+class ShortConvServed(_ExpertStats, _TiledQueries):
     """Gated short-convolution layers beside QK-normed attention, with a
     sparse-expert feed-forward chosen by sigmoid and a selection bias
     (``models/short_conv.py``): pages for the attention layers and a

@@ -285,7 +285,61 @@ def test_tile_rows_are_each_layers_groups_rounded_up_to_whole_tiles(
     assert MoEWindowServed.step_stats[-1] == "expert_tile_rows"
 
 
-def test_the_pallas_kernels_under_the_interpreter_give_the_jnp_paths_step():
+def test_query_tiles_count_the_attention_kernels_runs_from_the_plan(tiny):
+    """``serving_attention_query_tiles_total{kind}`` is, over every step's
+    live rows, the runs of 16-slot query tiles the attention kernel reads:
+    a run of one tile (``single``: a decode row, a short chunk, a long
+    chunk's last tile) or of 2 to 8 tiles of one row (``run``).  A GPT
+    engine, whose queries are not tile-packed, leaves it at zero."""
+    import dataclasses
+
+    from paddle_tpu.models.gpt import GPT_CONFIGS, gpt_init
+
+    cfg, params, _ = tiny
+    eng = Engine(cfg, params, page_size=4, num_pages=160, max_batch_size=3,
+                 chunk_len=144)
+    plans, commit = [], eng._commit
+
+    def spy(step, sampled, t1):
+        plans.append([q for _, _, q, _ in step.sched])
+        return commit(step, sampled, t1)
+
+    eng._commit = spy
+    rng = np.random.default_rng(9)
+    # a 150-token prompt alone: a chunk of over 128 (9 tiles: a run of 8,
+    # then one); then beside it chunks of 19 (a run of 2 tiles) and 7, and
+    # decode rows
+    for n in (150, 19, 7):
+        eng.add_request(rng.integers(0, 512, n).tolist(),
+                        SamplingParams(max_new_tokens=3))
+        eng.step()
+    while eng.has_work():
+        eng.step()
+    want = {"single": 0, "run": 0}
+    for q in (q for plan in plans for q in plan):
+        tiles = -(-q // 16)
+        want["single"] += tiles % 8 == 1
+        want["run"] += tiles // 8 + (tiles % 8 > 1)
+    m = eng.metrics
+    assert (m.attention_tiles_single.value, m.attention_tiles_run.value) \
+        == (want["single"], want["run"])
+    assert want["run"] >= 2 and want["single"] > want["run"]
+    assert plans[0] == [144]
+
+    gpt = dataclasses.replace(GPT_CONFIGS["tiny"], dtype="float32")
+    eng = Engine(gpt, gpt_init(gpt, jax.random.key(0), dtype=jnp.float32),
+                 page_size=4, num_pages=32, max_batch_size=2, chunk_len=8)
+    eng.add_request([1, 2, 3, 4, 5], SamplingParams(max_new_tokens=3))
+    while eng.has_work():
+        eng.step()
+    assert not hasattr(eng.model, "query_tile_runs")
+    m = eng.metrics
+    assert (m.attention_tiles_single.value, m.attention_tiles_run.value) \
+        == (0, 0)
+
+
+def test_the_pallas_kernels_under_the_interpreter_give_the_jnp_paths_step(
+        monkeypatch):
     """One step of the model with both kernels (the window's lower edge,
     the grouped expert product) under the Pallas interpreter against the
     same step on their ``jnp`` paths: a chunk, a chunk of two, decode rows
@@ -311,13 +365,16 @@ def test_the_pallas_kernels_under_the_interpreter_give_the_jnp_paths_step():
     batch = WindowRaggedBatch(*(jnp.asarray(a) for a in (
         tokens, rows, slots, np.asarray(q, np.int32),
         np.asarray(ctx, np.int32), tables, tables[::-1].copy())))
-    run = lambda path, tile=None: moe_window_ragged_step(
-        cfg, params, batch, *state, max_q=16, attn_path=path,
-        query_tile=tile)
+    run = lambda path: moe_window_ragged_step(
+        cfg, params, batch, *state, max_q=16, attn_path=path)
     ref, got = run(dispatch.REFERENCE), run(dispatch.INTERPRET)
-    # the chunk of 13 in two tiles of 8 query slots: the same step
+    # the chunk of 13 in four tiles of 4 query slots, read as one run, and
+    # the chunk of two in one: the same step
+    from paddle_tpu.kernels import paged_attention as pa
+
+    monkeypatch.setattr(pa, "QUERY_TILE", 4)
     for path in (dispatch.REFERENCE, dispatch.INTERPRET):
-        np.testing.assert_allclose(np.asarray(run(path, 8)[0])[[0, 1, 2, 4]],
+        np.testing.assert_allclose(np.asarray(run(path)[0])[[0, 1, 2, 4]],
                                    np.asarray(ref[0])[[0, 1, 2, 4]],
                                    atol=2e-5)
     live = np.asarray(q) > 0

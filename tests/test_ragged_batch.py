@@ -239,11 +239,12 @@ def test_empty_batch_is_all_idle_rows_and_padding_slots():
 # ------------------------------------------------- tiles and window tables
 
 
-@pytest.mark.parametrize("n", [2, 4, 8])
+@pytest.mark.parametrize("n", [2, 4, 8, 16])
 def test_tiles_hold_one_rows_query_slots_each_and_unpad_inverts(n):
-    """Row 0's chunk of 5 in tiles of ``n`` slots, one tile for each decode
-    row, none for the idle row; ``B + T / n`` tiles in all, the rest
-    unused (row ``B``)."""
+    """Row 0's chunk of 5 in tiles of ``n`` slots (16: the tile the
+    tile-packed attention kernel takes), one tile for each decode row, none
+    for the idle row; ``B + ceil(T / n)`` tiles in all, the rest unused
+    (row ``B``)."""
     batch = _mixed()
     v = _view(batch)
     rows, index = (np.asarray(a) for a in v.tiles(n))

@@ -389,3 +389,39 @@ def test_short_conv_serve_step_compiles_for_v5e_and_fits_the_chip(devices):
                   "router", "mlp", "lm_head", "embed"):
         assert any(scope in scopes(p) for p in table.values()), scope
     assert _scoped_share(cw, table) >= 0.8
+
+
+def test_tiled_attention_body_lowers_no_larger_than_twice_one_tile_body(
+        devices):
+    """The tile-packed attention kernel reads up to 8 tiles of 16 query
+    slots of one row in an item through one pair of products over their
+    flattened rows; a body written out once per tile would multiply what
+    every call of a step traces, lowers and compiles.  At the agent cell's
+    window-layer shapes (132 tiles of 16 slots: 64 rows beside a chunk of
+    1024; 18 query heads over 2 key/value heads; 6 layers of 256 pages of
+    512, tables 4 wide) the Mosaic payload stays within twice the 16,992
+    characters of the one-tile body at tiles of 128 it replaced."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from paddle_tpu.kernels import dispatch
+    from paddle_tpu.kernels.paged_attention import ragged_paged_attention
+
+    one = SingleDeviceSharding(devices[0])
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    pool = ((6, 256, 2, 512, 128), bf16)
+    args = tpu_aot._on(one, ((132, 16, 18, 128), bf16), pool, pool,
+                       ((64, 4), i32), ((64,), i32), ((64,), i32),
+                       ((132,), i32), ((132,), i32), ((), i32))
+
+    def call(q, kp, vp, tables, q_lens, ctx_lens, rows, index, layer):
+        return ragged_paged_attention(
+            q, kp, vp, tables, q_lens, ctx_lens, path=dispatch.MOSAIC,
+            layer=layer, selected=(None, 2 ** 30), window=512,
+            q_tiles=(rows, index))
+
+    text = jax.jit(call).lower(*args).as_text()
+    payloads = re.findall(r'backend_config = "([^"]*)"', text)
+    assert len(payloads) == 1
+    assert 10_000 < len(payloads[0]) <= 34_000, len(payloads[0])

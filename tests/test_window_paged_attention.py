@@ -124,42 +124,121 @@ def test_a_window_comes_with_the_grouped_mode():
 TILE = 8
 
 
-def tiled(q):
-    """``q [B, Q, ...]`` packed in tiles of ``TILE`` slots of one row each,
+def tiled(q, query_lens=QUERY_LENS, tile=TILE, unused=2):
+    """``q [B, Q, ...]`` packed in tiles of ``tile`` slots of one row each,
     as ``RaggedView.pad_tiles`` lays them out: a row's tiles in order, rows
-    in order, and two unused tiles at the end."""
+    in order, and ``unused`` tiles at the end (filled with 7s)."""
     rows, index = [], []
-    for b, n in enumerate(QUERY_LENS):
-        for t in range(-(-int(n) // TILE)):
+    for b, n in enumerate(query_lens):
+        for t in range(-(-int(n) // tile)):
             rows.append(b)
             index.append(t)
-    tiles = jnp.stack([q[b, t * TILE:(t + 1) * TILE]
+    nb = len(query_lens)
+    tiles = jnp.stack([q[b, t * tile:(t + 1) * tile]
                        for b, t in zip(rows, index)]
-                      + [jnp.full_like(q[0, :TILE], 7.0)] * 2)
-    return tiles, jnp.asarray(rows + [B, B], jnp.int32), \
-        jnp.asarray(index + [0, 0], jnp.int32)
+                      + [jnp.full_like(q[0, :tile], 7.0)] * unused)
+    return tiles, jnp.asarray(rows + [nb] * unused, jnp.int32), \
+        jnp.asarray(index + [0] * unused, jnp.int32)
+
+
+def attend_tiles(path, tiles, rows, index, kp, vp, tables, query_lens,
+                 context_lens, window):
+    return np.asarray(jax.jit(lambda *a: ragged_paged_attention(
+        *a, path=path, layer=jnp.int32(1), selected=(None, 2 ** 30),
+        window=window, q_tiles=(rows, index)))(
+            tiles, kp, vp, tables, jnp.asarray(query_lens),
+            jnp.asarray(context_lens)))
+
+
+# (tile, run): the chunk of 32 in four tiles of 8 read as one run, or in
+# eight tiles of 4 read as four runs of 2 (the chunk of 5 a run of 2)
+@pytest.mark.parametrize("tile,run", [(8, 8), (4, 2)])
+@pytest.mark.parametrize("window", [WINDOW, None])
+@pytest.mark.parametrize("path", [dispatch.REFERENCE, dispatch.INTERPRET])
+def test_queries_packed_in_tiles_give_the_padded_rows_result(
+        path, window, tile, run, monkeypatch):
+    """A chunk of 32 in tiles of ``tile``, a chunk of 5, decode rows in one
+    tile each, nothing for the idle row: tile by tile what the padded
+    ``[B, Q]`` layout gives, whatever the runs the kernel reads them in."""
+    monkeypatch.setattr(pa, "_RUN_TILES", run)
+    q, kp, vp, tables, pos = inputs(3, seed=4)
+    want = np.asarray(attend(dispatch.REFERENCE, q, kp, vp, tables, window))
+    tiles, rows, index = tiled(q, tile=tile)
+    assert tiles.shape[0] == 32 // tile + -(-5 // tile) + 1 + 0 + 1 + 2
+    got = attend_tiles(path, tiles, rows, index, kp, vp, tables,
+                       QUERY_LENS, CONTEXT_LENS, window)
+    assert got.shape == tiles.shape
+    for n, (b, t) in enumerate(zip(rows.tolist()[:-2], index.tolist())):
+        np.testing.assert_allclose(got[n], want[b, t * tile:(t + 1) * tile],
+                                   atol=2e-5)
+    assert not got[-2:].any()                        # the unused tiles
+
+
+# a chunk of 130 (a run of 8 tiles of 16, then a run of one tile of 2
+# tokens), a decode row, an idle row, a chunk of 3, a chunk of 20 (a run of
+# 2 tiles) and a decode row, in 14 tiles and 2 unused ones: the chunk of
+# 20's run is read in the last 8 tiles, whose first three are the chunk of
+# 130's last tile, the decode row's and the chunk of 3's, and whose sixth
+# is the last decode row's
+RUN_QUERY_LENS = np.array([130, 1, 0, 3, 20, 1])
+RUN_CONTEXT_LENS = np.array([150, 40, 0, 3, 90, 151])
+RUN_W, RUN_Q = 40, 144
+
+
+def run_inputs(seed):
+    nb = len(RUN_QUERY_LENS)
+    rng = np.random.default_rng(seed)
+    ks = jax.random.split(jax.random.key(seed), 3)
+    q = jax.random.normal(ks[0], (nb, RUN_Q, HKV * 3, HD))
+    kp = jax.random.normal(ks[1], (L, nb * RUN_W, HKV, PS, HD))
+    vp = jax.random.normal(ks[2], (L, nb * RUN_W, HKV, PS, HD))
+    tables = jnp.asarray(rng.permutation(nb * RUN_W).reshape(nb, RUN_W),
+                         jnp.int32)
+    return q, kp, vp, tables
 
 
 @pytest.mark.parametrize("window", [WINDOW, None])
 @pytest.mark.parametrize("path", [dispatch.REFERENCE, dispatch.INTERPRET])
-def test_queries_packed_in_tiles_give_the_padded_rows_result(path, window):
-    """A chunk of 32 in four tiles of 8, a chunk of 5 in one, decode rows
-    in one each, nothing for the idle row: tile by tile what the padded
-    ``[B, Q]`` layout gives."""
-    q, kp, vp, tables, pos = inputs(3, seed=4)
-    want = np.asarray(attend(dispatch.REFERENCE, q, kp, vp, tables, window))
-    tiles, rows, index = tiled(q)
-    assert tiles.shape[0] == 1 + 1 + 0 + 4 + 1 + 2
-    got = np.asarray(jax.jit(lambda *a: ragged_paged_attention(
-        *a, path=path, layer=jnp.int32(1), selected=(None, 2 ** 30),
-        window=window, q_tiles=(rows, index)))(
-            tiles, kp, vp, tables, jnp.asarray(QUERY_LENS),
-            jnp.asarray(CONTEXT_LENS)))
-    assert got.shape == tiles.shape
+def test_runs_of_tiles_of_16_give_the_padded_rows_result(path, window):
+    q, kp, vp, tables = run_inputs(5)
+    want = np.asarray(jax.jit(lambda *a: ragged_paged_attention(
+        *a, path=dispatch.REFERENCE, layer=jnp.int32(1),
+        selected=(None, 2 ** 30), window=window))(
+            q, kp, vp, tables, jnp.asarray(RUN_QUERY_LENS),
+            jnp.asarray(RUN_CONTEXT_LENS)))
+    tile = pa.QUERY_TILE
+    tiles, rows, index = tiled(q, RUN_QUERY_LENS, tile)
+    assert tiles.shape[0] == 16
+    assert pa.query_tile_runs(130) == (1, 1)
+    assert [pa.query_tile_runs(n) for n in (1, 3, 20)] == [(1, 0), (1, 0),
+                                                           (0, 1)]
+    got = attend_tiles(path, tiles, rows, index, kp, vp, tables,
+                       RUN_QUERY_LENS, RUN_CONTEXT_LENS, window)
     for n, (b, t) in enumerate(zip(rows.tolist()[:-2], index.tolist())):
-        np.testing.assert_allclose(got[n], want[b, t * TILE:(t + 1) * TILE],
+        live = min(tile, int(RUN_QUERY_LENS[b]) - t * tile)
+        np.testing.assert_allclose(got[n, :live],
+                                   want[b, t * tile:t * tile + live],
                                    atol=2e-5)
-    assert not got[-2:].any()                        # the unused tiles
+        assert not got[n, live:].any()               # padded query slots
+    assert not got[-2:].any()
+
+
+def test_a_run_writes_its_own_tiles_and_no_others():
+    """Every tile of a row comes back as the row alone gives it, whatever
+    the runs around it read: the same call with every other row idle."""
+    q, kp, vp, tables = run_inputs(6)
+    tiles, rows, index = tiled(q, RUN_QUERY_LENS, pa.QUERY_TILE)
+    both = attend_tiles(dispatch.INTERPRET, tiles, rows, index, kp, vp,
+                        tables, RUN_QUERY_LENS, RUN_CONTEXT_LENS, WINDOW)
+    for b in np.flatnonzero(RUN_QUERY_LENS):
+        mine = np.asarray(rows) == b
+        alone = np.where(np.arange(len(RUN_QUERY_LENS)) == b,
+                         RUN_QUERY_LENS, 0)
+        got = attend_tiles(dispatch.INTERPRET, tiles,
+                           jnp.where(mine, rows, len(alone)), index, kp, vp,
+                           tables, alone, RUN_CONTEXT_LENS, WINDOW)
+        np.testing.assert_array_equal(got[mine], both[mine])
+        assert not got[~mine].any()
 
 
 def test_tiles_come_with_the_grouped_mode_and_no_lists():
