@@ -18,7 +18,10 @@ beside them (compressed keys for block selection) are allocated, shared,
 copied on write, compacted and freed together; kind ``"slots"`` has the
 batch row on axis 1 and holds one fixed state per in-flight sequence (a
 recurrent layer's state).  A sequence is bound to its row slot when it is
-allocated and released with its pages.  Without ``state=`` the manager
+allocated and released with its pages.  What a page holds per position is
+the model's: per-head keys and values, or a **latent page** of latent
+attention (``models/mla_sparse.py``), one row a position shared by every
+head, in either kind; the manager sees the page axis alone.  Without ``state=`` the manager
 holds the two pools of a model with ``num_heads`` equal heads.
 
 Window pools (kind ``"window_pages"``: the pages of sliding-window

@@ -37,6 +37,10 @@ process-wide registry); this module keeps the serving-shaped facade:
   expert_tile_rows — the same model: rows of the tiles its expert kernels
                  visited, the pairs and their tiles' padding (pairs over
                  tile rows is the fill of a tile)
+  latent_rows / latent_row_pages — a model with latent attention: rows
+                 its kernel fetched one by one, by layer kind (selected by
+                 the indexer, or a window), and the pages the selected
+                 ones lay in (rows over 64 pages is how scattered a choice is)
   attention_query_tiles — a model whose attention takes its queries in
                  tiles of 16 slots: how often the kernel's narrow body (a
                  run of one tile) and its wide body (a run of several
@@ -252,6 +256,22 @@ class ServingMetrics:
                  "whole tiles, what the kernels fetch room for, multiply "
                  "and add; counted on the device, read with the step's "
                  "ids"))
+        self.latent_rows = add(Counter(
+            "serving_latent_rows_total", labelnames=("kind",),
+            help="a model with latent attention: the cached rows its "
+                 "kernel fetched one by one over a step's layers, by what "
+                 "chose them (kind=selected: the full layers' indexer; "
+                 "kind=window: the sliding layers' window); counted on the "
+                 "device, read with the step's ids"))
+        self.latent_rows_selected = self.latent_rows.labels(kind="selected")
+        self.latent_rows_window = self.latent_rows.labels(kind="window")
+        self.latent_row_pages = add(Counter(
+            "serving_latent_row_pages_total",
+            help="the pages the selected rows lay in, a page counted once "
+                 "a token and layer: over serving_latent_rows_total"
+                 "{kind=selected} / page size, how scattered the indexer's "
+                 "choice is (1 = rows packed in whole pages); counted on "
+                 "the device, read with the step's ids"))
         self.attention_query_tiles = add(Counter(
             "serving_attention_query_tiles_total", labelnames=("kind",),
             help="a model whose attention takes its queries packed in "

@@ -13,7 +13,12 @@ the state that step carries.
   kind ``"window_pages"`` a page axis of its own size
   (``num_window_pages=``, passed to a model whose ``window`` is set): the
   pools of sliding-window layers, with a page table of their own whose
-  pages behind the window are given back.
+  pages behind the window are given back.  What a pool holds per position
+  is the model's: a **latent page** (``MLASparseServed``) holds one row a
+  position shared by every head, the latent and the rotary key of latent
+  attention as float32 slabs ``[S, 128]`` that a kernel fetches one row at
+  a time, in a ``"pages"`` pool for full layers and a ``"window_pages"``
+  pool for sliding ones; the allocator sees pages either way.
 - ``make_step(max_q=, mesh=)``: ``step(params, state, batch) -> (logits
   [B, V], state)`` with ``state`` the tuple of pools and ``batch`` the
   scheduler's ``RaggedBatch`` (``models/ragged.py`` owns the format);
@@ -46,7 +51,7 @@ import jax
 import numpy as np
 
 __all__ = ["GPTServed", "HybridServed", "SSMServed", "MoEWindowServed",
-           "ShortConvServed", "as_served"]
+           "ShortConvServed", "MLASparseServed", "as_served"]
 
 
 class GPTServed:
@@ -336,6 +341,77 @@ class ShortConvServed(_ExpertStats, _TiledQueries):
         return n, n
 
 
+class MLASparseServed(_ExpertStats):
+    """Latent attention in every layer — full layers reading the positions
+    a learned indexer chooses token by token, sliding layers their window —
+    with sigmoid-routed experts and a shared one (``models/mla_sparse.py``):
+    latent pages and index keys for the full layers, latent window pages for
+    the sliding ones, an expert layer that holds a share of the experts.
+    Served cold: a cached prefix whose window pages were given back cannot
+    be resumed."""
+
+    recurrent = False
+    step_stats = _ExpertStats.step_stats + (
+        "latent_rows_selected", "latent_row_pages", "latent_rows_window")
+
+    def __init__(self, cfg):
+        from ..models.mla_sparse import FULL, SLIDING, SPARSE
+
+        self.cfg = cfg
+        self.window = cfg.window
+        self.expert_slots = cfg.count(SPARSE) * cfg.experts_held[1]
+        self._layers = (cfg.count(FULL), cfg.count(SLIDING))
+
+    def init_params(self):
+        from ..models.mla_sparse import mla_sparse_init
+
+        return mla_sparse_init(self.cfg)
+
+    def state_spec(self, **sizes):
+        from ..models.mla_sparse import mla_sparse_state_spec
+
+        return mla_sparse_state_spec(self.cfg, **sizes)
+
+    def make_step(self, *, max_q, mesh=None):
+        from ..models.mla_sparse import mla_sparse_ragged_step
+
+        if mesh is not None:
+            raise NotImplementedError(
+                "Engine(mesh=...) with latent attention, a learned indexer "
+                "and sparse experts: the deployment serves attention data-"
+                "parallel and the experts by expert, which needs rows "
+                "routed to replicas and an exchange of tokens between "
+                "expert holders; the latent and index-key pools have no "
+                "rule table and the kernels no shard_map (PERF.md, open "
+                "questions)")
+        cfg = self.cfg
+
+        def step(params, state, batch):
+            logits, *state, stats = mla_sparse_ragged_step(
+                cfg, params, batch, *state, max_q=max_q)
+            return logits, tuple(state), stats
+
+        return jax.jit(step)       # as GPTServed.make_step
+
+    def attention_positions(self, ctx, q):
+        # summed over the attention layers: a token at position p has p + 1
+        # positions in context; a full layer reads the indexer's top
+        # index_topk of them, a sliding layer the last `window`
+        cfg = self.cfg
+        n = np.arange(ctx - q + 1, ctx + 1, dtype=np.int64)
+        full, sliding = self._layers
+        return (int(n.sum()) * (full + sliding),
+                full * int(np.minimum(n, cfg.index_topk).sum())
+                + sliding * int(np.minimum(n, self.window).sum()))
+
+    def record_stats(self, metrics, values):
+        *expert, rows, pages, window_rows = values
+        super().record_stats(metrics, expert)
+        metrics.latent_rows_selected.inc(rows)
+        metrics.latent_rows_window.inc(window_rows)
+        metrics.latent_row_pages.inc(pages)
+
+
 def as_served(model):
     """``model`` if it already has the interface, else the served form of
     a known config object."""
@@ -360,5 +436,9 @@ def as_served(model):
 
     if isinstance(model, ShortConvMoEConfig):
         return ShortConvServed(model)
+    from ..models.mla_sparse import MLASparseConfig
+
+    if isinstance(model, MLASparseConfig):
+        return MLASparseServed(model)
     raise TypeError(f"Engine cannot serve a {type(model).__name__}: pass a "
                     f"served-model object (paddle_tpu.serving.model)")

@@ -425,3 +425,69 @@ def test_tiled_attention_body_lowers_no_larger_than_twice_one_tile_body(
     payloads = re.findall(r'backend_config = "([^"]*)"', text)
     assert len(payloads) == 1
     assert 10_000 < len(payloads[0]) <= 34_000, len(payloads[0])
+
+
+def test_latent_attention_kernel_compiles_at_the_cells_shapes(devices):
+    """The absorbed latent kernel at the long-context cell's shapes, both
+    ways it names its rows: a full layer's 527 queries of 128 heads, each
+    reading 2,048 listed positions of float32 slabs [8, 128] through a
+    16 x 544 page table (the list's [8, 2048] blocks in scalar memory), and
+    a sliding layer's 64 heads over a 513-position window of slabs [16,
+    128]."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from paddle_tpu.kernels import dispatch
+    from paddle_tpu.kernels.latent_attention import latent_attention
+
+    one = SingleDeviceSharding(devices[0])
+    sd = lambda s, t: jax.ShapeDtypeStruct(s, t, sharding=one)
+    N, B, max_pages, i32 = 527, 16, 544, jnp.int32
+    for slab, heads, width, latent, rows in ((8, 128, 640, 512, 2048),
+                                             (16, 64, 1152, 1024, None)):
+        args = [sd((N, heads, width), jnp.bfloat16),
+                sd((2, 256, 64, slab, 128), jnp.float32),
+                sd((B, max_pages), i32), sd((N,), i32), sd((N,), i32)]
+        if rows:
+            kernel = lambda q, pool, t, r, c, lst: latent_attention(
+                q, pool, t, r, c, layer=1, scale=0.07, latent=512,
+                listed=lst, path=dispatch.MOSAIC)
+            args.append(sd((N, rows), i32))
+        else:
+            kernel = lambda q, pool, t, r, c, first: latent_attention(
+                q, pool, t, r, c, layer=1, scale=0.06, latent=1024,
+                first=first, window=513, path=dispatch.MOSAIC)
+            args.append(sd((N,), i32))
+        text = jax.jit(kernel).lower(*args).compile().as_text()
+        assert "tpu_custom_call" in text, (slab, heads)
+
+
+def test_mla_sparse_serve_step_compiles_for_v5e_and_fits_the_chip(devices):
+    """The latent-attention step with a learned indexer at the benchmark
+    cell's shapes — published widths, 5 layers (2 full with the indexer, 3
+    sliding; 1 dense, 4 expert), 32 of 256 experts, 19,008 ids, 16 rows,
+    chunks of 512, 8,704 pages of 64 and 288 window pages: Mosaic accepts
+    the latent kernel in every layer (5 calls) and the grouped expert
+    product (two calls in each of the 4 expert layers); the three pools
+    are donated and aliased, and the plan fits the chip: 8.17 GB of
+    weights and 5.30 GB of pools.  The scopes the cell's readers read name
+    the instructions."""
+    compiled = tpu_aot.lower_mla_sparse_serve_step(devices).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert text.count('custom_call_target="tpu_custom_call"') == 5 + 2 * 4
+    pools = (2 * 8704 * 64 * 8 * 128 * 4 + 2 * 8704 * 64 * 128 * 2
+             + 3 * 288 * 64 * 16 * 128 * 4)
+    assert mem.alias_size_in_bytes == pools
+    assert mem.temp_size_in_bytes < 0.5e9
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes) < 2 ** 34
+    cw, table, calls = _table(compiled)
+    scopes = lambda p: cw.named_scopes(p)
+    assert len([p for p in calls.values()
+                if "latent_attn" in scopes(p)]) == 5
+    assert len([p for p in calls.values() if "experts" in scopes(p)]) == 8
+    for scope in ("indexer", "index_topk", "latent_write", "latent_attn",
+                  "full_attn", "window_attn", "router", "mlp", "lm_head",
+                  "embed"):
+        assert any(scope in scopes(p) for p in table.values()), scope

@@ -264,6 +264,26 @@ def lower_short_conv_serve_step(devices, num_pages=256, max_batch_size=64,
         max_batch_size, chunk_len, page_size)
 
 
+def lower_mla_sparse_serve_step(devices, num_pages=8704,
+                                num_window_pages=288, max_batch_size=16,
+                                chunk_len=512, page_size=64,
+                                config="dots3-note-prev-5l"):
+    """The unified step of the latent-attention decoder with a learned
+    indexer at the benchmark cell's knobs, on one device: the full layers'
+    latent and index-key pools and the sliding layers' latent window pools
+    donated, both page tables in the batch."""
+    from paddle_tpu.models.mla_sparse import (MLA_SPARSE_CONFIGS,
+                                              mla_sparse_init)
+    from paddle_tpu.serving.kv_cache import window_pages_per_row
+
+    cfg = MLA_SPARSE_CONFIGS[config]
+    return _lower_recurrent_serve_step(
+        devices, cfg, mla_sparse_init, num_pages, max_batch_size, chunk_len,
+        page_size, num_window_pages=num_window_pages,
+        held_window_pages=window_pages_per_row(cfg.window, page_size,
+                                               chunk_len))
+
+
 def _report(name, compile_fn):
     t0 = time.perf_counter()
     compiled = compile_fn()
@@ -298,6 +318,8 @@ def main(argv):
                   lambda: lower_moe_window_serve_step(devices).compile())
     ok &= _report("serve short-conv 12l B64 chunk128 256 pages of 512",
                   lambda: lower_short_conv_serve_step(devices).compile())
+    ok &= _report("serve mla-sparse 5l B16 chunk512 8704 + 288 pages of 64",
+                  lambda: lower_mla_sparse_serve_step(devices).compile())
     if "--four" in argv:
         ok &= _report("train pp=2 x mp=2",
                       lambda: lower_train_step(devices, pp=2, mp=2).compile())
