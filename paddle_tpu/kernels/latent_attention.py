@@ -38,6 +38,28 @@ The grid is ``(N / _QUERIES,)``: a grid step holds ``_QUERIES`` queries,
 so that a step's block of listed positions is an aligned ``[8, R]`` piece
 of the int32 list (scalar memory, fetched by the pipeline).
 
+**Staged** (listed mode with ``tiles=``): a copy a row is some 57 ns of
+issuing, not of bytes (a ``[8, 128]`` float32 row moves in 5 ns), so where a
+tile of one batch row's queries holds two or more live ones their rows
+come another way, a second ``pallas_call`` (``latent_attention_staged``)
+that shares no body with the first.  Its grid step is a tile: the row's
+context is copied into VMEM a whole page a copy, in blocks of ``_STAGE``
+positions (the next block's pages in flight while this one is used), and
+each query picks the rows it chose out of the staged block, one vector
+load and store a row, into a buffer of its own that blocks fill in turn,
+``_ROWS`` rows a time for the same two products and online softmax.  Its
+list comes sorted by position, with the bounds of each block's run counted
+beside it in XLA, so a block's rows of a query are one run of its list;
+the sum runs in position order, not the list's.
+The tile's own queries come in and leave by copies of their own, and its
+output is the per-row call's, aliased: the rows of the queries the per-row
+call left at zero are overwritten, the others stay.  The split
+(:func:`staged_queries`) follows from the two costs: staging a tile's
+context is ``C x 4 KiB / 819 GB/s``, at most some 175 us at ``C`` =
+34,816, where copying rows one by one is ``n x min(C, 2,048) x 57 ns``,
+some 117 us a query: one query alone is cheaper by its rows, two or more
+by the pages.
+
 ``path=`` as every kernel here (``kernels/dispatch.py``): Mosaic on a TPU,
 the ``jnp`` reference elsewhere, the interpreter for CPU tests of the body.
 """
@@ -54,13 +76,17 @@ from ..ops.linalg import mxu_precision
 from . import dispatch
 
 __all__ = ["latent_attention", "latent_attention_ref", "latent_slab",
-           "pack_latent_rows", "LANES"]
+           "pack_latent_rows", "staged_queries", "LANES", "STAGED_TILE"]
 
 LANES = 128
 _QUERIES = 8            # queries a grid step: an aligned block of the list
-_ROWS = 128             # rows a fetched block of one query
+_ROWS = 128             # rows of a query's product: fetched, or picked
 _VMEM_LIMIT = 64 << 20
 _NEG_INF = -1e30
+STAGED_TILE = 16        # query slots of a staged tile, one batch row's
+_STAGE = 2048           # positions of a staged block, whole pages
+_PICKS = 8              # rows picked a loop iteration, written out
+_PAST = 1 << 30         # a sorted list's entries past its count
 
 
 def latent_slab(width):
@@ -114,6 +140,37 @@ def latent_attention_ref(q, pool, page_tables, rows, counts, *, layer,
     return jnp.where((counts > 0)[:, None, None], out, 0.0)
 
 
+def _attend(q, lanes, mask, m_ref, l_ref, acc_ref, *, latent, dtype):
+    """A block of rows into one query's online softmax: ``q [H, Wq]``,
+    ``lanes(j)`` the rows' ``j``-th 128 lanes ``[R, 128]`` float32,
+    ``mask(s)`` the scaled scores ``[H, R]`` with the rows not read at
+    ``_NEG_INF``; ``m``, ``l`` and ``acc`` the query's running maximum, sum
+    and weighted latent."""
+    s = None
+    for j in range(q.shape[1] // LANES):
+        kj = lanes(j).astype(dtype)                           # [R, 128]
+        qj = q[:, j * LANES:(j + 1) * LANES]
+        part = jax.lax.dot_general(
+            qj, kj, (((1,), (1,)), ((), ())),
+            precision=mxu_precision(qj, kj),
+            preferred_element_type=jnp.float32)               # [H, R]
+        s = part if s is None else s + part
+    s = mask(s)
+    m_prev = m_ref[...][:, :1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)
+    l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+    p = p.astype(dtype)
+    for j in range(latent // LANES):
+        vj = lanes(j).astype(dtype)
+        at = pl.ds(j * LANES, LANES)
+        acc_ref[:, at] = alpha * acc_ref[:, at] + jnp.dot(
+            p, vj, precision=mxu_precision(p, vj),
+            preferred_element_type=jnp.float32)
+
+
 def _kernel(rows_ref, counts_ref, first_ref, layer_ref, tables_ref, q_ref,
             pool_hbm, *rest, listed, latent, scale, page_size, dtype):
     if listed:
@@ -122,7 +179,7 @@ def _kernel(rows_ref, counts_ref, first_ref, layer_ref, tables_ref, q_ref,
         o_ref, buf, sem, m_ref, l_ref, acc_ref = rest
     i = pl.program_id(0)
     G = o_ref.shape[0]
-    R, used = buf.shape[1], q_ref.shape[2] // LANES
+    R = buf.shape[1]
     per_block = R // page_size              # a window's pages in a block
     base = i * G
     layer = layer_ref[0]
@@ -232,37 +289,18 @@ def _kernel(rows_ref, counts_ref, first_ref, layer_ref, tables_ref, q_ref,
             l_ref[...] = jnp.zeros_like(l_ref)
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        q = q_ref[g]                                          # [H, Wq]
-        s = None
-        for j in range(used):
-            kj = buf[slot, :, j, :].astype(dtype)             # [R, 128]
-            qj = q[:, j * LANES:(j + 1) * LANES]
-            part = jax.lax.dot_general(
-                qj, kj, (((1,), (1,)), ((), ())),
-                precision=mxu_precision(qj, kj),
-                preferred_element_type=jnp.float32)           # [H, R]
-            s = part if s is None else s + part
-        r = b * R + jax.lax.broadcasted_iota(jnp.int32, (1, R), 1)
-        if listed:
-            ok = r < count
-        else:
-            pos = start(g) + r
-            lo = first_ref[base + g]
-            ok = (pos >= lo) & (pos < lo + count)
-        s = jnp.where(ok, s * scale, _NEG_INF)
-        m_prev = m_ref[...][:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        p = p.astype(dtype)
-        for j in range(latent // LANES):
-            vj = buf[slot, :, j, :].astype(dtype)
-            at = pl.ds(j * LANES, LANES)
-            acc_ref[:, at] = alpha * acc_ref[:, at] + jnp.dot(
-                p, vj, precision=mxu_precision(p, vj),
-                preferred_element_type=jnp.float32)
+        def mask(s):
+            r = b * R + jax.lax.broadcasted_iota(jnp.int32, (1, R), 1)
+            if listed:
+                ok = r < count
+            else:
+                pos = start(g) + r
+                lo = first_ref[base + g]
+                ok = (pos >= lo) & (pos < lo + count)
+            return jnp.where(ok, s * scale, _NEG_INF)
+
+        _attend(q_ref[g], lambda j: buf[slot, :, j, :], mask, m_ref, l_ref,
+                acc_ref, latent=latent, dtype=dtype)
 
         @pl.when(b * R + R >= span(g))
         def _finish():
@@ -273,9 +311,227 @@ def _kernel(rows_ref, counts_ref, first_ref, layer_ref, tables_ref, q_ref,
     jax.lax.fori_loop(0, total, block, 0)
 
 
+def _staged_kernel(layer_ref, tables_ref, tile_rows_ref, tokens_ref,
+                   pages_ref, listed_ref, bounds_ref, q_hbm, pool_hbm,
+                   base_hbm, o_hbm, blk, pick, q_buf, m_ref, l_ref, acc_ref,
+                   fill, sem, *, latent, scale, page_size, dtype):
+    del base_hbm                    # o_hbm itself: the other rows stay
+    t = pl.program_id(0)
+    G, K, R = q_buf.shape[0], blk.shape[1], _ROWS
+    last = listed_ref.shape[1] - 1
+    per_block = K // page_size
+    pages, layer, row = pages_ref[t], layer_ref[0], tile_rows_ref[t]
+
+    @pl.when(t == 0)
+    def _clear():
+        # a picked row past a run's end comes from anywhere in the block,
+        # some of it never staged: zeroed once, every row is finite and a
+        # zero weight cancels it
+        blk[...] = jnp.zeros_like(blk)
+        pick[...] = jnp.zeros_like(pick)
+
+    def token(g):
+        return tokens_ref[t * G + g]
+
+    def each_query(body):
+        def one(g, carry):
+            @pl.when(token(g) >= 0)
+            def _live():
+                body(g)
+            return carry
+        jax.lax.fori_loop(0, G, one, 0)
+
+    def query_in(g, wait):
+        src, dst = (q_hbm.at[0], q_buf.at[0]) if wait \
+            else (q_hbm.at[token(g)], q_buf.at[g])
+        copy = pltpu.make_async_copy(src, dst, sem.at[2])
+        copy.wait() if wait else copy.start()
+
+    def result_out(g, wait):
+        src, dst = (acc_ref.at[0], o_hbm.at[0]) if wait \
+            else (acc_ref.at[g], o_hbm.at[token(g)])
+        copy = pltpu.make_async_copy(src, dst, sem.at[2])
+        copy.wait() if wait else copy.start()
+
+    def stage(b, slot, wait):
+        """Block ``b``'s pages of the row, a copy a page."""
+        first = b * per_block
+
+        def one(j, carry):
+            # a wait needs the copy's shape and semaphore, not its source
+            src = pool_hbm.at[0, 0] if wait else pool_hbm.at[
+                layer, tables_ref[row, first + j]]
+            copy = pltpu.make_async_copy(
+                src, blk.at[slot, pl.ds(j * page_size, page_size)],
+                sem.at[slot])
+            copy.wait() if wait else copy.start()
+            return carry
+
+        jax.lax.fori_loop(0, jnp.minimum(per_block, pages - first), one, 0)
+
+    def attend(g, n):
+        """The first ``n`` rows of query ``g``'s picks into its softmax."""
+        ok = jax.lax.broadcasted_iota(jnp.int32, (1, R), 1) < n
+        _attend(q_buf[g], lambda j: pick[g, pl.ds(0, R), j, :],
+                lambda s: jnp.where(ok, s * scale, _NEG_INF), m_ref.at[g],
+                l_ref.at[g], acc_ref.at[g], latent=latent, dtype=dtype)
+
+    def block(b, carry):
+        slot = b % 2
+
+        @pl.when((b + 1) * per_block < pages)
+        def _next():
+            stage(b + 1, 1 - slot, wait=False)
+
+        stage(b, slot, wait=True)
+
+        def segment(g, carry):
+            # the run of query g's sorted list that lies in block b, picked
+            # behind the rows earlier blocks left, a product each time
+            # ``R`` are ready: a query's products are its rows / R, not
+            # one more for each block its list ends a run in
+            lo, hi = bounds_ref[g, b], bounds_ref[g, b + 1]
+
+            def more(first):
+                f = fill[g]
+                n = jnp.minimum(R - f, hi - first)
+
+                def picks(k, carry):
+                    # a row past the run is some row of the block (its
+                    # position wrapped into it), overwritten or masked
+                    for u in range(_PICKS):
+                        r = k * _PICKS + u
+                        p = listed_ref[g, jnp.minimum(first + r, last)]
+                        pick[g, f + r] = blk[slot, p & (K - 1)]
+                    return carry
+
+                jax.lax.fori_loop(0, (n + _PICKS - 1) // _PICKS, picks, 0)
+                full = f + n == R
+
+                @pl.when(full)
+                def _product():
+                    attend(g, R)
+
+                fill[g] = jnp.where(full, 0, f + n)
+                return first + n
+
+            jax.lax.while_loop(lambda first: first < hi, more, lo)
+            return carry
+
+        jax.lax.fori_loop(0, G, segment, 0)
+        return carry
+
+    @pl.when(pages > 0)
+    def _tile():
+        each_query(lambda g: query_in(g, wait=False))
+        stage(0, 0, wait=False)
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        for g in range(G):
+            fill[g] = 0
+        each_query(lambda g: query_in(g, wait=True))
+        jax.lax.fori_loop(0, (pages + per_block - 1) // per_block, block, 0)
+
+        def finish(g):
+            @pl.when(fill[g] > 0)
+            def _rest():
+                attend(g, fill[g])
+
+            acc_ref[g] = acc_ref[g] / l_ref[g][:, :1]
+            result_out(g, wait=False)
+
+        each_query(finish)
+        each_query(lambda g: result_out(g, wait=True))
+
+
+def staged_queries(counts, tiles):
+    """``[N]`` bool: the queries of a tile with two or more live queries
+    (``counts > 0``), which the staged call reads; ``tiles = (tile_rows
+    [NT], tokens [NT, n])``, each tile's batch row and the query of each of
+    its slots (``-1``: none)."""
+    tokens = tiles[1]
+    at = jnp.maximum(tokens, 0)
+    live = (tokens >= 0) & (counts[at] > 0)
+    many = jnp.sum(live.astype(jnp.int32), axis=1, keepdims=True) >= 2
+    drop = jnp.where(live, tokens, counts.shape[0])
+    return jnp.zeros(counts.shape, bool).at[drop].set(
+        jnp.broadcast_to(many, drop.shape), mode="drop")
+
+
+def _staged(q, pool, page_tables, counts, listed, tiles, layer, out, *,
+            scale, latent, interpret):
+    """The staged call over the queries with ``counts > 0``, the rows of
+    ``out [n, H, latent]`` of the others left as they are."""
+    tile_rows, tokens = tiles
+    NT, G = tokens.shape
+    N, R = listed.shape
+    H, Wq = q.shape[1:]
+    page_size, S = pool.shape[2], pool.shape[3]
+    K = _STAGE
+    if K % page_size or K & (K - 1):
+        raise ValueError(f"a staged block of {K} positions: a power of two "
+                         f"of whole pages of {page_size}")
+    blocks = -(-page_tables.shape[1] * page_size // K)
+    # each list by position, the entries past its count last; the run of
+    # it in block b is bounds[b] .. bounds[b + 1], counted here and not by
+    # a cursor in the kernel: a cursor would walk every list a second time
+    # on the scalar unit, which the picks keep busy.  Not stable: the keys
+    # are distinct but for the equal padding, and a stable sort of [527,
+    # 2048] took the v5e compiler 7 s where this one takes 1
+    real = jnp.arange(R, dtype=jnp.int32)[None, :] < counts[:, None]
+    listed = jax.lax.sort(jnp.where(real, listed, _PAST), dimension=1,
+                          is_stable=False)
+    edges = jnp.arange(blocks + 1, dtype=jnp.int32) * K
+    bounds = jnp.sum((listed[:, :, None] < edges).astype(jnp.int32), axis=1)
+    top = jnp.take_along_axis(listed, jnp.maximum(counts - 1, 0)[:, None],
+                              axis=1)[:, 0]
+    reach = jnp.where(counts > 0, top // page_size + 1, 0)   # pages
+    at = jnp.maximum(tokens, 0)
+    live = (tokens >= 0) & (counts[at] > 0)
+    tokens = jnp.where(live, tokens, -1)
+    pages = jnp.max(jnp.where(live, reach[at], 0), axis=1)
+    listed = listed[at].reshape(NT * G, R)
+    bounds = jnp.where(live[..., None], bounds[at], 0).reshape(
+        NT * G, blocks + 1)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5, grid=(NT,),
+        in_specs=[pl.BlockSpec((G, R), lambda t, *_: (t, 0),
+                               memory_space=pltpu.SMEM),
+                  pl.BlockSpec((G, blocks + 1), lambda t, *_: (t, 0),
+                               memory_space=pltpu.SMEM),
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=[pltpu.VMEM((2, K, S, LANES), jnp.float32),
+                        pltpu.VMEM((G, _ROWS + _PICKS, S, LANES),
+                                   jnp.float32),
+                        pltpu.VMEM((G, H, Wq), q.dtype),
+                        pltpu.VMEM((G, H, LANES), jnp.float32),
+                        pltpu.VMEM((G, H, LANES), jnp.float32),
+                        pltpu.VMEM((G, H, latent), jnp.float32),
+                        pltpu.SMEM((G,), jnp.int32),
+                        pltpu.SemaphoreType.DMA((3,))])
+    kwargs = {} if interpret else {
+        "compiler_params": pltpu.CompilerParams(
+            vmem_limit_bytes=_VMEM_LIMIT)}
+    call = pl.pallas_call(
+        functools.partial(_staged_kernel, latent=latent, scale=scale,
+                          page_size=page_size, dtype=q.dtype),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(out.shape, jnp.float32),
+        input_output_aliases={9: 0}, interpret=interpret,
+        name="latent_attention_staged", **kwargs)
+    return call(layer, page_tables.astype(jnp.int32),
+                tile_rows.astype(jnp.int32),
+                tokens.reshape(-1).astype(jnp.int32), pages.astype(jnp.int32),
+                listed, bounds, q, pool, out)
+
+
 def latent_attention(q, pool, page_tables, rows, counts, *, layer, scale,
                      latent, first=None, listed=None, window=None,
-                     path=None):
+                     tiles=None, path=None):
     """Each query's attention over its rows of a latent page pool, with the
     key and value matrices absorbed: ``[N, H, latent]`` float32.
 
@@ -289,12 +545,18 @@ def latent_attention(q, pool, page_tables, rows, counts, *, layer, scale,
     zeros).  Listed mode: ``listed [N, R]`` the positions, the first
     ``counts[n]`` real.  Window mode: ``first [N]`` the first position and
     ``window`` (static) the most rows any query reads.  ``scale`` multiplies
-    the scores."""
+    the scores.  ``tiles = (tile_rows [NT], tokens [NT, n])`` (listed mode):
+    the queries in tiles of one batch row each (``tokens`` the query of a
+    slot, ``-1`` none); the queries of a tile with two or more live ones
+    are read by the staged call (:func:`staged_queries`), the rest one row
+    a copy."""
     path = dispatch.resolve_path(path, off_tpu=dispatch.REFERENCE)
     if (listed is None) == (first is None):
         raise ValueError("name the rows by listed= or by first= (a window)")
     if listed is None and window is None:
         raise ValueError("a window's rows need window=")
+    if tiles is not None and listed is None:
+        raise ValueError("tiles= stages a listed query's rows")
     N, H, Wq = q.shape
     S = pool.shape[3]
     if Wq % LANES or Wq > S * LANES or latent % LANES or latent > Wq:
@@ -308,6 +570,11 @@ def latent_attention(q, pool, page_tables, rows, counts, *, layer, scale,
                                     layer=layer[0], scale=scale,
                                     latent=latent, first=first,
                                     listed=listed, window=window)
+    if tiles is not None:
+        staged = staged_queries(counts, tiles)
+        staged_args = (q, pool, page_tables, jnp.where(staged, counts, 0),
+                       listed.astype(jnp.int32), tiles, layer)
+        counts = jnp.where(staged, 0, counts)
     G = _QUERIES
     pad = -N % G
     if pad:
@@ -350,4 +617,7 @@ def latent_attention(q, pool, page_tables, rows, counts, *, layer, scale,
     out = call(rows.astype(jnp.int32), counts.astype(jnp.int32),
                first.astype(jnp.int32), layer,
                page_tables.astype(jnp.int32), *args)
+    if tiles is not None:
+        out = _staged(*staged_args, out, scale=scale, latent=latent,
+                      interpret=interpret)
     return out[:N]

@@ -47,8 +47,9 @@ import jax
 import jax.numpy as jnp
 
 from ..kernels.expert_matmul import visited_rows
-from ..kernels.latent_attention import (LANES, latent_attention,
-                                        latent_slab, pack_latent_rows)
+from ..kernels.latent_attention import (LANES, STAGED_TILE,
+                                        latent_attention, latent_slab,
+                                        pack_latent_rows, staged_queries)
 from .experts import dropless_experts, expert_tile, route_top_k
 from .hybrid import _gated_mlp, _rms
 from .ragged import RaggedBatch, RaggedView
@@ -347,13 +348,15 @@ def mla_sparse_ragged_step(cfg: MLASparseConfig, params, batch: RaggedBatch,
     page tables (a ``WindowRaggedBatch``).
 
     Returns ``(logits [B, V] float32, latent_pages, index_pages,
-    window_latent_pages, stats [7] int32)``: ``stats`` is the (token,
+    window_latent_pages, stats [8] int32)``: ``stats`` is the (token,
     expert) pairs the held experts computed over all sparse layers, the
     most any one held expert got in one layer, the (layer, held expert)s
     that got a pair at all, the rows of the tiles the expert kernels
-    visited, the latent rows the full layers' attention fetched, the pages
-    those rows lay in (a page counted once a token and layer), and the
-    latent rows the sliding layers fetched."""
+    visited, the latent rows the full layers' attention read, the pages
+    those rows lay in (a page counted once a token and layer), the latent
+    rows the sliding layers fetched, and of the full layers' rows those
+    the staged call picked out of VMEM (a tile of two or more live
+    queries: a prompt's chunk)."""
     tokens = batch.tokens
     T = tokens.shape[0]
     f32, dtype = jnp.float32, cfg.jdtype()
@@ -369,8 +372,13 @@ def mla_sparse_ragged_step(cfg: MLASparseConfig, params, batch: RaggedBatch,
     view.tiles(_INDEX_TILE)
     width = batch.page_tables.shape[1] * page_size
     topk = min(cfg.index_topk, width)
-    stats = dict.fromkeys(("rows", "pages", "window_rows"),
+    stats = dict.fromkeys(("rows", "pages", "window_rows", "staged"),
                           jnp.zeros((), jnp.int32))
+    # the full layers' queries in tiles of one row each, for the kernel's
+    # staged call
+    tiles = (view.tiles(STAGED_TILE)[0],
+             view.pad_tiles(jnp.arange(T, dtype=jnp.int32), STAGED_TILE,
+                            fill=-1))
 
     def attention(x, kind, i, pool, index_pool):
         p, m = params[kind], cfg.mla(kind)
@@ -401,8 +409,11 @@ def mla_sparse_ragged_step(cfg: MLASparseConfig, params, batch: RaggedBatch,
                 stats["window_rows"] = stats["window_rows"] + jnp.sum(counts)
             else:
                 index_pool, rows_of = indexer(u, cq, i, index_pool)
-                stats["rows"] = stats["rows"] + jnp.sum(rows_of["counts"])
                 counts = rows_of.pop("counts")
+                stats["rows"] = stats["rows"] + jnp.sum(counts)
+                stats["staged"] = stats["staged"] + jnp.sum(jnp.where(
+                    staged_queries(counts, tiles), counts, 0))
+                rows_of["tiles"] = tiles
             with jax.named_scope("latent_attn"):
                 q_lat = _mm(q[..., :dn].astype(dtype), p["k_b"][i],
                             "thd,chd->thc")
@@ -520,4 +531,5 @@ def mla_sparse_ragged_step(cfg: MLASparseConfig, params, batch: RaggedBatch,
                             preferred_element_type=f32)
     return (logits, latent_pages, index_pages, window_latent_pages,
             jnp.stack([pairs, fullest, active, tile_rows, stats["rows"],
-                       stats["pages"], stats["window_rows"]]))
+                       stats["pages"], stats["window_rows"],
+                       stats["staged"]]))

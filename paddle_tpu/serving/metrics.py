@@ -38,9 +38,10 @@ process-wide registry); this module keeps the serving-shaped facade:
                  visited, the pairs and their tiles' padding (pairs over
                  tile rows is the fill of a tile)
   latent_rows / latent_row_pages — a model with latent attention: rows
-                 its kernel fetched one by one, by layer kind (selected by
-                 the indexer, or a window), and the pages the selected
-                 ones lay in (rows over 64 pages is how scattered a choice is)
+                 its kernel read, by layer kind (selected by the indexer,
+                 or a window), the selected ones it picked out of a staged
+                 context (staged), and the pages the selected ones lay in
+                 (rows over 64 pages is how scattered a choice is)
   attention_query_tiles — a model whose attention takes its queries in
                  tiles of 16 slots: how often the kernel's narrow body (a
                  run of one tile) and its wide body (a run of several
@@ -259,12 +260,16 @@ class ServingMetrics:
         self.latent_rows = add(Counter(
             "serving_latent_rows_total", labelnames=("kind",),
             help="a model with latent attention: the cached rows its "
-                 "kernel fetched one by one over a step's layers, by what "
-                 "chose them (kind=selected: the full layers' indexer; "
-                 "kind=window: the sliding layers' window); counted on the "
-                 "device, read with the step's ids"))
+                 "kernel read over a step's layers, by what chose them "
+                 "(kind=selected: the full layers' indexer; kind=window: "
+                 "the sliding layers' window); kind=staged: of the "
+                 "selected, those picked out of a row's context staged in "
+                 "VMEM for a tile of two or more queries, not copied one "
+                 "by one; counted on the device, read with the step's "
+                 "ids"))
         self.latent_rows_selected = self.latent_rows.labels(kind="selected")
         self.latent_rows_window = self.latent_rows.labels(kind="window")
+        self.latent_rows_staged = self.latent_rows.labels(kind="staged")
         self.latent_row_pages = add(Counter(
             "serving_latent_row_pages_total",
             help="the pages the selected rows lay in, a page counted once "

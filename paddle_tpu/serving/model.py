@@ -352,7 +352,8 @@ class MLASparseServed(_ExpertStats):
 
     recurrent = False
     step_stats = _ExpertStats.step_stats + (
-        "latent_rows_selected", "latent_row_pages", "latent_rows_window")
+        "latent_rows_selected", "latent_row_pages", "latent_rows_window",
+        "latent_rows_staged")
 
     def __init__(self, cfg):
         from ..models.mla_sparse import FULL, SLIDING, SPARSE
@@ -405,9 +406,10 @@ class MLASparseServed(_ExpertStats):
                 + sliding * int(np.minimum(n, self.window).sum()))
 
     def record_stats(self, metrics, values):
-        *expert, rows, pages, window_rows = values
+        *expert, rows, pages, window_rows, staged = values
         super().record_stats(metrics, expert)
         metrics.latent_rows_selected.inc(rows)
+        metrics.latent_rows_staged.inc(staged)
         metrics.latent_rows_window.inc(window_rows)
         metrics.latent_row_pages.inc(pages)
 

@@ -211,6 +211,114 @@ def test_the_kernel_in_interpret_mode_is_its_reference(monkeypatch, listed):
     assert not np.any(np.asarray(got)[3 if listed else 5])
 
 
+# the staged call's cases: how the queries of each tile and their lists
+# are drawn (pages of 8, blocks of 32 positions, lists of up to 40)
+STAGED_CASES = ("every_live_count", "short_lists", "boundaries",
+                "empty_blocks", "different_ends", "score_order")
+
+
+def _staged_inputs(case, monkeypatch):
+    """A listed call's arguments and tiles of 16 slots for one of
+    ``STAGED_CASES``: rows picked out of blocks of 32 positions (4 pages of
+    8) in runs of 16, so a list spans several blocks and a block's run
+    several picks; two padding queries read nothing."""
+    monkeypatch.setattr(la, "_ROWS", 16)
+    monkeypatch.setattr(la, "_STAGE", 32)
+    rng = np.random.default_rng(STAGED_CASES.index(case))
+    B, page, max_pages, L, R, G = 4, 8, 20, 2, 40, 16
+    P = B * max_pages + 6
+    latent, rope, H = 128, 64, 2
+    S = la.latent_slab(latent + rope)
+    pool = jnp.asarray(rng.standard_normal((L, P, page, S, 128)),
+                       jnp.float32)
+    tables = rng.permutation(P)[: B * max_pages].reshape(B, max_pages)
+    # (batch row, positions of the tile's queries) a tile
+    if case == "every_live_count":
+        tiles = [(k % B, rng.integers(k, 150) - np.arange(k)[::-1])
+                 for k in range(1, G + 1)]
+    elif case == "short_lists":
+        tiles = [(0, np.arange(16)), (1, np.arange(5, 9)), (2, [3])]
+    else:
+        tiles = [(r, np.arange(140, 140 + G - 2 * r)) for r in range(B)]
+    rows, pos = [], []
+    tokens = np.full((len(tiles) + 2, G), -1, np.int32)
+    for t, (r, ps) in enumerate(tiles):
+        tokens[t, : len(ps)] = np.arange(len(pos), len(pos) + len(ps))
+        rows += [r] * len(ps)
+        pos += list(ps)
+    N = len(pos) + 2
+    rows, pos = np.asarray(rows + [0, 0]), np.asarray(pos + [0, 0])
+    counts = np.minimum(R, pos + 1)
+    counts[-2:] = 0
+    listed = np.zeros((N, R), np.int32)
+    edges = [p for p in range(0, 160, page)
+             for p in (p - 1, p) if p >= 0]        # pages' and blocks' ends
+    for n in range(N - 2):
+        ctx = np.arange(pos[n] + 1)
+        if case == "boundaries":
+            ctx = np.asarray([p for p in edges if p <= pos[n]])
+        elif case == "empty_blocks":
+            ctx = ctx[(ctx < 32) | (ctx >= 96)]
+        elif case == "different_ends":
+            ctx = ctx[: 41 + 9 * (n % 12)]
+        counts[n] = min(counts[n], len(ctx))
+        pick = rng.permutation(ctx)[: counts[n]]
+        listed[n, : counts[n]] = -np.sort(-pick) if case == "score_order" \
+            else pick
+    tile_rows = np.full(len(tokens), B, np.int32)
+    tile_rows[: len(tiles)] = [r for r, _ in tiles]
+    q = jnp.asarray(rng.standard_normal((N, H, 256)), jnp.float32)
+    args = (q, pool, jnp.asarray(tables, jnp.int32),
+            jnp.asarray(rows, jnp.int32), jnp.asarray(counts, jnp.int32))
+    kw = dict(layer=1, scale=0.1, latent=latent, listed=jnp.asarray(listed))
+    return args, kw, (jnp.asarray(tile_rows), jnp.asarray(tokens))
+
+
+@pytest.mark.parametrize("case", STAGED_CASES)
+def test_the_staged_call_is_the_reference_and_the_per_row_call(
+        monkeypatch, case):
+    """Tiles of one row's queries read through the staged call (their
+    rows picked out of blocks of pages in VMEM, lists sorted by position)
+    under the interpreter: the ``jnp`` oracle, and the per-row call on the
+    same inputs; a query that reads nothing reads zeros."""
+    args, kw, tiles = _staged_inputs(case, monkeypatch)
+    want = la.latent_attention(*args, path=dispatch.REFERENCE, **kw)
+    per_row = jax.jit(lambda *a: la.latent_attention(
+        *a, path=dispatch.INTERPRET, **kw))(*args)
+    got = jax.jit(lambda *a: la.latent_attention(
+        *a, path=dispatch.INTERPRET, tiles=tiles, **kw))(*args)
+    assert np.asarray(la.staged_queries(args[4], tiles)).any()
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(got, per_row, atol=2e-5, rtol=2e-5)
+    assert not np.any(np.asarray(got)[-2:])
+
+
+def test_the_two_calls_split_the_queries_by_tile(monkeypatch):
+    """A tile of one live query goes to the per-row call, a tile of two or
+    more to the staged one; each call alone leaves the other's queries at
+    zero, and the two add up to one per-row call over all of them."""
+    args, kw, tiles = _staged_inputs("every_live_count", monkeypatch)
+    q, pool, tables, rows, counts = args
+    staged = np.asarray(la.staged_queries(counts, tiles))
+    tokens = np.asarray(tiles[1])
+    for t, slots in enumerate(tokens):
+        live = slots[slots >= 0]
+        assert (staged[live] == (len(live) >= 2)).all(), t
+    assert staged.sum() == sum(range(2, 17)) and not staged[-2:].any()
+    per_row = lambda c: jax.jit(lambda *a: la.latent_attention(
+        *a, path=dispatch.INTERPRET, **kw))(q, pool, tables, rows, c)
+    alone = per_row(jnp.where(staged, 0, counts))
+    whole = per_row(counts)
+    picked = jax.jit(lambda c, lst, out: la._staged(
+        q, pool, tables, c, lst, tiles, jnp.ones(1, jnp.int32), out,
+        scale=kw["scale"], latent=kw["latent"], interpret=True))(
+        jnp.where(staged, counts, 0), kw["listed"],
+        jnp.zeros(whole.shape, jnp.float32))
+    assert not np.any(np.asarray(alone)[staged])
+    assert not np.any(np.asarray(picked)[~staged])
+    np.testing.assert_allclose(alone + picked, whole, atol=2e-5, rtol=2e-5)
+
+
 def _one_layer(cfg, params, n):
     """One prompt of ``n`` tokens through the program's step as one chunk,
     every layer a full layer: ``(step outputs, view inputs)``."""
@@ -394,11 +502,41 @@ def test_expert_shares_add_up_to_the_uncut_layer():
     np.testing.assert_allclose(whole, plain, atol=1e-5)
 
 
+def test_the_staged_rows_are_a_chunk_steps_and_never_a_decode_steps(tiny):
+    """``serving_latent_rows_total{kind="staged"}``: a step that reads a
+    prompt's chunk (tiles of two or more of its tokens) counts its full
+    layers' rows there, a step of decode rows alone counts none, and the
+    staged rows never pass the selected ones."""
+    cfg, params, _ = tiny
+    eng = Engine(cfg, params, page_size=4, num_pages=64, max_batch_size=2,
+                 chunk_len=16)
+    m = eng.metrics
+    rng = np.random.default_rng(2)
+    req = eng.add_request(rng.integers(0, 512, 40).tolist(),
+                          SamplingParams(max_new_tokens=6))
+    seen = []
+    while eng.has_work():
+        read = req.prompt_pos
+        before = m.latent_rows_staged.value, m.latent_rows_selected.value
+        eng.step()
+        eng._drain("test")
+        staged = m.latent_rows_staged.value - before[0]
+        selected = m.latent_rows_selected.value - before[1]
+        seen.append((req.prompt_pos - read, staged, selected))
+        assert m.latent_rows_staged.value <= m.latent_rows_selected.value
+    chunks = [s for s in seen if s[0] > 1]
+    decodes = [s for s in seen if s[0] == 0 and s[2] > 0]
+    assert [c[0] for c in chunks] == [16, 16, 8]
+    # a chunk's tiles of 16 hold 2 or more tokens each: every row staged
+    assert all(staged == selected > 0 for _, staged, selected in chunks)
+    assert len(decodes) >= 5 and all(staged == 0 for _, staged, _ in decodes)
+
+
 def test_the_engine_wraps_the_config_and_refuses_a_mesh(tiny):
     cfg, _, _ = tiny
     served = as_served(cfg)
     assert isinstance(served, MLASparseServed) and served.window == 9
-    assert len(served.step_stats) == 7
+    assert len(served.step_stats) == 8
     with pytest.raises(NotImplementedError, match="latent"):
         served.make_step(max_q=4, mesh=object())
     # positions: 2 full layers read min(24, n), 3 sliding min(9, n)

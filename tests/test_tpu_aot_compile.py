@@ -428,39 +428,60 @@ def test_tiled_attention_body_lowers_no_larger_than_twice_one_tile_body(
 
 
 def test_latent_attention_kernel_compiles_at_the_cells_shapes(devices):
-    """The absorbed latent kernel at the long-context cell's shapes, both
-    ways it names its rows: a full layer's 527 queries of 128 heads, each
+    """The absorbed latent kernel at the long-context cell's shapes, every
+    way it reads its rows: a full layer's 527 queries of 128 heads, each
     reading 2,048 listed positions of float32 slabs [8, 128] through a
-    16 x 544 page table (the list's [8, 2048] blocks in scalar memory), and
-    a sliding layer's 64 heads over a 513-position window of slabs [16,
-    128]."""
+    16 x 544 page table (the list's [8, 2048] blocks in scalar memory), a
+    sliding layer's 64 heads over a 513-position window of slabs [16,
+    128], and the full layer's queries in 49 tiles of 16 (the step's 16
+    rows and 33 tiles of its chunk of 512) through the per-row call and the
+    staged one, whose VMEM plan is printed: two blocks of 2,048 staged
+    positions, each query's picked rows, and the tile's queries and
+    softmax state."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
 
     from paddle_tpu.kernels import dispatch
-    from paddle_tpu.kernels.latent_attention import latent_attention
+    from paddle_tpu.kernels import latent_attention as la
 
     one = SingleDeviceSharding(devices[0])
     sd = lambda s, t: jax.ShapeDtypeStruct(s, t, sharding=one)
     N, B, max_pages, i32 = 527, 16, 544, jnp.int32
-    for slab, heads, width, latent, rows in ((8, 128, 640, 512, 2048),
-                                             (16, 64, 1152, 1024, None)):
+    G = la.STAGED_TILE
+    NT = B + -(-N // G)
+    for slab, heads, width, latent, rows, tiles in (
+            (8, 128, 640, 512, 2048, False), (16, 64, 1152, 1024, None, False),
+            (8, 128, 640, 512, 2048, True)):
         args = [sd((N, heads, width), jnp.bfloat16),
                 sd((2, 256, 64, slab, 128), jnp.float32),
                 sd((B, max_pages), i32), sd((N,), i32), sd((N,), i32)]
-        if rows:
-            kernel = lambda q, pool, t, r, c, lst: latent_attention(
+        if tiles:
+            kernel = lambda q, pool, t, r, c, lst, tr, tt: la.latent_attention(
+                q, pool, t, r, c, layer=1, scale=0.07, latent=512,
+                listed=lst, tiles=(tr, tt), path=dispatch.MOSAIC)
+            args += [sd((N, rows), i32), sd((NT,), i32), sd((NT, G), i32)]
+        elif rows:
+            kernel = lambda q, pool, t, r, c, lst: la.latent_attention(
                 q, pool, t, r, c, layer=1, scale=0.07, latent=512,
                 listed=lst, path=dispatch.MOSAIC)
             args.append(sd((N, rows), i32))
         else:
-            kernel = lambda q, pool, t, r, c, first: latent_attention(
+            kernel = lambda q, pool, t, r, c, first: la.latent_attention(
                 q, pool, t, r, c, layer=1, scale=0.06, latent=1024,
                 first=first, window=513, path=dispatch.MOSAIC)
             args.append(sd((N,), i32))
         text = jax.jit(kernel).lower(*args).compile().as_text()
-        assert "tpu_custom_call" in text, (slab, heads)
+        assert text.count("tpu_custom_call") >= 1 + tiles, (slab, heads)
+    plan = {"staged blocks": 2 * la._STAGE * 8 * 128 * 4,
+            "picked rows": G * (la._ROWS + la._PICKS) * 8 * 128 * 4,
+            "queries": G * 128 * 640 * 2,
+            "m and l": 2 * G * 128 * 128 * 4, "acc": G * 128 * 512 * 4}
+    print("latent_attention_staged VMEM plan (MiB):",
+          {k: v / 2 ** 20 for k, v in plan.items()},
+          f"total {sum(plan.values()) / 2 ** 20:.2f} of "
+          f"{la._VMEM_LIMIT / 2 ** 20:.0f}")
+    assert sum(plan.values()) < la._VMEM_LIMIT
 
 
 def test_mla_sparse_serve_step_compiles_for_v5e_and_fits_the_chip(devices):
@@ -468,14 +489,16 @@ def test_mla_sparse_serve_step_compiles_for_v5e_and_fits_the_chip(devices):
     cell's shapes — published widths, 5 layers (2 full with the indexer, 3
     sliding; 1 dense, 4 expert), 32 of 256 experts, 19,008 ids, 16 rows,
     chunks of 512, 8,704 pages of 64 and 288 window pages: Mosaic accepts
-    the latent kernel in every layer (5 calls) and the grouped expert
+    the latent kernel in every layer (5 calls, and the staged call beside
+    the per-row one in each of the 2 full layers) and the grouped expert
     product (two calls in each of the 4 expert layers); the three pools
     are donated and aliased, and the plan fits the chip: 8.17 GB of
     weights and 5.30 GB of pools.  The scopes the cell's readers read name
     the instructions."""
     compiled = tpu_aot.lower_mla_sparse_serve_step(devices).compile()
     text, mem = compiled.as_text(), compiled.memory_analysis()
-    assert text.count('custom_call_target="tpu_custom_call"') == 5 + 2 * 4
+    assert text.count('custom_call_target="tpu_custom_call"') \
+        == 5 + 2 + 2 * 4
     pools = (2 * 8704 * 64 * 8 * 128 * 4 + 2 * 8704 * 64 * 128 * 2
              + 3 * 288 * 64 * 16 * 128 * 4)
     assert mem.alias_size_in_bytes == pools
@@ -485,7 +508,7 @@ def test_mla_sparse_serve_step_compiles_for_v5e_and_fits_the_chip(devices):
     cw, table, calls = _table(compiled)
     scopes = lambda p: cw.named_scopes(p)
     assert len([p for p in calls.values()
-                if "latent_attn" in scopes(p)]) == 5
+                if "latent_attn" in scopes(p)]) == 5 + 2
     assert len([p for p in calls.values() if "experts" in scopes(p)]) == 8
     for scope in ("indexer", "index_topk", "latent_write", "latent_attn",
                   "full_attn", "window_attn", "router", "mlp", "lm_head",
